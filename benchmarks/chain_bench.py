@@ -25,7 +25,7 @@ submit/dispatch time, so the comparison isolates the dispatch pipeline,
 not a code-version diff.
 
 Modes:
-  --measure   real measurement child (run by run_aux_ladder)
+  --measure   real measurement child (run by bench.run_measure_child)
   --smoke     fast CPU correctness check: chain result integrity, hit rate
               >= 0.9, prefetch not slower than legacy (tier-1 test hook)
   --trace     tracing acceptance run (ISSUE 6): prefetch mode with spans
@@ -38,12 +38,11 @@ Modes:
               mid-run and asserts /api/cluster + /api/alerts visibility,
               plus leak-detector attribution of a planted leak; persists
               the record under benchmarks/results/
-  (no flag)   self-orchestrating parent: bench.run_aux_ladder resilience
-              ladder, persists the rung record under benchmarks/results/
+  (no flag)   parent: runs --measure once under a timeout and persists its
+              record under benchmarks/results/
 
-Never imports jax — the dispatch pipeline is accelerator-agnostic — so the
-init sentinel prints immediately and the CPU-scrub rung measures the
-identical thing.
+Never imports jax — the dispatch pipeline is accelerator-agnostic; what it
+reports are host counts and rates, never device metrics.
 """
 
 import json
@@ -55,8 +54,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# keep ray_tpu.init() from importing jax for chip discovery (r4 lesson:
-# backend probes can wedge under a broken accelerator runtime)
+# a host-only bench: head and loopback nodes advertise no chips
 os.environ.setdefault("RAY_TPU_NUM_CHIPS", "0")
 
 BLOCK_MB = int(os.environ.get("RAY_TPU_CHAIN_BENCH_MB", 64))
@@ -195,10 +193,6 @@ def run_all(steps, block_mb, compute_s):
 
 
 def measure():
-    from bench import _INIT_SENTINEL  # repo root on sys.path (line 41)
-    # no jax import here — the dispatch pipeline can't wedge on a backend,
-    # so the watchdog sentinel goes out immediately
-    print(f"{_INIT_SENTINEL} backend=data-plane", file=sys.stderr, flush=True)
     out = {"bench": "chain_dp", "backend": "data-plane"}
     out.update(run_all(STEPS, BLOCK_MB, COMPUTE_S))
     from bench import observability_snapshot
@@ -443,6 +437,7 @@ if __name__ == "__main__":
     elif "--chaos" in sys.argv[1:]:
         chaos()
     else:
-        # parent mode: resilience ladder (persists the result artifact)
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        # parent mode: one --measure child under a timeout, its record
+        # persisted, its exit code ours
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))

@@ -32,17 +32,15 @@ best rep — same min-of-reps reasoning as trace_overhead: the min discards
 scheduler-noise outliers, all reps are recorded alongside.
 
 Modes:
-  --measure        real measurement child (run by run_aux_ladder)
+  --measure        real measurement child (run by bench.run_measure_child)
   --smoke          fast CPU correctness check: pipelined mode only, asserts
                    the ≤ 1 round-trip invariant (tier-1 test hook)
   --driver-child   internal: one attached driver in the saturation fleet
-  (no flag)        self-orchestrating parent: bench.run_aux_ladder
-                   resilience ladder, persists the record under
-                   benchmarks/results/
+  (no flag)        parent: runs --measure once under a timeout and
+                   persists its record under benchmarks/results/
 
-This bench never imports jax — the control plane is accelerator-agnostic —
-so the init sentinel prints immediately and the CPU-scrub rung measures the
-identical thing.
+This bench never imports jax — the control plane is accelerator-agnostic;
+what it reports are host counts and rates, never device metrics.
 """
 
 import json
@@ -54,8 +52,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# keep ray_tpu.init() from importing jax for chip discovery (r4 lesson:
-# backend probes can wedge under a broken accelerator runtime)
+# a host-only bench: head and loopback nodes advertise no chips
 os.environ.setdefault("RAY_TPU_NUM_CHIPS", "0")
 
 N = int(os.environ.get("RAY_TPU_CORE_BENCH_N", 400))
@@ -511,13 +508,9 @@ def health_overhead(n: int, reps: int = 2):
 
 
 def measure():
-    from bench import _INIT_SENTINEL, observability_snapshot  # repo root on sys.path
+    from bench import observability_snapshot  # repo root on sys.path
     from ray_tpu._native import codec as _codec
     from ray_tpu._native import objdir as _objdir
-    # no jax import here — the control plane can't wedge on a backend, so
-    # the watchdog sentinel goes out immediately
-    print(f"{_INIT_SENTINEL} backend=control-plane", file=sys.stderr,
-          flush=True)
     # throwaway cycle: pay one-time import/worker-spawn warmness before
     # either timed mode (ordering would otherwise favor whichever runs
     # second)
@@ -589,6 +582,7 @@ if __name__ == "__main__":
     elif "--driver-child" in sys.argv[1:]:
         _driver_child(int(os.environ.get("RAY_TPU_CORE_BENCH_N", 400)))
     else:
-        # parent mode: resilience ladder (persists the result artifact)
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        # parent mode: one --measure child under a timeout, its record
+        # persisted, its exit code ours
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))

@@ -22,18 +22,19 @@ Two sections, one JSON record:
              post-burst scale-down must drain without a single failed
              request (drain_timeout count comes from the same ledger).
 
-Modes (the ladder contract every aux bench follows):
+Modes:
   --measure   the real measurement child (asserts the acceptance gates)
   --smoke     tier-1 CPU gate: small fixed-count fleet — affinity fleet
               hit rate must beat the p2c baseline, and the autoscale
               rung must scale up, then drain down with zero dropped
               requests
-  (no flag)   self-orchestrating parent (bench.run_aux_ladder)
+  (no flag)   parent: runs --measure once under a timeout (bench.py's
+              run_measure_child)
 
-The fleet replicas are separate worker processes; several jax TPU inits
-would fight over the same chips, and everything measured here lives in
-the routing/control plane — so every mode pins the CPU backend up front
-(the accelerator rung of the ladder simply records backend=cpu).
+The fleet replicas are separate CPU worker processes (no `num_tpus`, `tiny`
+model), and everything counted here lives in the routing/control plane — so
+every mode pins the CPU backend up front and the record says backend=cpu.
+Four one-chip replicas at real widths are ROADMAP R5's cell, not this.
 """
 
 import asyncio
@@ -435,8 +436,7 @@ def bench_autoscale(interval_s=1.0, burst_conc=10, burst_s=None,
 # ------------------------------------------------------------------- modes
 
 def main():
-    from bench import _INIT_SENTINEL, _write_result_artifact
-    print(f"{_INIT_SENTINEL} backend=fleet-cpu", file=sys.stderr, flush=True)
+    from bench import _write_result_artifact
     import ray_tpu
     ray_tpu.init(num_cpus=max(REPLICAS * 2 + 2, 8), ignore_reinit_error=True)
     rec = {"bench": "fleet_bench", "backend": "cpu",
@@ -521,5 +521,5 @@ if __name__ == "__main__":
     elif "--measure" in sys.argv[1:]:
         main()
     else:
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))

@@ -22,16 +22,16 @@ Reported:
     LeakDetector sees nothing big left pinned/unreleased after the run
 
 Modes:
-  --measure   real measurement child (run by run_aux_ladder)
+  --measure   real measurement child (run by bench.run_measure_child)
   --smoke     fast CPU gate (tier-1 test hook): single-host pipeline,
               MPMD forward bit-matches SPMD pipeline_apply, stage
               fwd/bwd windows + nonzero xfer windows on the head
               timeline, one 1F1B step trains without leaking
-  (no flag)   self-orchestrating parent: bench.run_aux_ladder ladder,
-              persists the rung record under benchmarks/results/
+  (no flag)   parent: runs --measure once under a timeout and persists its
+              record under benchmarks/results/
 
-jax imports only happen in child modes (the parent must print nothing
-and never wedge on a backend probe).
+jax imports only happen in child modes; the stages are CPU workers (`tiny`
+sizes), so what this reports is pipeline structure, not device speed.
 """
 
 import json
@@ -43,8 +43,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# keep ray_tpu.init() from importing jax for chip discovery; the bench
-# imports jax itself in child modes, where the watchdog sentinel covers it
+# head and loopback node advertise no chips: every stage is a CPU worker
 os.environ.setdefault("RAY_TPU_NUM_CHIPS", "0")
 # the driver runs the SPMD parity reference over a pp mesh of virtual
 # host devices; workers inherit the flag harmlessly (each uses 1 device)
@@ -255,10 +254,8 @@ def _bubble_report(events, step_marks, num_stages, num_micro):
 
 
 def measure():
-    from bench import _INIT_SENTINEL, observability_snapshot
     import jax
-    print(f"{_INIT_SENTINEL} backend={jax.default_backend()}",
-          file=sys.stderr, flush=True)
+    from bench import observability_snapshot
     os.environ["RAY_TPU_TRACE"] = "1"
     os.environ["RAY_TPU_TRACE_SAMPLE"] = "1.0"
     from ray_tpu.util import tracing
@@ -347,6 +344,7 @@ if __name__ == "__main__":
     elif "--smoke" in sys.argv[1:]:
         smoke()
     else:
-        # parent mode: resilience ladder (persists the result artifact)
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        # parent mode: one --measure child under a timeout, its record
+        # persisted, its exit code ours
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))

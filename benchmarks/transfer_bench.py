@@ -22,16 +22,15 @@ turned down — the comparison isolates the data plane, not a code-version
 diff. `speedup` is the parallel/single ratio of median MB/s.
 
 Modes:
-  --measure   real measurement child (run by run_aux_ladder)
+  --measure   real measurement child (run by bench.run_measure_child)
   --smoke     fast CPU correctness check: parallel fetch integrity, batched
               get ordering/dedup, pipeline locality hit rate ≥ 90% with
               ~zero cross-node block bytes (tier-1 test hook)
-  (no flag)   self-orchestrating parent: bench.run_aux_ladder resilience
-              ladder, persists the rung record under benchmarks/results/
+  (no flag)   parent: runs --measure once under a timeout and persists its
+              record under benchmarks/results/
 
-Never imports jax — the data plane is accelerator-agnostic — so the init
-sentinel prints immediately and the CPU-scrub rung measures the identical
-thing.
+Never imports jax — the data plane is accelerator-agnostic; what it reports
+are host counts and rates, never device metrics.
 """
 
 import json
@@ -43,8 +42,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# keep ray_tpu.init() from importing jax for chip discovery (r4 lesson:
-# backend probes can wedge under a broken accelerator runtime)
+# a host-only bench: head and loopback nodes advertise no chips
 os.environ.setdefault("RAY_TPU_NUM_CHIPS", "0")
 
 SIZE_MB = int(os.environ.get("RAY_TPU_TRANSFER_BENCH_MB", 64))
@@ -287,10 +285,6 @@ def run_all(size_mb, reps, small_n, blocks):
 
 
 def measure():
-    from bench import _INIT_SENTINEL  # repo root on sys.path (line 40)
-    # no jax import here — the data plane can't wedge on a backend, so the
-    # watchdog sentinel goes out immediately
-    print(f"{_INIT_SENTINEL} backend=data-plane", file=sys.stderr, flush=True)
     out = {"bench": "transfer_dp", "backend": "data-plane",
            "size_mb": SIZE_MB, "reps": REPS, "small_n": SMALL_N,
            "pipe_blocks": PIPE_BLOCKS}
@@ -322,6 +316,7 @@ if __name__ == "__main__":
     elif "--smoke" in sys.argv[1:]:
         smoke()
     else:
-        # parent mode: resilience ladder (persists the result artifact)
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        # parent mode: one --measure child under a timeout, its record
+        # persisted, its exit code ours
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))

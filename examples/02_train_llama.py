@@ -1,9 +1,13 @@
 """Train: JaxTrainer fitting a tiny Llama with checkpointing.
 
-On a TPU host this shards over the chips via the mesh config; here it runs
-the same code on CPU devices. Run:
+Run on a CPU box:
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/02_train_llama.py
+On a TPU host the same script runs the loop in a chip-bound worker:
+`ray.init()` counts the chips (the driver itself never imports jax) and
+`ScalingConfig(use_tpu=True, chips_per_worker=N)` binds the TrainWorker actor
+to N of them. Without `use_tpu` the worker is a CPU worker, whatever the host
+has. chip_smoke.py does this at llama_1b width.
 """
 import numpy as np
 
@@ -11,6 +15,7 @@ import ray_tpu as ray
 from ray_tpu import train
 
 ray.init(num_cpus=2)
+CHIPS = int(ray.cluster_resources().get("TPU", 0))
 
 
 def train_loop(config):
@@ -45,7 +50,8 @@ def train_loop(config):
 
 trainer = train.JaxTrainer(
     train_loop, train_loop_config={"steps": 5},
-    scaling_config=train.ScalingConfig(num_workers=1),
+    scaling_config=train.ScalingConfig(num_workers=1, use_tpu=CHIPS > 0,
+                                       chips_per_worker=CHIPS or None),
     run_config=train.RunConfig(name="example-llama"),
 )
 result = trainer.fit()

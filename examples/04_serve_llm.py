@@ -1,6 +1,10 @@
 """Serve: HTTP deployments + a continuous-batching LLM with a paged KV cache.
 
-Run: python examples/04_serve_llm.py
+Run on a CPU box:  JAX_PLATFORMS=cpu python examples/04_serve_llm.py
+On a TPU host the same script binds the LLM replica to a chip: `ray.init()`
+counts the chips (the driver itself never imports jax), `CHIPS` below picks
+them up, and the replica runs in a worker spawned with `num_tpus=1`.
+chip_smoke.py does this at llama_1b width.
 """
 import http.client
 import json
@@ -10,6 +14,7 @@ from ray_tpu import serve
 from ray_tpu.serve.llm import LLMConfig, LLMServer
 
 ray.init(num_cpus=4)
+CHIPS = int(ray.cluster_resources().get("TPU", 0))
 
 
 @serve.deployment
@@ -19,7 +24,8 @@ class Hello:
         return {"hello": name}
 
 
-@serve.deployment
+# without num_tpus a replica is a CPU worker, whatever the host has
+@serve.deployment(ray_actor_options={"num_tpus": 1} if CHIPS else {})
 class Generate:
     def __init__(self):
         # paged=True: vLLM-style block-table KV cache; on TPU the decode

@@ -43,11 +43,10 @@ import ctypes
 import os
 import pickle
 import struct
-import subprocess
 import threading
 from typing import List, Optional, Tuple
 
-from . import objdir
+from . import build, objdir
 
 MAGIC = 0xC3
 VERSION = 1
@@ -71,26 +70,13 @@ _ENT = struct.Struct("<BI")     # opcode, body_len
 _U16 = struct.Struct("<H")
 _U32 = struct.Struct("<I")
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "src", "frame_codec.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
 def _compile() -> str:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so = os.path.join(_BUILD_DIR, "libframe_codec.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
-        return so
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", so + ".tmp"]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(f"frame_codec build failed: {proc.stderr[:2000]}")
-    os.replace(so + ".tmp", so)
-    return so
+    return build("frame_codec")
 
 
 def _load():

@@ -21,13 +21,11 @@ source keyed by mtime — same recipe as the sched-queue binding.
 import ctypes
 import os
 import struct
-import subprocess
 import threading
 from typing import Dict, List, Optional, Tuple
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "src", "obj_directory.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
+from . import build
+
 _lock = threading.Lock()
 _lib = None        # PyDLL handle: scalar ops, GIL held
 _bulk_lib = None   # CDLL handle: bulk ops, GIL released
@@ -93,17 +91,7 @@ def unpack_delta_result(buf) -> List[Tuple[str, int, int]]:
 
 
 def _compile() -> str:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so = os.path.join(_BUILD_DIR, "libobj_directory.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
-        return so
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", so + ".tmp"]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(f"obj_directory build failed: {proc.stderr[:2000]}")
-    os.replace(so + ".tmp", so)
-    return so
+    return build("obj_directory")
 
 
 def _load():

@@ -14,30 +14,18 @@ recipe as the shm store binding (_native/store.py).
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Dict, List, Optional, Tuple
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "src", "sched_queue.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
+from . import build
+
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
 def _compile() -> str:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so = os.path.join(_BUILD_DIR, "libsched_queue.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
-        return so
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", so + ".tmp"]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(f"sched_queue build failed: {proc.stderr[:2000]}")
-    os.replace(so + ".tmp", so)
-    return so
+    return build("sched_queue")
 
 
 def _load():

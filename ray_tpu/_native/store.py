@@ -7,31 +7,18 @@ returns a zero-copy memoryview into it.
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "src", "shm_store.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
+from . import build
+
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
-def _compile() -> Optional[str]:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so = os.path.join(_BUILD_DIR, "libshm_store.so")
-    if (os.path.exists(so)
-            and os.path.getmtime(so) >= os.path.getmtime(_SRC)):
-        return so
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", so + ".tmp", "-lpthread", "-lrt"]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(f"shm_store build failed: {proc.stderr[:2000]}")
-    os.replace(so + ".tmp", so)
-    return so
+def _compile() -> str:
+    return build("shm_store", "-lpthread", "-lrt")
 
 
 def _load():

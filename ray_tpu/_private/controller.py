@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Set
 from .. import exceptions as exc
 from .._native import codec as _codec
 from .._native import objdir as _objdir
+from ..util import tpu as tpu_util
 from ..util import tracing
 from . import ids, protocol
 from .object_store import StoreClient
@@ -171,12 +172,21 @@ class TaskRecord:
     phases: Optional[Dict[str, float]] = None
 
 
+def needs_own_worker(spec: TaskSpec) -> bool:
+    """Actor creations and chip-bound tasks run in a worker spawned for them.
+    libtpu reads chip visibility when the process first opens a chip and then
+    holds it until the process exits, so a chip-bound task can neither reuse
+    a running pool worker nor leave one behind: its worker is spawned with
+    the binding in its env and exits with the task."""
+    return spec.is_actor_creation or spec.resources.get("TPU", 0) > 0
+
+
 class _ReadyIndex:
     """Ready queue over the C++ signature-bucketed index (src/sched_queue.cpp,
     ctypes via _native/schedq.py; Python mirror when the toolchain is absent).
 
     Reference contrast: raylet's ClusterTaskManager keeps per-scheduling-class
-    C++ queues. Tasks are bucketed by (pool, demand, env_key, tpu, creation);
+    C++ queues. Tasks are bucketed by (pool, demand, env_key, own_worker);
     `next_rec` asks the index for the earliest pending task whose demand fits
     its pool, masked by worker availability per signature — O(#signatures)
     per dispatch instead of rescanning every queued task. The controller's
@@ -257,10 +267,10 @@ class _ReadyIndex:
         from .runtime_env import runtime_env_key
         pool_key = self._pool_key_for(spec)
         env_key = runtime_env_key(spec.runtime_env)
-        tpu = spec.resources.get("TPU", 0) > 0
+        own_worker = needs_own_worker(spec)
         pg_id = spec.placement_group_id
         key = (pool_key, pg_id, tuple(sorted(spec.resources.items())),
-               env_key, tpu, spec.is_actor_creation)
+               env_key, own_worker)
         sig = self._sig_cache.get(key)
         if sig is None:
             sig = self.q.register_sig(pool_key, spec.resources)
@@ -278,8 +288,7 @@ class _ReadyIndex:
             else:
                 pool_ref = lambda: self.c.available  # noqa: E731
             meta = {
-                "env_key": env_key, "tpu": tpu,
-                "creation": spec.is_actor_creation,
+                "env_key": env_key, "own_worker": own_worker,
                 "need": dict(spec.resources),
                 "runtime_env": spec.runtime_env,
                 "pool_ref": pool_ref, "dead": False}
@@ -327,30 +336,30 @@ class _ReadyIndex:
         for sig_id, meta in enumerate(self._sig_meta):
             if sig_id in deferred or meta["dead"]:
                 mask.append(False)
-            elif meta["creation"]:
-                mask.append(True)  # creations spawn their own worker
+            elif meta["own_worker"]:
+                mask.append(True)  # spawns its own worker
             else:
-                mask.append((meta["tpu"], meta["env_key"]) in idle)
+                mask.append(meta["env_key"] in idle)
         return mask
 
     def batch_inputs(self, deferred: Set[int]):
         """(sig_modes, sig_buckets, bucket_idle) for schedule_batch: mode 0
-        skip / 1 plain / 2 creation-barrier, plus per-(tpu, env) idle-worker
+        skip / 1 plain / 2 own-worker barrier, plus per-env idle-worker
         counts from the controller's O(1) idle index."""
         modes: List[int] = []
         buckets: List[int] = []
         idle_counts: List[int] = []
-        bucket_ids: Dict[tuple, int] = {}
+        bucket_ids: Dict[Optional[str], int] = {}
         idle_index = self.c.idle_index
         for sig_id, meta in enumerate(self._sig_meta):
             if sig_id in deferred or meta["dead"]:
                 modes.append(0)
                 buckets.append(-1)
-            elif meta["creation"]:
+            elif meta["own_worker"]:
                 modes.append(2)
                 buckets.append(-1)
             else:
-                key = (meta["tpu"], meta["env_key"])
+                key = meta["env_key"]
                 b = bucket_ids.get(key)
                 if b is None:
                     b = len(idle_counts)
@@ -387,11 +396,11 @@ class _ReadyIndex:
 
     # -- per-signature aggregates (keeps demand counting O(#signatures)) -----
     def demand_by_sig(self):
-        """[(meta, live_count)] for non-creation signatures whose demand
+        """[(meta, live_count)] for pool-worker signatures whose demand
         currently fits their pool (pool checked against the dict truth)."""
         out = []
         for sig_id, meta in enumerate(self._sig_meta):
-            if meta["creation"] or meta["dead"]:
+            if meta["own_worker"] or meta["dead"]:
                 continue
             n = self.q.pending_sig(sig_id)
             if not n:
@@ -422,13 +431,9 @@ class WorkerConn:
     state: str = "starting"  # starting | idle | busy | dead
     running: Set[str] = field(default_factory=set)
     actor_id: Optional[str] = None  # dedicated actor worker
+    task_id: Optional[str] = None  # the one chip-bound task it was spawned for
     blocked_tasks: Set[str] = field(default_factory=set)
     pid: int = 0
-    # TPU-capable workers keep the accelerator runtime env; CPU-only workers
-    # get it stripped at spawn (a process merely *initializing* the TPU
-    # platform library can block on the chip while another process computes,
-    # so plain workers must never touch it)
-    tpu_capable: bool = False
     # runtime_env content hash this worker was built for (None = default env);
     # tasks only dispatch to workers whose env_key matches theirs
     env_key: Optional[str] = None
@@ -517,12 +522,12 @@ class Controller:
         self.ready_queue.register_pool(self.available)  # cluster pool = 0
         self.dep_waiters: Dict[str, Set[str]] = collections.defaultdict(set)
         self.workers: Dict[str, WorkerConn] = {}
-        # idle pool workers indexed by (tpu_capable, env_key) so
+        # idle pool workers indexed by env_key so
         # _find_idle_worker and the schedule pass's per-class idle counts are
         # O(1) instead of scanning self.workers per dispatch. Maintained at
         # every state transition; readers still validate entries (a stale
         # entry degrades to a deferred dispatch, never a wrong one).
-        self.idle_index: Dict[tuple, Dict[str, WorkerConn]] = {}
+        self.idle_index: Dict[Optional[str], Dict[str, WorkerConn]] = {}
         # Batched scheduling pass (src/sched_queue.cpp sq_schedule): one
         # selection+claim call per _schedule invocation instead of one index
         # round-trip per dispatch. RAY_TPU_NATIVE=0 / RAY_TPU_NATIVE_SCHED=0
@@ -551,7 +556,12 @@ class Controller:
         self.store_capacity = store_capacity
         self.store_spilled_bytes = 0   # disk-tier occupancy (spill ladder)
         self._last_spill_scan = 0.0
-        self.tpu_free: List[int] = list(range(int(resources.get("TPU", 0))))
+        # chips are addressed by position among this host's device nodes
+        # (what TPU_VISIBLE_CHIPS indexes); a chip is handed out again only
+        # once the process last bound to it has exited (_free_chips)
+        self.tpu_total = int(resources.get("TPU", 0))
+        self.tpu_free: List[int] = list(range(self.tpu_total))
+        self.chip_holder: Dict[int, subprocess.Popen] = {}
         self._server = None
         self._shutdown = False
         # Bounded bookkeeping (ref: GCS job-level GC,
@@ -695,12 +705,12 @@ class Controller:
 
     # ------------------------------------------------------- idle worker index
     def _mark_idle(self, w: WorkerConn):
-        if w.actor_id is not None:
+        if w.actor_id is not None or w.task_id is not None:
             return
-        self.idle_index.setdefault((w.tpu_capable, w.env_key), {})[w.worker_id] = w
+        self.idle_index.setdefault(w.env_key, {})[w.worker_id] = w
 
     def _unmark_idle(self, w: WorkerConn):
-        bucket = self.idle_index.get((w.tpu_capable, w.env_key))
+        bucket = self.idle_index.get(w.env_key)
         if bucket is not None:
             bucket.pop(w.worker_id, None)
 
@@ -770,6 +780,13 @@ class Controller:
                 self._kill_worker_proc(w)  # killed before its worker registered
             elif actor.creation_spec is not None:
                 rec = self.tasks[actor.creation_spec.task_id]
+                self._dispatch(rec, w)
+        elif w.task_id:
+            # chip-bound task worker: run the one task it was spawned for
+            rec = self.tasks.get(w.task_id)
+            if rec is None or rec.state != "SPAWNING":
+                self._kill_worker_proc(w)  # cancelled/failed while spawning
+            else:
                 self._dispatch(rec, w)
         self._schedule()
         try:
@@ -1172,6 +1189,12 @@ class Controller:
         rec = TaskRecord(spec=spec, result_oids=result_oids,
                         retries_left=retries, ts_submit=time.time())
         self.tasks[spec.task_id] = rec
+        if spec.is_actor_creation and spec.actor_id in self.actors:
+            # a worker registers an actor and submits its creation in two
+            # frames, each unpickled into its own copy: keep ONE spec, so the
+            # chips assigned at dispatch are the chips the actor's worker is
+            # bound to, restarted on, and gives back
+            self.actors[spec.actor_id].creation_spec = spec
         if spec.actor_id and not spec.is_actor_creation:
             # a submitted method pins its target: the caller may drop its
             # handle while this task is still waiting on deps, and the actor
@@ -1402,16 +1425,12 @@ class Controller:
         # grouped by runtime_env so each env gets workers built for it.
         # Aggregated per signature — O(#signatures), not O(pending tasks).
         demand: Dict[Optional[str], int] = {}
-        tpu_demand: Dict[Optional[str], int] = {}
         env_specs: Dict[Optional[str], Optional[dict]] = {}
         for meta, n in self.ready_queue.demand_by_sig():
             key = meta["env_key"]
             env_specs.setdefault(key, meta["runtime_env"])
-            if meta["tpu"]:
-                tpu_demand[key] = tpu_demand.get(key, 0) + n
-            else:
-                demand[key] = demand.get(key, 0) + n
-        self._spawn_for_demand(demand, tpu_demand, env_specs)
+            demand[key] = demand.get(key, 0) + n
+        self._spawn_for_demand(demand, env_specs)
         # 2. actor method calls → their dedicated workers
         for actor in self.actors.values():
             if actor.state != A_ALIVE:
@@ -1447,20 +1466,17 @@ class Controller:
             if pool is None or not self._resources_fit(rec.spec.resources, pool):
                 deferred.add(sig)  # mirror drift; dict pool is the truth
                 continue
-            if rec.spec.is_actor_creation:
+            if needs_own_worker(rec.spec):
                 self.ready_queue.take(rec)
-                if not self._start_actor_worker(rec, pool):
+                if not self._start_own_worker(rec, pool):
                     deferred.add(sig)  # env building; rec was re-queued
                 continue
-            w = self._find_idle_worker(
-                need_tpu=rec.spec.resources.get("TPU", 0) > 0,
-                env_key=runtime_env_key(rec.spec.runtime_env))
+            w = self._find_idle_worker(runtime_env_key(rec.spec.runtime_env))
             if w is None:
                 deferred.add(sig)
                 continue
             self.ready_queue.take(rec)
             self._claim(rec.spec.resources, pool)
-            self._assign_tpus(rec)
             self._dispatch(rec, w)
 
     def _dispatch_ready_batch(self):
@@ -1468,9 +1484,9 @@ class Controller:
         a single GIL release on the native queue) selects, pops, and claims
         every dispatchable task; Python then only applies the decisions
         (validate against the dict truth, pick the concrete idle worker,
-        assign TPUs, build the exec frame). Actor creations act as barriers:
-        the native pass stops where the oracle loop would have run
-        `_start_actor_worker`, Python handles the creation, and the pass
+        build the exec frame). Actor creations and chip-bound tasks act as
+        barriers: the native pass stops where the oracle loop would have run
+        `_start_own_worker`, Python spawns the worker, and the pass
         resumes — preserving the oracle's exact FIFO interleaving."""
         rq = self.ready_queue
         deferred: Set[int] = set()
@@ -1497,7 +1513,7 @@ class Controller:
                     rq.append(rec)
                     undid = True
                     continue
-                w = self._find_idle_worker(meta["tpu"], meta["env_key"])
+                w = self._find_idle_worker(meta["env_key"])
                 if w is None:
                     rq.unclaim(sig)
                     deferred.add(sig)
@@ -1508,10 +1524,9 @@ class Controller:
                 # already debited its pool for this decision
                 for k, v in rec.spec.resources.items():
                     pool[k] = pool.get(k, 0) - v
-                self._assign_tpus(rec)
                 self._dispatch(rec, w)
             if barrier_sig >= 0:
-                # actor creation won the FIFO race: handle it exactly like
+                # an own-worker task won the FIFO race: handle it exactly like
                 # the oracle iteration would, then resume the batch pass
                 rec = rq.recs.get(barrier_seq)
                 if rec is None or rec.state != PENDING:
@@ -1523,16 +1538,16 @@ class Controller:
                     deferred.add(barrier_sig)
                     continue
                 rq.take(rec)
-                if not self._start_actor_worker(rec, pool):
+                if not self._start_own_worker(rec, pool):
                     deferred.add(barrier_sig)  # env building; rec re-queued
                 continue
             if undid or len(decisions) >= _SCHED_BATCH_MAX:
                 continue  # refunds freed resources / output array was full
             return
 
-    def _find_idle_worker(self, need_tpu: bool = False,
-                          env_key: Optional[str] = None) -> Optional[WorkerConn]:
-        bucket = self.idle_index.get((need_tpu, env_key))
+    def _find_idle_worker(self, env_key: Optional[str] = None
+                          ) -> Optional[WorkerConn]:
+        bucket = self.idle_index.get(env_key)
         if not bucket:
             return None
         for wid in list(bucket):
@@ -1598,10 +1613,10 @@ class Controller:
         return False
 
     def _spawn_for_demand(self, demand: Dict[Optional[str], int],
-                          tpu_demand: Dict[Optional[str], int],
                           env_specs: Dict[Optional[str], Optional[dict]]):
         n_alive = sum(1 for w in list(self.workers.values()) + list(self.spawning.values())
-                      if w.actor_id is None and w.state not in ("dead", "driver"))
+                      if w.actor_id is None and w.task_id is None
+                      and w.state not in ("dead", "driver"))
         n_blocked = sum(1 for w in self.workers.values()
                         if w.actor_id is None and w.blocked_tasks)
         headroom = self.max_workers - (n_alive - n_blocked)
@@ -1609,7 +1624,7 @@ class Controller:
             if not self._env_ready(env_specs.get(env_key)):
                 continue  # async build in flight; tasks stay queued
             spawning = sum(1 for w in self.spawning.values()
-                           if w.actor_id is None and not w.tpu_capable
+                           if w.actor_id is None and w.task_id is None
                            and w.env_key == env_key)
             for _ in range(max(0, n - spawning)):
                 if headroom <= 0:
@@ -1619,7 +1634,7 @@ class Controller:
                     victim = next(
                         (w for w in self.workers.values()
                          if w.state == "idle" and w.actor_id is None
-                         and not w.tpu_capable and w.env_key != env_key),
+                         and w.task_id is None and w.env_key != env_key),
                         None)
                     if victim is None:
                         break
@@ -1633,32 +1648,6 @@ class Controller:
                     break
                 self._spawn_failures.pop(env_key, None)
                 headroom -= 1
-        # TPU pool-workers: one persistent worker serves the chip queue (a
-        # second process can't initialize the platform while the first
-        # computes, so more would just block at startup). If the sole worker
-        # was built for a different runtime_env and sits idle, recycle it.
-        for env_key in tpu_demand:
-            tpu_workers = [
-                w for w in list(self.workers.values()) + list(self.spawning.values())
-                if w.actor_id is None and w.tpu_capable and w.state != "dead"]
-            if any(w.env_key == env_key for w in tpu_workers):
-                continue
-            if any(w.state != "idle" or w.running for w in tpu_workers):
-                # a busy OR still-starting worker owns the chip; never run
-                # two processes against the platform at once
-                continue
-            if not self._env_ready(env_specs.get(env_key)):
-                continue
-            for w in tpu_workers:
-                self._retire_idle_worker(w)
-            try:
-                self._spawn_worker(tpu_capable=True, env_key=env_key,
-                                   runtime_env=env_specs.get(env_key))
-            except Exception as e:  # noqa: BLE001
-                self._note_spawn_failure(env_key, e)
-            else:
-                self._spawn_failures.pop(env_key, None)
-            break
 
     # ------------------------------------------------------------ autoscaler
     def request_resources(self, num_cpus=None, bundles=None) -> dict:
@@ -1679,7 +1668,7 @@ class Controller:
             "target_tpus": target_tpus, "ts": time.time()}
         n_alive = sum(
             1 for w in list(self.workers.values()) + list(self.spawning.values())
-            if w.actor_id is None and not w.tpu_capable
+            if w.actor_id is None and w.task_id is None
             and w.state not in ("dead", "driver"))
         want = min(target, self.max_workers)
         spawned = 0
@@ -1912,17 +1901,16 @@ class Controller:
             "leaks": list(self.health.leaks),
         }
 
-    # env vars that bind a process to the accelerator runtime; stripped for
-    # CPU-only workers (see WorkerConn.tpu_capable). Single source of truth:
-    # ray_tpu/util/tpu.py (shared with bench.py / __graft_entry__).
-    from ..util.tpu import ACCEL_ENV_KEYS as _TPU_ENV_KEYS
-
     def _spawn_worker(self, actor: ActorRecord = None,
-                      tpu_capable: bool = False,
                       env_key: Optional[str] = None,
-                      runtime_env: Optional[dict] = None) -> WorkerConn:
-        if actor is not None and actor.creation_spec is not None:
-            runtime_env = actor.creation_spec.runtime_env
+                      runtime_env: Optional[dict] = None,
+                      task_rec: TaskRecord = None) -> WorkerConn:
+        """Spawn a pool worker (no args), an actor's dedicated worker, or the
+        one-shot worker of a chip-bound task (`task_rec`)."""
+        own = actor.creation_spec if actor is not None else (
+            task_rec.spec if task_rec is not None else None)
+        if own is not None:
+            runtime_env = own.runtime_env
             env_key = runtime_env_key(runtime_env)
         # build (or fetch cached) runtime env BEFORE claiming a worker id —
         # raises on bad py_modules paths / failed pip installs
@@ -1945,49 +1933,68 @@ class Controller:
             env["PYTHONPATH"] = os.pathsep.join(
                 extra + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
         if actor is not None:
-            tpu_capable = (actor.creation_spec is not None and
-                           actor.creation_spec.resources.get("TPU", 0) > 0)
             env.update({k: str(v) for k, v in (actor.env or {}).items()})
             # the worker's exec pool honors the actor's declared concurrency
             # (ref: ray core max_concurrency) instead of a fixed 64 threads
             mc = getattr(actor.options, "max_concurrency", 1) or 1
             env["RAY_TPU_MAX_CONCURRENCY"] = str(max(1, int(mc)))
-        if not tpu_capable:
-            for k in self._TPU_ENV_KEYS:
-                env.pop(k, None)
-            env["JAX_PLATFORMS"] = "cpu"
+        chips = list((runtime_env or {}).get("_tpu_ids", ())) if own else []
+        if chips:
+            # chip visibility must be in the env before the new process
+            # imports jax; the driver's JAX_PLATFORMS passes through as is —
+            # a chip-bound worker that lands on the CPU is for its caller to
+            # see and fail on, not for this layer to hide
+            env.update(tpu_util.chip_binding_env(chips, self.tpu_total))
+            env["JAX_COMPILATION_CACHE_DIR"] = tpu_util.compile_cache_dir()
+            self._evict_chip_holders(chips)
+        else:
+            # bound to the CPU: a process that merely initializes the TPU
+            # platform takes the chip from the worker it was given to
+            env = tpu_util.scrub_accel_env(env)
         renv_ctx.apply(env)  # env_vars, staged py_modules/working_dir paths
         proc = subprocess.Popen(
             [renv_ctx.python_exe, "-m", "ray_tpu._private.worker_main",
              self.socket_path, wid],
             env=env, stdin=subprocess.DEVNULL)
+        for c in chips:
+            self.chip_holder[c] = proc
         w = WorkerConn(worker_id=wid, proc=proc,
                        actor_id=actor.actor_id if actor else None,
-                       tpu_capable=tpu_capable, env_key=env_key)
+                       task_id=task_rec.spec.task_id if task_rec else None,
+                       env_key=env_key)
         self.spawning[wid] = w
         return w
 
-    def _start_actor_worker(self, rec: TaskRecord, pool: Dict[str, float]) -> bool:
-        """Actor creation always gets a dedicated worker (ref: raylet leases a
-        worker for the actor's lifetime). TPU actors get chip binding env.
+    def _start_own_worker(self, rec: TaskRecord, pool: Dict[str, float]) -> bool:
+        """Actor creations and chip-bound tasks get a worker spawned for them
+        (ref: raylet leases a worker for the actor's lifetime), with the chip
+        binding in its env; the rec dispatches when that worker registers.
         Returns False (rec left queued) while its runtime env is still
         building asynchronously."""
         if not self._env_ready(rec.spec.runtime_env):
             self.ready_queue.append(rec)
             return False
         self._claim(rec.spec.resources, pool)
-        actor = self.actors[rec.spec.actor_id]
-        actor.resources_claimed = True
         rec.state = "SPAWNING"
-        self._assign_tpus(rec, actor)
+        self._assign_tpus(rec)
+        if rec.spec.is_actor_creation:
+            actor = self.actors[rec.spec.actor_id]
+            actor.resources_claimed = True
+            try:
+                self._spawn_worker(actor)
+            except Exception as e:  # noqa: BLE001 - env build / binding refused
+                self._fail_actor(actor, f"worker spawn failed: {e}",
+                                 allow_restart=False)
+            return True
         try:
-            self._spawn_worker(actor)
-        except Exception as e:  # noqa: BLE001 - runtime_env build failure
-            self._fail_actor(actor, f"runtime_env setup failed: {e}",
-                             allow_restart=False)
+            self._spawn_worker(task_rec=rec)
+        except Exception as e:  # noqa: BLE001 - env build / binding refused
+            self._fail_task(rec, exc.RuntimeEnvSetupError(
+                f"chip-bound worker spawn failed: {e}"))
+            self._release_task_resources(rec)
         return True
 
-    def _assign_tpus(self, rec: TaskRecord, actor: ActorRecord = None):
+    def _assign_tpus(self, rec: TaskRecord):
         n = int(rec.spec.resources.get("TPU", 0))
         if n <= 0:
             return
@@ -2000,10 +2007,29 @@ class Controller:
         assigned, self.tpu_free = self.tpu_free[:n], self.tpu_free[n:]
         rec.spec.runtime_env = dict(rec.spec.runtime_env or {})
         rec.spec.runtime_env["_tpu_ids"] = assigned
-        if actor is not None:
-            # chip visibility must be set before jax imports in the new process
-            actor.env["TPU_VISIBLE_CHIPS"] = ",".join(map(str, assigned))
-            actor.env["RAY_TPU_IDS"] = ",".join(map(str, assigned))
+
+    def _evict_chip_holders(self, chips: List[int]):
+        """Make sure no earlier process still holds `chips`. libtpu gives a
+        chip to one process at a time and the holder keeps it until it exits,
+        so a chip is free when its last holder is gone — not when the
+        accounts say so. SIGKILL + reap: by the time wait() returns the
+        kernel has closed the dead process's device files."""
+        for c in chips:
+            proc = self.chip_holder.pop(c, None)
+            if proc is None or proc.poll() is not None:
+                continue
+            try:
+                proc.kill()
+                proc.wait(timeout=30)
+            except (OSError, subprocess.TimeoutExpired) as e:
+                print(f"[controller] chip {c}: holder pid={proc.pid} did not "
+                      f"exit ({e!r}); the next worker bound to it may fail "
+                      f"to open the chip", file=sys.stderr)
+
+    def _free_chips(self, spec: TaskSpec):
+        chips = (spec.runtime_env or {}).pop("_tpu_ids", [])
+        self._evict_chip_holders(chips)
+        self.tpu_free.extend(chips)
 
     def _dispatch(self, rec: TaskRecord, w: WorkerConn):
         rec.state = RUNNING
@@ -2044,7 +2070,12 @@ class Controller:
             w.blocked_tasks.discard(task_id)
             if rec is not None:
                 self._reclaim_blocked_cpu(rec)
-        if w.actor_id is None and not w.running:
+        if w.task_id is not None:
+            # one-shot chip-bound worker: its chips go back to the free list
+            # below (_release_task_resources), which first sees it gone
+            w.state = "dying"
+            self._kill_worker_proc(w)
+        elif w.actor_id is None and not w.running:
             w.state = "idle"
             self._mark_idle(w)
         if rec is None:
@@ -2194,8 +2225,7 @@ class Controller:
             # creation allocation — releasing here would double-free
             return
         self._release(rec.spec.resources, self._task_pool(rec.spec))
-        tpus = (rec.spec.runtime_env or {}).get("_tpu_ids", [])
-        self.tpu_free.extend(tpus)
+        self._free_chips(rec.spec)
 
     def _release_actor_allocation(self, actor: ActorRecord):
         """Exactly-once release of an actor's standing resources + chips."""
@@ -2203,8 +2233,7 @@ class Controller:
             return
         actor.resources_claimed = False
         self._release(actor.creation_spec.resources, self._task_pool(actor.creation_spec))
-        tpus = (actor.creation_spec.runtime_env or {}).get("_tpu_ids", [])
-        self.tpu_free.extend(tpus)
+        self._free_chips(actor.creation_spec)
 
     def _unpin(self, rec: TaskRecord):
         for oid in rec.pinned:
@@ -3572,6 +3601,17 @@ class Controller:
             actor = self.actors.get(w.actor_id)
             if actor is not None and actor.state in (A_ALIVE, A_PENDING):
                 self._fail_actor(actor, f"worker died: {reason}", allow_restart=True)
+        elif w.task_id:
+            # died before registering: its chip-bound task never dispatched,
+            # so the w.running sweep above did not see it
+            rec = self.tasks.get(w.task_id)
+            if rec is not None and rec.state == "SPAWNING":
+                self._release_task_resources(rec)
+                if rec.retries_left > 0 and not rec.cancelled:
+                    rec.retries_left -= 1
+                    self._enqueue_ready(rec)
+                else:
+                    self._fail_task(rec, crash)
         # release handle/stream refs the dead worker's deserialized handles
         # held — a crash must not pin other actors or streams alive forever
         for aid, n in list(w.actor_refs.items()):
@@ -3610,6 +3650,14 @@ class Controller:
                         actor.queue.remove(rec)
                     except ValueError:
                         pass
+        elif rec.state == "SPAWNING" and not rec.spec.is_actor_creation:
+            # chip-bound task whose worker has not registered yet: fail it and
+            # let the worker be killed when it registers (or by the reaper)
+            for sw in self.spawning.values():
+                if sw.task_id == task_id:
+                    self._kill_worker_proc(sw)
+            self._fail_task(rec, exc.TaskCancelledError(task_id))
+            self._release_task_resources(rec)
         elif rec.state == RUNNING:
             w = self.workers.get(rec.worker_id)
             if w is None:

@@ -1160,8 +1160,11 @@ async def _amain(args) -> int:
                         f"rtpu-node-{os.getpid()}.sock")
     os.environ["RAY_TPU_ADDRESS"] = sock
     resources = {"CPU": float(args.num_cpus), "memory": 32 << 30}
-    if args.num_tpus:
-        resources["TPU"] = float(args.num_tpus)
+    from ..util.tpu import count_local_chips
+    num_tpus = (args.num_tpus if args.num_tpus is not None
+                else count_local_chips())
+    if num_tpus:
+        resources["TPU"] = float(num_tpus)
     import json
     for k, v in (json.loads(args.resources) if args.resources else {}).items():
         resources[k] = float(v)
@@ -1187,7 +1190,8 @@ def main(argv=None) -> int:
                     "ray_tpu.init(cluster_port=...))")
     ap.add_argument("--address", required=True, help="head HOST:PORT")
     ap.add_argument("--num-cpus", type=float, default=float(os.cpu_count() or 4))
-    ap.add_argument("--num-tpus", type=float, default=0.0)
+    ap.add_argument("--num-tpus", type=float, default=None,
+                    help="default: this host's TPU device nodes")
     ap.add_argument("--resources", default="", help='extra resources, JSON '
                     '(e.g. \'{"worker_node": 1}\')')
     ap.add_argument("--object-store-memory", type=int, default=0)

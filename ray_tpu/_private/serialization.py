@@ -6,14 +6,20 @@ out-of-band into plasma so `get()` can map them zero-copy.
 
 We do the same: `dumps_oob` returns (pickle_bytes, [raw buffers]); callers lay
 the buffers into shared memory and `loads_oob` reconstructs with memoryviews
-into that shm — numpy arrays then alias the segment with no copy. jax host
-arrays hand back their device buffers via __array__ and re-upload with
-device_put on the consumer side (the host→HBM hop is the one unavoidable copy
-on TPU).
+into that shm — numpy arrays then alias the segment with no copy.
+
+Host values cross process boundaries: a `jax.Array` is written as the numpy
+array it holds. jax's own pickle support re-uploads with `device_put` inside
+`loads`, which makes whoever unpickles — typically the driver, reading a
+`report()` or a task result — initialize a backend; on libtpu that is an
+attempt to open the chip the producing worker still owns. The consumer gets
+numpy and uploads when (and if) it computes.
 """
 
+import io
 import pickle
 import struct
+import sys
 import threading
 
 import cloudpickle
@@ -44,6 +50,25 @@ class _CollectRefs:
 
     def __exit__(self, *a):
         _collector.ids = self._prev
+
+
+class _HostValuePickler(cloudpickle.Pickler):
+    """cloudpickle, except that device arrays go out as host arrays."""
+
+    def reducer_override(self, obj):
+        jax = sys.modules.get("jax")  # no jax in this process → no jax.Array
+        if (jax is not None and isinstance(obj, jax.Array)
+                and not jax.dtypes.issubdtype(obj.dtype, jax.dtypes.prng_key)):
+            import numpy as np
+            return np.asarray(obj).__reduce_ex__(pickle.HIGHEST_PROTOCOL)
+        return super().reducer_override(obj)
+
+
+def _dumps(obj, buffer_callback=None) -> bytes:
+    with io.BytesIO() as f:
+        _HostValuePickler(f, protocol=5,
+                          buffer_callback=buffer_callback).dump(obj)
+        return f.getvalue()
 
 
 # Exact-type scalars take the plain-pickle fast path below: no cloudpickle
@@ -87,7 +112,7 @@ def dumps_oob(obj):
         return False
 
     with _CollectRefs() as contained:
-        payload = cloudpickle.dumps(obj, protocol=5, buffer_callback=callback)
+        payload = _dumps(obj, buffer_callback=callback)
     header = struct.pack("<I", len(payload)) + payload
     for b in buffers:
         header += struct.pack("<Q", b.nbytes)
@@ -106,7 +131,7 @@ def dumps_with_refs(obj):
     """cloudpickle.dumps + contained ObjectRef ids (for function/class blobs
     that may capture refs in closures or globals)."""
     with _CollectRefs() as contained:
-        blob = cloudpickle.dumps(obj)
+        blob = _dumps(obj)
     return blob, list(contained)
 
 

@@ -2,8 +2,8 @@
 python/ray/__init__.py exports).
 
 `init()` starts the single-host controller on a background asyncio thread;
-TPU chips are first-class resources ("TPU"), discovered from jax when
-available without forcing a jax import in workers.
+TPU chips are first-class resources ("TPU"), counted from the host's device
+nodes — neither the driver nor a worker imports jax to find them.
 """
 
 import asyncio
@@ -18,6 +18,7 @@ from ._private.controller import Controller, DEFAULT_CAPACITY
 from ._private.object_ref import ObjectRef, ObjectRefGenerator
 from .actor import ActorClass, ActorHandle
 from .remote_function import RemoteFunction
+from .util import tpu
 from . import exceptions as exc
 
 _runtime = None
@@ -31,18 +32,6 @@ class _Runtime:
         self.thread = thread
         self.client = client
         self.namespace = namespace
-
-
-def _detect_tpus():
-    """Chip count without importing jax in this process if possible."""
-    env = os.environ.get("RAY_TPU_NUM_CHIPS")
-    if env is not None:
-        return int(env)
-    try:
-        import jax
-        return sum(1 for d in jax.devices() if d.platform not in ("cpu",))
-    except Exception:  # noqa: BLE001 - jax missing/unconfigured → no TPU resource
-        return 0
 
 
 def is_initialized() -> bool:
@@ -96,7 +85,10 @@ def init(num_cpus=None, num_tpus=None, resources=None, namespace=None,
             return
         total = dict(resources or {})
         total["CPU"] = float(num_cpus if num_cpus is not None else max(os.cpu_count(), 4))
-        ntpu = num_tpus if num_tpus is not None else _detect_tpus()
+        # counted from device nodes: the driver must never open the chip its
+        # workers are about to be bound to (one process per libtpu chip)
+        ntpu = (num_tpus if num_tpus is not None
+                else tpu.count_local_chips())
         if ntpu:
             total["TPU"] = float(ntpu)
         total.setdefault("memory", 64 << 30)

@@ -15,15 +15,14 @@ Reference contrast: the reference gets this from flash-attn CUDA via torch.
 On the CPU test mesh the same kernels run in pallas interpret mode, so
 numerics are tested without hardware (SURVEY.md §4 models/ops).
 
-Block sizes default to 1024: on v5e the per-grid-step overhead dominates small
-blocks (measured r3: 256-blocks ran 4.9% of peak, 1024-blocks 17-25% — the
-practical ceiling for head_dim 64, which half-fills the 128-wide MXU).
+Block sizes default to 1024 (per-grid-step overhead dominates small blocks);
+fwd, dq and dkv all compile at 1024x1024 on a v5e under libtpu 0.0.34 and
+match `mha_reference` at B=1,T=2048,H=32,Kh=8,D=64 (chip_smoke.py checks this
+on every run). Their speed is not measured.
 """
 
 import functools
 import math
-import os
-import warnings
 from typing import Optional
 
 import jax
@@ -118,6 +117,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_kv, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -245,6 +245,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, scale, block_q, block_kv,
         out_shape=jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse4, delta)
 
     # KV-centric pass: grid folds the GQA group so dk/dv scratch accumulates
@@ -265,6 +266,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, scale, block_q, block_kv,
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
                         pltpu.VMEM((bkv, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse4, delta)
     return dq, dk, dv
 
@@ -280,46 +282,75 @@ def _flash_vjp_bwd(causal, scale, block_q, block_kv, interpret, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _shard_spec(q, k):
+    """(PartitionSpec, mesh axes it uses) for running the kernel per shard of
+    the context mesh (`jax.set_mesh`), or None with nothing to shard over.
+
+    A pallas custom call has no partitioning rule: left bare under GSPMD,
+    XLA all-gathers q/k/v and runs the WHOLE batch on every device. Attention
+    is independent per batch row and per kv-head group, so batch splits over
+    the data axes (`parallel.sharding.batch_spec`) and heads over `tp`; the
+    sequence stays whole — splitting it is ring_attention's job."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
+    from jax.sharding import AxisType, PartitionSpec
+
+    from ray_tpu.parallel.sharding import batch_spec
+    free = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != AxisType.Manual and mesh.shape[a] > 1}
+
+    def fit(axes, *dims):
+        axes = tuple(a for a in axes if a in free)
+        n = math.prod(mesh.shape[a] for a in axes)
+        return axes if axes and all(d % n == 0 for d in dims) else None
+
+    batch = fit(batch_spec()[0], q.shape[0])
+    heads = fit(("tp",), q.shape[2], k.shape[2])
+    if batch is None and heads is None:
+        return None
+    return PartitionSpec(batch, None, heads, None), {*(batch or ()),
+                                                      *(heads or ())}
+
+
 def flash_attention(
     q: jax.Array,  # [B, T, H, D]
     k: jax.Array,  # [B, S, Kh, D]
     v: jax.Array,  # [B, S, Kh, D]
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: Optional[int] = None,
-    block_kv: Optional[int] = None,
+    block_q: int = 1024,
+    block_kv: int = 1024,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash attention in [B, T, H, D] layout (matches `mha_reference`).
 
-    `interpret=None` auto-selects: pallas-compiled on TPU, interpret mode
-    elsewhere. Sequence lengths that don't tile into the (clipped) block
-    sizes fall back to the XLA reference path — the grid would otherwise
-    silently drop the remainder rows.
+    `interpret=None` selects by backend: pallas-compiled on TPU, interpret
+    mode elsewhere. Sequence lengths must tile into the (clipped) block
+    sizes — the grid would silently drop the remainder rows, and an O(T²)
+    fallback hiding behind the kernel's name is worse than an error; callers
+    with ragged lengths pad, or call `mha_reference` themselves. Under a
+    `jax.set_mesh` context the kernel runs per shard (see `_shard_spec`).
     """
-    from ray_tpu.ops.attention import mha_reference
-
-    # block sizes: explicit arg > env override (perf sweeps) > default 1024
-    if block_q is None:
-        block_q = int(os.environ.get("RAY_TPU_FLASH_BLOCK_Q", 1024))
-    if block_kv is None:
-        block_kv = int(os.environ.get("RAY_TPU_FLASH_BLOCK_KV", 1024))
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     tq, tk = q.shape[1], k.shape[1]
     if tq % min(block_q, tq) or tk % min(block_kv, tk):
-        # Loud fallback (VERDICT r2 weak #4): O(T²) XLA attention silently
-        # replacing the flash path hid real perf regressions.
-        msg = (f"flash_attention: seq lengths (q={tq}, kv={tk}) don't tile "
-               f"into blocks ({block_q}, {block_kv}); falling back to the "
-               f"O(T²) XLA reference path")
-        if os.environ.get("RAY_TPU_STRICT_FLASH"):
-            raise ValueError(msg + " (RAY_TPU_STRICT_FLASH is set)")
-        warnings.warn(msg, stacklevel=2)
-        return mha_reference(q, k, v, causal=causal, scale=scale)
+        raise ValueError(
+            f"flash_attention: seq lengths (q={tq}, kv={tk}) don't tile into "
+            f"blocks ({block_q}, {block_kv})")
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    qt = jnp.swapaxes(q, 1, 2)  # [B, H, T, D]
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash(qt, kt, vt, causal, scale, block_q, block_kv, interpret)
-    return jnp.swapaxes(out, 1, 2)
+
+    def kernel(q, k, v):
+        out = _flash(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                     jnp.swapaxes(v, 1, 2), causal, scale, block_q, block_kv,
+                     interpret)   # [B, H, T, D]
+        return jnp.swapaxes(out, 1, 2)
+
+    sharded = _shard_spec(q, k)
+    if sharded is not None:
+        spec, axes = sharded
+        kernel = jax.shard_map(kernel, in_specs=(spec, spec, spec),
+                               out_specs=spec, axis_names=axes,
+                               check_vma=False)
+    return kernel(q, k, v)

@@ -65,20 +65,11 @@ def pipeline_apply(
         outs = jnp.where(is_last, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)
 
-    # jax moved shard_map out of experimental in 0.5.x and renamed the
-    # check_rep knob to check_vma; support both so the SPMD reference runs
-    # on the baked-in 0.4.x toolchain too
-    shard_map = getattr(jax, "shard_map", None)
-    check_kw = {"check_vma": False}
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-        check_kw = {"check_rep": False}
-
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(axis), P()),  # stages sharded; microbatches replicated
         out_specs=P(),
-        **check_kw,
+        check_vma=False,
     )(stage_params, microbatches)
 
 

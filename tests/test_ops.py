@@ -5,14 +5,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-# jax moved shard_map out of experimental in 0.5.x; support the 0.4.x
-# toolchain baked into this image too
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from ray_tpu.ops.attention import apply_rope, decode_attention, mha_reference
 from ray_tpu.ops.flash_attention import flash_attention
@@ -51,6 +45,26 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             flash_attention(q, k, v, block_q=64, block_kv=64),
             mha_reference(q, k, v), atol=2e-5)
+
+    def test_lengths_that_do_not_tile_raise(self):
+        """No O(T^2) path hides behind the kernel's name: callers with
+        ragged lengths pad, or call mha_reference themselves."""
+        q, k, v = _qkv(T=96)
+        with pytest.raises(ValueError, match="don't tile"):
+            flash_attention(q, k, v, block_q=64, block_kv=64)
+
+    def test_runs_per_shard_under_a_context_mesh(self):
+        """Under jax.set_mesh the kernel is shard_mapped over the data axes
+        (batch) and tp (heads): same numbers, outputs left sharded."""
+        from ray_tpu.parallel.mesh import make_mesh
+        q, k, v = _qkv(B=4, T=64, H=4, Kh=2, D=16)
+        ref = mha_reference(q, k, v)
+        mesh = make_mesh({"fsdp": 2, "tp": 2}, devices=jax.devices()[:4])
+        with jax.set_mesh(mesh):
+            out = jax.jit(lambda *a: flash_attention(
+                *a, block_q=64, block_kv=64))(q, k, v)
+        np.testing.assert_allclose(out, ref, atol=2e-5)
+        assert out.sharding.spec == P("fsdp", None, "tp")
 
 
 class TestRingAttention:

@@ -164,23 +164,3 @@ def test_model_paged_decode_matches_dense():
         return toks
 
     assert greedy_dense() == greedy_paged()
-
-
-@pytest.mark.tpu
-def test_kernel_on_tpu_hardware():
-    """Real-TPU lowering of the paged kernel vs the XLA reference (run with
-    RAY_TPU_TEST_TPU=1 on hardware; validated manually on v5e)."""
-    import os
-    if not os.environ.get("RAY_TPU_TEST_TPU"):
-        pytest.skip("no TPU opt-in")
-    # includes a tiny-head case (kh*g = 2 < the 8-row sublane tile)
-    for kh, g in ((2, 4), (2, 1)):
-        b, d, page, max_pages = 4, 64, 16, 8
-        lengths = np.array([1, 37, 100, 128])
-        q, kp, vp, tbl, lens = _random_paged(b, kh, g, d, page, max_pages,
-                                             lengths)
-        qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
-        out_k = jax.jit(paged_attention)(qb, kb, vb, tbl, lens)
-        out_r = paged_attention_reference(qb, kb, vb, tbl, lens)
-        np.testing.assert_allclose(np.asarray(out_k, np.float32),
-                                   np.asarray(out_r, np.float32), atol=2e-2)

@@ -18,20 +18,15 @@ cd "$(dirname "$0")/.."
 MODE="${1:-build}"
 
 python - "$MODE" <<'EOF'
+import importlib
 import sys
 
-MODULES = [
-    ("shm_store", "ray_tpu._native.store"),
-    ("sched_queue", "ray_tpu._native.schedq"),
-    ("frame_codec", "ray_tpu._native.codec"),
-    ("obj_directory", "ray_tpu._native.objdir"),
-]
+from ray_tpu._native import TARGETS
 
 failed = 0
-for name, modpath in MODULES:
+for name, mod in TARGETS.items():
     try:
-        mod = __import__(modpath, fromlist=["_compile"])
-        so = mod._compile()
+        so = importlib.import_module(f"ray_tpu._native.{mod}")._compile()
         print(f"  [ok] {name:14s} -> {so}")
     except Exception as e:  # noqa: BLE001 - report and count
         failed += 1

@@ -15,13 +15,14 @@ prove the resilience stack end to end:
     two heartbeat intervals, with the alert-id -> create causality
     recorded.
 
-Modes (same ladder contract as the other aux benches):
+Modes:
   --measure   full ladder: baseline + chaos per rung, one combined
               artifact under benchmarks/results/
   --smoke     fast tier-1 gate: one kill-mid-run rung + the reconcile
               rung, correctness asserts only (wall-clock ratios are for
               --measure; a loaded CI box makes them flaky)
-  (no flag)   self-orchestrating parent (bench.run_aux_ladder)
+  (no flag)   parent: runs --measure once under a timeout (bench.py's
+              run_measure_child)
 
 Never imports jax — faults live in the control/data planes.
 """
@@ -459,8 +460,7 @@ def run_ladder(rungs=None):
 
 
 def measure():
-    from bench import _INIT_SENTINEL, _write_result_artifact
-    print(f"{_INIT_SENTINEL} backend=chaos", file=sys.stderr, flush=True)
+    from bench import _write_result_artifact
     rec = {"bench": "chaos_ladder", "backend": "chaos",
            "block_kb": BLOCK_KB, "task_s": TASK_S,
            "slowdown_budget": SLOWDOWN_BUDGET}
@@ -491,5 +491,5 @@ if __name__ == "__main__":
     elif "--smoke" in sys.argv[1:]:
         smoke()
     else:
-        from bench import run_aux_ladder
-        sys.exit(run_aux_ladder(os.path.abspath(__file__)))
+        from bench import run_measure_child
+        sys.exit(run_measure_child(os.path.abspath(__file__)))
