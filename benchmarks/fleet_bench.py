@@ -14,8 +14,9 @@ Two sections, one JSON record:
              TTFT p50/p99 (slot-queue time included), client TPOT p99,
              goodput under the TTFT SLO, the fleet prefix-cache hit rate
              per mode, the handle's affinity hit/miss/spill counters, and
-             the per-replica serve-phase trace decomposition (PR 12
-             windows: serve.pd.* on the pd path, serve.decode_chunk here).
+             the engine's own phase account summed over the replicas
+             (`stats()["decode"]["phase_s"/"phase_n"]`: decode_sync,
+             prefill_dispatch, admit_allocate, demote, ...).
   autoscale  SLO-driven scaling through the controller ledger: a burst
              against a min_replicas fleet must produce a scale_up record
              within 2 evaluation intervals of burst start, and the
@@ -114,20 +115,12 @@ def _deployment(num_replicas, pool_pages, autoscaling=None):
                      "pages_in_use")}
 
         def trace_phases(self):
-            """Serve-phase windows from this replica's local trace ring
-            (PR 12): name -> {count, total_s}."""
-            from ray_tpu.util import tracing
-            out = {}
-            for ev in tracing.events():
-                if ev.get("cat") != "serve":
-                    continue
-                d = out.setdefault(ev.get("name"),
-                                   {"count": 0, "total_s": 0.0})
-                d["count"] += 1
-                d["total_s"] += ev.get("dur", 0) / 1e6
-            for d in out.values():
-                d["total_s"] = round(d["total_s"], 4)
-            return out
+            """This replica's engine phases, from the engine's own
+            counters: name -> {count, total_s}."""
+            d = self._srv.stats()["decode"]
+            return {k: {"count": d["phase_n"][k],
+                        "total_s": round(d["phase_s"][k], 4)}
+                    for k in d["phase_s"]}
 
     return FleetLLM
 

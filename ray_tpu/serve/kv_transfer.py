@@ -205,6 +205,7 @@ class KVPageStash:
                        else budget_bytes)
         self.shm_bytes = 0
         self.disk_bytes = 0
+        self.spilled_pages = 0     # segments the budget moved to disk, ever
 
     def _gauge(self):
         try:
@@ -255,6 +256,7 @@ class KVPageStash:
                 continue
             self._disk[oid] = (path, nbytes)
             self.disk_bytes += nbytes
+            self.spilled_pages += 1
 
     def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, np.ndarray]:
         """Restore one page's (k, v), promoting a disk-resident segment

@@ -31,6 +31,20 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util.tracing import PhaseTotals, phase
+
+# Every stretch of host time on the engine's event-loop thread belongs to one
+# of these phases (util.tracing.phase: a profiler annotation `engine.<key>`
+# plus stats()["decode"]["phase_s"/"phase_n"]). LOOP_PHASES tile one iteration
+# of the tick loop and sum to `loop_s`; NESTED_PHASES are their children,
+# mostly inside `yield`: admit_allocate holds evict, which holds demote, which
+# holds demote_stash. Keys carry no dot: readers split counter paths on ".".
+LOOP_PHASES = ("decode_build", "decode_dispatch", "decode_sync",
+               "decode_emit", "prefill_dispatch", "prefill_first_token",
+               "yield")
+NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
+                 "restore")
+
 
 @dataclasses.dataclass
 class LLMConfig:
@@ -175,6 +189,7 @@ class LLMServer:
                 self.model_cfg = _dc.replace(self.model_cfg,
                                              capacity_factor=dropless)
         self.model = Llama(self.model_cfg)
+        self._phases = PhaseTotals("engine", LOOP_PHASES + NESTED_PHASES)
         B = cfg.max_batch_slots
         key = jax.random.PRNGKey(cfg.seed)
         if cfg.tp > 1:
@@ -235,9 +250,10 @@ class LLMServer:
             if cfg.prefix_cache and _radix.radix_enabled():
                 from ray_tpu.serve.kv_transfer import (KVPageStash,
                                                        kv_demote_enabled)
+                hooks = dict(phases=self._phases)
                 if kv_demote_enabled():
                     self._kv_stash = KVPageStash()
-                    hooks = dict(demote_cb=self._demote_page,
+                    hooks.update(demote_cb=self._demote_page,
                                  restore_cb=self._restore_page,
                                  drop_cb=self._drop_page)
             self.page_mgr = _radix.make_page_manager(
@@ -273,22 +289,18 @@ class LLMServer:
         self._spec = None
         self._spec_stats = {"spec_ticks": 0, "decode_ticks": 0,
                             "drafted": 0, "accepted": 0}
-        # decode-chunk accounting: ONE host sync per chunk is the whole
-        # perf story, so it is a recorded metric (stats() + util.metrics),
+        # the engine loop's own accounting (stats()["decode"]), monotonic
+        # over the server's life and read as window deltas. ONE host sync
+        # per chunk is the whole perf story, so it is a recorded counter,
         # not an inference — decode_bench.py asserts on it
-        self._decode_stats = {"host_syncs": 0, "tokens": 0,
-                              "chunk_s_total": 0.0, "chunk_sizes": {}}
+        self._decode_stats = {
+            "host_syncs": 0, "tokens": 0, "chunk_s_total": 0.0,
+            "chunk_sizes": {}, "loop_s": 0.0, "ticks": 0,
+            "decode_steps": 0, "active_slot_syncs": 0,
+            "prefill_chunks": 0, "prefill_tokens": 0,
+            "prefill_padded_tokens": 0, "admitted": 0, "slot_wait_s": 0.0,
+            "slot_wait_max_s": 0.0, "demote_bytes": 0}
         from ray_tpu.util import metrics as _metrics
-        self._m_syncs = _metrics.get_or_create(
-            _metrics.Counter, "serve_decode_host_syncs",
-            "decode engine host syncs (one per decode chunk / spec tick)")
-        self._m_tokens = _metrics.get_or_create(
-            _metrics.Counter, "serve_decode_tokens",
-            "tokens emitted by the decode engine")
-        self._m_chunk_ms = _metrics.get_or_create(
-            _metrics.Histogram, "serve_decode_chunk_latency_ms",
-            "wall latency of one fused decode chunk (ms)",
-            boundaries=[1, 2, 5, 10, 20, 50, 100, 200, 500, 1000])
         # serving SLO histograms (TTFT / TPOT / occupancy / KV utilization),
         # tagged by engine flavor so paged and dense replicas in one process
         # keep separate series; stats()["slo"] summarizes via
@@ -579,13 +591,12 @@ class LLMServer:
         st["host_syncs"] += 1
         st["tokens"] += tokens
         st["chunk_s_total"] += dt_s
+        st["decode_steps"] += chunk or 1     # a speculative tick is one step
+        st["active_slot_syncs"] += len(self._active)
         if chunk is not None:
             st["chunk_sizes"][chunk] = st["chunk_sizes"].get(chunk, 0) + 1
-        self._m_syncs.inc()
         if tokens:
-            self._m_tokens.inc(tokens)
             self._m_tpot.observe(dt_s / tokens * 1e3, tags=self._slo_tags)
-        self._m_chunk_ms.observe(dt_s * 1e3)
         eng_tags = {"engine": self._slo_tags["engine"]}
         cap = len(self._active) + len(self._free)
         if cap:
@@ -595,15 +606,6 @@ class LLMServer:
             self._m_kv_util.observe(
                 self.page_mgr.pages_in_use / self.page_mgr.num_pages,
                 tags=eng_tags)
-        from ray_tpu.util import tracing
-        if tracing.enabled():
-            # one span per device round trip — the decode timeline shows
-            # chunked ticks (N tokens / sync) next to the task spans
-            tracing.record_span(
-                "serve.decode_chunk", "serve", tracing.current_trace_id(),
-                tracing.new_span_id(), None, time.time() - dt_s, dt_s,
-                args={"tokens": tokens, "chunk": chunk,
-                      "batch": len(self._active)})
 
     def reconfigure(self, user_config: Optional[Dict[str, Any]]):
         """Serve `user_config` hook (replica.py calls this at deployment
@@ -691,6 +693,7 @@ class LLMServer:
         Returns (slot_idx, cached_prefix_tokens)."""
         import jax.numpy as jnp
 
+        t_in = time.perf_counter()
         if total_len > self.config.max_seq_len:
             raise ValueError(
                 f"request needs {total_len} tokens but max_seq_len is "
@@ -722,28 +725,37 @@ class LLMServer:
         while not self._free or not fits():
             self._capacity_event.clear()
             await self._capacity_event.wait()
+        # the wait straddles awaits and other tasks' phases, so it is a
+        # counter and not an annotation
+        st = self._decode_stats
+        waited = time.perf_counter() - t_in
+        st["admitted"] += 1
+        st["slot_wait_s"] += waited
+        st["slot_wait_max_s"] = max(st["slot_wait_max_s"], waited)
         slot_idx = self._free.pop()
         self._req_counter += 1
         cached = 0
         try:
-            if mgr is not None:
-                if use_prefix and self.config.prefix_cache:
-                    row, cached = mgr.allocate_prefix(
-                        slot_idx, list(prompt_ids), total_len)
-                    self._flush_restored_pages()
-                else:
-                    row = mgr.allocate(slot_idx, total_len)
-                # lengths[slot] must point PAST the shared prefix before the
-                # next decode tick: write_layer_tokens writes every row at
-                # its length each tick, and a 0 here would land garbage KV
-                # at position 0 of a SHARED page — corrupting the cached
-                # prefix for every borrower. At `cached` the stray write
-                # hits the first FRESH page and prefill chunk 1 overwrites
-                # it (same contract as the uncached pos-0 write).
-                self.cache = self.cache.replace(
-                    block_tables=self.cache.block_tables.at[slot_idx].set(
-                        jnp.asarray(row, jnp.int32)),
-                    lengths=self.cache.lengths.at[slot_idx].set(cached))
+            with phase(self._phases, "admit_allocate"):   # no await inside
+                if mgr is not None:
+                    if use_prefix and self.config.prefix_cache:
+                        row, cached = mgr.allocate_prefix(
+                            slot_idx, list(prompt_ids), total_len)
+                        self._flush_restored_pages()
+                    else:
+                        row = mgr.allocate(slot_idx, total_len)
+                    # lengths[slot] must point PAST the shared prefix before
+                    # the next decode tick: write_layer_tokens writes every
+                    # row at its length each tick, and a 0 here would land
+                    # garbage KV at position 0 of a SHARED page — corrupting
+                    # the cached prefix for every borrower. At `cached` the
+                    # stray write hits the first FRESH page and prefill chunk
+                    # 1 overwrites it (same contract as the uncached pos-0
+                    # write).
+                    self.cache = self.cache.replace(
+                        block_tables=self.cache.block_tables.at[slot_idx].set(
+                            jnp.asarray(row, jnp.int32)),
+                        lengths=self.cache.lengths.at[slot_idx].set(cached))
         except BaseException:
             self._release_slot(slot_idx)
             raise
@@ -776,6 +788,10 @@ class LLMServer:
         else:
             self.cache, last_logits = self._prefill(*args)
         job.pos += n
+        st = self._decode_stats
+        st["prefill_chunks"] += 1
+        st["prefill_tokens"] += n
+        st["prefill_padded_tokens"] += bucket
         return last_logits if final else None
 
     @staticmethod
@@ -866,9 +882,12 @@ class LLMServer:
         synchronously inside eviction — the extraction must complete
         before the pool page can be reused by another request."""
         import jax
-        k, v = jax.device_get((self.cache.k_pages[:, :, pid],
-                               self.cache.v_pages[:, :, pid]))
-        return self._kv_stash.put(np.asarray(k), np.asarray(v))
+        with phase(self._phases, "demote"):
+            k, v = jax.device_get((self.cache.k_pages[:, :, pid],
+                                   self.cache.v_pages[:, :, pid]))
+            self._decode_stats["demote_bytes"] += k.nbytes + v.nbytes
+            with phase(self._phases, "demote_stash"):
+                return self._kv_stash.put(np.asarray(k), np.asarray(v))
 
     def _restore_page(self, handle: Dict[str, Any], pid: int) -> bool:
         """radix restore_cb: fetch the demoted page's KV (bit-exact — the
@@ -876,8 +895,9 @@ class LLMServer:
         lands every staged page in one batched scatter right after the
         allocation. A per-page .at[].set would rewrite the whole pool
         buffer per page, making restore cost rival the prefill it avoids."""
-        k, v = self._kv_stash.get(handle)
-        self._pending_restores.append((pid, k, v))
+        with phase(self._phases, "restore"):
+            k, v = self._kv_stash.get(handle)
+            self._pending_restores.append((pid, k, v))
         return True
 
     def _flush_restored_pages(self) -> None:
@@ -887,15 +907,16 @@ class LLMServer:
         if not self._pending_restores:
             return
         import jax.numpy as jnp
-        staged, self._pending_restores = self._pending_restores, []
-        pids = np.array([p for p, _, _ in staged], dtype=np.int32)
-        ks = jnp.moveaxis(
-            jnp.asarray(np.stack([k for _, k, _ in staged])), 0, 2)
-        vs = jnp.moveaxis(
-            jnp.asarray(np.stack([v for _, _, v in staged])), 0, 2)
-        self.cache = self.cache.replace(
-            k_pages=self.cache.k_pages.at[:, :, pids].set(ks),
-            v_pages=self.cache.v_pages.at[:, :, pids].set(vs))
+        with phase(self._phases, "restore"):
+            staged, self._pending_restores = self._pending_restores, []
+            pids = np.array([p for p, _, _ in staged], dtype=np.int32)
+            ks = jnp.moveaxis(
+                jnp.asarray(np.stack([k for _, k, _ in staged])), 0, 2)
+            vs = jnp.moveaxis(
+                jnp.asarray(np.stack([v for _, _, v in staged])), 0, 2)
+            self.cache = self.cache.replace(
+                k_pages=self.cache.k_pages.at[:, :, pids].set(ks),
+                v_pages=self.cache.v_pages.at[:, :, pids].set(vs))
 
     def _drop_page(self, handle: Dict[str, Any]) -> None:
         self._kv_stash.drop(handle)
@@ -949,89 +970,90 @@ class LLMServer:
             return (len(slot.generated) >= slot.max_tokens or hit_eos
                     or total >= self.config.max_seq_len)
 
+        ph, st = self._phases, self._decode_stats
         while self._active or self._prefill_q:
+            t_tick = time.perf_counter()
             if self._active:
-                drafts = self._spec_drafts()
-                mask = np.zeros((B,), bool)
-                temps = np.zeros((B,), np.float32)
-                top_ps = np.ones((B,), np.float32)
-                top_ks = np.zeros((B,), np.int32)
-                for i, slot in self._active.items():
-                    mask[i] = True
-                    temps[i] = slot.temperature
-                    top_ps[i] = slot.top_p
-                    top_ks[i] = slot.top_k
-                any_logp = any(s.want_logprobs
-                               for s in self._active.values())
-                finished = []
-                t0 = time.perf_counter()
-                if drafts is not None:
-                    # speculative tick: one [B, K+1] verify forward
-                    self._sample_key, sub = jax.random.split(
-                        self._sample_key)
-                    toks = np.zeros((B, K + 1), np.int32)
+                with phase(ph, "decode_build"):
+                    drafts = self._spec_drafts()
+                    mask = np.zeros((B,), bool)
+                    temps = np.zeros((B,), np.float32)
+                    top_ps = np.ones((B,), np.float32)
+                    top_ks = np.zeros((B,), np.int32)
                     for i, slot in self._active.items():
-                        toks[i, 0] = slot.generated[-1]
-                        d = drafts.get(i, [])
-                        toks[i, 1:1 + len(d)] = d
-                    self.cache, emit, n_emit, logp = self._spec(
-                        self.params, self.cache, jnp.asarray(toks),
-                        jnp.asarray(mask), sub, jnp.asarray(temps),
-                        jnp.asarray(top_ps), jnp.asarray(top_ks), any_logp)
-                    emit, n_emit, logp = (
-                        np.asarray(x) for x in jax.device_get(
-                            (emit, n_emit, logp)))
-                    st = self._spec_stats
-                    st["spec_ticks"] += 1
-                    st["drafted"] += sum(len(d) for d in drafts.values())
-                    emitted = 0
-                    for i, slot in self._active.items():
-                        cnt = int(n_emit[i])
-                        if i in drafts:
-                            # clip: a short draft's zero-padding can
-                            # "accidentally" match argmax (still exact
-                            # output) but must not count as acceptance
-                            st["accepted"] += min(cnt - 1, len(drafts[i]))
-                        for j in range(cnt):
-                            emitted += 1
-                            if emit_one(slot, int(emit[i, j]),
-                                        float(logp[i, j])):
-                                finished.append(i)
-                                break
-                    self._note_sync(emitted, time.perf_counter() - t0)
-                else:
-                    # fused multi-token decode: n steps on device, ONE sync.
-                    # The chunk fn splits the sample key once per step and
-                    # returns the carried key — the same key stream the
-                    # per-step loop consumed, so chunking never changes
-                    # sampled outputs.
-                    n = self._chunk_len()
-                    last = np.zeros((B,), np.int32)
-                    eos = np.full((B,), -1, np.int32)   # -1 never matches
-                    budget = np.zeros((B,), np.int32)
-                    room = np.zeros((B,), np.int32)
-                    for i, slot in self._active.items():
-                        last[i] = slot.generated[-1]
-                        if slot.eos_id is not None:
-                            eos[i] = slot.eos_id
-                        budget[i] = slot.max_tokens - len(slot.generated)
-                        room[i] = self.config.max_seq_len - (
-                            slot.prompt_len + len(slot.generated))
-                    self.cache, toks, n_valid, logp, self._sample_key = \
-                        self._decode_chunk(
+                        mask[i] = True
+                        temps[i] = slot.temperature
+                        top_ps[i] = slot.top_p
+                        top_ks[i] = slot.top_k
+                    any_logp = any(s.want_logprobs
+                                   for s in self._active.values())
+                    finished = []
+                    t0 = time.perf_counter()
+                    if drafts is not None:
+                        n = None
+                        last = np.zeros((B, K + 1), np.int32)
+                        for i, slot in self._active.items():
+                            last[i, 0] = slot.generated[-1]
+                            d = drafts.get(i, [])
+                            last[i, 1:1 + len(d)] = d
+                    else:
+                        n = self._chunk_len()
+                        last = np.zeros((B,), np.int32)
+                        eos = np.full((B,), -1, np.int32)  # -1 never matches
+                        budget = np.zeros((B,), np.int32)
+                        room = np.zeros((B,), np.int32)
+                        for i, slot in self._active.items():
+                            last[i] = slot.generated[-1]
+                            if slot.eos_id is not None:
+                                eos[i] = slot.eos_id
+                            budget[i] = slot.max_tokens - len(slot.generated)
+                            room[i] = self.config.max_seq_len - (
+                                slot.prompt_len + len(slot.generated))
+                with phase(ph, "decode_dispatch"):
+                    if drafts is not None:
+                        # speculative tick: one [B, K+1] verify forward
+                        self._sample_key, sub = jax.random.split(
+                            self._sample_key)
+                        self.cache, toks, n_valid, logp = self._spec(
+                            self.params, self.cache, jnp.asarray(last),
+                            jnp.asarray(mask), sub, jnp.asarray(temps),
+                            jnp.asarray(top_ps), jnp.asarray(top_ks),
+                            any_logp)
+                    else:
+                        # fused multi-token decode: n steps on device, ONE
+                        # sync. The chunk fn splits the sample key once per
+                        # step and returns the carried key — the same key
+                        # stream the per-step loop consumed, so chunking
+                        # never changes sampled outputs.
+                        (self.cache, toks, n_valid, logp,
+                         self._sample_key) = self._decode_chunk(
                             self.params, self.cache, jnp.asarray(last),
                             jnp.asarray(mask), self._sample_key,
                             jnp.asarray(temps), jnp.asarray(top_ps),
                             jnp.asarray(top_ks), jnp.asarray(eos),
                             jnp.asarray(budget), jnp.asarray(room),
                             any_logp, n)
+                with phase(ph, "decode_sync"):
+                    # host blocked, device busy: the one sync of the tick
                     toks, n_valid, logp = (
                         np.asarray(x) for x in jax.device_get(
                             (toks, n_valid, logp)))
-                    self._spec_stats["decode_ticks"] += 1
+                with phase(ph, "decode_emit"):
+                    sp = self._spec_stats
+                    if drafts is not None:
+                        sp["spec_ticks"] += 1
+                        sp["drafted"] += sum(len(d) for d in drafts.values())
+                    else:
+                        sp["decode_ticks"] += 1
                     emitted = 0
                     for i, slot in self._active.items():
-                        for j in range(int(n_valid[i])):
+                        cnt = int(n_valid[i])
+                        if drafts is not None and i in drafts:
+                            # clip: a short draft's zero-padding can
+                            # "accidentally" match argmax (still exact
+                            # output) but must not count as acceptance
+                            sp["accepted"] += min(cnt - 1, len(drafts[i]))
+                        for j in range(cnt):
                             emitted += 1
                             if emit_one(slot, int(toks[i, j]),
                                         float(logp[i, j])):
@@ -1039,16 +1061,17 @@ class LLMServer:
                                 break
                     self._note_sync(emitted, time.perf_counter() - t0,
                                     chunk=n)
-                for i in finished:
-                    slot = self._active.pop(i)
-                    slot.done_event.set()
-                    if slot.stream_queue is not None:
-                        slot.stream_queue.put_nowait(None)
-                    self._release_slot(i)
+                    for i in finished:
+                        slot = self._active.pop(i)
+                        slot.done_event.set()
+                        if slot.stream_queue is not None:
+                            slot.stream_queue.put_nowait(None)
+                        self._release_slot(i)
             if self._prefill_q:
                 job = self._prefill_q[0]
                 try:
-                    last_logits = self._prefill_chunk(job)
+                    with phase(ph, "prefill_dispatch"):
+                        last_logits = self._prefill_chunk(job)
                 except BaseException as e:  # noqa: BLE001 - fail the request
                     self._prefill_q.popleft()
                     job.slot.error = e
@@ -1059,29 +1082,39 @@ class LLMServer:
                     self._release_slot(job.slot_idx)
                 else:
                     if last_logits is not None:  # prompt fully prefilled
-                        self._prefill_q.popleft()
-                        if (self.page_mgr is not None
-                                and self.config.prefix_cache):
-                            # publish this prompt's full pages for reuse
-                            self.page_mgr.register_prefix(
-                                job.slot_idx, job.prompt.tolist())
-                        self._sample_key, sub = jax.random.split(
-                            self._sample_key)
-                        first, flogp = self._sample_first(
-                            last_logits, sub,
-                            jnp.float32(job.slot.temperature),
-                            jnp.float32(job.slot.top_p),
-                            jnp.int32(job.slot.top_k),
-                            job.slot.want_logprobs)
-                        first = int(first)
-                        job.slot.generated.append(first)
-                        if job.slot.want_logprobs:
-                            job.slot.logprobs.append(float(flogp))
-                        if job.slot.stream_queue is not None:
-                            job.slot.stream_queue.put_nowait(first)
-                        self._active[job.slot_idx] = job.slot
-                        job.slot.first_token.set()
-            await asyncio.sleep(0)  # let admits interleave between ticks
+                        with phase(ph, "prefill_first_token"):
+                            self._first_token(job, last_logits)
+            with phase(ph, "yield"):
+                # let admits interleave between ticks: their phases
+                # (admit_allocate, evict, demote, restore) nest in this one,
+                # beside the stream flushes and the replica's calls
+                await asyncio.sleep(0)
+            st["loop_s"] += time.perf_counter() - t_tick
+            st["ticks"] += 1
+
+    def _first_token(self, job: _PrefillJob, last_logits):
+        """The prompt is fully prefilled: publish its pages, sample the
+        first token (the `int()` is a host sync) and activate the slot."""
+        import jax
+        import jax.numpy as jnp
+
+        self._prefill_q.popleft()
+        if self.page_mgr is not None and self.config.prefix_cache:
+            # publish this prompt's full pages for reuse
+            self.page_mgr.register_prefix(job.slot_idx, job.prompt.tolist())
+        self._sample_key, sub = jax.random.split(self._sample_key)
+        first, flogp = self._sample_first(
+            last_logits, sub, jnp.float32(job.slot.temperature),
+            jnp.float32(job.slot.top_p), jnp.int32(job.slot.top_k),
+            job.slot.want_logprobs)
+        first = int(first)
+        job.slot.generated.append(first)
+        if job.slot.want_logprobs:
+            job.slot.logprobs.append(float(flogp))
+        if job.slot.stream_queue is not None:
+            job.slot.stream_queue.put_nowait(first)
+        self._active[job.slot_idx] = job.slot
+        job.slot.first_token.set()
 
     # -- public api ----------------------------------------------------------
     async def generate(self, prompt_ids: List[int], max_tokens: int = 32,
@@ -1212,6 +1245,23 @@ class LLMServer:
             "chunk_ms_avg": round(
                 st["chunk_s_total"] / max(st["host_syncs"], 1) * 1e3, 3),
             "chunk_sizes": dict(st["chunk_sizes"]),
+            # the loop's own account of its time (LOOP_PHASES sum to loop_s,
+            # NESTED_PHASES are their children) and of its work
+            "loop_s": st["loop_s"], "ticks": st["ticks"],
+            "phase_s": dict(self._phases.seconds),
+            "phase_n": dict(self._phases.counts),
+            **{k: st[k] for k in (
+                "decode_steps", "active_slot_syncs", "prefill_chunks",
+                "prefill_tokens", "prefill_padded_tokens", "admitted",
+                "slot_wait_s", "slot_wait_max_s", "demote_bytes")},
+            # the page manager's and the stash's own tallies, read and not
+            # counted twice (0 where the engine has no such tier)
+            **{k: getattr(self.page_mgr, k, 0) for k in (
+                "evicted_pages", "demoted_pages", "restored_pages",
+                "demote_failed")},
+            "demote_last_error": getattr(self.page_mgr, "demote_last_error",
+                                         None),
+            "stash_spilled_pages": getattr(self._kv_stash, "spilled_pages", 0),
         }
         if self.config.speculate > 0:
             st = dict(self._spec_stats)

@@ -41,6 +41,7 @@ import collections
 import os
 
 from ray_tpu.ops.paged_attention import PageManager
+from ray_tpu.util.tracing import PhaseTotals, phase
 
 
 def radix_enabled() -> bool:
@@ -93,12 +94,15 @@ class RadixPageManager(PageManager):
       drop_cb(handle)
           The demoted payload will never be restored (cap overflow or node
           removal); release its storage.
+      phases
+          The engine's `util.tracing.PhaseTotals`: one `_evict_to_free` call
+          is its `evict` phase (the demotions nest inside it).
     """
 
     def __init__(self, num_pages: int, page_size: int, batch_slots: int,
                  max_pages_per_seq: int, prefix_cache: bool = True,
                  demote_cb=None, restore_cb=None, drop_cb=None,
-                 demote_cap: int = None):
+                 demote_cap: int = None, phases: PhaseTotals = None):
         super().__init__(num_pages, page_size, batch_slots,
                          max_pages_per_seq, prefix_cache)
         self._root = _Node((), None)
@@ -116,6 +120,9 @@ class RadixPageManager(PageManager):
         self.evicted_pages = 0         # pages taken off the tree by the LRU
         self.demoted_pages = 0         # of those, extracted to the store
         self.restored_pages = 0        # demoted pages pulled back on a hit
+        self.demote_failed = 0         # demote_cb raised: page discarded
+        self.demote_last_error = None  # repr of the last such exception
+        self._phases = phases or PhaseTotals("engine", ("evict",))
 
     # ------------------------------------------------------------- tree walk
     def _page_tuples(self, prompt_ids) -> list:
@@ -178,8 +185,10 @@ class RadixPageManager(PageManager):
         if node.handle is None and self.demote_cb is not None:
             try:
                 node.handle = self.demote_cb(pid, node)
-            except Exception:  # noqa: BLE001 - demotion is best-effort
+            except Exception as e:  # noqa: BLE001 - demotion is best-effort
                 node.handle = None
+                self.demote_failed += 1
+                self.demote_last_error = repr(e)
         if node.handle is not None:
             self.demoted_pages += 1
             _count("radix_demoted_pages")
@@ -206,31 +215,32 @@ class RadixPageManager(PageManager):
         those whose node has no resident children are candidates, so an
         interior page is never freed while a descendant still depends on
         it for prefix matching."""
-        while len(self.free_pages) < need and self._lru:
-            victim = None
-            for pid in self._lru:  # oldest first
-                node = self._node_of.get(pid)
-                if node is None or node.resident_children == 0:
-                    victim = pid
-                    break
-            if victim is None:
-                # borrowed pages pin their whole ancestor chain, so a
-                # resident leaf is always in the LRU before its ancestors;
-                # reaching here means the invariant broke — fail safe by
-                # taking the oldest (its node becomes a hole, walks stop
-                # there, nothing dangles).
-                victim, _ = next(iter(self._lru.items()))
-            node = self._node_of.get(victim)
-            if node is not None:
-                self._evict_node(victim, node)
-            else:  # flat-cache page (shouldn't happen under radix) — discard
-                self._lru.pop(victim, None)
-                key = self._key_of.pop(victim, None)
-                if key is not None:
-                    self._by_key.pop(key, None)
-                self._refs.pop(victim, None)
-                self.free_pages.append(victim)
-        return len(self.free_pages) >= need
+        with phase(self._phases, "evict"):
+            while len(self.free_pages) < need and self._lru:
+                victim = None
+                for pid in self._lru:  # oldest first
+                    node = self._node_of.get(pid)
+                    if node is None or node.resident_children == 0:
+                        victim = pid
+                        break
+                if victim is None:
+                    # borrowed pages pin their whole ancestor chain, so a
+                    # resident leaf is always in the LRU before its ancestors;
+                    # reaching here means the invariant broke — fail safe by
+                    # taking the oldest (its node becomes a hole, walks stop
+                    # there, nothing dangles).
+                    victim, _ = next(iter(self._lru.items()))
+                node = self._node_of.get(victim)
+                if node is not None:
+                    self._evict_node(victim, node)
+                else:  # flat-cache page (shouldn't be, under radix): discard
+                    self._lru.pop(victim, None)
+                    key = self._key_of.pop(victim, None)
+                    if key is not None:
+                        self._by_key.pop(key, None)
+                    self._refs.pop(victim, None)
+                    self.free_pages.append(victim)
+            return len(self.free_pages) >= need
 
     # ------------------------------------------------------------- admission
     def can_fit_prompt(self, prompt_ids, n_tokens: int) -> bool:

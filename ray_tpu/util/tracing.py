@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 import zlib
@@ -52,7 +53,7 @@ __all__ = [
     "trace_id_for", "stamp", "record_span", "span", "set_current",
     "get_current", "current_trace_id", "events", "drain", "clear",
     "to_chrome", "summary", "set_process_label", "record_window",
-    "ship_window", "take_shipped", "bubble_stats",
+    "ship_window", "take_shipped", "bubble_stats", "PhaseTotals", "phase",
 ]
 
 _lock = threading.Lock()
@@ -290,6 +291,72 @@ def span(name: str, cat: str = "app", trace_id: Optional[str] = None,
     finally:
         record_span(name, cat, trace_id, sid, parent_id, t0,
                     time.monotonic() - m0, tid=tid, args=args)
+
+
+class PhaseTotals:
+    """The accumulator of `phase`, owned by the caller (an engine's
+    `stats()`): seconds and entries by key, every key present from
+    construction at 0 so that a reader can take the delta of two snapshots.
+    A phase `key` is annotated as `<prefix>.<key>`."""
+
+    __slots__ = ("seconds", "counts", "names")
+
+    def __init__(self, prefix: str, keys):
+        self.seconds: Dict[str, float] = dict.fromkeys(keys, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(keys, 0)
+        self.names: Dict[str, str] = {k: f"{prefix}.{k}" for k in keys}
+
+
+# jax.profiler.TraceAnnotation, resolved once and only in a process that has
+# ALREADY imported jax: the driver, the controller and the node agent import
+# this module and must never load jax (on libtpu that takes the chip from the
+# workers).
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class phase:
+    """One stretch of a loop's host time, to two sinks: a profiler annotation
+    (the ring above is on this process's own `time.time()`; a span that is to
+    explain a gap on the DEVICE timeline has to sit on the profiler's clock,
+    in the host plane of the same trace) and `totals.seconds[key]` /
+    `totals.counts[key]` on `time.perf_counter()`. Nothing goes into the ring
+    and `RAY_TPU_TRACE` is not asked: the counters are exact whatever it
+    says, and with no profiler session an annotation is a flag test. Phases
+    nest; all of one `totals` run on one thread, and an `await` inside one
+    means the other tasks' phases are its children."""
+
+    __slots__ = ("_totals", "_key", "_ann", "_t0")
+
+    def __init__(self, totals: PhaseTotals, key: str):
+        self._totals = totals
+        self._key = key
+
+    def __enter__(self):
+        cls = _annotation or _trace_annotation()
+        if cls is None:
+            self._ann = None
+        else:
+            self._ann = cls(self._totals.names[self._key])
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        dt = time.perf_counter() - self._t0
+        totals, key = self._totals, self._key
+        totals.seconds[key] += dt
+        totals.counts[key] += 1
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        return False
 
 
 def _format(raw) -> Dict[str, Any]:

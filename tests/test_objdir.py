@@ -273,7 +273,11 @@ def test_make_directory_native_when_available(monkeypatch):
         d.close()
 
 
-def test_directory_singleton_reset():
+def test_directory_singleton_reset(monkeypatch):
+    # put back afterwards what this process had: a session that an earlier
+    # test file started on this xdist worker keeps resolving its object
+    # metadata through the singleton, and must not be cut off from it
+    monkeypatch.setattr(objdir, "_dir", objdir._dir)
     objdir.reset_directory()
     d1 = objdir.get_directory()
     assert objdir.get_directory() is d1
