@@ -30,6 +30,7 @@ lands the copy under the wire id.
 """
 
 import asyncio
+import concurrent.futures
 import itertools
 import os
 import socket as _socket
@@ -39,6 +40,7 @@ import numpy as np
 
 from ray_tpu._private.object_store import StoreClient, seg_name
 from ray_tpu.util import metrics as _metrics
+from ray_tpu.util.tracing import PhaseTotals, phase
 
 _proc_tag = os.urandom(4).hex()          # 8 chars, fresh per process
 _ship_counter = itertools.count(1)
@@ -190,9 +192,18 @@ class KVPageStash:
     ``StoreClient.restore``. Per-tier occupancy is exported on the
     ``store_tier_*`` gauges under the ``owner=kv_stash`` series.
 
+    The store and both tier tables belong to ONE worker thread: `put`,
+    `get`, `drop` and `close` all run there, in the order they were
+    called, so nothing here takes a lock. `put` returns at once (the
+    engine loop hands over gathered pages whose transfer to the host is
+    still in flight and goes on); `get` and `close` wait for their turn,
+    so everything submitted before them has run. The caller keeps the
+    arrays it gave `put` until the returned future is done: until then
+    they are the only copy.
+
     Handles are content-immutable (a prefix page's tokens fully determine
     its KV), so a handle stays valid across any number of demote/restore
-    round trips."""
+    round trips, and is valid from `new_handle` on."""
 
     def __init__(self, budget_bytes: Optional[int] = None):
         import collections
@@ -206,6 +217,11 @@ class KVPageStash:
         self.shm_bytes = 0
         self.disk_bytes = 0
         self.spilled_pages = 0     # segments the budget moved to disk, ever
+        # the worker's busy seconds, and its `stash.put` span in a
+        # profiler trace (a PhaseTotals of its own: one runs on one thread)
+        self.phases = PhaseTotals("stash", ("put",))
+        self._worker = concurrent.futures.ThreadPoolExecutor(
+            1, thread_name_prefix="kv-stash")
 
     def _gauge(self):
         try:
@@ -223,27 +239,83 @@ class KVPageStash:
         except Exception:  # noqa: BLE001 - accounting never breaks serving
             pass
 
-    def put(self, k_page: np.ndarray, v_page: np.ndarray) -> Dict[str, Any]:
-        """Seal one evicted page's KV (k block then v block, C-contiguous)
-        and return its restore handle."""
+    # -- the caller's side: each submits to the worker ----------------------
+    def new_handle(self, page_shape, dtype) -> Dict[str, Any]:
+        """The restore handle of a page that `put` will be given later."""
+        dtype = np.dtype(dtype)
+        nbytes = 2 * int(np.prod(page_shape)) * dtype.itemsize
+        return {"oid": f"kvd{_proc_tag}{next(self._seq):08x}",
+                "nbytes": nbytes, "shape": list(page_shape),
+                "dtype": dtype.name}
+
+    def put(self, handles: List[Dict[str, Any]], k_pages, v_pages
+            ) -> concurrent.futures.Future:
+        """Seal page i of `k_pages` / `v_pages` ([G, *page_shape], numpy
+        or device arrays whose copy to the host may still be in flight)
+        under `handles[i]`; rows past `len(handles)` are padding. Returns
+        at once: the future's result is one entry a handle, None or the
+        exception that kept that page out of the stash."""
+        return self._worker.submit(self._put, handles, k_pages, v_pages)
+
+    def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, np.ndarray]:
+        """Restore one page's (k, v), promoting a disk-resident segment
+        back to shm first. Byte-exact: the arrays round-trip untouched."""
+        return self._worker.submit(self._get, handle).result()
+
+    def drop(self, handle: Dict[str, Any]) -> None:
+        """The handle will never be restored; free its tier residency
+        (after the put that fills it, if that is still queued)."""
+        self._worker.submit(self._drop, handle)
+
+    def close(self) -> None:
+        """Runs what is queued, then gives back every segment and spill
+        file and ends the worker thread."""
+        try:
+            done = self._worker.submit(self._drop_all)
+        except RuntimeError:    # closed before
+            return
+        try:
+            done.result()
+        finally:
+            self._worker.shutdown()
+
+    def tier_stats(self) -> Dict[str, int]:
+        return {"shm_objects": len(self._shm), "shm_bytes": self.shm_bytes,
+                "disk_objects": len(self._disk),
+                "disk_bytes": self.disk_bytes}
+
+    # -- the worker's side ----------------------------------------------------
+    def _put(self, handles, k_pages, v_pages) -> List[Optional[Exception]]:
+        with phase(self.phases, "put"):
+            # waits for the transfer
+            k_pages, v_pages = np.asarray(k_pages), np.asarray(v_pages)
+            errors: List[Optional[Exception]] = []
+            for i, handle in enumerate(handles):
+                try:
+                    self._seal(handle, k_pages[i], v_pages[i])
+                    errors.append(None)
+                except Exception as e:  # noqa: BLE001 - the caller counts it
+                    errors.append(e)
+            self._gauge()
+            return errors
+
+    def _seal(self, handle, k_page: np.ndarray, v_page: np.ndarray) -> None:
+        """One evicted page's KV (k block then v block, C-contiguous) into
+        a sealed segment."""
         k_page = np.ascontiguousarray(k_page)
         v_page = np.ascontiguousarray(v_page)
-        nbytes = k_page.nbytes + v_page.nbytes
-        oid = f"kvd{_proc_tag}{next(self._seq):08x}"
-        handle = self.store.create_writable(oid, nbytes)
+        oid, nbytes = handle["oid"], handle["nbytes"]
+        buf = self.store.create_writable(oid, nbytes)
         try:
-            handle.view[:k_page.nbytes] = _as_bytes(k_page)
-            handle.view[k_page.nbytes:nbytes] = _as_bytes(v_page)
+            buf.view[:k_page.nbytes] = _as_bytes(k_page)
+            buf.view[k_page.nbytes:nbytes] = _as_bytes(v_page)
         except BaseException:
-            handle.abort()
+            buf.abort()
             raise
-        handle.seal()
+        buf.seal()
         self._shm[oid] = nbytes
         self.shm_bytes += nbytes
         self._enforce_budget()
-        self._gauge()
-        return {"oid": oid, "nbytes": nbytes,
-                "shape": list(k_page.shape), "dtype": k_page.dtype.name}
 
     def _enforce_budget(self):
         """shm → disk rung: spill oldest stash segments past the budget."""
@@ -258,9 +330,7 @@ class KVPageStash:
             self.disk_bytes += nbytes
             self.spilled_pages += 1
 
-    def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, np.ndarray]:
-        """Restore one page's (k, v), promoting a disk-resident segment
-        back to shm first. Byte-exact: the arrays round-trip untouched."""
+    def _get(self, handle) -> Tuple[np.ndarray, np.ndarray]:
         oid = handle["oid"]
         dtype = _np_dtype(handle["dtype"])
         if oid in self._disk:
@@ -281,8 +351,7 @@ class KVPageStash:
                           offset=half)
         return k.reshape(shape), v.reshape(shape)
 
-    def drop(self, handle: Dict[str, Any]) -> None:
-        """The handle will never be restored; free its tier residency."""
+    def _drop(self, handle) -> None:
         oid = handle["oid"]
         if oid in self._shm:
             self.shm_bytes -= self._shm.pop(oid)
@@ -299,16 +368,9 @@ class KVPageStash:
                 pass
         self._gauge()
 
-    def tier_stats(self) -> Dict[str, int]:
-        return {"shm_objects": len(self._shm), "shm_bytes": self.shm_bytes,
-                "disk_objects": len(self._disk),
-                "disk_bytes": self.disk_bytes}
-
-    def close(self) -> None:
-        for oid in list(self._shm):
-            self.drop({"oid": oid})
-        for oid in list(self._disk):
-            self.drop({"oid": oid})
+    def _drop_all(self) -> None:
+        for oid in list(self._shm) + list(self._disk):
+            self._drop({"oid": oid})
 
 
 class KVDataServer:

@@ -25,6 +25,8 @@ kernel over a real block table is the round-2 upgrade path.
 """
 
 import asyncio
+import collections
+import concurrent.futures
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional
@@ -37,13 +39,24 @@ from ray_tpu.util.tracing import PhaseTotals, phase
 # of these phases (util.tracing.phase: a profiler annotation `engine.<key>`
 # plus stats()["decode"]["phase_s"/"phase_n"]). LOOP_PHASES tile one iteration
 # of the tick loop and sum to `loop_s`; NESTED_PHASES are their children,
-# mostly inside `yield`: admit_allocate holds evict, which holds demote, which
-# holds demote_stash. Keys carry no dot: readers split counter paths on ".".
+# mostly inside `yield`: admit_allocate holds evict, which holds demote (one
+# entry an eviction pass), which holds demote_stash. Keys carry no dot:
+# readers split counter paths on ".".
 LOOP_PHASES = ("decode_build", "decode_dispatch", "decode_sync",
                "decode_emit", "prefill_dispatch", "prefill_first_token",
                "yield")
 NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
                  "restore")
+
+# Demotion of evicted prefix pages. One gather program whatever the pass
+# size: its index vector always has DEMOTE_GROUP entries, padded with the
+# reserved placeholder page 0, and a longer pass calls it again. Gathered
+# pages are staged (on the device and, once copied, on the host) until the
+# stash's thread has sealed them: over STAGED_CAP_BYTES the loop waits for
+# the oldest hand-off. Constants, in pages and bytes, so every page size is
+# covered by one path.
+DEMOTE_GROUP = 8
+STAGED_CAP_BYTES = 128 << 20
 
 
 @dataclasses.dataclass
@@ -254,6 +267,7 @@ class LLMServer:
                 if kv_demote_enabled():
                     self._kv_stash = KVPageStash()
                     hooks.update(demote_cb=self._demote_page,
+                                 demote_flush_cb=self._demote_pass,
                                  restore_cb=self._restore_page,
                                  drop_cb=self._drop_page)
             self.page_mgr = _radix.make_page_manager(
@@ -299,7 +313,17 @@ class LLMServer:
             "decode_steps": 0, "active_slot_syncs": 0,
             "prefill_chunks": 0, "prefill_tokens": 0,
             "prefill_padded_tokens": 0, "admitted": 0, "slot_wait_s": 0.0,
-            "slot_wait_max_s": 0.0, "demote_bytes": 0}
+            "slot_wait_max_s": 0.0, "demote_bytes": 0, "demote_passes": 0,
+            "demote_wait_s": 0.0, "demote_inflight_max_bytes": 0,
+            "restored_in_flight": 0}
+        # demotion in flight, loop thread only: the pass being evicted
+        # [(page id, node, handle)]; hand-offs the stash's thread has not
+        # been seen to finish, oldest first [(future, pages, nbytes)]; and
+        # the staged copy of every page in them, oid -> (k, v, row)
+        self._evicting = []
+        self._handoffs = collections.deque()
+        self._staged = {}
+        self._staged_bytes = 0
         from ray_tpu.util import metrics as _metrics
         # serving SLO histograms (TTFT / TPOT / occupancy / KV utilization),
         # tagged by engine flavor so paged and dense replicas in one process
@@ -338,7 +362,6 @@ class LLMServer:
         self._req_counter = 0
         self._tick_task = None
         self._sample_key = key
-        import collections
         self._prefill_q: "collections.deque[_PrefillJob]" = collections.deque()
         # signaled whenever capacity frees (slot or pages) — admission waits
         # on this instead of polling (VERDICT r3 weak #6: 5 ms busy-poll)
@@ -544,6 +567,20 @@ class LLMServer:
         # O(log decode_chunk), and n=1 IS the old per-step program
         self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,),
                                      static_argnums=(11, 12))
+        if self._kv_stash is not None:
+            def gather_pages(k_pages, v_pages, idx):
+                """Pool pages `idx` ([L, Kh, P, ps, D] along P) as buffers of
+                their own, page-major ([G, L, Kh, ps, D]) so that each page
+                is contiguous on the host."""
+                take = lambda pool: jnp.moveaxis(  # noqa: E731
+                    jnp.take(pool, idx, axis=2, mode="clip"), 2, 0)
+                return take(k_pages), take(v_pages)
+
+            self._gather_pages = jax.jit(gather_pages)
+            # compiled here and not at the first eviction: nothing may
+            # compile once a replica serves
+            self._gather_pages(self.cache.k_pages, self.cache.v_pages,
+                               np.zeros((DEMOTE_GROUP,), np.int32))
         # first token goes through the SAME sampling policy as later ones
         self._sample_first = jax.jit(
             lambda logits, key, t, p, k, want_logp=True: tuple(
@@ -876,27 +913,104 @@ class LLMServer:
             raise
 
     # -- tiered KV: radix demote/restore hooks (ISSUE 19) --------------------
-    def _demote_page(self, pid: int, node) -> Optional[Dict[str, Any]]:
-        """radix demote_cb: pull page `pid`'s KV ([L, Kh, ps, D] k and v
-        blocks) off the device and seal it into the stash. Runs
-        synchronously inside eviction — the extraction must complete
-        before the pool page can be reused by another request."""
-        import jax
+    def _demote_page(self, pid: int, node) -> Dict[str, Any]:
+        """radix demote_cb: note page `pid` for this pass's gather and give
+        its node the handle its KV ([L, Kh, ps, D] k and v blocks) will be
+        stashed under. Nothing leaves the device here."""
+        k_pages = self.cache.k_pages
+        handle = self._kv_stash.new_handle(
+            k_pages.shape[:2] + k_pages.shape[3:], k_pages.dtype)
+        self._evicting.append((pid, node, handle))
+        return handle
+
+    def _demote_pass(self) -> None:
+        """radix demote_flush_cb, at the end of an eviction pass: DISPATCH
+        the gather of the pass's pages out of the pool, start their copy to
+        the host and hand them to the stash's thread, which waits for the
+        copy, seals and spills. The loop waits for none of it: the gather
+        is on the device stream before the admitting request's prefill and
+        every later decode chunk, so the pool pages are free to be written
+        at once. Whatever raises here discards the pages it had not handed
+        over and is counted; serving goes on."""
+        pages, self._evicting = self._evicting, []
+        if not pages:
+            return
+        st = self._decode_stats
         with phase(self._phases, "demote"):
-            k, v = jax.device_get((self.cache.k_pages[:, :, pid],
-                                   self.cache.v_pages[:, :, pid]))
-            self._decode_stats["demote_bytes"] += k.nbytes + v.nbytes
-            with phase(self._phases, "demote_stash"):
-                return self._kv_stash.put(np.asarray(k), np.asarray(v))
+            self._reap_handoffs()
+            handed = 0
+            try:
+                groups = []
+                for i in range(0, len(pages), DEMOTE_GROUP):
+                    part = pages[i:i + DEMOTE_GROUP]
+                    idx = np.zeros((DEMOTE_GROUP,), np.int32)
+                    idx[:len(part)] = [pid for pid, _, _ in part]
+                    k, v = self._gather_pages(
+                        self.cache.k_pages, self.cache.v_pages, idx)
+                    k.copy_to_host_async()
+                    v.copy_to_host_async()
+                    groups.append((part, k, v))
+                st["demote_passes"] += 1
+                with phase(self._phases, "demote_stash"):
+                    for part, k, v in groups:
+                        self._hand_off(part, k, v)
+                        handed += len(part)
+            except Exception as e:  # noqa: BLE001 - demotion is best-effort
+                for _, node, handle in pages[handed:]:
+                    self.page_mgr.demotion_failed(node, handle, e)
+
+    def _hand_off(self, part, k, v) -> None:
+        """Give one gathered group to the stash's thread, first waiting
+        for the oldest hand-offs while the staged bytes are over the cap."""
+        st = self._decode_stats
+        nbytes = sum(handle["nbytes"] for _, _, handle in part)
+        while (self._handoffs
+               and self._staged_bytes + nbytes > STAGED_CAP_BYTES):
+            t0 = time.perf_counter()
+            concurrent.futures.wait([self._handoffs[0][0]])
+            st["demote_wait_s"] += time.perf_counter() - t0
+            self._reap_handoffs()
+        done = self._kv_stash.put([h for _, _, h in part], k, v)
+        self._handoffs.append((done, part, nbytes))
+        for row, (_, _, handle) in enumerate(part):
+            self._staged[handle["oid"]] = (k, v, row)
+        self._staged_bytes += nbytes
+        st["demote_bytes"] += nbytes
+        st["demote_inflight_max_bytes"] = max(
+            st["demote_inflight_max_bytes"], self._staged_bytes)
+
+    def _reap_handoffs(self) -> None:
+        """Let go of the staged copy of every hand-off the stash's thread
+        has finished, oldest first, and report to the page manager each
+        page that an exception over there kept out of the stash."""
+        while self._handoffs and self._handoffs[0][0].done():
+            done, part, nbytes = self._handoffs.popleft()
+            try:
+                errors = done.result()
+            except Exception as e:  # noqa: BLE001 - the transfer itself
+                errors = [e] * len(part)
+            for (_, node, handle), error in zip(part, errors):
+                del self._staged[handle["oid"]]
+                if error is not None:
+                    self.page_mgr.demotion_failed(node, handle, error)
+            self._staged_bytes -= nbytes
 
     def _restore_page(self, handle: Dict[str, Any], pid: int) -> bool:
         """radix restore_cb: fetch the demoted page's KV (bit-exact — the
-        stash round-trips raw bytes) and STAGE it; _flush_restored_pages()
-        lands every staged page in one batched scatter right after the
-        allocation. A per-page .at[].set would rewrite the whole pool
-        buffer per page, making restore cost rival the prefill it avoids."""
+        stash round-trips raw bytes, and a page still on its way there is
+        read from its staged copy, waiting for the transfer if it must) and
+        STAGE it; _flush_restored_pages() lands every staged page in one
+        batched scatter right after the allocation. A per-page .at[].set
+        would rewrite the whole pool buffer per page, making restore cost
+        rival the prefill it avoids."""
         with phase(self._phases, "restore"):
-            k, v = self._kv_stash.get(handle)
+            staged = self._staged.get(handle["oid"])
+            if staged is not None:
+                k_group, v_group, row = staged
+                k, v = np.asarray(k_group)[row], np.asarray(v_group)[row]
+                self._decode_stats["restored_in_flight"] += 1
+            else:
+                k, v = self._kv_stash.get(handle)
             self._pending_restores.append((pid, k, v))
         return True
 
@@ -1232,6 +1346,7 @@ class LLMServer:
     def stats(self) -> Dict[str, Any]:
         s = {"active": len(self._active), "free_slots": len(self._free),
              "requests": self._req_counter}
+        self._reap_handoffs()
         st = self._decode_stats
         s["decode"] = {
             "decode_chunk": self.config.decode_chunk,
@@ -1253,7 +1368,9 @@ class LLMServer:
             **{k: st[k] for k in (
                 "decode_steps", "active_slot_syncs", "prefill_chunks",
                 "prefill_tokens", "prefill_padded_tokens", "admitted",
-                "slot_wait_s", "slot_wait_max_s", "demote_bytes")},
+                "slot_wait_s", "slot_wait_max_s", "demote_bytes",
+                "demote_passes", "demote_wait_s",
+                "demote_inflight_max_bytes", "restored_in_flight")},
             # the page manager's and the stash's own tallies, read and not
             # counted twice (0 where the engine has no such tier)
             **{k: getattr(self.page_mgr, k, 0) for k in (
@@ -1262,6 +1379,9 @@ class LLMServer:
             "demote_last_error": getattr(self.page_mgr, "demote_last_error",
                                          None),
             "stash_spilled_pages": getattr(self._kv_stash, "spilled_pages", 0),
+            # the stash thread's busy seconds: its `stash.put` spans
+            "stash_worker_s": (self._kv_stash.phases.seconds["put"]
+                               if self._kv_stash is not None else 0.0),
         }
         if self.config.speculate > 0:
             st = dict(self._spec_stats)

@@ -85,9 +85,18 @@ class RadixPageManager(PageManager):
     evicts leaf-first, it just discards instead of demoting):
 
       demote_cb(page_id, node) -> handle | None
-          Extract the page's KV from the device cache into durable storage
-          (a sealed object-store segment). Called synchronously at eviction
-          time, BEFORE the pool page can be reused. None → discard.
+          Note that the page's KV is to be extracted from the device cache
+          into durable storage (a sealed object-store segment) and return
+          the handle a later `restore_cb` is given: valid at once, whether
+          or not the bytes have left the device. Called at eviction time,
+          once a page. None → discard.
+      demote_flush_cb()
+          Called once when an eviction pass ends, still inside
+          `_evict_to_free`: no caller has yet been handed a freed page, so
+          an extraction the hook DISPATCHES here runs on the device before
+          any program that writes those pages. It need not wait for it.
+          The owner reports a payload that never reached storage through
+          `demotion_failed`.
       restore_cb(handle, page_id) -> bool
           Load a demoted page's KV back into the device cache at
           `page_id`. False/raise → the node is treated as a miss.
@@ -96,18 +105,20 @@ class RadixPageManager(PageManager):
           removal); release its storage.
       phases
           The engine's `util.tracing.PhaseTotals`: one `_evict_to_free` call
-          is its `evict` phase (the demotions nest inside it).
+          is its `evict` phase (the pass's demotion nests inside it).
     """
 
     def __init__(self, num_pages: int, page_size: int, batch_slots: int,
                  max_pages_per_seq: int, prefix_cache: bool = True,
                  demote_cb=None, restore_cb=None, drop_cb=None,
-                 demote_cap: int = None, phases: PhaseTotals = None):
+                 demote_cap: int = None, phases: PhaseTotals = None,
+                 demote_flush_cb=None):
         super().__init__(num_pages, page_size, batch_slots,
                          max_pages_per_seq, prefix_cache)
         self._root = _Node((), None)
         self._node_of = {}  # page id -> resident published _Node
         self.demote_cb = demote_cb
+        self.demote_flush_cb = demote_flush_cb
         self.restore_cb = restore_cb
         self.drop_cb = drop_cb
         # demoted nodes, oldest-first (a second-chance tier, capped so the
@@ -120,7 +131,7 @@ class RadixPageManager(PageManager):
         self.evicted_pages = 0         # pages taken off the tree by the LRU
         self.demoted_pages = 0         # of those, extracted to the store
         self.restored_pages = 0        # demoted pages pulled back on a hit
-        self.demote_failed = 0         # demote_cb raised: page discarded
+        self.demote_failed = 0         # demotion raised: page discarded
         self.demote_last_error = None  # repr of the last such exception
         self._phases = phases or PhaseTotals("engine", ("evict",))
 
@@ -172,9 +183,10 @@ class RadixPageManager(PageManager):
     # -------------------------------------------------------------- eviction
     def _evict_node(self, pid: int, node):
         """Take `pid` off the tree: demote its KV if a demotion plane is
-        wired (extraction happens NOW, before the pool page is recycled),
-        else discard the node. The page returns to the free list either
-        way."""
+        wired (the node gets its handle NOW; the extraction is dispatched
+        by `demote_flush_cb` when the pass ends, before the pool page can
+        be recycled), else discard the node. The page returns to the free
+        list either way."""
         self._lru.pop(pid, None)
         self._refs.pop(pid, None)
         self._key_of.pop(pid, None)
@@ -200,6 +212,18 @@ class RadixPageManager(PageManager):
         else:
             self._maybe_remove(node)
         self.free_pages.append(pid)
+
+    def demotion_failed(self, node, handle, error):
+        """The payload behind a handle `demote_cb` returned never reached
+        storage: the page counts as discarded, and the node stops
+        advertising it (unless the handle is gone or replaced already)."""
+        self.demote_failed += 1
+        self.demote_last_error = repr(error)
+        self.demoted_pages -= 1
+        if node.handle is handle:
+            node.handle = None
+            self._demoted.pop(node, None)
+            self._maybe_remove(node)
 
     def _drop_handle(self, node):
         handle, node.handle = node.handle, None
@@ -240,6 +264,8 @@ class RadixPageManager(PageManager):
                         self._by_key.pop(key, None)
                     self._refs.pop(victim, None)
                     self.free_pages.append(victim)
+            if self.demote_flush_cb is not None:
+                self.demote_flush_cb()
             return len(self.free_pages) >= need
 
     # ------------------------------------------------------------- admission

@@ -1,0 +1,310 @@
+"""KV demotion off the engine loop (ISSUE 26): an eviction pass dispatches ONE
+kind of gather program for its pages and hands the result to the stash's own
+thread; the loop waits for neither the transfer nor the stash. What must hold:
+a restored page is byte for byte the demoted page wherever it is met (still in
+flight, in shared memory, on disk), although the admitting request's prefill
+wrote its pool slot right after the eviction; an exception on the stash's
+thread is counted and serving goes on; nothing compiles after construction.
+
+A module's servers share one event loop: the engine's `asyncio.Event`s bind to
+the loop they are first awaited on.
+"""
+
+import asyncio
+import concurrent.futures
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from ray_tpu._private import object_store
+from ray_tpu.serve import llm as llm_mod
+from ray_tpu.serve.kv_transfer import KVPageStash
+from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+PRESETS = ("tiny", "moe_tiny")
+MAX_TOKENS = 9
+WAIT_S = 120.0
+# 5 distinct prompts that take 5 or 6 pages of 8 tokens (prompt and answer),
+# 3 of them full prompt pages that stay cached: with 14 usable pages the fifth
+# admission evicts every page of the first prompt, and its prefill writes them
+LENS = (28, 25, 26, 27, 33)
+
+
+class _Engine:
+    def __init__(self, preset, loop, num_pages=15):
+        self.loop = loop
+        self.srv = LLMServer(LLMConfig(
+            preset=preset, max_batch_slots=2, max_seq_len=64, paged=True,
+            page_size=8, num_pages=num_pages, prefill_chunk=16,
+            decode_chunk=4, seed=0))
+        rng = np.random.default_rng(3)
+        self.prompts = [rng.integers(1, 250, n).tolist() for n in LENS]
+
+    def generate(self, prompt):
+        return self.loop.run_until_complete(asyncio.wait_for(
+            self.srv.generate(prompt, max_tokens=MAX_TOKENS), WAIT_S))
+
+    def cached_pages(self, prompt):
+        """[(node, page id, k bytes, v bytes)] of the prompt's cached pages."""
+        out = []
+        for node in self.srv.page_mgr._walk(prompt):
+            assert node.page is not None
+            out.append((node, node.page) + self.page_bytes(node.page))
+        return out
+
+    def page_bytes(self, pid):
+        c = self.srv.cache
+        return (np.asarray(c.k_pages[:, :, pid]).tobytes(),
+                np.asarray(c.v_pages[:, :, pid]).tobytes())
+
+    def page_nbytes(self):
+        k = self.srv.cache.k_pages
+        return 2 * k.dtype.itemsize * int(np.prod(k.shape[:2] + k.shape[3:]))
+
+    def settle(self):
+        """Wait for the stash's thread to finish what it was handed, then
+        let the engine reap it; returns the engine's counters."""
+        concurrent.futures.wait([h[0] for h in self.srv._handoffs], WAIT_S)
+        return self.srv.stats()["decode"]
+
+    def close(self):
+        self.srv._kv_stash.close()
+
+
+@pytest.fixture(scope="module")
+def loop():
+    loop = asyncio.new_event_loop()
+    yield loop
+    loop.close()
+
+
+@pytest.fixture(params=PRESETS)
+def engine(request, loop, monkeypatch):
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    e = _Engine(request.param, loop)
+    yield e
+    e.close()
+
+
+class _Gate:
+    """Holds the stash's thread until opened, so that what the loop hands
+    over stays in flight."""
+
+    def __init__(self, stash):
+        self._open = threading.Event()
+        self._held = stash._worker.submit(self._open.wait, WAIT_S)
+
+    def open(self):
+        self._open.set()
+        assert self._held.result(WAIT_S)
+
+
+@pytest.mark.parametrize("tier", ["shm", "in_flight", "disk"])
+def test_restored_page_is_the_demoted_page(engine, tier):
+    srv, stash = engine.srv, engine.srv._kv_stash
+    first, *others = engine.prompts
+    out = engine.generate(first)
+    for p in others[:-1]:
+        engine.generate(p)
+    before = engine.cached_pages(first)
+    assert len(before) == 3 and srv.page_mgr.evicted_pages == 0
+    if tier == "disk":
+        stash.budget = engine.page_nbytes()    # all but the newest spill
+    gate = _Gate(stash) if tier == "in_flight" else None
+    engine.generate(others[-1])     # evicts `first`; its prefill reuses them
+    handles = [node.handle for node, _, _, _ in before]
+    assert all(node.page is None for node, _, _, _ in before)
+    assert all(h is not None for h in handles)
+    # the pool slots hold the admitting request's KV by now
+    assert all(engine.page_bytes(pid) != (k, v) for _, pid, k, v in before)
+    if gate is None:
+        d = engine.settle()
+        assert d["demote_failed"] == 0 and not srv._staged
+        assert [h["oid"] in stash._disk for h in handles] == (
+            [tier == "disk"] * 3)
+        assert d["stash_spilled_pages"] == (
+            d["demoted_pages"] - 1 if tier == "disk" else 0)
+    else:
+        assert all(h["oid"] in srv._staged for h in handles)
+
+    again = engine.generate(first)             # restores the three pages
+    if gate is not None:
+        gate.open()
+    d = engine.settle()
+    assert d["restored_pages"] == 3 and d["demote_failed"] == 0
+    assert d["restored_in_flight"] == (3 if tier == "in_flight" else 0)
+    assert again["tokens"] == out["tokens"]
+    for node, _, k, v in before:
+        assert node.page is not None
+        assert engine.page_bytes(node.page) == (k, v)
+    # and once it is there, what the stash holds is those bytes too
+    for handle, (_, _, k, v) in zip(handles, before):
+        got_k, got_v = stash.get(handle)
+        assert (got_k.tobytes(), got_v.tobytes()) == (k, v)
+    assert d["demote_passes"] > 0 and d["stash_worker_s"] > 0
+    assert d["demote_wait_s"] == 0
+    assert 0 < d["demote_inflight_max_bytes"] <= llm_mod.STAGED_CAP_BYTES
+
+
+def test_exception_on_the_stash_thread_is_counted_and_serving_goes_on(engine):
+    srv, stash = engine.srv, engine.srv._kv_stash
+    first, *others = engine.prompts
+    out = engine.generate(first)
+    calls, seal = [], stash._seal
+
+    def every_other_one_fails(handle, k_page, v_page):
+        calls.append(handle["oid"])
+        if len(calls) % 2:
+            raise OSError("no space left on /dev/shm")
+        return seal(handle, k_page, v_page)
+
+    stash._seal = every_other_one_fails
+    try:
+        outs = [engine.generate(p) for p in others + others[:1]]
+        d = engine.settle()
+    finally:
+        stash._seal = seal
+    assert all(len(o["tokens"]) == MAX_TOKENS for o in outs)
+    assert d["demote_failed"] == (len(calls) + 1) // 2 > 0
+    assert d["demoted_pages"] == len(calls) // 2 > 0
+    assert d["demote_failed"] == d["evicted_pages"] - d["demoted_pages"]
+    assert d["demote_last_error"].startswith("OSError")
+    assert "no space left on /dev/shm" in d["demote_last_error"]
+    # a page that never reached the stash is not offered for a restore: the
+    # first prompt lost its first page, so it prefills from the start
+    hit = srv.page_mgr.prefix_hit_tokens
+    again = engine.generate(first)
+    assert srv.page_mgr.prefix_hit_tokens == hit
+    assert again["tokens"] == out["tokens"]
+
+
+def test_loop_waits_for_the_oldest_hand_off_over_the_cap(engine, monkeypatch):
+    """Back-pressure: with the stash's thread slower than the evictions and
+    room for four staged pages, the loop waits for the oldest hand-off and
+    counts the wait."""
+    srv, stash = engine.srv, engine.srv._kv_stash
+    seal = stash._seal
+
+    def slow(handle, k_page, v_page):
+        time.sleep(0.02)
+        return seal(handle, k_page, v_page)
+
+    stash._seal = slow
+    page_bytes = engine.page_nbytes()
+    monkeypatch.setattr(llm_mod, "STAGED_CAP_BYTES", 4 * page_bytes)
+    try:
+        for p in engine.prompts + engine.prompts[:2]:
+            engine.generate(p)
+        d = engine.settle()
+    finally:
+        stash._seal = seal
+    assert d["demote_failed"] == 0 and d["demoted_pages"] > 8
+    assert d["demote_wait_s"] > 0
+    # the cap bounds what was staged before a hand-off, so the most ever
+    # staged is under the cap plus the group that was let in
+    assert d["demote_inflight_max_bytes"] <= (
+        4 + llm_mod.DEMOTE_GROUP) * page_bytes
+
+
+@pytest.fixture(scope="module")
+def compile_events():
+    """jax's own monitoring events, as the benchmark's CompileMeter reads
+    them: one `backend_compile_duration` a program compiled or fetched."""
+    import jax
+    seen = []
+
+    def on_duration(event, duration, **_):
+        if event.endswith("/backend_compile_duration"):
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return seen
+
+
+@pytest.mark.parametrize("n_pages", [1, 7, 20])
+@pytest.mark.parametrize("preset", PRESETS)
+def test_pass_of_any_size_compiles_nothing_and_is_exact(
+        preset, n_pages, loop, compile_events, monkeypatch):
+    import jax.numpy as jnp
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    e = _Engine(preset, loop, num_pages=24)
+    srv, stash = e.srv, e.srv._kv_stash
+    try:
+        rng = np.random.default_rng(n_pages)
+        shape, dtype = srv.cache.k_pages.shape, srv.cache.k_pages.dtype
+        fill = lambda: jnp.asarray(  # noqa: E731
+            rng.normal(size=shape).astype(np.float32)).astype(dtype)
+        srv.cache = srv.cache.replace(k_pages=fill(), v_pages=fill())
+        pids = rng.permutation(np.arange(1, 24))[:n_pages].tolist()
+        want = [e.page_bytes(pid) for pid in pids]
+        before = len(compile_events)
+        handles = [srv._demote_page(pid, object()) for pid in pids]
+        srv._demote_pass()
+        assert len(srv._handoffs) == -(-n_pages // llm_mod.DEMOTE_GROUP)
+        d = e.settle()
+        assert len(compile_events) == before
+        assert d["demote_passes"] == d["phase_n"]["demote"] == 1
+        assert d["demote_failed"] == 0
+        assert d["demote_bytes"] == sum(h["nbytes"] for h in handles)
+        for handle, (k, v) in zip(handles, want):
+            got_k, got_v = stash.get(handle)
+            assert got_k.dtype == dtype and list(got_k.shape) == handle["shape"]
+            assert (got_k.tobytes(), got_v.tobytes()) == (k, v)
+    finally:
+        e.close()
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_new_counters_are_there_at_zero_from_construction(
+        preset, loop, monkeypatch):
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    e = _Engine(preset, loop)
+    try:
+        d = e.srv.stats()["decode"]
+        for key in ("demote_passes", "demote_wait_s", "restored_in_flight",
+                    "demote_inflight_max_bytes", "stash_worker_s",
+                    "demote_bytes", "demoted_pages", "demote_failed"):
+            assert d[key] == 0, key
+        assert e.srv._kv_stash.phases.names == {"put": "stash.put"}
+        for p in e.prompts:
+            e.generate(p)
+        after = e.settle()
+        for key in ("demote_passes", "demote_inflight_max_bytes",
+                    "stash_worker_s", "demote_bytes", "demoted_pages"):
+            assert after[key] > 0, key
+    finally:
+        e.close()
+
+
+def test_close_with_puts_queued_leaves_no_segment_and_no_spill_file(
+        monkeypatch):
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    shape = (2, 3, 8, 8)
+    one_page = 2 * int(np.prod(shape)) * 4
+    stash = KVPageStash(budget_bytes=2 * one_page)   # the rest goes to disk
+    gate = _Gate(stash)
+    rng = np.random.default_rng(0)
+    handles, puts = [], []
+    for _ in range(3):
+        k = rng.normal(size=(4,) + shape).astype(np.float32)
+        group = [stash.new_handle(shape, k.dtype) for _ in range(3)]
+        puts.append(stash.put(group, k, k + 1))      # row 3 is padding
+        handles += group
+    assert not any(p.done() for p in puts)
+    threading.Timer(0.2, gate.open).start()
+    stash.close()                                    # runs the queue first
+    assert [p.result(0) for p in puts] == [[None] * 3] * 3
+    assert stash.spilled_pages == 7
+    assert stash.tier_stats() == {"shm_objects": 0, "shm_bytes": 0,
+                                  "disk_objects": 0, "disk_bytes": 0}
+    for handle in handles:
+        name = object_store.seg_name(handle["oid"])
+        assert not os.path.exists(os.path.join("/dev/shm", name))
+        assert not os.path.exists(os.path.join(
+            object_store._spill_dir(), name))
+    stash.close()                                    # closing twice is fine
+    with pytest.raises(RuntimeError):
+        stash.put([], k, k)

@@ -10,6 +10,7 @@ the tests read that run. A module's servers share one event loop: the engine's
 """
 
 import asyncio
+import concurrent.futures
 import glob
 import os
 import subprocess
@@ -105,8 +106,11 @@ def test_every_phase_that_must_have_run_ran(run):
     assert d["phase_n"]["prefill_dispatch"] == d["prefill_chunks"]
     assert d["phase_n"]["prefill_first_token"] == len(run.prompts)
     assert d["phase_n"]["admit_allocate"] == d["admitted"] == len(run.prompts)
-    assert d["phase_n"]["demote"] == d["demoted_pages"] > 0
-    assert d["phase_n"]["demote_stash"] == d["demoted_pages"]
+    # an entry is an eviction pass that had pages to demote, not a page
+    assert 0 < d["phase_n"]["demote"] == d["demote_passes"]
+    assert d["phase_n"]["demote_stash"] == d["demote_passes"]
+    assert d["demote_passes"] <= d["demoted_pages"]
+    assert d["demote_passes"] <= d["phase_n"]["evict"]
     assert d["evicted_pages"] >= d["demoted_pages"]
     assert d["restored_pages"] > 0 and d["demote_failed"] == 0
     assert d["demote_last_error"] is None
@@ -126,7 +130,9 @@ def test_counters_are_all_there_at_zero_from_construction():
                 "prefill_chunks", "prefill_tokens", "prefill_padded_tokens",
                 "admitted", "slot_wait_s", "slot_wait_max_s", "evicted_pages",
                 "demoted_pages", "restored_pages", "demote_failed",
-                "demote_bytes", "stash_spilled_pages", "host_syncs", "tokens"):
+                "demote_bytes", "stash_spilled_pages", "host_syncs", "tokens",
+                "demote_passes", "demote_wait_s", "demote_inflight_max_bytes",
+                "restored_in_flight", "stash_worker_s"):
         assert d[key] == 0, key
     assert d["demote_last_error"] is None
     assert d["phase_s"] == dict.fromkeys(LOOP_PHASES + NESTED_PHASES, 0.0)
@@ -240,9 +246,12 @@ def test_profiler_host_plane_holds_engine_phases(run, tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         run.generate(prompts)
+        # the stash's thread ends its last `stash.put` inside the session
+        concurrent.futures.wait([h[0] for h in run.srv._handoffs], 60.0)
     finally:
         jax.profiler.stop_trace()
     names = _host_event_names(str(tmp_path))
+    assert "stash.put" in names       # on the stash's own thread
     for key in ("decode_sync", "demote", "demote_stash", "evict",
                 "admit_allocate", "yield", "decode_dispatch",
                 "prefill_dispatch", "prefill_first_token"):

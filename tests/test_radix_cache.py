@@ -201,9 +201,12 @@ def test_kv_page_stash_roundtrip_two_tiers(monkeypatch):
         rng = np.random.default_rng(0)
         k1 = rng.normal(size=(2, 3, PS, 8)).astype(np.float32)
         v1 = rng.normal(size=(2, 3, PS, 8)).astype(np.float32)
-        h1 = stash.put(k1, v1)
+        h1 = stash.new_handle(k1.shape, k1.dtype)
+        stash.put([h1], k1[None], v1[None])
         k2, v2 = k1 * 2, v1 * 2
-        h2 = stash.put(k2, v2)           # budget: h1 spills to disk
+        h2 = stash.new_handle(k2.shape, k2.dtype)
+        # budget: h1 spills to disk, on the stash's own thread
+        assert stash.put([h2], k2[None], v2[None]).result(60) == [None]
         ts = stash.tier_stats()
         assert ts["disk_objects"] == 1 and ts["shm_objects"] == 1, ts
 
