@@ -30,6 +30,9 @@ from ray_tpu.ops.attention import apply_rope, decode_attention, mha_reference
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          paged_attention_reference,
+                                         sparse_attention_reference,
+                                         sparse_paged_decode,
+                                         sparse_paged_prefill,
                                          write_layer_tokens)
 from ray_tpu.ops.ring_attention import ring_attention
 
@@ -61,6 +64,21 @@ class LlamaConfig:
     moe_every: int = 1              # 1 = every block (Mixtral layout)
     capacity_factor: float = 1.25   # per-expert token budget multiplier
     router_aux_weight: float = 0.01  # load-balance loss weight (sowed)
+    expert_dim: int = 0             # an expert's width; 0 = ffn_dim
+    # ---- what the Qwen3-MoE trunk and a learned sparse attention add; all
+    # off by default, so the other presets trace to the programs they did.
+    qk_norm: bool = False           # RMS norm of q and k over each head
+    # rotary positions of three components (temporal, height, width): how
+    # many of the head_dim/2 frequencies each turns. Used when `positions`
+    # is [3, B, T]; [B, T] positions (text) are one-component rotary.
+    rope_sections: Optional[Tuple[int, ...]] = None
+    # lightning indexer (DeepSeek-V3.2-Exp): `index_heads` query heads of
+    # `index_dim` against one key head score every earlier token, and
+    # attention sees the `index_topk` best (all while there are no more).
+    # 0 = dense attention. Needs the paged cache's third pool to decode.
+    index_heads: int = 0
+    index_dim: int = 64
+    index_topk: int = 0
 
     # ---- presets (sizes follow the Llama family; test config is `tiny`).
     # kwargs override the preset's own values (e.g. tiny(max_seq_len=64)).
@@ -78,6 +96,32 @@ class LlamaConfig:
             vocab_size=256, d_model=64, n_layers=2, n_heads=4,
             n_kv_heads=2, head_dim=16, ffn_dim=128, max_seq_len=128,
             rope_theta=10000.0, n_experts=4, moe_top_k=2), **kw})
+
+    @staticmethod
+    def keye_tiny(**kw):
+        """Test-scale Keye-VL-2.0 language model: q/k norm, three-component
+        rotary, 16 experts top-2 of width 32 (the grouped product), an indexer
+        of 2 heads of 8 that selects 16 keys."""
+        return LlamaConfig(**{**dict(
+            vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+            n_kv_heads=2, head_dim=16, ffn_dim=128, max_seq_len=128,
+            rope_theta=10000.0, norm_eps=1e-6, n_experts=16, moe_top_k=2,
+            expert_dim=32, qk_norm=True, rope_sections=(2, 3, 3),
+            index_heads=2, index_dim=8, index_topk=16), **kw})
+
+    @staticmethod
+    def keye_vl2_30b_a3b(**kw):
+        """Keye-VL-2.0-30B-A3B, the language model (the vision tower is not
+        built): Qwen3-MoE trunk, 128 experts of 768, 8 a token, GQA 32 / 4
+        of 128, q/k norm, rotary sections [16, 24, 24], a 16-head indexer of
+        width 64 selecting 2048 keys. `ffn_dim` is the config's unused
+        `intermediate_size`: every layer has experts."""
+        return LlamaConfig(**{**dict(
+            vocab_size=151936, d_model=2048, n_layers=48, n_heads=32,
+            n_kv_heads=4, head_dim=128, ffn_dim=6144, max_seq_len=262144,
+            rope_theta=1e7, norm_eps=1e-6, n_experts=128, moe_top_k=8,
+            expert_dim=768, qk_norm=True, rope_sections=(16, 24, 24),
+            index_heads=16, index_dim=64, index_topk=2048), **kw})
 
     @staticmethod
     def mixtral_8x7b(**kw):
@@ -162,6 +206,40 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(self.dtype)
 
 
+class Indexer(nn.Module):
+    """The lightning indexer's projections: `index_heads` query heads, one
+    key head (LayerNorm, cached in the third pool) and a weight a head, all
+    from the block's normed input; rotary by the temporal position."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02))
+        b, t, _ = x.shape
+        qi = dense(cfg.index_heads * cfg.index_dim, name="wq")(x)
+        ki = nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                          param_dtype=jnp.float32, name="k_norm")(
+            dense(cfg.index_dim, name="wk")(x))
+        wi = dense(cfg.index_heads, name="w")(x)
+        qi = apply_rope(qi.reshape(b, t, cfg.index_heads, cfg.index_dim),
+                        positions, cfg.rope_theta)
+        ki = apply_rope(ki[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+        return qi, ki, wi
+
+
+def _chunk_local_attention(cfg: LlamaConfig, q, k, v):
+    """Causal attention of a chunk over itself (a fresh row's first chunk
+    into the paged cache); honors attn_impl like the cache=None branch."""
+    impl = cfg.attn_impl
+    if impl in ("auto", "ring"):
+        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+    return (flash_attention(q, k, v, causal=True) if impl == "flash"
+            else mha_reference(q, k, v, causal=True))
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
     layer_idx: int = 0
@@ -181,11 +259,49 @@ class Attention(nn.Module):
         q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_sections)
+        if positions.ndim == 3:
+            # three-component rotary; the order in the sequence (where the
+            # cache is written, what is causal) is the row's own count
+            temporal = positions[0]
+            positions = (jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+                         if cache is None else
+                         cache.length[:, None] + jnp.arange(t)[None])
+        else:
+            temporal = positions
 
         new_cache_kv = None
-        if isinstance(cache, PagedKVCache):
+        if cfg.index_topk:
+            with jax.named_scope("indexer"):
+                qi, ki, wi = Indexer(cfg, name="indexer")(x, temporal)
+            if isinstance(cache, PagedKVCache):
+                cache = write_layer_tokens(cache, layer_idx, k, v, positions,
+                                           idx_new=ki)
+                if t == 1:
+                    out = sparse_paged_decode(
+                        q[:, 0], qi[:, 0], wi[:, 0], cache, layer_idx,
+                        positions[:, -1] + 1, cfg.index_topk)[:, None]
+                elif paged_chunk_local and t <= cfg.index_topk:
+                    # a fresh row's first chunk of no more than `index_topk`
+                    # tokens: every query selects all its keys, so
+                    # chunk-local causal attention is exact
+                    out = _chunk_local_attention(cfg, q, k, v)
+                else:
+                    out = sparse_paged_prefill(q, qi, wi, cache, layer_idx,
+                                               positions, cfg.index_topk)
+                new_cache_kv = cache
+            elif cache is not None:
+                raise NotImplementedError(
+                    "learned sparse attention decodes through the paged "
+                    "cache (its indexer keys live in the third pool)")
+            else:
+                out = sparse_attention_reference(q, k, v, qi, ki, wi,
+                                                 cfg.index_topk)
+        elif isinstance(cache, PagedKVCache):
             # Paged decode/prefill (vLLM memory model, ops/paged_attention):
             # write this layer's K/V into its page slice, then attend. The
             # cache threads through the block stack; decode writes use
@@ -205,11 +321,7 @@ class Attention(nn.Module):
                 # the caller asserts this statically): chunk-local causal
                 # attention is exact, no page gather. The hot cold-prompt
                 # TTFT path; honors attn_impl like the cache=None branch.
-                impl = cfg.attn_impl
-                if impl in ("auto", "ring"):
-                    impl = "flash" if jax.default_backend() == "tpu" else "xla"
-                out = (flash_attention(q, k, v, causal=True) if impl == "flash"
-                       else mha_reference(q, k, v, causal=True))
+                out = _chunk_local_attention(cfg, q, k, v)
             else:
                 # chunked prefill continuation: queries must see the row's
                 # CACHED prefix (chunks 2+ of a long prompt, and
@@ -375,13 +487,23 @@ def _attn_params(cfg: LlamaConfig) -> int:
     """Per-layer attention weights — single source for count AND flops so
     a layout change (biases, MLA, ...) can't desynchronize reported MFU
     from the real parameter count."""
-    return cfg.d_model * cfg.head_dim * (cfg.n_heads * 2
-                                         + cfg.n_kv_heads * 2)
+    n = cfg.d_model * cfg.head_dim * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
+    if cfg.qk_norm:
+        n += 2 * cfg.head_dim
+    if cfg.index_topk:   # wq, wk, w, and the key's LayerNorm
+        n += cfg.d_model * (cfg.index_heads * cfg.index_dim + cfg.index_dim
+                            + cfg.index_heads) + 2 * cfg.index_dim
+    return n
 
 
 def _mlp_params(cfg: LlamaConfig) -> int:
-    """One dense SwiGLU FFN (also the per-expert size in an MoE bank)."""
+    """One dense SwiGLU FFN."""
     return 3 * cfg.d_model * cfg.ffn_dim
+
+
+def _expert_params(cfg: LlamaConfig) -> int:
+    """One expert of an MoE bank (`expert_dim` wide, else as the dense FFN)."""
+    return 3 * cfg.d_model * (cfg.expert_dim or cfg.ffn_dim)
 
 
 def llama_param_count(cfg: LlamaConfig) -> int:
@@ -389,7 +511,7 @@ def llama_param_count(cfg: LlamaConfig) -> int:
     total = cfg.n_layers * per_layer
     # MoE blocks swap the dense FFN for E experts + a router
     n_moe = _n_moe_layers(cfg)
-    total += n_moe * ((cfg.n_experts - 1) * _mlp_params(cfg)
+    total += n_moe * (cfg.n_experts * _expert_params(cfg) - _mlp_params(cfg)
                       + cfg.d_model * cfg.n_experts)
     embed = cfg.vocab_size * cfg.d_model
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
@@ -404,7 +526,7 @@ def llama_compute_flops(cfg: LlamaConfig, batch: int, seq: int) -> float:
     n_dense = cfg.n_layers - n_moe
     n_active = (cfg.n_layers * _attn_params(cfg)
                 + n_dense * _mlp_params(cfg)
-                + n_moe * (cfg.moe_top_k * _mlp_params(cfg)
+                + n_moe * (cfg.moe_top_k * _expert_params(cfg)
                            + cfg.d_model * cfg.n_experts))
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
     n_active += head

@@ -30,15 +30,28 @@ def rope_table(max_len: int, head_dim: int, theta: float = 10000.0):
     return jnp.sin(angles), jnp.cos(angles)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
-    """Rotate-half RoPE. x: [B, T, H, D], positions: [B, T] int32.
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               sections=None):
+    """Rotate-half RoPE. x: [B, T, H, D], positions: [B, T] int32, or
+    [3, B, T] with `sections` (s_t, s_h, s_w) summing to D/2: of the D/2
+    frequencies the first s_t turn by positions[0] (temporal), the next s_h
+    by positions[1] (height), the rest by positions[2] (width). Text has all
+    three equal, which is [B, T] positions exactly.
 
     Computed in f32 and cast back to x.dtype (bf16 rotation loses precision
     at long context).
     """
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, D/2]
+    if positions.ndim == 3:
+        assert sections is not None and sum(sections) == d // 2, sections
+        component = jnp.repeat(jnp.arange(len(sections)),
+                               jnp.asarray(sections),
+                               total_repeat_length=d // 2)     # [D/2]
+        per_freq = jnp.moveaxis(positions, 0, -1)[..., component]
+        angles = per_freq.astype(jnp.float32) * freqs          # [B, T, D/2]
+    else:
+        angles = positions[..., None].astype(jnp.float32) * freqs
     sin = jnp.sin(angles)[:, :, None, :]  # [B, T, 1, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -205,17 +205,46 @@ import flax.struct
 class PagedKVCache(flax.struct.PyTreeNode):
     """Per-layer page pools and shared block tables.
 
-    k_pages/v_pages: [L, Kh, P, page, D]; block_tables: [B, max_pages];
-    lengths: [B]. Rows whose slot is free have length 0 and table entries 0.
+    Two layouts, told apart by whether the model has an indexer:
+
+    - dense attention: k_pages/v_pages [L, Kh, P, page, D] (the layout the
+      `paged_decode` kernel walks), `idx_pages` None: the pytree and the
+      programs of a cache without an indexer are what they were.
+    - learned sparse attention: a third pool `idx_pages` (the indexer's one
+      key head, Di values a token) and k_pages/v_pages TOKEN-MAJOR,
+      [L, P, page, Kh, D]: decode gathers the selected tokens' rows, and a
+      gathered unit has to be a whole (Kh, D) tile of the pool or XLA copies
+      the layer's pool to re-tile it on every step. For the same reason the
+      indexer's pool is [L, P, page / r, r * Di] with r tokens side by side
+      in a row of 128 lanes (`index_pack`): at a minor dimension of 64 the
+      TPU lays the pool out pages-minor and copies all of it for every
+      page gather and scatter (403 MB a layer a prefill chunk, seen in the
+      HLO compiled for the v5e). `index_keys` reads pages back as
+      [tokens, Di].
+
+    block_tables: [B, max_pages]; lengths: [B]. Rows whose slot is free have
+    length 0 and table entries 0. `page_axis` is where a pool's page index
+    sits; whatever moves pages (demotion, restore, the P/D hand-off) goes
+    over `pools()` and that axis and so carries every per-page array.
     """
     k_pages: jax.Array
     v_pages: jax.Array
     block_tables: jax.Array
     lengths: jax.Array
+    idx_pages: Optional[jax.Array] = None
+
+    @property
+    def page_axis(self) -> int:
+        return 2 if self.idx_pages is None else 1
 
     @property
     def page_size(self):
-        return self.k_pages.shape[3]
+        return self.k_pages.shape[self.page_axis + 1]
+
+    @property
+    def index_dim(self) -> int:
+        ps = self.page_size
+        return self.idx_pages.shape[-1] * self.idx_pages.shape[-2] // ps
 
     @property
     def length(self):
@@ -223,21 +252,53 @@ class PagedKVCache(flax.struct.PyTreeNode):
         cache-type agnostic."""
         return self.lengths
 
+    def pools(self) -> tuple:
+        """Every per-page array, in the order hand-offs carry them."""
+        kv = (self.k_pages, self.v_pages)
+        return kv if self.idx_pages is None else kv + (self.idx_pages,)
+
+    def with_pools(self, pools) -> "PagedKVCache":
+        names = ("k_pages", "v_pages", "idx_pages")
+        return self.replace(**dict(zip(names, pools)))
+
     @staticmethod
     def init(n_layers: int, n_kv_heads: int, head_dim: int, num_pages: int,
              page_size: int, batch_slots: int, max_pages_per_seq: int,
-             dtype=jnp.bfloat16) -> "PagedKVCache":
-        shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
-        return PagedKVCache(
-            k_pages=jnp.zeros(shape, dtype),
-            v_pages=jnp.zeros(shape, dtype),
+             dtype=jnp.bfloat16, index_dim: int = 0) -> "PagedKVCache":
+        tables = dict(
             block_tables=jnp.zeros((batch_slots, max_pages_per_seq), jnp.int32),
             lengths=jnp.zeros((batch_slots,), jnp.int32))
+        if index_dim:
+            r = index_pack(page_size, index_dim)
+            shape = (n_layers, num_pages, page_size, n_kv_heads, head_dim)
+            return PagedKVCache(
+                k_pages=jnp.zeros(shape, dtype), v_pages=jnp.zeros(shape, dtype),
+                idx_pages=jnp.zeros(
+                    (n_layers, num_pages, page_size // r, r * index_dim),
+                    dtype), **tables)
+        shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
+        return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
+                            v_pages=jnp.zeros(shape, dtype), **tables)
+
+
+def index_pack(page_size: int, index_dim: int) -> int:
+    """Tokens side by side in one row of the indexer's pool: as many as fill
+    128 lanes and divide the page."""
+    return math.gcd(page_size, max(1, _LANES // index_dim))
+
+
+def index_keys(cache: PagedKVCache, layer_idx: int, page_ids) -> jax.Array:
+    """The indexer's keys of pages `page_ids` [..., n] as [..., n * page, Di],
+    a row a token. The layer index rides in the gather: a slice of the pool
+    first would be copied."""
+    pages = cache.idx_pages[layer_idx, page_ids]      # [..., n, page/r, r*Di]
+    return pages.reshape(page_ids.shape[:-1] + (-1, cache.index_dim))
 
 
 def write_tokens(cache: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
                  positions: jax.Array) -> PagedKVCache:
-    """Scatter new tokens into their pages (jit-safe pure update).
+    """Scatter new tokens into their pages (jit-safe pure update; the dense
+    layout only).
 
     k_new/v_new: [L, B, T, Kh, D] (T tokens per row this step; T=1 decode,
     T=prompt_len prefill). positions: [B, T] absolute token positions; the
@@ -257,10 +318,12 @@ def write_tokens(cache: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
 
 
 def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
-                       v_new: jax.Array, positions: jax.Array) -> PagedKVCache:
-    """Write ONE layer's new K/V into its page slice (jit-safe).
+                       v_new: jax.Array, positions: jax.Array,
+                       idx_new: Optional[jax.Array] = None) -> PagedKVCache:
+    """Write ONE layer's new tokens into every per-page pool (jit-safe).
 
-    k_new/v_new: [B, T, Kh, D]; positions: [B, T]. Layers touch disjoint
+    k_new/v_new: [B, T, Kh, D]; idx_new: [B, T, Di], given exactly when the
+    cache has an indexer pool; positions: [B, T]. Layers touch disjoint
     pool slices, so the decoder threads the cache through its blocks.
 
     Decode (T == 1) uses per-row dynamic_update_slice, UNROLLED over B:
@@ -289,35 +352,265 @@ def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
     iteration. Keep this path free of ops that break carry aliasing
     (no reshapes of the pool, no scatter).
     """
-    bsz, t, kh, d = k_new.shape
+    bsz, t = k_new.shape[:2]
     ps = cache.page_size
+    token_major = cache.idx_pages is not None
+    assert (idx_new is not None) == token_major, "indexer key and its pool"
+    pools = cache.pools()
     # match the pool's dtype in both branches: scatter casts silently, but
     # dynamic_update_slice requires exact dtype agreement
-    k_new = k_new.astype(cache.k_pages.dtype)
-    v_new = v_new.astype(cache.v_pages.dtype)
+    news = [n.astype(p.dtype) for n, p in
+            zip((k_new, v_new, idx_new)[:len(pools)], pools)]
     if t == 1:
-        k_pages, v_pages = cache.k_pages, cache.v_pages
+        pools = list(pools)
         for b in range(bsz):  # B is static; one fused program, aliased DUS
             p0 = positions[b, 0]
             page_id = cache.block_tables[b, p0 // ps]
             off = p0 % ps
-            start = (layer_idx, 0, page_id, off, 0)
-            k_pages = jax.lax.dynamic_update_slice(
-                k_pages, k_new[b, 0][None, :, None, None, :], start)
-            v_pages = jax.lax.dynamic_update_slice(
-                v_pages, v_new[b, 0][None, :, None, None, :], start)
-        return cache.replace(k_pages=k_pages, v_pages=v_pages)
+            for i, new in enumerate(news):
+                row = new[b, 0]                      # [Kh, D] or [Di]
+                if i == 2:     # r tokens share a row of the indexer's pool
+                    di = row.shape[0]
+                    r = pools[2].shape[-1] // di
+                    start = (layer_idx, page_id, off // r, (off % r) * di)
+                    row = row[None, None, None]
+                elif token_major:
+                    start = (layer_idx, page_id, off, 0, 0)
+                    row = row[None, None, None]
+                else:
+                    start = (layer_idx, 0, page_id, off, 0)
+                    row = row[None, :, None, None, :]
+                pools[i] = jax.lax.dynamic_update_slice(pools[i], row, start)
+        return cache.with_pools(pools)
     pos = positions.reshape(-1)
     rows = jnp.repeat(jnp.arange(bsz), t)
     page_ids = cache.block_tables[rows, pos // ps]
     offs = pos % ps
+    flat = [n.reshape((bsz * t,) + n.shape[2:]) for n in news]
+    if token_major:
+        di = flat[2].shape[-1]
+        r = pools[2].shape[-1] // di
+        # a token's Di values into its share of a row: a windowed scatter
+        # (layer, page, row, first lane), which indexing cannot spell
+        where = jnp.stack([jnp.full_like(offs, layer_idx), page_ids,
+                           offs // r, (offs % r) * di], axis=-1)
+        idx_pool = jax.lax.scatter(
+            pools[2], where, flat[2],
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+                scatter_dims_to_operand_dims=(0, 1, 2, 3)),
+            indices_are_sorted=False, unique_indices=False,
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        return cache.with_pools([
+            p.at[layer_idx, page_ids, offs].set(n)
+            for p, n in zip(pools[:2], flat)] + [idx_pool])
     # index tuple (scalar, :, ids, offs): the advanced indices are separated
     # by a slice, so numpy/jax moves the broadcast dim FIRST → values must be
     # [B*T, Kh, D] (contrast write_tokens, whose adjacent indices keep order)
-    kv = lambda x: x.reshape(bsz * t, kh, d)
-    return cache.replace(
-        k_pages=cache.k_pages.at[layer_idx, :, page_ids, offs].set(kv(k_new)),
-        v_pages=cache.v_pages.at[layer_idx, :, page_ids, offs].set(kv(v_new)))
+    return cache.with_pools([
+        p.at[layer_idx, :, page_ids, offs].set(n)
+        for p, n in zip(pools, flat)])
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention over the paged cache (a lightning indexer's top-k
+# selection, DeepSeek-V3.2-Exp style): the indexer scores every cached key of
+# a row, the `topk` best are selected (all of them while the row holds `topk`
+# or fewer), and attention runs over the selected keys only. Pure XLA on the
+# token-major layout; one selection a query token, shared by every head.
+# ---------------------------------------------------------------------------
+
+_NEG = -1e30       # masked attention logit: finite, so no row turns to NaN
+
+
+def index_scores(qi, wi, ki):
+    """I[.., t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) in f32.
+    qi [.., T, J, Di], wi [.., T, J], ki [.., S, Di] -> [.., T, S]."""
+    s = jnp.einsum("...tjd,...sd->...tjs", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("...tjs,...tj->...ts", jax.nn.relu(s),
+                      wi.astype(jnp.float32))
+
+
+def kth_largest(scores, k: int):
+    """The k-th largest of each row of `scores` [T, S] (f32, -inf allowed),
+    exactly, as a radix select over the floats' bits: 8 passes of 4 bits, each
+    one read of the scores that counts, for 15 candidate prefixes, how many
+    keys reach it. Returns (keys [T, S], kth [T, 1]) as uint32 whose order is
+    the floats'. `lax.top_k` of a [512, 30720] block is a full sort on the
+    TPU: 14 ms of a prefill chunk's layer against 2 ms this way (v5e, PR 28);
+    where the positions are wanted too (decode) `lax.top_k` stays."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    bits = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    keys = jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(1 << 31)
+    digits = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def digit(i, kth):
+        shift = (28 - 4 * i).astype(jnp.uint32)
+        cands = kth | (digits[None, :] << shift)                  # [T, 15]
+        reach = (keys[:, :, None] >= cands[:, None, :]).sum(1)    # [T, 15]
+        best = (reach >= k).sum(-1, keepdims=True).astype(jnp.uint32)
+        return kth | (best << shift)   # reach falls as the candidate grows
+
+    kth = jax.lax.fori_loop(0, 8, digit,
+                            jnp.zeros((scores.shape[0], 1), jnp.uint32))
+    return keys, kth
+
+
+def _table_lookup(tables, slots):
+    """tables[b, slots[b, j]] for tables [B, mp] of page ids and slots
+    [B, K], as a one-hot contraction on the MXU: XLA's gather of 49,152
+    scalars took 0.50 ms a layer a decode step on the v5e (PR 28), as long as
+    the sort. Exact: a page id goes through in base-128 digits, which bf16
+    holds, and each sum has one term."""
+    one_hot = (slots[:, :, None] == jnp.arange(tables.shape[1])[None, None]
+               ).astype(jnp.bfloat16)                             # [B, K, mp]
+    digits = jnp.stack([(tables >> shift) & 127 for shift in (0, 7, 14, 21)]
+                       ).astype(jnp.bfloat16)                     # [4, B, mp]
+    got = jnp.einsum("bkp,dbp->dbk", one_hot, digits,
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    return got[0] | (got[1] << 7) | (got[2] << 14) | (got[3] << 21)
+
+
+def sparse_paged_decode(q, qi, wi, cache: PagedKVCache, layer_idx: int,
+                        lengths, topk: int, *, scale=None):
+    """One decode token a row over its `topk` selected cached keys.
+
+    q [B, H, D]; qi [B, J, Di], wi [B, J]: the indexer's query heads and
+    their weights; lengths [B]: valid tokens, this step's included (its
+    keys are already written). Returns [B, H, D]. Shape-stable and free of
+    host callbacks: it is the body of the fused decode scan. A row of `topk`
+    tokens or fewer selects every valid key (the masked ones fill the rest of
+    the static top-k and get no weight), so it attends to everything.
+    """
+    b, h, d = q.shape
+    kh = cache.k_pages.shape[-2]
+    ps, tb = cache.page_size, cache.block_tables
+    s_max = tb.shape[1] * ps
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    with jax.named_scope("sparse_index"):
+        ki = index_keys(cache, layer_idx, tb)                     # [B, S, Di]
+        scores = index_scores(qi[:, None], wi[:, None], ki)[:, 0]  # [B, S]
+        scores = jnp.where(jnp.arange(s_max)[None] < lengths[:, None],
+                           scores, -jnp.inf)
+    with jax.named_scope("sparse_select"):
+        top, sel = jax.lax.top_k(scores, min(topk, s_max))        # [B, K]
+        page_ids = _table_lookup(tb, sel // ps)
+        k_sel = cache.k_pages[layer_idx, page_ids, sel % ps]      # [B,K,Kh,D]
+        v_sel = cache.v_pages[layer_idx, page_ids, sel % ps]
+    with jax.named_scope("sparse_attend"):
+        qg = q.reshape(b, kh, h // kh, d)
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, k_sel,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where((top > -jnp.inf)[:, None, None, :], s, _NEG)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_sel.dtype), v_sel)
+    return out.reshape(b, h, d).astype(q.dtype)
+
+
+def sparse_paged_prefill(q, qi, wi, cache: PagedKVCache, layer_idx: int,
+                         positions, topk: int, *, key_block: int = 1024,
+                         scale=None):
+    """A chunk of queries over each row's cached prefix and the chunk itself
+    (already written), by key blocks: nothing of size [T, heads, context]
+    is held. Two passes over the row's pages, each as far as the last query
+    reaches: the indexer's scores [T, S] (f32, no head axis), from which each
+    query's `topk` best are marked; then attention with an online softmax in
+    which a key counts iff it is marked: the selection as a mask, the same
+    mathematics as gathering.
+
+    q [B, T, H, D]; qi [B, T, J, Di]; wi [B, T, J]; positions [B, T]
+    absolute. Returns [B, T, H, D].
+    """
+    _, t, h, d = q.shape
+    kh = cache.k_pages.shape[-2]
+    g = h // kh
+    ps = cache.page_size
+    ppb = max(1, key_block // ps)              # pages a key block
+    kb = ppb * ps
+    mp = cache.block_tables.shape[1]
+    n_static = -(-mp // ppb)
+    s_pad = n_static * kb
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    k_pool, v_pool, _ = cache.pools()
+
+    def one_row(q, qi, wi, pos, table):
+        table = jnp.pad(table, (0, n_static * ppb - mp))
+        n_blocks = (jnp.max(pos) + kb) // kb   # blocks the last query reaches
+        col = jnp.arange(kb)
+
+        def block_pages(i):
+            return jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb)
+
+        def index_block(i, scores):
+            ki = index_keys(cache, layer_idx, block_pages(i))     # [kb, Di]
+            s = index_scores(qi, wi, ki)                          # [T, kb]
+            s = jnp.where((i * kb + col)[None] <= pos[:, None], s, -jnp.inf)
+            return jax.lax.dynamic_update_slice_in_dim(scores, s, i * kb, 1)
+
+        with jax.named_scope("sparse_index"):
+            scores = jax.lax.fori_loop(
+                0, n_blocks, index_block,
+                jnp.full((t, s_pad), -jnp.inf, jnp.float32))
+        with jax.named_scope("sparse_select"):
+            # lax.top_k's own set as a mask: all above the k-th value, and
+            # of those equal to it the first by position (scores tie at 0,
+            # where every head's relu is shut)
+            k_sel = min(topk, s_pad)
+            keys, kth = kth_largest(scores, k_sel)
+            above, tied = keys > kth, keys == kth
+            room = k_sel - above.sum(-1, keepdims=True)
+            selected = (above | (tied & (jnp.cumsum(tied, -1) <= room))) & (
+                scores > -jnp.inf)                                    # [T, S]
+
+        qg = q.reshape(t, kh, g, d)
+
+        def attend_block(i, carry):
+            m, l, acc = carry
+            pages = block_pages(i)
+            k = k_pool[layer_idx, pages].reshape(kb, kh, d)
+            v = v_pool[layer_idx, pages].reshape(kb, kh, d)
+            keep = jax.lax.dynamic_slice_in_dim(selected, i * kb, kb, 1)
+            s = jnp.einsum("tkgd,skd->kgts", qg, k,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep[None, None], s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.where(keep[None, None], jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + p.sum(-1)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "kgts,skd->kgtd", p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        with jax.named_scope("sparse_attend"):
+            init = (jnp.full((kh, g, t), _NEG, jnp.float32),
+                    jnp.zeros((kh, g, t), jnp.float32),
+                    jnp.zeros((kh, g, t, d), jnp.float32))
+            _, l, acc = jax.lax.fori_loop(0, n_blocks, attend_block, init)
+            out = acc / jnp.maximum(l, 1e-30)[..., None]          # [Kh,G,T,D]
+        return out.transpose(2, 0, 1, 3).reshape(t, h, d).astype(q.dtype)
+
+    return jax.vmap(one_row)(q, qi, wi, positions, cache.block_tables)
+
+
+def sparse_attention_reference(q, k, v, qi, ki, wi, topk: int, *, scale=None):
+    """The same selection without a cache, all at once (the CPU oracle and
+    the model's uncached forward; holds [B, T, T] scores, so short
+    sequences only). q [B, T, H, D]; k, v [B, T, Kh, D]; qi [B, T, J, Di];
+    ki [B, T, Di]; wi [B, T, J]."""
+    from ray_tpu.ops.attention import mha_reference
+    t = q.shape[1]
+    if t <= topk:
+        return mha_reference(q, k, v, causal=True, scale=scale)
+    scores = index_scores(qi, wi, ki)                             # [B, T, T]
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    scores = jnp.where(causal, scores, -jnp.inf)
+    sel = jax.lax.top_k(scores, topk)[1]                          # [B, T, K]
+    keep = jnp.zeros(scores.shape, bool).at[
+        jnp.arange(q.shape[0])[:, None, None], jnp.arange(t)[None, :, None],
+        sel].set(True) & causal
+    return mha_reference(q, k, v, causal=False, mask=keep, scale=scale)
 
 
 class PageManager:

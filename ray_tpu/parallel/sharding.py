@@ -95,6 +95,11 @@ def shard_tree(tree, mesh: Mesh, rules: "ShardingRules"):
 def llama_rules() -> ShardingRules:
     return ShardingRules([
         (r"embed/embedding", P(("fsdp",), ("tp",))),          # [vocab, d]
+        # the lightning indexer (one key head, 2M parameters a layer) and
+        # the per-head q/k norm scales are replicated: every shard makes the
+        # same selection. Before the wq/wk rule, which its names would match
+        (r"attn/indexer/", P()),
+        (r"attn/(q_norm|k_norm)/scale", P()),
         (r"(wq|wk|wv)/kernel", P(("fsdp",), ("tp",))),         # [d, heads*hd]
         (r"wo/kernel", P(("tp",), ("fsdp",))),                 # [heads*hd, d]
         (r"(w_gate|w_up)/kernel", P(("fsdp",), ("tp",))),      # [d, ffn]

@@ -119,25 +119,26 @@ class ShipWriter:
         self._sizes: Dict[str, int] = {}       # storage oid -> nbytes
         self._ship_oids: Dict[str, List[str]] = {}  # ship -> storage oids
 
-    def publish(self, ship_id: str, seg_index: int, k_pages: np.ndarray,
-                v_pages: np.ndarray, page_start: int) -> Dict[str, Any]:
-        """Seal one segment (k block then v block, each [L,Kh,n,ps,D]
-        C-contiguous) and return its wire metadata."""
-        k_pages = np.ascontiguousarray(k_pages)
-        v_pages = np.ascontiguousarray(v_pages)
-        nbytes = k_pages.nbytes + v_pages.nbytes
+    def publish(self, ship_id: str, seg_index: int, blocks,
+                page_start: int, page_axis: int = 2) -> Dict[str, Any]:
+        """Seal one segment: `blocks` is one array of every per-page pool of
+        the cache (k, v, and an indexer's keys where it has them), each in
+        its pool's own layout with the segment's n pages along `page_axis`
+        ([L,Kh,n,ps,D] for the dense layout), written C-contiguous one after
+        another. Returns the segment's wire metadata."""
+        blocks = [np.ascontiguousarray(b) for b in blocks]
+        nbytes = sum(b.nbytes for b in blocks)
         oid = storage_oid(ship_id, seg_index)
         handle = self.store.create_writable(oid, nbytes)
         try:
-            handle.view[:k_pages.nbytes] = _as_bytes(k_pages)
-            handle.view[k_pages.nbytes:nbytes] = _as_bytes(v_pages)
+            _write_blocks(handle.view, blocks)
         except BaseException:
             handle.abort()
             raise
         handle.seal()
         self._sizes[oid] = nbytes
         self._ship_oids.setdefault(ship_id, []).append(oid)
-        n_pages = int(k_pages.shape[2])
+        n_pages = int(blocks[0].shape[page_axis])
         _counter("kv_ship_bytes", "KV bytes sealed for PD shipment").inc(
             nbytes)
         _counter("kv_ship_pages", "KV pages sealed for PD shipment").inc(
@@ -174,6 +175,35 @@ class ShipWriter:
     def close(self) -> None:
         for ship_id in list(self._ship_oids):
             self.drop_ship(ship_id)
+
+
+def _write_blocks(view, blocks) -> None:
+    """`blocks` C-contiguous one after another into a segment's `view`."""
+    at = 0
+    for block in blocks:
+        block = np.ascontiguousarray(block)
+        view[at:at + block.nbytes] = _as_bytes(block)
+        at += block.nbytes
+
+
+def _read_blocks(buf, specs) -> Tuple[Tuple[np.ndarray, ...], int]:
+    """The arrays `_write_blocks` laid into `buf`, zero-copy, from their
+    (shape, dtype) `specs` in order; and the bytes they take."""
+    out, at = [], 0
+    for shape, dtype in specs:
+        n = int(np.prod(shape))
+        out.append(np.frombuffer(buf, dtype=dtype, count=n,
+                                 offset=at).reshape(shape))
+        at += n * dtype.itemsize
+    return tuple(out), at
+
+
+def _blocks_of(handle: Dict[str, Any]) -> List[Tuple[tuple, np.dtype]]:
+    """(shape, dtype) of each block a stash handle's segment holds, in
+    order: k, v, then the handle's `extra` ones."""
+    kv = (tuple(handle["shape"]), _np_dtype(handle["dtype"]))
+    return [kv, kv] + [(tuple(e["shape"]), _np_dtype(e["dtype"]))
+                       for e in handle.get("extra", ())]
 
 
 class KVPageStash:
@@ -240,26 +270,34 @@ class KVPageStash:
             pass
 
     # -- the caller's side: each submits to the worker ----------------------
-    def new_handle(self, page_shape, dtype) -> Dict[str, Any]:
-        """The restore handle of a page that `put` will be given later."""
+    def new_handle(self, page_shape, dtype, extra=()) -> Dict[str, Any]:
+        """The restore handle of a page that `put` will be given later: a
+        k and a v block of `page_shape` / `dtype`, then one block for each
+        (shape, dtype) of `extra` (a cache's further per-page arrays, as an
+        indexer's keys). The handle records every block's shape and type."""
         dtype = np.dtype(dtype)
-        nbytes = 2 * int(np.prod(page_shape)) * dtype.itemsize
-        return {"oid": f"kvd{_proc_tag}{next(self._seq):08x}",
-                "nbytes": nbytes, "shape": list(page_shape),
-                "dtype": dtype.name}
+        handle = {"oid": f"kvd{_proc_tag}{next(self._seq):08x}",
+                  "shape": list(page_shape), "dtype": dtype.name,
+                  "extra": [{"shape": list(sh), "dtype": np.dtype(dt).name}
+                            for sh, dt in extra]}
+        handle["nbytes"] = sum(int(np.prod(sh)) * dt.itemsize
+                               for sh, dt in _blocks_of(handle))
+        return handle
 
-    def put(self, handles: List[Dict[str, Any]], k_pages, v_pages
+    def put(self, handles: List[Dict[str, Any]], *pages
             ) -> concurrent.futures.Future:
-        """Seal page i of `k_pages` / `v_pages` ([G, *page_shape], numpy
-        or device arrays whose copy to the host may still be in flight)
-        under `handles[i]`; rows past `len(handles)` are padding. Returns
-        at once: the future's result is one entry a handle, None or the
-        exception that kept that page out of the stash."""
-        return self._worker.submit(self._put, handles, k_pages, v_pages)
+        """Seal page i of each of `pages` (k, v and the handle's further
+        arrays, each [G, *its page shape], numpy or device arrays whose copy
+        to the host may still be in flight) under `handles[i]`; rows past
+        `len(handles)` are padding. Returns at once: the future's result is
+        one entry a handle, None or the exception that kept that page out of
+        the stash."""
+        return self._worker.submit(self._put, handles, pages)
 
-    def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, np.ndarray]:
-        """Restore one page's (k, v), promoting a disk-resident segment
-        back to shm first. Byte-exact: the arrays round-trip untouched."""
+    def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, ...]:
+        """Restore one page's blocks (k, v, then the further ones),
+        promoting a disk-resident segment back to shm first. Byte-exact:
+        the arrays round-trip untouched."""
         return self._worker.submit(self._get, handle).result()
 
     def drop(self, handle: Dict[str, Any]) -> None:
@@ -285,30 +323,30 @@ class KVPageStash:
                 "disk_bytes": self.disk_bytes}
 
     # -- the worker's side ----------------------------------------------------
-    def _put(self, handles, k_pages, v_pages) -> List[Optional[Exception]]:
+    def _put(self, handles, pages) -> List[Optional[Exception]]:
         with phase(self.phases, "put"):
-            # waits for the transfer
-            k_pages, v_pages = np.asarray(k_pages), np.asarray(v_pages)
+            pages = [np.asarray(p) for p in pages]   # waits for the transfer
             errors: List[Optional[Exception]] = []
             for i, handle in enumerate(handles):
                 try:
-                    self._seal(handle, k_pages[i], v_pages[i])
+                    self._seal(handle, *(p[i] for p in pages))
                     errors.append(None)
                 except Exception as e:  # noqa: BLE001 - the caller counts it
                     errors.append(e)
             self._gauge()
             return errors
 
-    def _seal(self, handle, k_page: np.ndarray, v_page: np.ndarray) -> None:
-        """One evicted page's KV (k block then v block, C-contiguous) into
-        a sealed segment."""
-        k_page = np.ascontiguousarray(k_page)
-        v_page = np.ascontiguousarray(v_page)
+    def _seal(self, handle, *blocks) -> None:
+        """One evicted page's blocks (k, v, then the further ones, each
+        C-contiguous) one after another into a sealed segment."""
         oid, nbytes = handle["oid"], handle["nbytes"]
+        if sum(b.nbytes for b in blocks) != nbytes:
+            raise ValueError(f"page {oid}: {len(blocks)} blocks of "
+                             f"{sum(b.nbytes for b in blocks)} bytes, the "
+                             f"handle records {nbytes}")
         buf = self.store.create_writable(oid, nbytes)
         try:
-            buf.view[:k_page.nbytes] = _as_bytes(k_page)
-            buf.view[k_page.nbytes:nbytes] = _as_bytes(v_page)
+            _write_blocks(buf.view, blocks)
         except BaseException:
             buf.abort()
             raise
@@ -330,9 +368,8 @@ class KVPageStash:
             self.disk_bytes += nbytes
             self.spilled_pages += 1
 
-    def _get(self, handle) -> Tuple[np.ndarray, np.ndarray]:
+    def _get(self, handle) -> Tuple[np.ndarray, ...]:
         oid = handle["oid"]
-        dtype = _np_dtype(handle["dtype"])
         if oid in self._disk:
             path, nbytes = self._disk.pop(oid)
             self.disk_bytes -= nbytes
@@ -344,12 +381,7 @@ class KVPageStash:
             self._shm.move_to_end(oid)  # hot again
         blob = self.store.read_raw(oid)
         self._gauge()
-        shape = tuple(handle["shape"])
-        half = handle["nbytes"] // 2
-        k = np.frombuffer(blob, dtype=dtype, count=half // dtype.itemsize)
-        v = np.frombuffer(blob, dtype=dtype, count=half // dtype.itemsize,
-                          offset=half)
-        return k.reshape(shape), v.reshape(shape)
+        return _read_blocks(blob, _blocks_of(handle))[0]
 
     def _drop(self, handle) -> None:
         oid = handle["oid"]
@@ -488,25 +520,25 @@ atexit.register(_final_drain)
 
 
 class AttachedSegment:
-    """One pulled segment exposed as zero-copy [L,Kh,n,ps,D] k/v arrays.
+    """One pulled segment exposed as zero-copy arrays, `blocks`: one of
+    every per-page pool, in the pool's layout with the segment's pages
+    along its page axis ([L,Kh,n,ps,D] k and v for the dense layout).
 
     Close ONLY after the install consumed the arrays; a pulled local
     copy (delete=True) is unlinked on close, a direct attach to the
     writer's segment is merely detached (the writer owns deletion)."""
 
-    def __init__(self, k: np.ndarray, v: np.ndarray, shm=None,
+    def __init__(self, blocks, shm=None,
                  store: Optional[StoreClient] = None,
                  oid: Optional[str] = None, delete: bool = False):
-        self.k = k
-        self.v = v
+        self.blocks = tuple(blocks)
         self._shm = shm
         self._store = store
         self._oid = oid
         self._delete = delete
 
     def close(self) -> None:
-        self.k = None
-        self.v = None
+        self.blocks = ()
         if self._delete and self._store is not None and self._oid:
             # unlink the name now — the open mapping stays valid (POSIX),
             # and the reclaim must not depend on the detach below landing
@@ -521,17 +553,27 @@ class AttachedSegment:
         _drain_pending_close()
 
 
-def _carve(buf, seg: Dict[str, Any], layout, dtype) -> Tuple[np.ndarray,
-                                                             np.ndarray]:
-    """Split one segment's bytes into the k and v page blocks."""
-    L, Kh, ps, D = layout
+def pool_layout(pools, page_axis: int) -> List[Dict[str, Any]]:
+    """What a shipment's header says of the cache it was cut from, and what
+    the receiving cache must equal: each per-page pool's shape without its
+    page dimension, where that dimension sits, and its type."""
+    return [{"shape": [int(d) for i, d in enumerate(p.shape)
+                       if i != page_axis],
+             "axis": page_axis, "dtype": str(p.dtype)} for p in pools]
+
+
+def _carve(buf, seg: Dict[str, Any], layout) -> Tuple[np.ndarray, ...]:
+    """Split one segment's bytes into its blocks, one a pool of `layout`
+    (`pool_layout`), each with the segment's n pages at the pool's page
+    axis."""
     n = seg["n_pages"]
-    shape = (L, Kh, n, ps, D)
-    half = seg["nbytes"] // 2
-    k = np.frombuffer(buf, dtype=dtype, count=half // dtype.itemsize)
-    v = np.frombuffer(buf, dtype=dtype, count=half // dtype.itemsize,
-                      offset=half)
-    return k.reshape(shape), v.reshape(shape)
+    out, at = _read_blocks(buf, [
+        (pool["shape"][:pool["axis"]] + [n] + pool["shape"][pool["axis"]:],
+         _np_dtype(pool["dtype"])) for pool in layout])
+    if at != seg["nbytes"]:
+        raise ValueError(f"segment {seg.get('oid')} holds {seg['nbytes']} "
+                         f"bytes, its layout reads {at}")
+    return out
 
 
 class ShipReader:
@@ -541,13 +583,13 @@ class ShipReader:
     def __init__(self):
         self.store = StoreClient(backend="pershm")
 
-    async def fetch(self, seg: Dict[str, Any], layout, dtype_name: str,
+    async def fetch(self, seg: Dict[str, Any], layout,
                     data_addr: Optional[str] = None,
                     rpc_fetch=None) -> AttachedSegment:
-        """Materialize one segment: shm attach → parallel_fetch → RPC."""
-        dtype = _np_dtype(dtype_name)
+        """Materialize one segment (`layout`: the header's `pool_layout`):
+        shm attach → parallel_fetch → RPC."""
         if local_attach_enabled():
-            att = self._attach(seg["oid"], seg, layout, dtype, delete=False)
+            att = self._attach(seg["oid"], seg, layout, delete=False)
             if att is not None:
                 _counter("kv_ship_attach_hits",
                          "KV segments attached zero-copy same-host").inc()
@@ -557,23 +599,21 @@ class ShipReader:
             got = await parallel_fetch([data_addr], seg["wire"],
                                        seg["nbytes"], 0, (), self.store)
             if got is not None:
-                att = self._attach(seg["wire"], seg, layout, dtype,
-                                   delete=True)
+                att = self._attach(seg["wire"], seg, layout, delete=True)
                 if att is not None:
                     _counter("kv_ship_stream_pulls",
                              "KV segments pulled via parallel_fetch").inc()
                     return att
         if rpc_fetch is not None:
             blob = await rpc_fetch(seg["oid"])
-            k, v = _carve(blob, seg, layout, dtype)
             _counter("kv_ship_rpc_pulls",
                      "KV segments fetched via the RPC fallback").inc()
-            return AttachedSegment(k, v)
+            return AttachedSegment(_carve(blob, seg, layout))
         raise RuntimeError(
             f"kv segment {seg['oid']} unreachable: no shm attach, no data "
             "server, no RPC fetch")
 
-    def _attach(self, oid: str, seg, layout, dtype,
+    def _attach(self, oid: str, seg, layout,
                 delete: bool) -> Optional[AttachedSegment]:
         from multiprocessing import shared_memory
         try:
@@ -583,6 +623,5 @@ class ShipReader:
         if shm.buf.nbytes < seg["nbytes"]:
             shm.close()
             return None
-        k, v = _carve(shm.buf, seg, layout, dtype)
-        return AttachedSegment(k, v, shm=shm, store=self.store, oid=oid,
-                               delete=delete)
+        return AttachedSegment(_carve(shm.buf, seg, layout), shm=shm,
+                               store=self.store, oid=oid, delete=delete)

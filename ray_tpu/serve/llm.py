@@ -28,6 +28,7 @@ import asyncio
 import collections
 import concurrent.futures
 import dataclasses
+import math
 import time
 from typing import Any, Dict, List, Optional
 
@@ -52,8 +53,8 @@ NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
 # size: its index vector always has DEMOTE_GROUP entries, padded with the
 # reserved placeholder page 0, and a longer pass calls it again. Gathered
 # pages are staged (on the device and, once copied, on the host) until the
-# stash's thread has sealed them: over STAGED_CAP_BYTES the loop waits for
-# the oldest hand-off. Constants, in pages and bytes, so every page size is
+# stash's thread has sealed them: over STAGED_CAP_BYTES (or the deployment's
+# own `staged_cap_bytes`) the loop waits for the oldest hand-off. Constants, in pages and bytes, so every page size is
 # covered by one path.
 DEMOTE_GROUP = 8
 STAGED_CAP_BYTES = 128 << 20
@@ -98,6 +99,13 @@ class LLMConfig:
     # installation that no longer exists and is not re-measured;
     # runtime-adjustable via serve user_config → reconfigure().
     decode_chunk: int = 8
+    # Bytes of demoted pages that may be staged (gathered on the device and
+    # copied to the host) before the stash's thread has sealed them; over it
+    # the engine loop waits for the oldest hand-off. None: STAGED_CAP_BYTES.
+    # A deployment whose ONE admission evicts more than that raises it (a
+    # 28k-token prompt of 557 KB pages is 250 MB), or every such admission
+    # stalls the loop at the pace of the stash's disk.
+    staged_cap_bytes: Optional[int] = None
     # Prefix caching (paged mode only; ref: the reference's sglang engine
     # serves RadixAttention prefix reuse): full prompt pages are
     # content-addressed and shared across requests with refcounts — a
@@ -248,6 +256,11 @@ class LLMServer:
                 "speculate requires paged=False: the paged decode kernel "
                 "is single-position; the dense cache path verifies [B, K+1] "
                 "windows natively (set paged=False or speculate=0)")
+        if self.model_cfg.index_topk and not cfg.paged:
+            raise ValueError(
+                "a model with learned sparse attention (index_topk > 0) "
+                "needs paged=True: its indexer keys live in the paged "
+                "cache's third pool")
         if cfg.paged:
             from ray_tpu.ops.paged_attention import PagedKVCache
             from ray_tpu.serve import radix_cache as _radix
@@ -273,9 +286,12 @@ class LLMServer:
             self.page_mgr = _radix.make_page_manager(
                 num_pages, cfg.page_size, B, max_pages,
                 prefix_cache=cfg.prefix_cache, **hooks)
+            # the cache follows the model's schema: a model with an indexer
+            # gets the third per-page pool (and the token-major layout)
             self.cache = PagedKVCache.init(
                 mc.n_layers, mc.n_kv_heads, mc.head_dim, num_pages,
-                cfg.page_size, B, max_pages, dtype=mc.dtype)
+                cfg.page_size, B, max_pages, dtype=mc.dtype,
+                index_dim=mc.index_dim if mc.index_topk else 0)
         else:
             self.page_mgr = None
             self._kv_stash = None
@@ -316,10 +332,31 @@ class LLMServer:
             "slot_wait_max_s": 0.0, "demote_bytes": 0, "demote_passes": 0,
             "demote_wait_s": 0.0, "demote_inflight_max_bytes": 0,
             "restored_in_flight": 0}
+        # what the learned selection and the expert product did, counted on
+        # the host at the syncs that are there (stats()["sparse"], ["moe"]):
+        # a decode row's context is known without asking the device
+        self._sparse_stats = {"context_keys": 0, "selected_keys": 0,
+                              "decode_rows": 0, "dense_rows": 0}
+        self._moe_stats = {"routed_rows": 0, "computed_rows": 0,
+                           "decode_layer_calls": 0,
+                           "decode_experts_touched": 0}
+        from ray_tpu.models.llama import _n_moe_layers
+        from ray_tpu.models.moe import grouped_product
+        mc = self.model_cfg
+        self._moe_layers = _n_moe_layers(mc)
+        self._moe_grouped = self._moe_layers > 0 and grouped_product(
+            mc.n_experts, mc.moe_top_k)
+        # the last decode syncs of a grouped product, (time.monotonic() at
+        # the sync, layer calls, experts touched): how many experts a call
+        # reaches moves with what the streams are saying, so a reader that
+        # times a slice of a run (a profiler's few seconds) needs the
+        # slice's own count and not the run's mean
+        self._moe_recent = collections.deque(maxlen=4096)
         # demotion in flight, loop thread only: the pass being evicted
         # [(page id, node, handle)]; hand-offs the stash's thread has not
         # been seen to finish, oldest first [(future, pages, nbytes)]; and
-        # the staged copy of every page in them, oid -> (k, v, row)
+        # the staged copy of every page in them, oid -> (its group of each
+        # per-page array, row)
         self._evicting = []
         self._handoffs = collections.deque()
         self._staged = {}
@@ -376,6 +413,10 @@ class LLMServer:
 
         cfg = self.config
         model = self.model
+        # a grouped expert product reads the weights of the experts its rows
+        # reach; how many that is only the device knows, so the decode chunk
+        # hands the count back with its tokens (the same sync)
+        count_touched = self._moe_grouped
 
         def sample(logits, key, temps, top_ps, top_ks, want_logp):
             """Per-request greedy / temperature / top-k / top-p (nucleus)
@@ -436,8 +477,7 @@ class LLMServer:
                                      lengths=start_len[None])
             logits, new_row = model.apply(params, tokens, cache=row_view,
                                           paged_chunk_local=chunk_local)
-            new_cache = cache.replace(
-                k_pages=new_row.k_pages, v_pages=new_row.v_pages,
+            new_cache = cache.with_pools(new_row.pools()).replace(
                 lengths=cache.lengths.at[slot].set(true_end))
             return new_cache, logits[0, true_end - start_len - 1]
 
@@ -475,7 +515,9 @@ class LLMServer:
 
             Returns (cache, tokens [B, n], n_valid [B], logps [B, n],
             key'): tokens[i, j] is valid iff j < n_valid[i] — termination
-            is a prefix property. Key discipline matches the host loop
+            is a prefix property. A model on the grouped expert product
+            appends experts_touched [n]: over its layers, the experts each
+            step's rows reached. Key discipline matches the host loop
             exactly (one jax.random.split per step, final carried key
             handed back), so a chunk of n is bit-identical to n per-step
             ticks — parity-tested in tests/test_llm_decode_chunk.py.
@@ -488,8 +530,15 @@ class LLMServer:
             def one_step(carry, _):
                 cache, last, active, emitted, key = carry
                 key, sub = jax.random.split(key)
-                logits, new_cache = model.apply(params, last[:, None],
-                                                cache=cache)
+                touched = ()
+                if count_touched:
+                    (logits, new_cache), seen = model.apply(
+                        params, last[:, None], cache=cache,
+                        mutable=["moe_stats"])
+                    touched = (sum(jax.tree_util.tree_leaves(seen)),)
+                else:
+                    logits, new_cache = model.apply(params, last[:, None],
+                                                    cache=cache)
                 nxt, logp = sample(logits[:, -1, :], sub, temps, top_ps,
                                    top_ks, want_logp)
                 emitted = emitted + active.astype(jnp.int32)
@@ -506,13 +555,14 @@ class LLMServer:
                         length=jnp.where(active, new_cache.length,
                                          cache.length))
                 last = jnp.where(still, nxt, last)
-                return (new_cache, last, still, emitted, key), (nxt, logp)
+                return ((new_cache, last, still, emitted, key),
+                        (nxt, logp) + touched)
 
             init = (cache, last_tokens, active_mask,
                     jnp.zeros_like(last_tokens), key)
-            (cache, _, _, n_valid, key), (toks, logps) = jax.lax.scan(
+            (cache, _, _, n_valid, key), (toks, logps, *touched) = jax.lax.scan(
                 one_step, init, None, length=n)
-            return cache, toks.T, n_valid, logps.T, key
+            return (cache, toks.T, n_valid, logps.T, key, *touched)
 
         def spec_step(params, cache, tokens, active_mask, key,
                       temps, top_ps, top_ks, want_logp):
@@ -568,18 +618,28 @@ class LLMServer:
         self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,),
                                      static_argnums=(11, 12))
         if self._kv_stash is not None:
-            def gather_pages(k_pages, v_pages, idx):
-                """Pool pages `idx` ([L, Kh, P, ps, D] along P) as buffers of
-                their own, page-major ([G, L, Kh, ps, D]) so that each page
-                is contiguous on the host."""
-                take = lambda pool: jnp.moveaxis(  # noqa: E731
-                    jnp.take(pool, idx, axis=2, mode="clip"), 2, 0)
-                return take(k_pages), take(v_pages)
+            axis = self.cache.page_axis
+
+            def gather_pages(pools, idx):
+                """Pages `idx` of every per-page pool (along its page axis)
+                as buffers of their own, page-major ([G, *page shape]) so
+                that each page is contiguous on the host."""
+                if axis == 1:
+                    # [L, P, ...] pools: the layer rides in the gather and
+                    # the pages come out first. A take along axis 1 and a
+                    # moveaxis copied both 3.2 GB pools a group of 8 pages
+                    # (20 ms, HLO and trace on the v5e, PR 28)
+                    return tuple(
+                        pool[jnp.arange(pool.shape[0])[None, :], idx[:, None]]
+                        for pool in pools)
+                return tuple(jnp.moveaxis(
+                    jnp.take(pool, idx, axis=axis, mode="clip"), axis, 0)
+                    for pool in pools)
 
             self._gather_pages = jax.jit(gather_pages)
             # compiled here and not at the first eviction: nothing may
             # compile once a replica serves
-            self._gather_pages(self.cache.k_pages, self.cache.v_pages,
+            self._gather_pages(self.cache.pools(),
                                np.zeros((DEMOTE_GROUP,), np.int32))
         # first token goes through the SAME sampling policy as later ones
         self._sample_first = jax.jit(
@@ -643,6 +703,42 @@ class LLMServer:
             self._m_kv_util.observe(
                 self.page_mgr.pages_in_use / self.page_mgr.num_pages,
                 tags=eng_tags)
+
+    def _count_moe(self, routed_tokens: int, call_tokens: int,
+                   calls: int = 1) -> None:
+        """The expert product's rows, by arithmetic on the host: a routed
+        row is one (token, expert) pair that a real token needs; a computed
+        row is one the programs multiplied. `calls` forwards of
+        `call_tokens` rows each ran (padding and idle slots included); the
+        grouped product computes top_k rows a token, the dropless one-hot
+        dispatch E x C with C = ceil(capacity_factor x top_k x S / E)."""
+        layers = self._moe_layers
+        if not layers:
+            return
+        mc = self.model_cfg
+        E, K = mc.n_experts, mc.moe_top_k
+        per_call = (call_tokens * K if self._moe_grouped else
+                    E * max(1, math.ceil(
+                        mc.capacity_factor * K * call_tokens / E)))
+        self._moe_stats["routed_rows"] += routed_tokens * K * layers
+        self._moe_stats["computed_rows"] += calls * per_call * layers
+
+    def _count_sparse(self, first_context: int, steps: int) -> None:
+        """`steps` decode steps of one row whose first query sees
+        `first_context` keys (its own included) and each next one more: how
+        many keys the contexts held, how many the selection kept (all of
+        them up to `index_topk`), and how many of the steps kept all."""
+        topk = self.model_cfg.index_topk
+        if not topk or steps <= 0:
+            return
+        last = first_context + steps - 1
+        dense = max(0, min(last, topk) - first_context + 1)
+        sp = self._sparse_stats
+        sp["decode_rows"] += steps
+        sp["dense_rows"] += dense
+        sp["context_keys"] += steps * (first_context + last) // 2
+        sp["selected_keys"] += (dense * (2 * first_context + dense - 1) // 2
+                                + (steps - dense) * topk)
 
     def reconfigure(self, user_config: Optional[Dict[str, Any]]):
         """Serve `user_config` hook (replica.py calls this at deployment
@@ -829,6 +925,7 @@ class LLMServer:
         st["prefill_chunks"] += 1
         st["prefill_tokens"] += n
         st["prefill_padded_tokens"] += bucket
+        self._count_moe(n, bucket)
         return last_logits if final else None
 
     @staticmethod
@@ -915,11 +1012,13 @@ class LLMServer:
     # -- tiered KV: radix demote/restore hooks (ISSUE 19) --------------------
     def _demote_page(self, pid: int, node) -> Dict[str, Any]:
         """radix demote_cb: note page `pid` for this pass's gather and give
-        its node the handle its KV ([L, Kh, ps, D] k and v blocks) will be
-        stashed under. Nothing leaves the device here."""
-        k_pages = self.cache.k_pages
-        handle = self._kv_stash.new_handle(
-            k_pages.shape[:2] + k_pages.shape[3:], k_pages.dtype)
+        its node the handle its blocks (one of every per-page pool: k, v,
+        and an indexer's keys where the cache has them) will be stashed
+        under. Nothing leaves the device here."""
+        axis = self.cache.page_axis
+        specs = [(p.shape[:axis] + p.shape[axis + 1:], p.dtype)
+                 for p in self.cache.pools()]
+        handle = self._kv_stash.new_handle(*specs[0], extra=specs[2:])
         self._evicting.append((pid, node, handle))
         return handle
 
@@ -945,35 +1044,36 @@ class LLMServer:
                     part = pages[i:i + DEMOTE_GROUP]
                     idx = np.zeros((DEMOTE_GROUP,), np.int32)
                     idx[:len(part)] = [pid for pid, _, _ in part]
-                    k, v = self._gather_pages(
-                        self.cache.k_pages, self.cache.v_pages, idx)
-                    k.copy_to_host_async()
-                    v.copy_to_host_async()
-                    groups.append((part, k, v))
+                    blocks = self._gather_pages(self.cache.pools(), idx)
+                    for block in blocks:
+                        block.copy_to_host_async()
+                    groups.append((part, blocks))
                 st["demote_passes"] += 1
                 with phase(self._phases, "demote_stash"):
-                    for part, k, v in groups:
-                        self._hand_off(part, k, v)
+                    for part, blocks in groups:
+                        self._hand_off(part, blocks)
                         handed += len(part)
             except Exception as e:  # noqa: BLE001 - demotion is best-effort
                 for _, node, handle in pages[handed:]:
                     self.page_mgr.demotion_failed(node, handle, e)
 
-    def _hand_off(self, part, k, v) -> None:
-        """Give one gathered group to the stash's thread, first waiting
-        for the oldest hand-offs while the staged bytes are over the cap."""
+    def _hand_off(self, part, blocks) -> None:
+        """Give one gathered group (a [G, ...] array of every per-page
+        pool) to the stash's thread, first waiting for the oldest hand-offs
+        while the staged bytes are over the cap."""
         st = self._decode_stats
         nbytes = sum(handle["nbytes"] for _, _, handle in part)
         while (self._handoffs
-               and self._staged_bytes + nbytes > STAGED_CAP_BYTES):
+               and self._staged_bytes + nbytes > (
+                   self.config.staged_cap_bytes or STAGED_CAP_BYTES)):
             t0 = time.perf_counter()
             concurrent.futures.wait([self._handoffs[0][0]])
             st["demote_wait_s"] += time.perf_counter() - t0
             self._reap_handoffs()
-        done = self._kv_stash.put([h for _, _, h in part], k, v)
+        done = self._kv_stash.put([h for _, _, h in part], *blocks)
         self._handoffs.append((done, part, nbytes))
         for row, (_, _, handle) in enumerate(part):
-            self._staged[handle["oid"]] = (k, v, row)
+            self._staged[handle["oid"]] = (blocks, row)
         self._staged_bytes += nbytes
         st["demote_bytes"] += nbytes
         st["demote_inflight_max_bytes"] = max(
@@ -996,9 +1096,10 @@ class LLMServer:
             self._staged_bytes -= nbytes
 
     def _restore_page(self, handle: Dict[str, Any], pid: int) -> bool:
-        """radix restore_cb: fetch the demoted page's KV (bit-exact — the
-        stash round-trips raw bytes, and a page still on its way there is
-        read from its staged copy, waiting for the transfer if it must) and
+        """radix restore_cb: fetch the demoted page's blocks, one of every
+        per-page pool (bit-exact — the stash round-trips raw bytes, and a
+        page still on its way there is read from its staged copy, waiting
+        for the transfer if it must) and
         STAGE it; _flush_restored_pages() lands every staged page in one
         batched scatter right after the allocation. A per-page .at[].set
         would rewrite the whole pool buffer per page, making restore cost
@@ -1006,12 +1107,12 @@ class LLMServer:
         with phase(self._phases, "restore"):
             staged = self._staged.get(handle["oid"])
             if staged is not None:
-                k_group, v_group, row = staged
-                k, v = np.asarray(k_group)[row], np.asarray(v_group)[row]
+                groups, row = staged
+                blocks = tuple(np.asarray(g)[row] for g in groups)
                 self._decode_stats["restored_in_flight"] += 1
             else:
-                k, v = self._kv_stash.get(handle)
-            self._pending_restores.append((pid, k, v))
+                blocks = self._kv_stash.get(handle)
+            self._pending_restores.append((pid, blocks))
         return True
 
     def _flush_restored_pages(self) -> None:
@@ -1023,14 +1124,13 @@ class LLMServer:
         import jax.numpy as jnp
         with phase(self._phases, "restore"):
             staged, self._pending_restores = self._pending_restores, []
-            pids = np.array([p for p, _, _ in staged], dtype=np.int32)
-            ks = jnp.moveaxis(
-                jnp.asarray(np.stack([k for _, k, _ in staged])), 0, 2)
-            vs = jnp.moveaxis(
-                jnp.asarray(np.stack([v for _, _, v in staged])), 0, 2)
-            self.cache = self.cache.replace(
-                k_pages=self.cache.k_pages.at[:, :, pids].set(ks),
-                v_pages=self.cache.v_pages.at[:, :, pids].set(vs))
+            pids = np.array([p for p, _ in staged], dtype=np.int32)
+            axis = self.cache.page_axis
+            at = (slice(None),) * axis + (pids,)
+            self.cache = self.cache.with_pools([
+                pool.at[at].set(jnp.moveaxis(jnp.asarray(
+                    np.stack([blocks[i] for _, blocks in staged])), 0, axis))
+                for i, pool in enumerate(self.cache.pools())])
 
     def _drop_page(self, handle: Dict[str, Any]) -> None:
         self._kv_stash.drop(handle)
@@ -1124,6 +1224,7 @@ class LLMServer:
                             room[i] = self.config.max_seq_len - (
                                 slot.prompt_len + len(slot.generated))
                 with phase(ph, "decode_dispatch"):
+                    touched = []
                     if drafts is not None:
                         # speculative tick: one [B, K+1] verify forward
                         self._sample_key, sub = jax.random.split(
@@ -1140,7 +1241,7 @@ class LLMServer:
                         # stream the per-step loop consumed, so chunking
                         # never changes sampled outputs.
                         (self.cache, toks, n_valid, logp,
-                         self._sample_key) = self._decode_chunk(
+                         self._sample_key, *touched) = self._decode_chunk(
                             self.params, self.cache, jnp.asarray(last),
                             jnp.asarray(mask), self._sample_key,
                             jnp.asarray(temps), jnp.asarray(top_ps),
@@ -1149,9 +1250,9 @@ class LLMServer:
                             any_logp, n)
                 with phase(ph, "decode_sync"):
                     # host blocked, device busy: the one sync of the tick
-                    toks, n_valid, logp = (
+                    toks, n_valid, logp, *touched = (
                         np.asarray(x) for x in jax.device_get(
-                            (toks, n_valid, logp)))
+                            (toks, n_valid, logp, *touched)))
                 with phase(ph, "decode_emit"):
                     sp = self._spec_stats
                     if drafts is not None:
@@ -1162,6 +1263,8 @@ class LLMServer:
                     emitted = 0
                     for i, slot in self._active.items():
                         cnt = int(n_valid[i])
+                        self._count_sparse(
+                            slot.prompt_len + len(slot.generated), cnt)
                         if drafts is not None and i in drafts:
                             # clip: a short draft's zero-padding can
                             # "accidentally" match argmax (still exact
@@ -1175,6 +1278,17 @@ class LLMServer:
                                 break
                     self._note_sync(emitted, time.perf_counter() - t0,
                                     chunk=n)
+                    if n is None:    # one verify forward of K + 1 positions
+                        self._count_moe(emitted, B * (K + 1))
+                    else:
+                        self._count_moe(emitted, B, calls=n)
+                    if touched:   # every step of the chunk ran every layer
+                        calls = n * self._moe_layers
+                        seen = int(touched[0].sum())
+                        self._moe_stats["decode_layer_calls"] += calls
+                        self._moe_stats["decode_experts_touched"] += seen
+                        self._moe_recent.append(
+                            (time.monotonic(), calls, seen))
                     for i in finished:
                         slot = self._active.pop(i)
                         slot.done_event.set()
@@ -1383,6 +1497,14 @@ class LLMServer:
             "stash_worker_s": (self._kv_stash.phases.seconds["put"]
                                if self._kv_stash is not None else 0.0),
         }
+        if self.model_cfg.index_topk:
+            # decode rows only: a prefill chunk's selection is not counted
+            s["sparse"] = dict(
+                self._sparse_stats, topk=self.model_cfg.index_topk,
+                index_pool_bytes=int(self.cache.idx_pages.nbytes))
+        if self.model_cfg.n_experts > 0:
+            s["moe"] = dict(self._moe_stats,
+                            recent_decode_syncs=list(self._moe_recent))
         if self.config.speculate > 0:
             st = dict(self._spec_stats)
             st["accept_rate"] = round(

@@ -43,6 +43,15 @@ from . import kv_transfer
 from .llm import LLMConfig, LLMServer
 
 
+def _pages_of(cache, rows) -> list:
+    """Pages `rows` of every per-page pool of `cache`, as host arrays in the
+    pool's own layout (the pages along its page axis)."""
+    import jax
+    import jax.numpy as jnp
+    return [np.asarray(jax.device_get(jnp.take(p, rows, axis=cache.page_axis)))
+            for p in cache.pools()]
+
+
 def _require_paged(server: LLMServer, who: str):
     if server.page_mgr is None:
         raise ValueError(f"{who} needs LLMConfig(paged=True): KV pages are "
@@ -136,10 +145,9 @@ class PrefillServer(LLMServer):
         job.task = asyncio.ensure_future(self._run_ship(
             ship_id, job, slot_idx, prompt, cached, skip_pages, trace_id,
             temperature, top_p, top_k, logprobs))
-        L, Kh, _n, pg, D = self.cache.k_pages.shape
         return {"ship": True, "ship_id": ship_id,
-                "layout": [int(L), int(Kh), int(pg), int(D)],
-                "dtype": str(self.cache.k_pages.dtype),
+                "layout": kv_transfer.pool_layout(self.cache.pools(),
+                                                  self.cache.page_axis),
                 "prompt_len": P, "page_size": ps,
                 "skip_pages": skip_pages, "total_pages": total_pages,
                 "prefill_cached_tokens": cached,
@@ -212,17 +220,15 @@ class PrefillServer(LLMServer):
     def _publish_pages(self, writer: kv_transfer.ShipWriter, ship_id: str,
                        seg_index: int, slot_idx: int, page_start: int,
                        n_pages: int) -> Dict[str, Any]:
-        """Extract the slot's pages [page_start, page_start+n_pages) as
-        [L,Kh,n,ps,D] host arrays and seal them into one shm segment.
-        Whole raw pages ship — attention masks by length, so the unfilled
-        tail of the final page needs no zero-padding round trip."""
-        import jax
-
+        """Extract the slot's pages [page_start, page_start+n_pages) of
+        every per-page pool as host arrays ([L,Kh,n,ps,D] k and v in the
+        dense layout) and seal them into one shm segment. Whole raw pages
+        ship — attention masks by length, so the unfilled tail of the final
+        page needs no zero-padding round trip."""
         rows = np.asarray(self.page_mgr.table_slice(
             slot_idx, page_start, n_pages), np.int32)
-        k = np.asarray(jax.device_get(self.cache.k_pages[:, :, rows]))
-        v = np.asarray(jax.device_get(self.cache.v_pages[:, :, rows]))
-        return writer.publish(ship_id, seg_index, k, v, page_start)
+        return writer.publish(ship_id, seg_index, _pages_of(self.cache, rows),
+                              page_start, self.cache.page_axis)
 
     async def prefill_wait(self, ship_id: str,
                            have: int = 0) -> Dict[str, Any]:
@@ -299,28 +305,37 @@ class PrefillServer(LLMServer):
                 jnp.float32(cfg.top_p if top_p is None else top_p),
                 jnp.int32(cfg.top_k if top_k is None else top_k),
                 logprobs)
-            k, v = self._extract_kv(slot_idx, P)
+            k, v, *extra = self._extract_kv(slot_idx, P)
         finally:
             self._release_slot(slot_idx)
         out = {"k": k, "v": v, "prompt_len": P, "token": int(first)}
+        if extra:       # a cache's further per-page arrays (indexer keys)
+            out["extra"] = extra
         if logprobs:
             out["logprob"] = float(flogp)
         return out
 
     def _extract_kv(self, slot_idx: int, P: int):
-        """Slot pages → contiguous [L, Kh, P, D] host arrays."""
+        """Slot pages → one host array a per-page pool with the P tokens
+        along one dimension, where the pool has its pages ([L, Kh, P, D] k
+        and v in the dense layout; an indexer's keys [L, P, Di], whatever
+        way its pool packs a page's tokens)."""
         import jax
 
         ps = self.config.page_size
         n = -(-P // ps)
         rows = np.asarray(jax.device_get(
             self.cache.block_tables[slot_idx]))[:n]
-        k = np.asarray(jax.device_get(self.cache.k_pages[:, :, rows]))
-        v = np.asarray(jax.device_get(self.cache.v_pages[:, :, rows]))
-        L, Kh, _n, pg, D = k.shape
-        k = k.reshape(L, Kh, _n * pg, D)[:, :, :P]
-        v = v.reshape(L, Kh, _n * pg, D)[:, :, :P]
-        return k, v
+        axis = self.cache.page_axis
+        out = []
+        for block in _pages_of(self.cache, rows):
+            sh = block.shape
+            per_token = sh[axis + 2:]
+            if int(np.prod(sh[axis + 1:])) != ps * int(np.prod(per_token)):
+                per_token = (int(np.prod(sh[axis + 1:])) // ps,)   # packed
+            tokens = block.reshape(sh[:axis] + (n * ps,) + per_token)
+            out.append(np.take(tokens, np.arange(P), axis=axis))
+        return out
 
 
 class ShipSource:
@@ -394,7 +409,8 @@ class DecodeServer(LLMServer):
         slot_idx, _ = await self._reserve(prompt, P + max_tokens,
                                           use_prefix=False)
         try:
-            self._install_kv(slot_idx, kv["k"], kv["v"], P)
+            self._install_kv(slot_idx, [kv["k"], kv["v"],
+                                        *kv.get("extra", ())], P)
         except BaseException:
             self._release_slot(slot_idx)
             raise
@@ -431,16 +447,13 @@ class DecodeServer(LLMServer):
             header = await source.begin(prompt, skip_pages, trace_id,
                                         temperature, top_p, top_k, logprobs)
             ship_id = header["ship_id"]
-            L, Kh, pg, D = header["layout"]
-            mL, mKh, _n, mpg, mD = (int(x) for x in
-                                    self.cache.k_pages.shape)
-            if ((L, Kh, pg, D) != (mL, mKh, mpg, mD)
-                    or header["dtype"] != str(self.cache.k_pages.dtype)
-                    or header["prompt_len"] != P):
+            layout = header["layout"]
+            mine = kv_transfer.pool_layout(self.cache.pools(),
+                                           self.cache.page_axis)
+            if layout != mine or header["prompt_len"] != P:
                 raise ValueError(
-                    f"shipment layout {header['layout']}/{header['dtype']} "
-                    f"does not match this decode replica's cache "
-                    f"[{mL},{mKh},{mpg},{mD}]/{self.cache.k_pages.dtype}")
+                    f"shipment layout {layout} does not match this decode "
+                    f"replica's cache {mine}")
             total_pages = header["total_pages"]
             data_addr = header.get("data_addr")
             have = 0
@@ -454,14 +467,13 @@ class DecodeServer(LLMServer):
                 for seg in res["segments"]:
                     t_s0 = time.time()
                     att = await reader.fetch(
-                        seg, (L, Kh, pg, D), header["dtype"], data_addr,
+                        seg, layout, data_addr,
                         rpc_fetch=lambda oid: source.fetch(ship_id, oid))
                     try:
                         plen = min(P, (seg["page_start"]
                                        + seg["n_pages"]) * ps)
                         self._install_pages(slot_idx, seg["page_start"],
-                                            seg["n_pages"], att.k, att.v,
-                                            plen)
+                                            seg["n_pages"], att.blocks, plen)
                     finally:
                         att.close()
                     installed = seg["page_start"] + seg["n_pages"]
@@ -566,75 +578,71 @@ class DecodeServer(LLMServer):
             out["logprobs"] = slot.logprobs[:len(toks)]
         return out
 
-    def _install_kv(self, slot_idx: int, k, v, P: int) -> None:
-        """Scatter [L, Kh, P, D] host KV into this slot's allocated pages.
-
-        The scatter runs jitted with the pools DONATED, so XLA updates the
-        page arrays in place — an un-jitted `.at[].set` here would copy
-        both full pools per admitted request (a transient 2x-KV-pool HBM
-        spike on the hot path; r5 review). One compile per page-count `n`,
-        the same bucketing cost profile as chunked prefill."""
+    def _install_kv(self, slot_idx: int, blocks, P: int) -> None:
+        """Scatter host KV, one array a per-page pool with the P tokens
+        contiguous (`_extract_kv`'s form: [L, Kh, P, D] k and v in the dense
+        layout), into this slot's allocated pages. One compile per
+        page-count `n`, the same bucketing cost profile as chunked prefill."""
         import jax
-        import jax.numpy as jnp
 
         ps = self.config.page_size
         n = -(-P // ps)
-        pad = n * ps - P
-        L, Kh, _p, D = np.shape(k)
+        axis = self.cache.page_axis
         rows = np.asarray(jax.device_get(
             self.cache.block_tables[slot_idx]))[:n]
-        dtype = self.cache.k_pages.dtype
 
-        def to_pages(x):
+        def to_pages(x, pool):
             x = np.asarray(x)
-            if pad:
-                x = np.concatenate(
-                    [x, np.zeros((L, Kh, pad, D), x.dtype)], axis=2)
-            return jnp.asarray(x.reshape(L, Kh, n, ps, D), dtype)
+            pad = [(0, 0)] * x.ndim
+            pad[axis] = (0, n * ps - P)
+            x = np.pad(x, pad)
+            return x.reshape(x.shape[:axis] + (n,) + pool.shape[axis + 1:])
 
-        if getattr(self, "_install_jit", None) is None:
-            def install(kp, vp, lengths, knew, vnew, rows, slot, plen):
-                return (kp.at[:, :, rows].set(knew),
-                        vp.at[:, :, rows].set(vnew),
-                        lengths.at[slot].set(plen))
-            self._install_jit = jax.jit(install, donate_argnums=(0, 1, 2))
-        kp, vp, lengths = self._install_jit(
-            self.cache.k_pages, self.cache.v_pages, self.cache.lengths,
-            to_pages(k), to_pages(v), jnp.asarray(rows),
-            jnp.int32(slot_idx), jnp.int32(P))
-        self.cache = self.cache.replace(k_pages=kp, v_pages=vp,
-                                        lengths=lengths)
+        self._scatter_pages(slot_idx, rows, [
+            to_pages(b, p) for b, p in zip(blocks, self.cache.pools())], P)
 
     def _install_pages(self, slot_idx: int, page_start: int, n_pages: int,
-                       k_pages, v_pages, plen: int) -> None:
-        """Scatter one shipment segment's [L,Kh,n,ps,D] page blocks into
-        the slot's pool rows [page_start, page_start+n_pages). The host
-        arrays alias the shm segment (zero-copy all the way from the
-        prefill replica's seal) and the device upload reads straight out
-        of it; pools donated for the same reason as _install_kv."""
+                       blocks, plen: int) -> None:
+        """Scatter one shipment segment's page blocks (one a per-page pool,
+        [L,Kh,n,ps,D] k and v in the dense layout) into the slot's pool rows
+        [page_start, page_start+n_pages). The host arrays alias the shm
+        segment (zero-copy all the way from the prefill replica's seal) and
+        the device upload reads straight out of it."""
+        rows = np.asarray(self.page_mgr.table_slice(
+            slot_idx, page_start, n_pages), np.int32)
+        self._scatter_pages(slot_idx, rows, blocks, plen)
+
+    def _scatter_pages(self, slot_idx: int, rows, blocks, plen: int) -> None:
+        """Pages `rows` of every pool <- `blocks`, and the slot's length.
+
+        The scatter runs jitted with the pools DONATED, so XLA updates the
+        page arrays in place — an un-jitted `.at[].set` here would copy
+        every full pool per admitted request (a transient 2x-KV-pool HBM
+        spike on the hot path; r5 review)."""
         import jax
         import jax.numpy as jnp
 
-        rows = np.asarray(self.page_mgr.table_slice(
-            slot_idx, page_start, n_pages), np.int32)
-        dtype = self.cache.k_pages.dtype
-        if getattr(self, "_install_pages_jit", None) is None:
-            def install(kp, vp, lengths, knew, vnew, rows, slot, plen):
-                return (kp.at[:, :, rows].set(knew),
-                        vp.at[:, :, rows].set(vnew),
+        pools = self.cache.pools()
+        if len(blocks) != len(pools):
+            raise ValueError(f"hand-off carries {len(blocks)} arrays a page, "
+                             f"this cache holds {len(pools)}")
+        if getattr(self, "_scatter_jit", None) is None:
+            at = (slice(None),) * self.cache.page_axis
+
+            def scatter(pools, lengths, new, rows, slot, plen):
+                return (tuple(p.at[at + (rows,)].set(n)
+                              for p, n in zip(pools, new)),
                         lengths.at[slot].set(plen))
-            self._install_pages_jit = jax.jit(install,
-                                              donate_argnums=(0, 1, 2))
-        kp, vp, lengths = self._install_pages_jit(
-            self.cache.k_pages, self.cache.v_pages, self.cache.lengths,
-            jnp.asarray(np.asarray(k_pages), dtype),
-            jnp.asarray(np.asarray(v_pages), dtype),
+            self._scatter_jit = jax.jit(scatter, donate_argnums=(0, 1))
+        pools, lengths = self._scatter_jit(
+            pools, self.cache.lengths,
+            tuple(jnp.asarray(np.asarray(b), p.dtype)
+                  for b, p in zip(blocks, pools)),
             jnp.asarray(rows), jnp.int32(slot_idx), jnp.int32(plen))
         # the upload may alias the shm segment (CPU zero-copy device_put);
         # wait for the scatter so the caller can close the segment safely
-        jax.block_until_ready(kp)
-        self.cache = self.cache.replace(k_pages=kp, v_pages=vp,
-                                        lengths=lengths)
+        jax.block_until_ready(pools)
+        self.cache = self.cache.with_pools(pools).replace(lengths=lengths)
 
 
 class PDServer(DecodeServer):

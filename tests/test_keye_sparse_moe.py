@@ -1,0 +1,471 @@
+"""Keye-VL-2.0's language model at a tiny size on the CPU (ISSUE 28): q/k norm,
+three-component rotary, the grouped expert product, the lightning indexer's
+top-k selection in the uncached forward, the paged prefill (chunk-local and
+continuation) and the paged decode (single steps and the fused chunk), the
+three-pool cache through demotion, restore and the P/D hand-off, and the new
+counters.
+
+The oracle is `_reference_logits`, a test-side copy of the equations of
+`perfbench/references/keye_vl2.py` (all positions at once, plain jax.numpy,
+float32). Everything here runs in float32, so the program must agree with it
+to rounding: 2e-4 on logits of magnitude 1 covers the different order of the
+sums (the engine's key blocks and online softmax, the grouped product's
+sort); a wrong selection moves logits by 1e-2 and more, as the control with
+the selection left out shows.
+"""
+
+import asyncio
+import time
+
+import jax
+import jax.numpy as jnp
+
+import numpy as np
+import pytest
+
+from ray_tpu.models.llama import Llama, LlamaConfig
+from ray_tpu.models.moe import MoEMLP, grouped_experts, grouped_product
+from ray_tpu.ops.paged_attention import PagedKVCache
+from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+TOL = 2e-4
+PS, MAX_PAGES = 8, 24          # pages of 8, rows of up to 192 tokens
+
+
+def _cfg(**kw):
+    return LlamaConfig.keye_tiny(dtype=jnp.float32, param_dtype=jnp.float32,
+                                 max_seq_len=256, **kw)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = _cfg()
+    m = Llama(cfg)
+    params = m.init(jax.random.PRNGKey(7), jnp.zeros((1, 8), jnp.int32))
+    # norm scales and the LayerNorm's bias away from 1 and 0, so that they count
+    params = jax.tree_util.tree_map(
+        lambda x: x + 0.1 * jax.random.normal(
+            jax.random.PRNGKey(x.size), x.shape, x.dtype) if x.ndim == 1 else x,
+        params)
+    return cfg, m, params
+
+
+# ---------------------------------------------------------------------------
+# the test-side reference
+# ---------------------------------------------------------------------------
+
+def _rms(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def _rope(x, pos, theta, sections=None):
+    """x [T, H, D]; pos [T] or [3, T]."""
+    d = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if pos.ndim == 2:
+        comp = np.repeat(np.arange(len(sections)), sections)
+        pos = pos[comp, :].T
+    else:
+        pos = pos[:, None]
+    ang = pos.astype(jnp.float32) * freqs
+    sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
+    x1, x2 = jnp.split(x, 2, -1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _reference_logits(params, tokens, cfg, positions=None, select=True):
+    """[T, V] logits of one sequence. `select=False` is the control: every
+    causal key attended, the selection left out."""
+    p = params["params"]
+    t = len(tokens)
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(t)[None], (3, t))
+    eps, theta = cfg.norm_eps, cfg.rope_theta
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    x = p["embed"]["embedding"][jnp.asarray(tokens)]
+    for i in range(cfg.n_layers):
+        lp = p[f"layers_{i}"]
+        a, ix = lp["attn"], lp["attn"]["indexer"]
+        h = _rms(x, lp["attn_norm"]["scale"], eps)
+        q = _rms((h @ a["wq"]["kernel"]).reshape(t, cfg.n_heads, -1),
+                 a["q_norm"]["scale"], eps)
+        k = _rms((h @ a["wk"]["kernel"]).reshape(t, cfg.n_kv_heads, -1),
+                 a["k_norm"]["scale"], eps)
+        v = (h @ a["wv"]["kernel"]).reshape(t, cfg.n_kv_heads, -1)
+        q = _rope(q, positions, theta, cfg.rope_sections)
+        k = _rope(k, positions, theta, cfg.rope_sections)
+        qi = _rope((h @ ix["wq"]["kernel"]).reshape(t, cfg.index_heads, -1),
+                   positions[0], theta)
+        ki = h @ ix["wk"]["kernel"]
+        mu = ki.mean(-1, keepdims=True)
+        ki = ((ki - mu) * jax.lax.rsqrt(((ki - mu) ** 2).mean(-1, keepdims=True)
+                                        + eps)
+              * ix["k_norm"]["scale"] + ix["k_norm"]["bias"])
+        ki = _rope(ki[:, None], positions[0], theta)[:, 0]
+        w = h @ ix["w"]["kernel"]
+        index = jnp.einsum("tjs,tj->ts", jax.nn.relu(
+            jnp.einsum("tjd,sd->tjs", qi, ki)), w)
+        index = jnp.where(causal, index, -jnp.inf)
+        keep = causal
+        if select and t > cfg.index_topk:
+            sel = jax.lax.top_k(index, cfg.index_topk)[1]
+            keep = jnp.zeros((t, t), bool).at[
+                jnp.arange(t)[:, None], sel].set(True) & causal
+        g = cfg.n_heads // cfg.n_kv_heads
+        s = jnp.einsum("tkgd,skd->kgts", q.reshape(t, cfg.n_kv_heads, g, -1),
+                       k) / np.sqrt(cfg.head_dim)
+        pr = jax.nn.softmax(jnp.where(keep[None, None], s, -jnp.inf), -1)
+        o = jnp.einsum("kgts,skd->tkgd", pr, v).reshape(t, -1)
+        x = x + o @ a["wo"]["kernel"]
+        h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
+        moe = lp["moe"]
+        probs = jax.nn.softmax(h2 @ moe["router"]["kernel"], -1)
+        vals, idx = jax.lax.top_k(probs, cfg.moe_top_k)
+        vals = vals / vals.sum(-1, keepdims=True)
+        gates = (jax.nn.one_hot(idx, cfg.n_experts) * vals[..., None]).sum(1)
+        for e in range(cfg.n_experts):
+            out = (jax.nn.silu(h2 @ moe["w_gate"][e]) * (h2 @ moe["w_up"][e])
+                   ) @ moe["w_down"][e]
+            x = x + gates[:, e:e + 1] * out
+    x = _rms(x, p["final_norm"]["scale"], eps)
+    return x @ p["lm_head"]["kernel"]
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).tolist()
+
+
+# ---------------------------------------------------------------------------
+# the model against the reference
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("t", [12, 16, 17, 70])   # below, at, just past, well past topk
+def test_full_forward_agrees_with_reference(model, t):
+    cfg, m, params = model
+    toks = _tokens(t, t)
+    got, _ = m.apply(params, jnp.asarray(toks)[None])
+    want = _reference_logits(params, toks, cfg)
+    np.testing.assert_allclose(got[0], want, atol=TOL)
+
+
+def test_three_unequal_rotary_components(model):
+    cfg, m, params = model
+    t = 40
+    toks = _tokens(t, 3)
+    pos3 = jnp.stack([jnp.arange(t), jnp.arange(t) // 3, (jnp.arange(t) * 7) % 5])
+    got, _ = m.apply(params, jnp.asarray(toks)[None], positions=pos3[:, None])
+    want = _reference_logits(params, toks, cfg, positions=pos3)
+    np.testing.assert_allclose(got[0], want, atol=TOL)
+    text = _reference_logits(params, toks, cfg)
+    assert float(jnp.abs(want - text).max()) > 1e-2    # the components count
+    # all three equal is one-component rotary exactly
+    same, _ = m.apply(params, jnp.asarray(toks)[None], positions=jnp.broadcast_to(
+        jnp.arange(t)[None, None], (3, 1, t)))
+    plain, _ = m.apply(params, jnp.asarray(toks)[None])
+    np.testing.assert_array_equal(same, plain)
+
+
+def _row_cache(cfg, n_rows=1):
+    cache = PagedKVCache.init(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
+                              n_rows * MAX_PAGES + 1, PS, n_rows, MAX_PAGES,
+                              dtype=jnp.float32, index_dim=cfg.index_dim)
+    tables = 1 + jnp.arange(n_rows * MAX_PAGES).reshape(n_rows, MAX_PAGES)
+    return cache.replace(block_tables=tables.astype(jnp.int32))
+
+
+# (first chunk, continuation chunks, decode steps): contexts below, at and
+# well above index_topk = 16 in each path
+@pytest.mark.parametrize("first,more,n_decode", [
+    (8, (), 6),              # decode below topk, all keys selected
+    (12, (4,), 12),          # continuation ends at topk; decode crosses it
+    (40, (), 4),             # a chunk-local first chunk longer than topk
+    (16, (24, 30), 20),      # continuation chunks and decode well above topk
+])
+def test_paged_prefill_then_decode_agrees_with_reference(model, first, more,
+                                                         n_decode):
+    cfg, m, params = model
+    total = first + sum(more) + n_decode
+    toks = jnp.asarray(_tokens(total, total))[None]
+    want = _reference_logits(params, toks[0].tolist(), cfg)
+    row = _row_cache(cfg)
+    got, pos = [], 0
+    for n, local in [(first, True)] + [(c, False) for c in more]:
+        logits, row = m.apply(params, toks[:, pos:pos + n], cache=row,
+                              paged_chunk_local=local)
+        got.append(logits[0])
+        pos += n
+    for i in range(pos, total):
+        logits, row = m.apply(params, toks[:, i:i + 1], cache=row)
+        got.append(logits[0])
+    np.testing.assert_allclose(jnp.concatenate(got), want, atol=TOL)
+    assert int(row.lengths[0]) == total
+
+
+def test_control_without_the_selection_fails_above_topk(model):
+    """The tolerance sees the mechanism: with every causal key attended the
+    comparison holds up to index_topk tokens and fails past them."""
+    cfg, m, params = model
+    toks = _tokens(70, 70)
+    got, _ = m.apply(params, jnp.asarray(toks)[None])
+    dense = _reference_logits(params, toks, cfg, select=False)
+    err = np.abs(np.asarray(got[0] - dense)).max(-1)
+    assert err[:cfg.index_topk].max() < TOL
+    assert err[cfg.index_topk + 8:].max() > 50 * TOL
+
+
+def test_grouped_product_equals_one_hot_dropless_on_mixtral_tiny():
+    cfg = LlamaConfig.moe_tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    assert not grouped_product(cfg.n_experts, cfg.moe_top_k)
+    assert grouped_product(128, 8) and grouped_product(16, 2)
+    import dataclasses
+    cfg = dataclasses.replace(
+        cfg, capacity_factor=cfg.n_experts / cfg.moe_top_k)   # dropless
+    layer = MoEMLP(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 11, cfg.d_model))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    one_hot = layer.apply(params, x)
+    p = params["params"]
+    xf = x.reshape(-1, cfg.d_model)
+    probs = jax.nn.softmax(xf @ p["router"]["kernel"], -1)
+    vals, idx = jax.lax.top_k(probs, cfg.moe_top_k)
+    vals = vals / vals.sum(-1, keepdims=True)
+    grouped = grouped_experts(xf, vals, idx, p["w_gate"], p["w_up"],
+                              p["w_down"]).reshape(x.shape)
+    np.testing.assert_allclose(grouped, one_hot, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the engine
+# ---------------------------------------------------------------------------
+
+def _llm_cfg(**kw):
+    base = dict(preset="keye_tiny", max_batch_slots=2, max_seq_len=192,
+                paged=True, page_size=PS, prefill_chunk=32, decode_chunk=4,
+                dtype="float32", param_dtype="float32", seed=5)
+    base.update(kw)
+    return LLMConfig(**base)
+
+
+@pytest.fixture(scope="module")
+def loop():
+    return asyncio.new_event_loop()
+
+
+def _generate(loop, srv, prompt, n, **kw):
+    return loop.run_until_complete(asyncio.wait_for(
+        srv.generate(prompt, max_tokens=n, **kw), 300))
+
+
+def test_engine_builds_three_pools_from_the_schema(loop):
+    srv = LLMServer(_llm_cfg(num_pages=40))
+    try:
+        c, mc = srv.cache, srv.model_cfg
+        assert c.page_axis == 1 and len(c.pools()) == 3
+        assert c.k_pages.shape == (mc.n_layers, 40, PS, mc.n_kv_heads,
+                                   mc.head_dim)
+        assert c.idx_pages.size == mc.n_layers * 40 * PS * mc.index_dim
+        assert c.index_dim == mc.index_dim and c.page_size == PS
+        dense = LLMServer(LLMConfig(preset="moe_tiny", paged=True, page_size=PS,
+                                    max_seq_len=64, max_batch_slots=2))
+        assert dense.cache.idx_pages is None and dense.cache.page_axis == 2
+        assert len(jax.tree_util.tree_leaves(dense.cache)) == 4
+        dense._kv_stash.close()
+        with pytest.raises(ValueError, match="paged=True"):
+            LLMServer(_llm_cfg(paged=False))
+    finally:
+        srv._kv_stash.close()
+
+
+@pytest.mark.parametrize("n_prompt", [10, 50, 100])
+def test_engine_logprobs_agree_with_reference_and_chunk_equals_steps(
+        model, loop, n_prompt):
+    """Through `generate`: chunked prefill (a first chunk of 32 > topk, then
+    continuation chunks), then the fused decode chunk; the same with single
+    steps must give the same tokens and log-probabilities bit for bit."""
+    cfg, _, params = model
+    prompt = _tokens(n_prompt, n_prompt)
+    outs = []
+    for chunk in (4, 1):
+        srv = LLMServer(_llm_cfg(decode_chunk=chunk, num_pages=60),
+                        params=params)
+        try:
+            outs.append(_generate(loop, srv, prompt, 11, logprobs=True))
+        finally:
+            srv._kv_stash.close()
+    fused, single = outs
+    assert fused["tokens"] == single["tokens"]
+    np.testing.assert_array_equal(fused["logprobs"], single["logprobs"])
+    seq = prompt + fused["tokens"]
+    logp = jax.nn.log_softmax(_reference_logits(params, seq[:-1], cfg), -1)
+    want = [float(logp[n_prompt - 1 + i, t])
+            for i, t in enumerate(fused["tokens"])]
+    np.testing.assert_allclose(fused["logprobs"], want, atol=TOL)
+
+
+def _pool_bytes(srv, pid):
+    axis = srv.cache.page_axis
+    return [np.asarray(jnp.take(p, pid, axis=axis)).tobytes()
+            for p in srv.cache.pools()]
+
+
+def test_demote_and_restore_are_bit_exact_in_three_pools(model, loop):
+    """Five prompts through a pool that holds two of them: the first one's
+    pages are evicted, demoted to the stash (all three arrays a page) and
+    restored when it is asked again; the restored pages are the bytes that
+    left, and the answer is the same."""
+    _, _, params = model
+    srv = LLMServer(_llm_cfg(num_pages=15, max_seq_len=64, prefill_chunk=16),
+                    params=params)
+    try:
+        prompts = [_tokens(n, 100 + n) for n in (28, 25, 26, 27, 33)]
+        first = _generate(loop, srv, prompts[0], 9)
+        before = {tuple(node.tokens) if hasattr(node, "tokens") else i:
+                  _pool_bytes(srv, node.page)
+                  for i, node in enumerate(srv.page_mgr._walk(prompts[0]))}
+        assert len(before) == 3          # the prompt's three full pages
+        for p in prompts[1:]:
+            _generate(loop, srv, p, 9)
+        d = srv.stats()["decode"]
+        assert d["demoted_pages"] >= 3 and d["demote_failed"] == 0
+        handle_bytes = sum(
+            int(np.prod(p.shape)) // p.shape[1] * p.dtype.itemsize
+            for p in srv.cache.pools())
+        assert d["demote_bytes"] == d["demoted_pages"] * handle_bytes
+        again = _generate(loop, srv, prompts[0], 9)
+        d = srv.stats()["decode"]
+        assert d["restored_pages"] >= 3
+        assert again["tokens"] == first["tokens"]
+        after = [_pool_bytes(srv, node.page)
+                 for node in srv.page_mgr._walk(prompts[0])]
+        assert after == list(before.values())
+    finally:
+        srv._kv_stash.close()
+
+
+def test_stash_handle_records_every_array(tmp_path):
+    from ray_tpu.serve.kv_transfer import KVPageStash
+    stash = KVPageStash(budget_bytes=1 << 20)
+    try:
+        rng = np.random.default_rng(0)
+        k = rng.normal(size=(3, 2, 8, 2, 16)).astype(np.float32)
+        idx = rng.integers(0, 255, (3, 2, 1, 64)).astype(np.uint8)
+        handles = [stash.new_handle(k.shape[1:], k.dtype,
+                                    extra=[(idx.shape[1:], idx.dtype)])
+                   for _ in range(2)]
+        assert handles[0]["extra"] == [{"shape": [2, 1, 64], "dtype": "uint8"}]
+        assert handles[0]["nbytes"] == 2 * k[0].nbytes + idx[0].nbytes
+        assert stash.put(handles, k, k + 1, idx).result(60) == [None, None]
+        for i, h in enumerate(handles):
+            gk, gv, gi = stash.get(h)
+            assert (gk.tobytes(), gv.tobytes(), gi.tobytes()) == (
+                k[i].tobytes(), (k + 1)[i].tobytes(), idx[i].tobytes())
+            assert gi.dtype == np.uint8 and gi.shape == (2, 1, 64)
+        # a hand-off that lacks an array the handle records is an error
+        assert isinstance(stash.put(handles[:1], k, k).result(60)[0],
+                          ValueError)
+    finally:
+        stash.close()
+
+
+@pytest.mark.parametrize("ship", ["1", "0"])     # the shipment plane, the RPC
+def test_pd_hand_off_is_bit_exact_in_three_pools(model, loop, monkeypatch,
+                                                 ship):
+    from ray_tpu.serve.pd import PDServer, PrefillServer
+    monkeypatch.setenv("RAY_TPU_KV_SHIP", ship)
+    _, _, params = model
+    kw = dict(num_pages=60, prefix_cache=False)
+    plain = LLMServer(_llm_cfg(**kw), params=params)
+    prefill = PrefillServer(_llm_cfg(**kw), params=params)
+    pd = PDServer(_llm_cfg(**kw), params=params, prefill=prefill)
+    prompt = _tokens(77, 77)
+    want = _generate(loop, plain, prompt, 9, logprobs=True)
+    got = _generate(loop, pd, prompt, 9, logprobs=True)
+    assert got["tokens"] == want["tokens"]
+    np.testing.assert_allclose(got["logprobs"], want["logprobs"], atol=1e-6)
+    assert pd.stats()["pd_requests"] == 1
+    # the pages the decode replica was handed are the prefill's, bit for bit
+    async def both():
+        kv = await prefill.prefill_kv(prompt)
+        slot, _ = await pd._reserve(prompt, len(prompt) + 1, use_prefix=False)
+        pd._install_kv(slot, [kv["k"], kv["v"], *kv["extra"]], len(prompt))
+        return kv, PrefillServer._extract_kv(pd, slot, len(prompt)), slot
+    kv, back, slot = loop.run_until_complete(both())
+    assert len(kv["extra"]) == 1 and len(back) == 3
+    for sent, got_back in zip([kv["k"], kv["v"], *kv["extra"]], back):
+        assert sent.tobytes() == got_back.tobytes()
+    pd._release_slot(slot)
+
+
+# ---------------------------------------------------------------------------
+# counters
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("first,steps", [(3, 5), (14, 6), (16, 1), (17, 9),
+                                         (40, 8), (10, 0)])
+def test_sparse_counters_arithmetic(loop, first, steps):
+    srv = LLMServer(_llm_cfg(num_pages=20))
+    try:
+        topk = srv.model_cfg.index_topk
+        srv._count_sparse(first, steps)
+        contexts = [first + j for j in range(steps)]
+        got = srv.stats()["sparse"]
+        assert got["decode_rows"] == steps
+        assert got["context_keys"] == sum(contexts)
+        assert got["selected_keys"] == sum(min(c, topk) for c in contexts)
+        assert got["dense_rows"] == sum(c <= topk for c in contexts)
+        assert got["topk"] == topk
+        assert got["index_pool_bytes"] == srv.cache.idx_pages.nbytes == (
+            2 * 20 * PS * srv.model_cfg.index_dim * 4)
+    finally:
+        srv._kv_stash.close()
+
+
+def test_moe_and_sparse_counters_follow_a_request(model, loop):
+    _, _, params = model
+    srv = LLMServer(_llm_cfg(num_pages=40), params=params)
+    try:
+        n_prompt, n_out = 50, 9
+        _generate(loop, srv, _tokens(n_prompt, 1), n_out)
+        st = srv.stats()
+        mc, B = srv.model_cfg, srv.config.max_batch_slots
+        k_layers = mc.moe_top_k * mc.n_layers
+        # prefill: 50 tokens in chunks of 32 and 18 (padded to 32); decode: the
+        # first token comes from the prefill, the other 8 from decode steps
+        steps = st["decode"]["decode_steps"]
+        assert st["moe"]["routed_rows"] == (n_prompt + n_out - 1) * k_layers
+        assert st["moe"]["computed_rows"] == (32 + 32 + steps * B) * k_layers
+        # counted on the device, handed back with the chunk's tokens: every
+        # step runs every layer, and a layer's B x top_k rows reach between
+        # top_k and B x top_k experts
+        calls = steps * mc.n_layers
+        assert st["moe"]["decode_layer_calls"] == calls
+        assert (mc.moe_top_k * calls <= st["moe"]["decode_experts_touched"]
+                <= B * mc.moe_top_k * calls)
+        # and sync by sync with its time, for a reader that times a slice
+        recent = st["moe"]["recent_decode_syncs"]
+        assert len(recent) == st["decode"]["host_syncs"]
+        assert [sum(r[i] for r in recent) for i in (1, 2)] == [
+            calls, st["moe"]["decode_experts_touched"]]
+        assert recent == sorted(recent) and recent[-1][0] <= time.monotonic()
+        sp = st["sparse"]
+        contexts = [n_prompt + 1 + j for j in range(n_out - 1)]
+        assert sp["decode_rows"] == n_out - 1
+        assert sp["context_keys"] == sum(contexts)
+        assert sp["selected_keys"] == (n_out - 1) * mc.index_topk
+        assert sp["dense_rows"] == 0
+        # Mixtral's tiny preset on the one-hot dispatch: E x C rows a call
+        dense = LLMServer(LLMConfig(preset="moe_tiny", paged=True, page_size=PS,
+                                    max_seq_len=64, max_batch_slots=2,
+                                    prefill_chunk=16))
+        _generate(loop, dense, _tokens(10, 2), 3)
+        moe = dense.stats()["moe"]
+        dc = dense.model_cfg
+        assert "sparse" not in dense.stats()
+        assert moe["routed_rows"] == (10 + 2) * dc.moe_top_k * dc.n_layers
+        calls = [16] + [2] * dense.stats()["decode"]["decode_steps"]
+        assert moe["computed_rows"] == sum(
+            dc.n_experts * s for s in calls) * dc.n_layers   # C = S: dropless
+        assert moe["decode_layer_calls"] == moe["decode_experts_touched"] == 0
+        dense._kv_stash.close()
+    finally:
+        srv._kv_stash.close()
