@@ -19,6 +19,14 @@ prefill jobs are queued (continuous batching must admit promptly),
 `decode_chunk` in steady-state decode; streaming slots flush their queue
 once per chunk, in order.
 
+The loop runs one program AHEAD of what it reads: what a chunk starts from
+(each slot's last token, active flag, what is left of its budget and its
+row, its eos and sampling parameters: `SlotState`) lives on the device and
+is carried from chunk to chunk, a prompt's first token joins it there, and
+chunk k+1 is dispatched before chunk k is read (`_tick_loop_inner`). The
+host never blocks on the device with nothing queued behind what it waits
+for; `stats()["decode"]["read_wait_s"]` says when it did.
+
 The per-row `length` mask plays the role of vLLM's page table in round 1:
 slot rows are the "pages", eviction = slot free. A pallas paged-attention
 kernel over a real block table is the round-2 upgrade path.
@@ -30,7 +38,7 @@ import concurrent.futures
 import dataclasses
 import math
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -58,6 +66,36 @@ NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
 # covered by one path.
 DEMOTE_GROUP = 8
 STAGED_CAP_BYTES = 128 << 20
+
+
+class SlotState(NamedTuple):
+    """What a decode chunk starts from, per slot, ON THE DEVICE ([B] arrays
+    beside the cache; a pytree of jax arrays): carried from chunk to chunk,
+    written at `slot` by the join when a prompt's first token exists, never
+    rebuilt by the host. `budget` and `room` count DOWN: tokens the request
+    may still emit, positions its cache row still has. A slot whose `active`
+    is False runs frozen, whatever its other fields hold."""
+    last: Any        # int32: the token the next step feeds
+    active: Any      # bool
+    budget: Any      # int32
+    room: Any        # int32
+    eos: Any         # int32, -1: none
+    temps: Any       # float32
+    top_ps: Any      # float32
+    top_ks: Any      # int32
+
+
+@dataclasses.dataclass
+class _Chunk:
+    """A dispatched decode chunk (or speculative verify) the host has not
+    read: its results as device arrays whose copy to the host has started,
+    and the host's view at dispatch."""
+    seq: int                    # 1-based count of dispatches
+    n: Optional[int]            # chunk length; None: a speculative tick
+    slots: List[tuple]          # (slot idx, _Slot) the host knew to be live
+    out: tuple                  # (toks, n_valid, logp, *touched)
+    t_dispatch: float
+    drafts: Optional[Dict[int, List[int]]] = None
 
 
 @dataclasses.dataclass
@@ -165,6 +203,11 @@ class _Slot:
     # set when the first token exists (prefill complete); TTFT boundary
     first_token: asyncio.Event = dataclasses.field(
         default_factory=asyncio.Event)
+    # decode steps dispatched for this slot whose tokens the host has not
+    # read, and `max_tokens` as the device was told at the join (the host's
+    # may shrink later: a consumer that walked away)
+    ahead: int = 0
+    joined_max: int = 0
 
 
 @dataclasses.dataclass
@@ -314,7 +357,19 @@ class LLMServer:
                     out_shardings=out_sh)()
             else:
                 self.cache = KVCache.init(self.model_cfg, B, cfg.max_seq_len)
-        self._active: Dict[int, _Slot] = {}   # slot idx -> request state
+        # slot idx -> request state, from the join of its first token (which
+        # the host may not have read yet: `generated` is then empty)
+        self._active: Dict[int, _Slot] = {}
+        # the device-resident slot state every decode chunk is carried
+        # through (SlotState), the chunks dispatched and not read (oldest
+        # first), and the first tokens sampled and joined on the device
+        # that the host has not read: (seq, slot idx, slot, token, logprob),
+        # `seq` the dispatch count at the join (later chunks hold the slot)
+        self._slots = self._idle_slots()
+        self._inflight: "collections.deque[_Chunk]" = collections.deque()
+        self._first_pending = collections.deque()
+        self._n_dispatched = 0
+        self._t_read = 0.0
         # speculative-decoding accounting (stats()/serving bench)
         self._spec = None
         self._spec_stats = {"spec_ticks": 0, "decode_ticks": 0,
@@ -331,7 +386,12 @@ class LLMServer:
             "prefill_padded_tokens": 0, "admitted": 0, "slot_wait_s": 0.0,
             "slot_wait_max_s": 0.0, "demote_bytes": 0, "demote_passes": 0,
             "demote_wait_s": 0.0, "demote_inflight_max_bytes": 0,
-            "restored_in_flight": 0}
+            "restored_in_flight": 0,
+            # the loop runs one program ahead of what it reads: chunks
+            # dispatched while an unread one was in flight, first tokens
+            # that joined their slot as device values, and seconds the host
+            # was blocked on a read with NO program queued behind it
+            "run_ahead_chunks": 0, "joined_on_device": 0, "read_wait_s": 0.0}
         # what the learned selection and the expert product did, counted on
         # the host at the syncs that are there (stats()["sparse"], ["moe"]):
         # a decode row's context is known without asking the device
@@ -502,33 +562,44 @@ class LLMServer:
             last = logits[0, true_end - start_len - 1]
             return KVCache(k=k, v=v, length=length), last
 
-        def decode_chunk(params, cache, last_tokens, active_mask, key,
-                         temps, top_ps, top_ks, eos_ids, budgets, rooms,
-                         want_logp, n):
-            """`n` decode steps entirely ON DEVICE (the tentpole): lax.scan
-            over the same [B, 1] forward + sample() the per-step loop ran,
-            with per-slot termination folded into the scan — a slot stops
-            the step it hits its EOS id, its token budget, or its cache
-            row's capacity, and stopped slots stay frozen (length pinned,
-            last token pinned) while the rest continue. ONE host sync per
-            chunk instead of per token.
+        def decode_chunk(params, cache, state, key, want_logp, n):
+            """`n` decode steps entirely ON DEVICE: lax.scan over the same
+            [B, 1] forward + sample() the per-step loop ran, with per-slot
+            termination folded into the scan — a slot stops the step it
+            hits its EOS id, its token budget, or its cache row's capacity,
+            and stopped slots stay frozen (length pinned, last token
+            pinned) while the rest continue.
 
-            Returns (cache, tokens [B, n], n_valid [B], logps [B, n],
-            key'): tokens[i, j] is valid iff j < n_valid[i] — termination
-            is a prefix property. A model on the grouped expert product
-            appends experts_touched [n]: over its layers, the experts each
-            step's rows reached. Key discipline matches the host loop
-            exactly (one jax.random.split per step, final carried key
-            handed back), so a chunk of n is bit-identical to n per-step
-            ticks — parity-tested in tests/test_llm_decode_chunk.py.
+            `state` (SlotState) is what the chunk starts from and what it
+            hands on: the scan carries `last`, `active`, `budget` and
+            `room` and the chunk returns them, so the next chunk is
+            dispatched from this one's result with nothing read by the
+            host in between; `eos` and the sampling parameters ride
+            through unchanged (the join writes them). Cache and state are
+            both donated.
+
+            Returns (cache, state', tokens [B, n], n_valid [B], logps
+            [B, n], key'): tokens[i, j] is valid iff j < n_valid[i] —
+            termination is a prefix property. A model on the grouped expert
+            product appends experts_touched [n]: over its layers, the
+            experts each step's rows reached. Key discipline matches the
+            host loop exactly (one jax.random.split per step, final carried
+            key handed back), so a chunk of n is bit-identical to n
+            per-step ticks — parity-tested in tests/test_llm_decode_chunk.py.
 
             Steps after a slot stops still write one KV entry at its
             frozen length (masked on read, overwritten on slot reuse) —
             the same contract inactive slots already had under the
-            per-step loop, for both cache layouts."""
+            per-step loop, for both cache layouts; a slot that stopped in
+            the chunk before this one and is not yet released runs so
+            through all of it."""
+            eos_ids = state.eos
+            # an idle slot keeps the parameters of its last request: it
+            # must not send an all-greedy batch down the sorting sampler
+            temps = jnp.where(state.active, state.temps, 0.0)
 
             def one_step(carry, _):
-                cache, last, active, emitted, key = carry
+                cache, last, active, budget, room, emitted, key = carry
                 key, sub = jax.random.split(key)
                 touched = ()
                 if count_touched:
@@ -539,11 +610,12 @@ class LLMServer:
                 else:
                     logits, new_cache = model.apply(params, last[:, None],
                                                     cache=cache)
-                nxt, logp = sample(logits[:, -1, :], sub, temps, top_ps,
-                                   top_ks, want_logp)
-                emitted = emitted + active.astype(jnp.int32)
-                done = ((nxt == eos_ids) | (emitted >= budgets)
-                        | (emitted >= rooms))
+                nxt, logp = sample(logits[:, -1, :], sub, temps,
+                                   state.top_ps, state.top_ks, want_logp)
+                step = active.astype(jnp.int32)
+                emitted, budget, room = (emitted + step, budget - step,
+                                         room - step)
+                done = (nxt == eos_ids) | (budget <= 0) | (room <= 0)
                 still = active & ~done
                 # slots not active THIS step must not advance their row
                 if cfg.paged:
@@ -555,22 +627,45 @@ class LLMServer:
                         length=jnp.where(active, new_cache.length,
                                          cache.length))
                 last = jnp.where(still, nxt, last)
-                return ((new_cache, last, still, emitted, key),
+                return ((new_cache, last, still, budget, room, emitted, key),
                         (nxt, logp) + touched)
 
-            init = (cache, last_tokens, active_mask,
-                    jnp.zeros_like(last_tokens), key)
-            (cache, _, _, n_valid, key), (toks, logps, *touched) = jax.lax.scan(
+            init = (cache, state.last, state.active, state.budget,
+                    state.room, jnp.zeros_like(state.last), key)
+            ((cache, last, active, budget, room, n_valid, key),
+             (toks, logps, *touched)) = jax.lax.scan(
                 one_step, init, None, length=n)
-            return (cache, toks.T, n_valid, logps.T, key, *touched)
+            state = state._replace(last=last, active=active, budget=budget,
+                                   room=room)
+            return (cache, state, toks.T, n_valid, logps.T, key, *touched)
 
-        def spec_step(params, cache, tokens, active_mask, key,
-                      temps, top_ps, top_ks, want_logp):
+        def join(state, ints, floats, token):
+            """Slot `ints[0]` starts decoding from `token`, its request's
+            first (a device value straight from `_sample_first`, or a host
+            value where the prompt was prefilled elsewhere): ints =
+            [slot, budget, room, eos, top_k] with budget and room what is
+            left AFTER that token, floats = [temperature, top_p]. A first
+            token that already ends the request (its eos, nothing left of
+            budget or row) leaves the slot inactive; so does a budget of 0
+            sent to take a slot out again."""
+            i, budget, room, eos = ints[0], ints[1], ints[2], ints[3]
+            live = (budget > 0) & (room > 0) & (token != eos)
+            return SlotState(
+                last=state.last.at[i].set(token),
+                active=state.active.at[i].set(live),
+                budget=state.budget.at[i].set(budget),
+                room=state.room.at[i].set(room),
+                eos=state.eos.at[i].set(eos),
+                temps=state.temps.at[i].set(floats[0]),
+                top_ps=state.top_ps.at[i].set(floats[1]),
+                top_ks=state.top_ks.at[i].set(ints[4]))
+
+        def spec_step(params, cache, state, drafts, key, want_logp):
             """Verify K drafts + emit a bonus token in ONE [B, K+1] forward.
 
-            tokens[:, 0] is each slot's last emitted token (its KV is
-            written at the row's length, same lag-by-one contract as
-            decode_step); tokens[:, 1:] are prompt-lookup drafts. Greedy
+            Position 0 is each slot's last emitted token, `state.last` (its
+            KV is written at the row's length, same lag-by-one contract as
+            decode_step); `drafts` [B, K] are prompt-lookup drafts. Greedy
             targets tgt[:, j] = argmax of position j's logits; draft j+1
             is accepted iff it equals tgt[:, j], so every accepted token
             IS the token step-by-step greedy decode would have produced
@@ -581,11 +676,18 @@ class LLMServer:
             rejected positions sits past `length`: masked on read
             (decode_attention's absolute-position mask) and overwritten
             by the next tick's [length, length+K] write before it can
-            ever become readable."""
+            ever become readable.
+
+            The slot state moves as the host's emit loop will: a slot takes
+            its n_emit tokens up to the first that ends it (eos, budget,
+            row), and that one is its `last`."""
+            tokens = jnp.concatenate([state.last[:, None], drafts], axis=1)
+            active_mask = state.active
+            temps = jnp.where(active_mask, state.temps, 0.0)
             logits, new_cache = model.apply(params, tokens, cache=cache)
             logits = logits.astype(jnp.float32)
-            nxt0, logp0 = sample(logits[:, 0, :], key, temps, top_ps,
-                                 top_ks, want_logp)
+            nxt0, logp0 = sample(logits[:, 0, :], key, temps, state.top_ps,
+                                 state.top_ks, want_logp)
             tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K+1]
             greedy = temps <= 0.0
             match = tokens[:, 1:] == tgt[:, :-1]                 # [B, K]
@@ -602,7 +704,21 @@ class LLMServer:
             length = jnp.where(active_mask, cache.length + n_emit,
                                cache.length)
             new_cache = KVCache(k=new_cache.k, v=new_cache.v, length=length)
-            return new_cache, emit, n_emit, lp
+            took = jnp.arange(1, emit.shape[1] + 1)[None, :]     # [1, K+1]
+            ends = ((emit == state.eos[:, None])
+                    | (took >= state.budget[:, None])
+                    | (took >= state.room[:, None])) & (
+                        took <= n_emit[:, None])
+            done = ends.any(axis=-1)
+            taken = jnp.where(active_mask, jnp.where(
+                done, jnp.argmax(ends, axis=-1) + 1, n_emit), 0)
+            last = jnp.take_along_axis(
+                emit, jnp.maximum(taken - 1, 0)[:, None], axis=-1)[:, 0]
+            state = state._replace(
+                last=jnp.where(active_mask, last, state.last),
+                active=active_mask & ~done,
+                budget=state.budget - taken, room=state.room - taken)
+            return new_cache, state, emit, n_emit, lp
 
         if cfg.paged:
             self._prefill = jax.jit(prefill_paged, donate_argnums=(1,),
@@ -610,13 +726,25 @@ class LLMServer:
         else:
             self._prefill = jax.jit(prefill_row, donate_argnums=(1,))
             if cfg.speculate > 0:
-                self._spec = jax.jit(spec_step, donate_argnums=(1,),
-                                     static_argnums=(8,))
+                self._spec = jax.jit(spec_step, donate_argnums=(1, 2),
+                                     static_argnums=(5,))
         # one compiled variant per (want_logp, chunk length); chunk lengths
         # are power-of-two bucketed by _chunk_len so the variant count stays
         # O(log decode_chunk), and n=1 IS the old per-step program
-        self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,),
-                                     static_argnums=(11, 12))
+        self._decode_chunk = jax.jit(decode_chunk, donate_argnums=(1, 2),
+                                     static_argnums=(4, 5))
+        # compiled here and not at the first admission: nothing may compile
+        # once a replica serves (a budget of 0 takes idle slot 0 out again)
+        self._join_fn = jax.jit(join, donate_argnums=(0,))
+        self._leave(0)
+        if cfg.paged:
+            # [slot, length, *row]: one small transfer a write
+            self._set_row_fn = jax.jit(
+                lambda tables, lengths, ints: (
+                    tables.at[ints[0]].set(ints[2:]),
+                    lengths.at[ints[0]].set(ints[1])),
+                donate_argnums=(0, 1))
+            self._write_table_row(0, 0, 0)
         if self._kv_stash is not None:
             axis = self.cache.page_axis
 
@@ -648,6 +776,43 @@ class LLMServer:
                                      k[None], want_logp)),
             static_argnums=(5,))
 
+    def _idle_slots(self) -> SlotState:
+        """The slot state with nothing decoding."""
+        import jax.numpy as jnp
+        B = self.config.max_batch_slots
+        # a buffer of its own each: the state is donated whole
+        return SlotState(
+            last=jnp.zeros((B,), jnp.int32), active=jnp.zeros((B,), bool),
+            budget=jnp.zeros((B,), jnp.int32), room=jnp.zeros((B,), jnp.int32),
+            eos=jnp.full((B,), -1, jnp.int32),
+            temps=jnp.zeros((B,), jnp.float32),
+            top_ps=jnp.ones((B,), jnp.float32),
+            top_ks=jnp.zeros((B,), jnp.int32))
+
+    def _join(self, slot_idx: int, slot: _Slot, first) -> None:
+        """`slot` decodes from the next chunk dispatched: scatter its first
+        token — an int32 scalar, on the device and unseen by the host, or a
+        host value (pd.py: the prompt was prefilled elsewhere) — and what
+        is left of its budget and its row after that token into the
+        device's slot state. Termination is the device's from here on; the
+        host follows it from the tokens it reads."""
+        slot.joined_max = slot.max_tokens
+        self._slots = self._join_fn(
+            self._slots,
+            np.array([slot_idx, slot.max_tokens - 1,
+                      self.config.max_seq_len - slot.prompt_len - 1,
+                      -1 if slot.eos_id is None else slot.eos_id,
+                      slot.top_k], np.int32),
+            np.array([slot.temperature, slot.top_p], np.float32), first)
+        self._active[slot_idx] = slot
+
+    def _leave(self, slot_idx: int) -> None:
+        """Take `slot_idx` out of the device's state (behind whatever chunk
+        is in flight): only the host knows that a consumer walked away."""
+        self._slots = self._join_fn(
+            self._slots, np.array([slot_idx, 0, 0, -1, 0], np.int32),
+            np.array([0.0, 1.0], np.float32), np.int32(0))
+
     def _chunk_len(self) -> int:
         """Adaptive decode-chunk length for THIS tick. Chunk 1 while any
         prompt is still prefilling (a queued request must not wait N device
@@ -655,29 +820,32 @@ class LLMServer:
         check runs per tick); otherwise min(decode_chunk, most remaining
         tokens over active slots), bucketed DOWN to a power of two so the
         jit cache holds O(log decode_chunk) variants, same idiom as the
-        prefill buckets."""
+        prefill buckets. The host's view: what it has read, less the steps
+        already dispatched for a slot (`ahead`). 0 when every slot ends in
+        what is in flight: nothing to dispatch. The device's own budget
+        ends a slot whatever length is chosen here."""
         cfg = self.config
+        rem = 0
+        for slot in self._active.values():
+            known = max(len(slot.generated), 1)    # first token joined, unread
+            rem = max(rem, min(
+                slot.max_tokens - known,
+                cfg.max_seq_len - (slot.prompt_len + known)) - slot.ahead)
+        if rem <= 0:
+            return 0
         if cfg.decode_chunk <= 1 or self._prefill_q or cfg.speculate > 0:
             return 1
-        rem = 1
-        for slot in self._active.values():
-            rem = max(rem, min(
-                slot.max_tokens - len(slot.generated),
-                cfg.max_seq_len - (slot.prompt_len + len(slot.generated))))
         n = min(cfg.decode_chunk, rem)
-        return 1 << (max(n, 1).bit_length() - 1)
+        return 1 << (n.bit_length() - 1)
 
     def lower_decode_chunk(self, n: Optional[int] = None):
         """The fused decode chunk the engine runs each tick, lowered
         (`jax.stages.Lowered`) at chunk length `n` (default `decode_chunk`)
-        against the live params and cache — for reading its compiled HLO or
-        cost analysis. Traces only: nothing is donated or run."""
-        import jax.numpy as jnp
-        zi = jnp.zeros((self.config.max_batch_slots,), jnp.int32)
-        zf = zi.astype(jnp.float32)
+        against the live params, cache and slot state — for reading its
+        compiled HLO or cost analysis. Traces only: nothing is donated or
+        run."""
         return self._decode_chunk.lower(
-            self.params, self.cache, zi, zi.astype(bool), self._sample_key,
-            zf, zf + 1.0, zi, zi - 1, zi, zi, False,
+            self.params, self.cache, self._slots, self._sample_key, False,
             n or self.config.decode_chunk)
 
     def _note_sync(self, tokens: int, dt_s: float,
@@ -824,8 +992,6 @@ class LLMServer:
         reserve the full request up front, so decode never OOMs), then
         allocate. Event-driven: _release_slot wakes every waiter; re-check.
         Returns (slot_idx, cached_prefix_tokens)."""
-        import jax.numpy as jnp
-
         t_in = time.perf_counter()
         if total_len > self.config.max_seq_len:
             raise ValueError(
@@ -885,10 +1051,7 @@ class LLMServer:
                     # stray write hits the first FRESH page and prefill chunk
                     # 1 overwrites it (same contract as the uncached pos-0
                     # write).
-                    self.cache = self.cache.replace(
-                        block_tables=self.cache.block_tables.at[slot_idx].set(
-                            jnp.asarray(row, jnp.int32)),
-                        lengths=self.cache.lengths.at[slot_idx].set(cached))
+                    self._write_table_row(slot_idx, row, cached)
         except BaseException:
             self._release_slot(slot_idx)
             raise
@@ -898,8 +1061,6 @@ class LLMServer:
         """Run ONE chunk of `job`'s prompt; returns final-chunk logits or
         None. Chunk shapes come from a fixed bucket set, so XLA compiles a
         handful of prefill programs total."""
-        import jax.numpy as jnp
-
         P = len(job.prompt)
         start = job.pos
         n = min(self.config.prefill_chunk, P - start)
@@ -911,8 +1072,9 @@ class LLMServer:
                   if final else self.config.prefill_chunk)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = job.prompt[start:start + n]
-        args = (self.params, self.cache, jnp.asarray(padded), job.slot_idx,
-                jnp.int32(start), jnp.int32(start + n))
+        # host values go up with the call itself
+        args = (self.params, self.cache, padded, job.slot_idx,
+                np.int32(start), np.int32(start + n))
         if self.config.paged:
             # start==0 → fresh row's first chunk: exact with chunk-local
             # attention (static flag, no full-row page gather on the hot
@@ -1007,6 +1169,10 @@ class LLMServer:
                     slot.stream_queue.put_nowait(None)
                 self._release_slot(i)
             self._active.clear()
+            # nothing in flight is read any more, and no slot decodes
+            self._inflight.clear()
+            self._first_pending.clear()
+            self._slots = self._idle_slots()
             raise
 
     # -- tiered KV: radix demote/restore hooks (ISSUE 19) --------------------
@@ -1135,166 +1301,85 @@ class LLMServer:
     def _drop_page(self, handle: Dict[str, Any]) -> None:
         self._kv_stash.drop(handle)
 
+    def _write_table_row(self, slot_idx: int, row, length: int) -> None:
+        """The slot's block-table row and its length on the newest cache, in
+        one donated program behind whatever is in flight. (Eager `.at[].set`
+        on arrays a queued chunk has yet to produce held the loop 12-24 ms
+        a call on the v5e: my chip runs, PR 29.)"""
+        ints = np.empty((2 + self.cache.block_tables.shape[1],), np.int32)
+        ints[0], ints[1], ints[2:] = slot_idx, length, row
+        tables, lengths = self._set_row_fn(
+            self.cache.block_tables, self.cache.lengths, ints)
+        self.cache = self.cache.replace(block_tables=tables, lengths=lengths)
+
     def _release_slot(self, i: int):
         """Return slot i to the pool; paged mode also frees its pages and
         zeroes its table row so inactive-slot decode writes land on the
         reserved placeholder page, never on another request's pages."""
         if self.page_mgr is not None:
             self.page_mgr.free(i)
-            self.cache = self.cache.replace(
-                block_tables=self.cache.block_tables.at[i].set(0),
-                lengths=self.cache.lengths.at[i].set(0))
+            self._write_table_row(i, 0, 0)
         self._free.append(i)
         self._capacity_event.set()  # wake admission waiters
 
     async def _tick_loop_inner(self):
         """The continuous-batching engine: each iteration runs ONE fused
-        decode chunk (1.._chunk_len() on-device steps, one host sync) for
-        every active slot AND (at most) one prefill chunk of the oldest
-        queued prompt — a long prompt adds one chunk of latency per tick
-        instead of stalling every stream for its full prefill (chunked
-        prefill; ref: the reference's PD-disaggregation serving pattern).
-        While prompts are queued the decode chunk stays at 1, so admission
-        latency never grows with decode_chunk; streaming slots' queues are
-        flushed once per chunk, in token order."""
-        import jax
-        import jax.numpy as jnp
+        decode chunk (1.._chunk_len() on-device steps) for every active
+        slot AND (at most) one prefill chunk of the oldest queued prompt —
+        a long prompt adds one chunk of latency per tick instead of
+        stalling every stream for its full prefill (chunked prefill; ref:
+        the reference's PD-disaggregation serving pattern). While prompts
+        are queued the decode chunk stays at 1, so admission latency never
+        grows with decode_chunk; streaming slots' queues are flushed once
+        per chunk, in token order.
 
-        B = self.config.max_batch_slots
-        K = self.config.speculate
+        The loop runs one program AHEAD of what it reads, so that it never
+        blocks on the device with nothing queued behind what it waits for.
+        What a chunk starts from is on the device (`self._slots`, carried
+        from chunk to chunk beside `self.cache`), so tick k dispatches
+        chunk k+1 from it and only then reads chunk k, whose copy to the
+        host was started at its dispatch; it emits k's tokens, releases
+        what finished (a slot that ended in k ran frozen through k+1, and
+        its table row is zeroed behind k+1), dispatches one prefill chunk
+        and yields. A prompt's first token is sampled and joined to its
+        slot on the device (`_first_token`); the host reads it just before
+        it reads the first chunk dispatched after the join (where it waits
+        for the prefill's end it does so with that chunk queued behind),
+        or at the end of a tick if it finds it ready. The order
+        of programs on the device is what it always was: one prefill chunk
+        between two decode chunks, a joined slot decodes from the next
+        chunk dispatched.
 
-        n_gram = self.config.spec_ngram
-
-        def emit_one(slot: _Slot, tok: int, lp: float) -> bool:
-            """Append one token to `slot`; True when the slot is done."""
-            slot.generated.append(tok)
-            if slot.ctx:   # incremental prompt-lookup index maintenance
-                ctx = slot.ctx
-                ctx.append(tok)
-                L = len(ctx)
-                if L > n_gram:
-                    # the n-gram ending at L-2 gained a continuation (L-1)
-                    slot.spec_index[tuple(ctx[L - 1 - n_gram:L - 1])] = L - 1
-            if slot.want_logprobs:
-                slot.logprobs.append(lp)
-            if slot.stream_queue is not None:
-                slot.stream_queue.put_nowait(tok)
-            hit_eos = slot.eos_id is not None and tok == slot.eos_id
-            total = slot.prompt_len + len(slot.generated)
-            return (len(slot.generated) >= slot.max_tokens or hit_eos
-                    or total >= self.config.max_seq_len)
-
+        A speculating engine (`speculate > 0`) drafts from the tokens of
+        the tick before, so it reads what it dispatched at once, first
+        tokens too: same calls, nothing in flight across a tick."""
+        ahead = self.config.speculate == 0
         ph, st = self._phases, self._decode_stats
-        while self._active or self._prefill_q:
+        while self._active or self._prefill_q or self._inflight:
             t_tick = time.perf_counter()
+            dispatched = False
             if self._active:
                 with phase(ph, "decode_build"):
+                    self._finish_shrunk()
                     drafts = self._spec_drafts()
-                    mask = np.zeros((B,), bool)
-                    temps = np.zeros((B,), np.float32)
-                    top_ps = np.ones((B,), np.float32)
-                    top_ks = np.zeros((B,), np.int32)
-                    for i, slot in self._active.items():
-                        mask[i] = True
-                        temps[i] = slot.temperature
-                        top_ps[i] = slot.top_p
-                        top_ks[i] = slot.top_k
-                    any_logp = any(s.want_logprobs
-                                   for s in self._active.values())
-                    finished = []
-                    t0 = time.perf_counter()
-                    if drafts is not None:
-                        n = None
-                        last = np.zeros((B, K + 1), np.int32)
-                        for i, slot in self._active.items():
-                            last[i, 0] = slot.generated[-1]
-                            d = drafts.get(i, [])
-                            last[i, 1:1 + len(d)] = d
-                    else:
-                        n = self._chunk_len()
-                        last = np.zeros((B,), np.int32)
-                        eos = np.full((B,), -1, np.int32)  # -1 never matches
-                        budget = np.zeros((B,), np.int32)
-                        room = np.zeros((B,), np.int32)
-                        for i, slot in self._active.items():
-                            last[i] = slot.generated[-1]
-                            if slot.eos_id is not None:
-                                eos[i] = slot.eos_id
-                            budget[i] = slot.max_tokens - len(slot.generated)
-                            room[i] = self.config.max_seq_len - (
-                                slot.prompt_len + len(slot.generated))
-                with phase(ph, "decode_dispatch"):
-                    touched = []
-                    if drafts is not None:
-                        # speculative tick: one [B, K+1] verify forward
-                        self._sample_key, sub = jax.random.split(
-                            self._sample_key)
-                        self.cache, toks, n_valid, logp = self._spec(
-                            self.params, self.cache, jnp.asarray(last),
-                            jnp.asarray(mask), sub, jnp.asarray(temps),
-                            jnp.asarray(top_ps), jnp.asarray(top_ks),
-                            any_logp)
-                    else:
-                        # fused multi-token decode: n steps on device, ONE
-                        # sync. The chunk fn splits the sample key once per
-                        # step and returns the carried key — the same key
-                        # stream the per-step loop consumed, so chunking
-                        # never changes sampled outputs.
-                        (self.cache, toks, n_valid, logp,
-                         self._sample_key, *touched) = self._decode_chunk(
-                            self.params, self.cache, jnp.asarray(last),
-                            jnp.asarray(mask), self._sample_key,
-                            jnp.asarray(temps), jnp.asarray(top_ps),
-                            jnp.asarray(top_ks), jnp.asarray(eos),
-                            jnp.asarray(budget), jnp.asarray(room),
-                            any_logp, n)
+                    n = self._chunk_len()
+                if n:
+                    with phase(ph, "decode_dispatch"):
+                        self._dispatch_chunk(drafts, n)
+                    dispatched = True
+            # all but the chunk just dispatched; all of them when the engine
+            # does not run ahead or has nothing more to dispatch
+            keep = 1 if ahead and dispatched else 0
+            while len(self._inflight) > keep:
+                self._read_first_tokens(before=self._inflight[0].seq)
                 with phase(ph, "decode_sync"):
-                    # host blocked, device busy: the one sync of the tick
-                    toks, n_valid, logp, *touched = (
-                        np.asarray(x) for x in jax.device_get(
-                            (toks, n_valid, logp, *touched)))
+                    # host blocked, device busy: the one sync of the tick,
+                    # with the next chunk already queued behind it
+                    chunk, out = self._read_chunk()
                 with phase(ph, "decode_emit"):
-                    sp = self._spec_stats
-                    if drafts is not None:
-                        sp["spec_ticks"] += 1
-                        sp["drafted"] += sum(len(d) for d in drafts.values())
-                    else:
-                        sp["decode_ticks"] += 1
-                    emitted = 0
-                    for i, slot in self._active.items():
-                        cnt = int(n_valid[i])
-                        self._count_sparse(
-                            slot.prompt_len + len(slot.generated), cnt)
-                        if drafts is not None and i in drafts:
-                            # clip: a short draft's zero-padding can
-                            # "accidentally" match argmax (still exact
-                            # output) but must not count as acceptance
-                            sp["accepted"] += min(cnt - 1, len(drafts[i]))
-                        for j in range(cnt):
-                            emitted += 1
-                            if emit_one(slot, int(toks[i, j]),
-                                        float(logp[i, j])):
-                                finished.append(i)
-                                break
-                    self._note_sync(emitted, time.perf_counter() - t0,
-                                    chunk=n)
-                    if n is None:    # one verify forward of K + 1 positions
-                        self._count_moe(emitted, B * (K + 1))
-                    else:
-                        self._count_moe(emitted, B, calls=n)
-                    if touched:   # every step of the chunk ran every layer
-                        calls = n * self._moe_layers
-                        seen = int(touched[0].sum())
-                        self._moe_stats["decode_layer_calls"] += calls
-                        self._moe_stats["decode_experts_touched"] += seen
-                        self._moe_recent.append(
-                            (time.monotonic(), calls, seen))
-                    for i in finished:
-                        slot = self._active.pop(i)
-                        slot.done_event.set()
-                        if slot.stream_queue is not None:
-                            slot.stream_queue.put_nowait(None)
-                        self._release_slot(i)
+                    self._emit_chunk(chunk, *out)
+            if not self._inflight:
+                self._read_first_tokens()
             if self._prefill_q:
                 job = self._prefill_q[0]
                 try:
@@ -1312,6 +1397,9 @@ class LLMServer:
                     if last_logits is not None:  # prompt fully prefilled
                         with phase(ph, "prefill_first_token"):
                             self._first_token(job, last_logits)
+                        if not ahead:
+                            self._read_first_tokens()
+            self._read_first_tokens(ready_only=True)
             with phase(ph, "yield"):
                 # let admits interleave between ticks: their phases
                 # (admit_allocate, evict, demote, restore) nest in this one,
@@ -1320,29 +1408,205 @@ class LLMServer:
             st["loop_s"] += time.perf_counter() - t_tick
             st["ticks"] += 1
 
+    def _emit_one(self, slot: _Slot, tok: int, lp: float) -> bool:
+        """Append one token to `slot`; True when the slot is done."""
+        slot.generated.append(tok)
+        if slot.ctx:   # incremental prompt-lookup index maintenance
+            ctx, n_gram = slot.ctx, self.config.spec_ngram
+            ctx.append(tok)
+            L = len(ctx)
+            if L > n_gram:
+                # the n-gram ending at L-2 gained a continuation (L-1)
+                slot.spec_index[tuple(ctx[L - 1 - n_gram:L - 1])] = L - 1
+        if slot.want_logprobs:
+            slot.logprobs.append(lp)
+        if slot.stream_queue is not None:
+            slot.stream_queue.put_nowait(tok)
+        hit_eos = slot.eos_id is not None and tok == slot.eos_id
+        total = slot.prompt_len + len(slot.generated)
+        return (len(slot.generated) >= slot.max_tokens or hit_eos
+                or total >= self.config.max_seq_len)
+
+    def _finish(self, i: int, slot: _Slot) -> None:
+        """`slot` has its last token: wake its waiters and give slot `i`
+        back. The device ended it at the same token from the budget it was
+        joined with; a `max_tokens` the host shrank since is the one end the
+        device cannot know of, so the slot is taken out of its state."""
+        if self._active.get(i) is not slot:
+            return
+        del self._active[i]
+        slot.done_event.set()
+        if slot.stream_queue is not None:
+            slot.stream_queue.put_nowait(None)
+        if slot.max_tokens != slot.joined_max:
+            self._leave(i)
+        self._release_slot(i)
+
+    def _finish_shrunk(self) -> None:
+        """A consumer that walked away shrank its slot's `max_tokens` to
+        what it had: such a slot is done now, with no token of it to wait
+        for unless steps of it are in flight."""
+        shrunk = [(i, slot) for i, slot in self._active.items()
+                  if slot.generated and not slot.ahead
+                  and len(slot.generated) >= slot.max_tokens]
+        for i, slot in shrunk:
+            self._finish(i, slot)
+
+    def _dispatch_chunk(self, drafts, n: int) -> None:
+        """Dispatch the next decode chunk of `n` steps (or, with `drafts`,
+        one speculative verify) from the carried slot state and start the
+        copy of its results to the host; nothing is waited for."""
+        import jax
+
+        st = self._decode_stats
+        t0 = time.perf_counter()
+        slots = list(self._active.items())
+        any_logp = any(s.want_logprobs for _, s in slots)
+        if drafts is not None:
+            # speculative tick: one [B, K+1] verify forward
+            n = None
+            padded = np.zeros((self.config.max_batch_slots,
+                               self.config.speculate), np.int32)
+            for i, d in drafts.items():
+                padded[i, :len(d)] = d
+            self._sample_key, sub = jax.random.split(self._sample_key)
+            self.cache, self._slots, *out = self._spec(
+                self.params, self.cache, self._slots, padded, sub, any_logp)
+        else:
+            # fused multi-token decode: n steps on device. The chunk fn
+            # splits the sample key once per step and returns the carried
+            # key — the same key stream the per-step loop consumed, so
+            # chunking never changes sampled outputs.
+            (self.cache, self._slots, toks, n_valid, logp,
+             self._sample_key, *touched) = self._decode_chunk(
+                self.params, self.cache, self._slots, self._sample_key,
+                any_logp, n)
+            out = [toks, n_valid, logp, *touched]
+        for x in out:
+            x.copy_to_host_async()
+        if self._inflight:
+            st["run_ahead_chunks"] += 1
+        for _, slot in slots:
+            slot.ahead += n or 1
+        self._n_dispatched += 1
+        self._inflight.append(_Chunk(
+            seq=self._n_dispatched, n=n, slots=slots, out=tuple(out),
+            t_dispatch=t0, drafts=drafts))
+
+    def _read_chunk(self):
+        """Block on the oldest chunk in flight; returns it and its results
+        as host arrays."""
+        import jax
+
+        chunk = self._inflight.popleft()
+        t0 = time.perf_counter()
+        out = [np.asarray(x) for x in jax.device_get(chunk.out)]
+        if not self._inflight:
+            self._decode_stats["read_wait_s"] += time.perf_counter() - t0
+        return chunk, out
+
+    def _emit_chunk(self, chunk: _Chunk, toks, n_valid, logp, *touched):
+        """Hand the tokens of a chunk just read to their requests, finish
+        what ended in it, and record the sync: everything that describes a
+        chunk is stamped here, at its read."""
+        B, K = self.config.max_batch_slots, self.config.speculate
+        n, drafts = chunk.n, chunk.drafts
+        sp = self._spec_stats
+        if drafts is not None:
+            sp["spec_ticks"] += 1
+            sp["drafted"] += sum(len(d) for d in drafts.values())
+        else:
+            sp["decode_ticks"] += 1
+        emitted = 0
+        finished = []
+        for i, slot in chunk.slots:
+            slot.ahead -= n or 1
+            if slot.done_event.is_set():    # the host ended it meanwhile
+                continue
+            cnt = int(n_valid[i])
+            self._count_sparse(slot.prompt_len + len(slot.generated), cnt)
+            if drafts is not None and i in drafts:
+                # clip: a short draft's zero-padding can "accidentally"
+                # match argmax (still exact output) but must not count as
+                # acceptance
+                sp["accepted"] += min(cnt - 1, len(drafts[i]))
+            for j in range(cnt):
+                emitted += 1
+                if self._emit_one(slot, int(toks[i, j]), float(logp[i, j])):
+                    finished.append((i, slot))
+                    break
+        # what a stream sees: the time from one chunk's read to the next
+        now = time.perf_counter()
+        self._note_sync(emitted, now - max(self._t_read, chunk.t_dispatch),
+                        chunk=n)
+        self._t_read = now
+        if n is None:    # one verify forward of K + 1 positions
+            self._count_moe(emitted, B * (K + 1))
+        else:
+            self._count_moe(emitted, B, calls=n)
+        if touched:   # every step of the chunk ran every layer
+            calls = n * self._moe_layers
+            seen = int(touched[0].sum())
+            self._moe_stats["decode_layer_calls"] += calls
+            self._moe_stats["decode_experts_touched"] += seen
+            self._moe_recent.append((time.monotonic(), calls, seen))
+        for i, slot in finished:
+            self._finish(i, slot)
+
     def _first_token(self, job: _PrefillJob, last_logits):
         """The prompt is fully prefilled: publish its pages, sample the
-        first token (the `int()` is a host sync) and activate the slot."""
+        first token with the policy of every later one (the key a chunk
+        handed back is split for it, then goes into the next chunk) and
+        join it to its slot ON THE DEVICE: token, budget and row's room go
+        into the carried slot state as device values, so the slot decodes
+        from the next chunk dispatched and nothing is read here. The host
+        reads the token later (`_read_first_tokens`), before any token of
+        the chunks that follow."""
         import jax
-        import jax.numpy as jnp
 
         self._prefill_q.popleft()
         if self.page_mgr is not None and self.config.prefix_cache:
             # publish this prompt's full pages for reuse
             self.page_mgr.register_prefix(job.slot_idx, job.prompt.tolist())
+        slot = job.slot
         self._sample_key, sub = jax.random.split(self._sample_key)
         first, flogp = self._sample_first(
-            last_logits, sub, jnp.float32(job.slot.temperature),
-            jnp.float32(job.slot.top_p), jnp.int32(job.slot.top_k),
-            job.slot.want_logprobs)
-        first = int(first)
-        job.slot.generated.append(first)
-        if job.slot.want_logprobs:
-            job.slot.logprobs.append(float(flogp))
-        if job.slot.stream_queue is not None:
-            job.slot.stream_queue.put_nowait(first)
-        self._active[job.slot_idx] = job.slot
-        job.slot.first_token.set()
+            last_logits, sub, np.float32(slot.temperature),
+            np.float32(slot.top_p), np.int32(slot.top_k), slot.want_logprobs)
+        self._join(job.slot_idx, slot, first)
+        first.copy_to_host_async()
+        flogp.copy_to_host_async()
+        self._first_pending.append(
+            (self._n_dispatched, job.slot_idx, slot, first, flogp))
+        self._decode_stats["joined_on_device"] += 1
+
+    def _read_first_tokens(self, before: Optional[int] = None,
+                           ready_only: bool = False) -> None:
+        """Hand the first tokens joined on the device to their requests,
+        oldest first: those joined before chunk `before` was dispatched
+        (that chunk holds their slots' next tokens; None: all of them), or
+        with `ready_only` those whose program has ended, without waiting.
+        A first token that ends its request finishes the slot here; the
+        device left it inactive at the join."""
+        import jax
+
+        pending = self._first_pending
+        while pending and (before is None or pending[0][0] < before):
+            _, i, slot, first, flogp = pending[0]
+            if ready_only and not first.is_ready():
+                return
+            pending.popleft()
+            # a further stretch of the entry `_first_token` counted
+            with phase(self._phases, "prefill_first_token", entries=0):
+                t0 = time.perf_counter()
+                first, flogp = jax.device_get((first, flogp))
+                if not self._inflight:
+                    self._decode_stats["read_wait_s"] += (
+                        time.perf_counter() - t0)
+                done = self._emit_one(slot, int(first), float(flogp))
+                slot.first_token.set()
+                if done:
+                    self._finish(i, slot)
 
     # -- public api ----------------------------------------------------------
     async def generate(self, prompt_ids: List[int], max_tokens: int = 32,
@@ -1484,7 +1748,8 @@ class LLMServer:
                 "prefill_tokens", "prefill_padded_tokens", "admitted",
                 "slot_wait_s", "slot_wait_max_s", "demote_bytes",
                 "demote_passes", "demote_wait_s",
-                "demote_inflight_max_bytes", "restored_in_flight")},
+                "demote_inflight_max_bytes", "restored_in_flight",
+                "run_ahead_chunks", "joined_on_device", "read_wait_s")},
             # the page manager's and the stash's own tallies, read and not
             # counted twice (0 where the engine has no such tier)
             **{k: getattr(self.page_mgr, k, 0) for k in (

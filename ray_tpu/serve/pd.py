@@ -529,7 +529,9 @@ class DecodeServer(LLMServer):
             if slot.stream_queue is not None:
                 slot.stream_queue.put_nowait(None)
         else:
-            self._active[slot_idx] = slot
+            # the same join as a colocated prompt's first token, with a
+            # host value: the slot decodes from the next chunk dispatched
+            self._join(slot_idx, slot, np.int32(first))
             self._ensure_tick_loop()
             if trace_id is not None and tracing.enabled():
                 t_act = time.time()

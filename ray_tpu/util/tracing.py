@@ -331,13 +331,16 @@ class phase:
     and `RAY_TPU_TRACE` is not asked: the counters are exact whatever it
     says, and with no profiler session an annotation is a flag test. Phases
     nest; all of one `totals` run on one thread, and an `await` inside one
-    means the other tasks' phases are its children."""
+    means the other tasks' phases are its children. `entries=0` is a further
+    stretch of an entry counted where it began (a phase whose work is
+    dispatched at one place of a loop and read at another)."""
 
-    __slots__ = ("_totals", "_key", "_ann", "_t0")
+    __slots__ = ("_totals", "_key", "_entries", "_ann", "_t0")
 
-    def __init__(self, totals: PhaseTotals, key: str):
+    def __init__(self, totals: PhaseTotals, key: str, entries: int = 1):
         self._totals = totals
         self._key = key
+        self._entries = entries
 
     def __enter__(self):
         cls = _annotation or _trace_annotation()
@@ -353,7 +356,7 @@ class phase:
         dt = time.perf_counter() - self._t0
         totals, key = self._totals, self._key
         totals.seconds[key] += dt
-        totals.counts[key] += 1
+        totals.counts[key] += self._entries
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
         return False

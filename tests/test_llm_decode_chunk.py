@@ -113,6 +113,76 @@ def test_mixed_budgets_slot_finishes_while_others_run(paged):
             assert a["logprobs"] == b["logprobs"]
 
 
+# -- requests admitted while others decode (run-ahead engine) -----------------
+# The engine dispatches chunk k+1 before it reads chunk k, and a prompt's first
+# token joins its slot on the device: a late request must still get exactly its
+# own solo greedy run, whatever ends it.
+LATE_PROMPT = [21, 22, 23, 24, 25, 26]
+LATE_CASES = {
+    # name: (prompt, generate kwargs; eos_id "first" = the solo first token)
+    "first_is_eos": (LATE_PROMPT, dict(max_tokens=9, eos_id="first")),
+    "max_tokens_1": (LATE_PROMPT, dict(max_tokens=1)),
+    "max_tokens_2": (LATE_PROMPT, dict(max_tokens=2)),
+    "budget_mid_chunk": (LATE_PROMPT, dict(max_tokens=6)),
+    # P + max_tokens == max_seq_len: the row is full at the last token
+    "row_fills": (list(range(3, 3 + 117)), dict(max_tokens=11)),
+}
+_SOLO = {}
+
+
+def _solo(paged, case):
+    """The late request alone on the per-step (chunk=1) server."""
+    if (paged, case) not in _SOLO:
+        prompt, kw = LATE_CASES[case]
+        kw = dict(kw, logprobs=True)
+        if kw.get("eos_id") == "first":
+            first = _gen(_server(1, paged), [prompt], max_tokens=1)[0]
+            kw["eos_id"] = first["tokens"][0]
+        _SOLO[(paged, case)] = (kw, _gen(_server(1, paged), [prompt], **kw)[0])
+    return _SOLO[(paged, case)]
+
+
+@pytest.mark.parametrize("case", sorted(LATE_CASES))
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_late_admission_parity(chunk, paged, case):
+    """Two requests decode; a third is admitted mid-flight and ends by its
+    first token being eos, max_tokens of 1 or 2, a budget that runs out
+    inside a chunk, or a full row. Each of the three equals its solo run,
+    tokens and logprobs, and every slot and page comes back."""
+    srv = _server(chunk, paged)
+    kw, want = _solo(paged, case)
+    prompt = LATE_CASES[case][0]
+    base = _base(paged)
+
+    async def go():
+        early = [asyncio.ensure_future(
+            srv.generate(list(p), max_tokens=12, logprobs=True))
+            for p in PROMPTS[:2]]
+        # until both decode: their first chunks are in flight or read
+        while (len(srv._active) < 2
+               or any(len(s.generated) < 2 for s in srv._active.values())):
+            await asyncio.sleep(0)
+        late = await srv.generate(list(prompt), **kw)
+        return late, await asyncio.gather(*early)
+
+    late, early = asyncio.run(go())
+    assert late["tokens"] == want["tokens"], case
+    assert late["logprobs"] == want["logprobs"]
+    if case == "first_is_eos":
+        assert late["tokens"] == []
+    else:
+        assert len(late["tokens"]) == kw["max_tokens"]
+    for a, b in zip(base, early):
+        assert a["tokens"] == b["tokens"]
+        assert a["logprobs"] == b["logprobs"]
+    st = srv.stats()
+    assert st["active"] == 0 and st["free_slots"] == 4
+    assert not np.asarray(srv._slots.active).any()
+    if paged:
+        assert st["pages_in_use"] == st["prefix_cached_pages"]
+
+
 def test_stream_queue_parity():
     """generate_stream consumers see the same tokens in the same order —
     the chunked loop flushes each slot's queue per chunk, in token order.
@@ -150,19 +220,25 @@ def test_seq_capacity_terminates_in_scan():
     allows 8 — the max-seq-len rung of the in-scan termination mask."""
     import jax.numpy as jnp
 
+    from ray_tpu.serve.llm import SlotState
+
     srv = _server(8)
     B = srv.config.max_batch_slots
     mask = np.zeros((B,), bool)
     mask[0] = True
-    cache, toks, n_valid, logps, key = srv._decode_chunk(
-        srv.params, srv.cache, jnp.asarray(np.full((B,), 3, np.int32)),
-        jnp.asarray(mask), srv._sample_key,
-        jnp.zeros((B,), np.float32), jnp.ones((B,), np.float32),
-        jnp.zeros((B,), np.int32), jnp.full((B,), -1, np.int32),
-        jnp.full((B,), 8, np.int32),          # budget: 8 tokens allowed
-        jnp.asarray(np.where(mask, 2, 0).astype(np.int32)),  # room: 2
-        False, 8)
-    srv.cache, srv._sample_key = cache, key   # old cache was donated
+    state = SlotState(
+        last=jnp.full((B,), 3, jnp.int32), active=jnp.asarray(mask),
+        budget=jnp.full((B,), 8, jnp.int32),   # budget: 8 tokens allowed
+        room=jnp.asarray(np.where(mask, 2, 0).astype(np.int32)),  # room: 2
+        eos=jnp.full((B,), -1, jnp.int32), temps=jnp.zeros((B,), jnp.float32),
+        top_ps=jnp.ones((B,), jnp.float32), top_ks=jnp.zeros((B,), jnp.int32))
+    cache, state, toks, n_valid, logps, key = srv._decode_chunk(
+        srv.params, srv.cache, state, srv._sample_key, False, 8)
+    # old cache was donated; the slot state the chunk hands on is the
+    # engine's again: slot 0 ended in the chunk, with 6 of its budget left
+    srv.cache, srv._sample_key, srv._slots = cache, key, state
+    assert not np.asarray(state.active).any()
+    assert int(state.budget[0]) == 6 and int(state.room[0]) == 0
     n_valid = np.asarray(n_valid)
     assert int(n_valid[0]) == 2
     assert all(int(n_valid[i]) == 0 for i in range(1, B))

@@ -132,7 +132,8 @@ def test_counters_are_all_there_at_zero_from_construction():
                 "demoted_pages", "restored_pages", "demote_failed",
                 "demote_bytes", "stash_spilled_pages", "host_syncs", "tokens",
                 "demote_passes", "demote_wait_s", "demote_inflight_max_bytes",
-                "restored_in_flight", "stash_worker_s"):
+                "restored_in_flight", "stash_worker_s", "run_ahead_chunks",
+                "joined_on_device", "read_wait_s"):
         assert d[key] == 0, key
     assert d["demote_last_error"] is None
     assert d["phase_s"] == dict.fromkeys(LOOP_PHASES + NESTED_PHASES, 0.0)

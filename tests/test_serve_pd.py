@@ -95,6 +95,56 @@ def test_pd_logprobs_and_eos(servers):
     assert got_eos["tokens"] == []
 
 
+@pytest.mark.parametrize("case", ["late_join", "max_tokens_1",
+                                  "max_tokens_2", "walks_away"])
+def test_pd_first_token_joins_with_a_host_value(servers, case):
+    """The decode replica knows a request's first token on the host (the
+    prefill replica sampled it), so its slot joins the device-resident slot
+    state through the SAME join as a colocated first token, with a host
+    value: it decodes from the next chunk dispatched while another request's
+    chunk is in flight, and ends where the colocated engine ends it."""
+    plain, _, pd = servers
+    long_p, late_p = list(range(40, 70)), [9, 4, 17] * 7
+    n_late = {"max_tokens_1": 1, "max_tokens_2": 2}.get(case, 9)
+    before = pd.stats()["decode"]
+
+    async def late(server):
+        if case != "walks_away":
+            return (await server.generate(late_p, max_tokens=n_late))["tokens"]
+        got = []
+        async for tok in server.generate_stream(late_p, max_tokens=40):
+            got.append(tok)
+            if len(got) == 4:
+                break
+        return got
+
+    async def both(server):
+        first = asyncio.ensure_future(server.generate(long_p, max_tokens=20))
+        while not server._inflight:
+            await asyncio.sleep(0)       # the long request decodes
+        out = await late(server)
+        return (await first)["tokens"], out
+
+    async def ref():
+        return ((await plain.generate(long_p, max_tokens=20))["tokens"],
+                (await plain.generate(late_p, max_tokens=40))["tokens"])
+
+    want_long, want_late = asyncio.run(ref())
+    got_long, got_late = asyncio.run(both(pd))
+    assert got_long == want_long
+    assert got_late == want_late[:len(got_late)]
+    assert len(got_late) == (4 if case == "walks_away" else n_late)
+    d = pd.stats()["decode"]
+    assert d["joined_on_device"] == before["joined_on_device"]  # host values
+    if case == "late_join":
+        assert d["run_ahead_chunks"] > before["run_ahead_chunks"]
+    st = pd.stats()
+    assert st["active"] == 0 and st["free_slots"] == 4
+    assert st["pages_in_use"] == 0
+    assert not np.asarray(pd._slots.active).any()
+    assert not pd._inflight
+
+
 def test_pd_requires_paged():
     from ray_tpu.serve.llm import LLMConfig
     from ray_tpu.serve.pd import PrefillServer
