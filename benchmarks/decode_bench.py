@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import KVCache, Llama, LlamaConfig
-from ray_tpu.ops.paged_attention import PagedKVCache, PageManager
+from ray_tpu.ops.paged_attention import PagedKVCache
+from ray_tpu.serve.radix_cache import PageManager
 
 B = int(os.environ.get("B", 8))
 SMAX = int(os.environ.get("SMAX", 1024))

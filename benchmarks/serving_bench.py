@@ -35,7 +35,7 @@ PROMPT_LEN = int(os.environ.get("PROMPT_LEN", 64))
 ROUNDS = int(os.environ.get("ROUNDS", 3))
 SECTIONS = set(s.strip() for s in os.environ.get(
     "SECTIONS",
-    "dense,paged,prefix,speculative,pd,tiered").split(",") if s.strip())
+    "dense,paged,prefix,speculative,pd").split(",") if s.strip())
 
 
 def bench_mode(paged: bool):
@@ -306,115 +306,6 @@ def bench_pd():
                 saved / max(saved + shipped, 1.0), 3)}
 
 
-def bench_tiered():
-    """Tiered KV memory under a working set ≫ the device pool (ISSUE 19):
-    F prompt families of long shared prefixes, the paged-KV pool capped to
-    ≤ 1/4 of the working set, visited round-robin so every family's pages
-    ride the radix cache's demote ladder (pool → stash shm → stash disk)
-    before the family comes back. Tiered (radix index + demote/restore
-    stash, the default build) vs the thrash baseline (RAY_TPU_RADIX=0
-    RAY_TPU_SPILL_KV=0: flat cache whose evictions discard, so every
-    re-hit repays the full prefill). On CPU the tiny preset's KV is
-    widened (bench_pd idiom) so prefill compute — the cost the restore
-    path avoids — dominates the measurement, and every token id stays in
-    the tiny vocab. Both modes are the same build with knobs turned down;
-    the comparison isolates the tier ladder, not a code-version diff."""
-    import jax
-
-    from ray_tpu.serve.llm import LLMConfig, LLMServer
-
-    on_tpu = jax.default_backend() not in ("cpu",)
-    page = 64 if on_tpu else 16
-    pages_per_prompt = int(os.environ.get("TIER_PAGES", 32))
-    plen = pages_per_prompt * page
-    fams = int(os.environ.get("TIER_FAMILIES", 8))
-    gen = int(os.environ.get("TIER_MAX_TOKENS", 2))
-    rounds = int(os.environ.get("TIER_ROUNDS", 2))  # measured re-hit rounds
-    # pool ≤ 1/4 of the working set (+1: page 0 is the reserved null page)
-    num_pages = (fams * pages_per_prompt) // 4 + 1
-    prompts = [[(f * 53 + i) % 251 + 1 for i in range(plen)]
-               for f in range(fams)]
-
-    def run(tiered: bool):
-        prev = {k: os.environ.get(k)
-                for k in ("RAY_TPU_RADIX", "RAY_TPU_SPILL_KV")}
-        os.environ["RAY_TPU_RADIX"] = "1" if tiered else "0"
-        os.environ["RAY_TPU_SPILL_KV"] = "1" if tiered else "0"
-        try:
-            cfg = LLMConfig(
-                preset="llama_125m" if on_tpu else "tiny",
-                max_batch_slots=2, max_seq_len=plen + gen + 2 * page,
-                paged=True, page_size=page, prefill_chunk=64,
-                prefix_cache=True, seed=0, num_pages=num_pages,
-                model_overrides=None if on_tpu else dict(
-                    n_layers=4, n_kv_heads=4, n_heads=4, head_dim=64,
-                    max_seq_len=plen + 64))
-            srv = LLMServer(cfg)
-
-            def rnd():
-                outs = []
-                for p in prompts:
-                    t0 = time.perf_counter()
-                    out = asyncio.run(srv.generate(p, max_tokens=gen))
-                    outs.append((out["ttft_s"], out["tokens"],
-                                 time.perf_counter() - t0))
-                return outs
-
-            cold = rnd()   # round 0: compile + cold prefill, populates tree
-            rnd()          # round 1: warm-shape compile round, discarded
-            ttfts, walls, toks = [], 0.0, 0
-            tokens_by_round = []
-            for _ in range(rounds):
-                outs = rnd()
-                tokens_by_round.append([t for _, t, _ in outs])
-                for ttft, tks, wall in outs:
-                    ttfts.append(ttft)
-                    walls += wall
-                    toks += len(tks)
-            # bit-identical restore: every measured re-hit (prefill served
-            # from restored pages) reproduces the cold round's tokens
-            cold_toks = [t for _, t, _ in cold]
-            bit_identical = all(r == cold_toks for r in tokens_by_round)
-            ttfts.sort()
-            stats = srv.stats()
-            rec = {"tokens_per_s": round(toks / max(walls, 1e-9), 1),
-                   "ttft_p50_ms": round(ttfts[len(ttfts) // 2] * 1e3, 1),
-                   "requests": len(ttfts),
-                   "bit_identical_rehits": bit_identical}
-            if tiered:
-                rec["radix"] = stats.get("radix")
-                rec["kv_stash"] = stats.get("kv_stash")
-            return rec, cold_toks
-        finally:
-            for k, v in prev.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-
-    tiered, toks_t = run(True)
-    thrash, toks_f = run(False)
-    speedup = round(
-        tiered["tokens_per_s"] / max(thrash["tokens_per_s"], 1e-9), 2)
-    ttft_ratio = round(
-        tiered["ttft_p50_ms"] / max(thrash["ttft_p50_ms"], 1e-9), 3)
-    rec = {"families": fams, "pages_per_prompt": pages_per_prompt,
-           "pool_pages": num_pages - 1,
-           "working_set_over_pool": round(
-               fams * pages_per_prompt / max(num_pages - 1, 1), 2),
-           "tiered": tiered, "thrash": thrash,
-           "speedup_tokens_per_s": speedup,
-           "ttft_p50_ratio": ttft_ratio,
-           "outputs_match_thrash": toks_t == toks_f}
-    # ISSUE 19 acceptance gates, asserted inside the measured record
-    assert tiered["bit_identical_rehits"], rec
-    assert rec["outputs_match_thrash"], rec
-    assert (tiered.get("radix") or {}).get("restored_pages", 0) > 0, rec
-    assert speedup >= 2.0, rec
-    assert ttft_ratio <= 0.5, rec
-    return rec
-
-
 def smoke() -> int:
     """Tier-1 CPU gate (run as `serving_bench.py --smoke`): one tiny PD
     round trip through the streaming plane, asserting the kv_ship counters
@@ -515,11 +406,6 @@ def main():
             out["pd"] = bench_pd()
         except Exception as e:  # noqa: BLE001 - record the failure, continue
             out["pd"] = {"error": repr(e)[:200]}
-    if "tiered" in SECTIONS:
-        try:
-            out["tiered"] = bench_tiered()
-        except Exception as e:  # noqa: BLE001 - record the failure, continue
-            out["tiered"] = {"error": repr(e)[:200]}
     print(json.dumps(out))
 
 

@@ -30,6 +30,7 @@ from ray_tpu.ops.attention import apply_rope, decode_attention, mha_reference
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          paged_attention_reference,
+                                         row_keys_values,
                                          sparse_attention_reference,
                                          sparse_paged_decode,
                                          sparse_paged_prefill,
@@ -334,14 +335,7 @@ class Attention(nn.Module):
                 # reuse decode_attention's absolute-position causal mask.
                 # B is 1 here (row view), so the gather is one row's
                 # capacity per layer.
-                kp = cache.k_pages[layer_idx]      # [Kh, P, ps, D]
-                vp = cache.v_pages[layer_idx]
-                tb = cache.block_tables            # [B, mp]
-                kh_, d_ = kp.shape[0], kp.shape[-1]
-                k_all = kp[:, tb].transpose(1, 2, 3, 0, 4).reshape(
-                    b, -1, kh_, d_)
-                v_all = vp[:, tb].transpose(1, 2, 3, 0, 4).reshape(
-                    b, -1, kh_, d_)
+                k_all, v_all = row_keys_values(cache, layer_idx)
                 out = decode_attention(q, k_all, v_all, positions[:, 0])
             new_cache_kv = cache
         elif cache is not None:

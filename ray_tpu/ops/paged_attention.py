@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -196,7 +197,8 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
 # ---------------------------------------------------------------------------
 # Paged KV cache: page pool + per-sequence block tables (vLLM's PagedAttention
 # memory model, jax-functional — the pool/table are pytree leaves updated
-# with pure scatters inside jit; page allocation is host-side bookkeeping).
+# with pure scatters inside jit; page allocation is host-side bookkeeping,
+# serve/radix_cache.py PageManager).
 # ---------------------------------------------------------------------------
 
 import flax.struct
@@ -224,8 +226,9 @@ class PagedKVCache(flax.struct.PyTreeNode):
 
     block_tables: [B, max_pages]; lengths: [B]. Rows whose slot is free have
     length 0 and table entries 0. `page_axis` is where a pool's page index
-    sits; whatever moves pages (demotion, restore, the P/D hand-off) goes
-    over `pools()` and that axis and so carries every per-page array.
+    sits: the functions under "Moving whole pages" below are its only
+    readers, and whatever moves pages (demotion, restore, the P/D hand-off)
+    calls them and so carries every per-page array.
     """
     k_pages: jax.Array
     v_pages: jax.Array
@@ -410,6 +413,117 @@ def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
     return cache.with_pools([
         p.at[layer_idx, :, page_ids, offs].set(n)
         for p, n in zip(pools, flat)])
+
+
+# ---------------------------------------------------------------------------
+# Moving whole pages. The only functions that index a pool by its page axis:
+# whatever takes pages out of the pools or puts them in (demotion, restore,
+# the P/D hand-off, a prefill continuation's row gather) calls these, so a
+# cache kind with another set of per-page arrays changes this file and the
+# model's block, and nothing that carries pages.
+# ---------------------------------------------------------------------------
+
+def page_layout(cache: PagedKVCache) -> list:
+    """What one page of `cache` consists of: for each per-page pool, in the
+    order of `pools()`, its block's shape (the pool's without the page
+    dimension) and type, and `axis`, where an array of n pages in the pool's
+    own form holds them. Plain values: a shipment's header carries the list
+    (the receiving cache's must equal it) and a stash handle records it."""
+    axis = cache.page_axis
+    return [{"shape": [int(d) for i, d in enumerate(p.shape) if i != axis],
+             "axis": axis, "dtype": str(p.dtype)} for p in cache.pools()]
+
+
+def gather_pages(cache: PagedKVCache, idx, page_major: bool = True) -> tuple:
+    """Pages `idx` [n] of every per-page pool as buffers of their own:
+    page-major ([n, *block shape]), so that each page is contiguous on the
+    host, or in the pool's own form with the n pages where the pool has its
+    pages ([L, Kh, n, ps, D] in the dense layout: a shipment's bytes)."""
+    axis = cache.page_axis
+    if page_major and axis == 1:
+        # [L, P, ...] pools: the layer rides in the gather and the pages
+        # come out first. A take along axis 1 and a moveaxis copied both
+        # 3.2 GB pools a group of 8 pages (20 ms, HLO and trace on the v5e,
+        # PR 28)
+        return tuple(
+            pool[jnp.arange(pool.shape[0])[None, :], idx[:, None]]
+            for pool in cache.pools())
+    taken = (jnp.take(pool, idx, axis=axis, mode="clip")
+             for pool in cache.pools())
+    return tuple(jnp.moveaxis(t, axis, 0) if page_major else t for t in taken)
+
+
+def scatter_pages(cache: PagedKVCache, idx, blocks,
+                  page_major: bool = True) -> PagedKVCache:
+    """Pages `idx` [n] of every pool <- `blocks`, one array a pool in the
+    form `gather_pages` gives for the same `page_major`. Under `jit` with the
+    cache donated XLA writes the pools in place; an eager call copies every
+    whole pool."""
+    pools = cache.pools()
+    if len(blocks) != len(pools):
+        raise ValueError(f"{len(blocks)} arrays a page were handed over, "
+                         f"this cache holds {len(pools)}")
+    axis = cache.page_axis
+    if page_major and axis == 1:
+        # as in gather_pages: the layer rides in the scatter. The moveaxis
+        # form copies a whole pool on the v5e (3.2 GB of temporaries in the
+        # program compiled for it at the Keye cell's sizes, PR 30)
+        return cache.with_pools([
+            pool.at[jnp.arange(pool.shape[0])[None, :, None],
+                    idx[:, None, None],
+                    jnp.arange(pool.shape[2])[None, None, :]].set(block)
+            for pool, block in zip(pools, blocks)])
+    at = (slice(None),) * axis + (idx,)
+    return cache.with_pools([
+        pool.at[at].set(jnp.moveaxis(block, 0, axis) if page_major else block)
+        for pool, block in zip(pools, blocks)])
+
+
+def row_keys_values(cache: PagedKVCache, layer_idx: int):
+    """Layer `layer_idx`'s keys and values of every row's pages, contiguous
+    by position ([B, mp * page, Kh, D] each; the dense layout): slot s is
+    absolute position s, and the padded table's placeholder pages sit past
+    every valid position."""
+    kp = cache.k_pages[layer_idx]      # [Kh, P, ps, D]
+    vp = cache.v_pages[layer_idx]
+    tb = cache.block_tables            # [B, mp]
+    b, kh, d = tb.shape[0], kp.shape[0], kp.shape[-1]
+    k_all = kp[:, tb].transpose(1, 2, 3, 0, 4).reshape(b, -1, kh, d)
+    v_all = vp[:, tb].transpose(1, 2, 3, 0, 4).reshape(b, -1, kh, d)
+    return k_all, v_all
+
+
+def pages_to_tokens(cache: PagedKVCache, blocks, n_tokens: int) -> list:
+    """Host blocks of n pages in the pools' own form (`gather_pages(...,
+    page_major=False)`) -> one array a pool with the first `n_tokens` tokens
+    along one dimension, where the pool has its pages ([L, Kh, T, D] k and v
+    in the dense layout; an indexer's keys [L, T, Di], whatever way its pool
+    packs a page's tokens). The legacy P/D hand-off's form."""
+    axis, ps = cache.page_axis, cache.page_size
+    out = []
+    for block in blocks:
+        sh = block.shape
+        per_token = sh[axis + 2:]
+        if int(np.prod(sh[axis + 1:])) != ps * int(np.prod(per_token)):
+            per_token = (int(np.prod(sh[axis + 1:])) // ps,)   # packed
+        tokens = block.reshape(sh[:axis] + (sh[axis] * ps,) + per_token)
+        out.append(np.take(tokens, np.arange(n_tokens), axis=axis))
+    return out
+
+
+def tokens_to_pages(cache: PagedKVCache, arrays) -> list:
+    """`pages_to_tokens` undone: each array padded to whole pages and cut
+    into them, in its pool's own form."""
+    axis, ps = cache.page_axis, cache.page_size
+    out = []
+    for x, pool in zip(arrays, cache.pools()):
+        x = np.asarray(x)
+        n = -(-x.shape[axis] // ps)
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, n * ps - x.shape[axis])
+        out.append(np.pad(x, pad).reshape(
+            x.shape[:axis] + (n,) + pool.shape[axis + 1:]))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -611,258 +725,3 @@ def sparse_attention_reference(q, k, v, qi, ki, wi, topk: int, *, scale=None):
         jnp.arange(q.shape[0])[:, None, None], jnp.arange(t)[None, :, None],
         sel].set(True) & causal
     return mha_reference(q, k, v, causal=False, mask=keep, scale=scale)
-
-
-class PageManager:
-    """Host-side page allocator (free list + per-slot table bookkeeping).
-
-    Mirrors vLLM's BlockSpaceManager at single-host scope: admission asks
-    `can_fit(n_tokens)`, `allocate(slot, n_tokens)` assigns pool pages and
-    returns the table row, `extend(slot)` grabs the next page when a decode
-    crosses a page boundary, `free(slot)` returns pages to the pool.
-
-    PREFIX CACHE (r5, VERDICT r4 missing #3; ref: sglang RadixAttention /
-    vLLM automatic prefix caching — the reference serves prefix reuse via
-    its sglang engine, python/ray/llm/_internal/serve/engines/sglang/
-    sglang_engine.py): FULL prompt pages are content-addressed by a chained
-    hash of the token prefix they cover. `allocate_prefix` links a new
-    request's table to every already-cached leading page (refcounted —
-    shared pages are read-only by construction: prefill skips them and
-    decode writes only at positions ≥ prompt_len, past every full prompt
-    page). `register_prefix` publishes a freshly-prefilled prompt's full
-    pages. Released pages with refcount 0 park in an LRU and are evicted
-    back to the free list only under pool pressure, so repeated prompts
-    keep hitting until memory actually runs out.
-    """
-
-    def __init__(self, num_pages: int, page_size: int, batch_slots: int,
-                 max_pages_per_seq: int, prefix_cache: bool = True):
-        self.num_pages = num_pages
-        self.page_size = page_size
-        self.max_pages_per_seq = max_pages_per_seq
-        # page 0 is reserved as the masked placeholder for unused table slots
-        self.free_pages = list(range(num_pages - 1, 0, -1))
-        self.tables = [[] for _ in range(batch_slots)]
-        self.prefix_cache_enabled = prefix_cache
-        # content-addressed full prompt pages
-        self._by_key: dict = {}          # chain-hash key -> page id
-        self._key_of: dict = {}          # page id -> key
-        self._refs: dict = {}            # page id -> live borrower count
-        import collections
-        self._lru: "collections.OrderedDict" = collections.OrderedDict()
-        #                                  # refcount-0 cached pages (evictable)
-        self._shared_count = [0] * batch_slots  # leading shared pages per slot
-        self.prefix_hit_tokens = 0
-        self.prefix_query_tokens = 0
-
-    # ---------------------------------------------------------- chain hashes
-    def _prefix_keys(self, prompt_ids) -> list:
-        """One chained key per FULL page of the prompt: key_i commits to all
-        tokens [0, (i+1)*page_size) — O(P) total, not O(P^2)."""
-        import hashlib
-        import numpy as np
-        ps = self.page_size
-        toks = np.asarray(prompt_ids, np.int32)
-        keys = []
-        h = hashlib.blake2b(digest_size=16)
-        for i in range(len(toks) // ps):
-            h.update(toks[i * ps:(i + 1) * ps].tobytes())
-            keys.append(h.hexdigest())
-            h = hashlib.blake2b(h.digest(), digest_size=16)
-        return keys
-
-    def _evict_to_free(self, need: int) -> bool:
-        """Evict LRU refcount-0 cached pages until ≥ `need` pages are free."""
-        while len(self.free_pages) < need and self._lru:
-            pid, _ = self._lru.popitem(last=False)
-            key = self._key_of.pop(pid, None)
-            if key is not None:
-                self._by_key.pop(key, None)
-            self._refs.pop(pid, None)
-            self.free_pages.append(pid)
-        return len(self.free_pages) >= need
-
-    def _take_page(self):
-        if not self.free_pages:
-            self._evict_to_free(1)
-        return self.free_pages.pop()
-
-    def _available(self) -> int:
-        return len(self.free_pages) + len(self._lru)
-
-    def can_fit(self, n_tokens: int) -> bool:
-        need = -(-n_tokens // self.page_size)
-        return need <= self._available() and need <= self.max_pages_per_seq
-
-    def can_fit_prompt(self, prompt_ids, n_tokens: int) -> bool:
-        """can_fit that credits the prompt's cached-prefix pages: a
-        prefix-hit request borrows those (refcounted, costing no free
-        pages), so it must not stall in admission behind the full page
-        bill while the pool is busy serving the very prompts it shares."""
-        if not self.prefix_cache_enabled:
-            return self.can_fit(n_tokens)
-        ps = self.page_size
-        P = len(prompt_ids)
-        shared = []
-        for key in self._prefix_keys(prompt_ids):
-            pid = self._by_key.get(key)
-            if pid is None:
-                break
-            shared.append(pid)
-        while shared and len(shared) * ps >= P:
-            shared.pop()  # mirror allocate_prefix: one token must prefill
-        need_total = -(-n_tokens // ps)
-        need_fresh = need_total - len(shared)
-        # matched pages parked in the LRU aren't evictable for THIS request
-        # (borrowing pins them) — don't double-count them as available
-        lru_matched = sum(1 for pid in shared if pid in self._lru)
-        return (need_fresh <= self._available() - lru_matched
-                and need_total <= self.max_pages_per_seq)
-
-    def allocate(self, slot: int, n_tokens: int):
-        need = -(-n_tokens // self.page_size)
-        if need > self._available():
-            raise MemoryError(
-                f"paged KV pool exhausted: need {need} pages, "
-                f"{self._available()} free/evictable")
-        if need > self.max_pages_per_seq:
-            raise ValueError(
-                f"sequence needs {need} pages > max_pages_per_seq "
-                f"{self.max_pages_per_seq}")
-        assert not self.tables[slot], f"slot {slot} already allocated"
-        pages = [self._take_page() for _ in range(need)]
-        self.tables[slot] = pages
-        self._shared_count[slot] = 0
-        return self.table_row(slot)
-
-    def allocate_prefix(self, slot: int, prompt_ids, n_tokens: int):
-        """Like allocate, but the leading pages reuse any cached prefix.
-        Returns (table_row, cached_token_count) — prefill starts at
-        cached_token_count. At least one prompt token is always left to
-        prefill (the final-chunk logits come from running it)."""
-        if not self.prefix_cache_enabled:
-            return self.allocate(slot, n_tokens), 0
-        ps = self.page_size
-        P = len(prompt_ids)
-        keys = self._prefix_keys(prompt_ids)
-        self.prefix_query_tokens += P
-        shared = []
-        for key in keys:
-            pid = self._by_key.get(key)
-            if pid is None:
-                break
-            shared.append(pid)
-        # a fully page-covered prompt must still prefill its last token
-        while shared and len(shared) * ps >= P:
-            shared.pop()
-        need_fresh = -(-n_tokens // ps) - len(shared)
-        total_need = len(shared) + need_fresh
-        if total_need > self.max_pages_per_seq:
-            raise ValueError(
-                f"sequence needs {total_need} pages > max_pages_per_seq "
-                f"{self.max_pages_per_seq}")
-        assert not self.tables[slot], f"slot {slot} already allocated"
-        # pin shared pages BEFORE evicting for fresh ones — eviction scans
-        # the LRU and could otherwise free the very pages being borrowed
-        for pid in shared:
-            self._refs[pid] = self._refs.get(pid, 0) + 1
-            self._lru.pop(pid, None)  # borrowed pages leave the evictable set
-        try:
-            if need_fresh > len(self.free_pages) and not self._evict_to_free(
-                    need_fresh):
-                raise MemoryError(
-                    f"paged KV pool exhausted: need {need_fresh} pages, "
-                    f"{self._available()} free/evictable")
-            fresh = [self.free_pages.pop() for _ in range(need_fresh)]
-        except BaseException:
-            for pid in shared:  # rollback the pins
-                self._refs[pid] -= 1
-                if self._refs[pid] <= 0:
-                    self._refs[pid] = 0
-                    self._lru[pid] = True
-            raise
-        self.tables[slot] = shared + fresh
-        self._shared_count[slot] = len(shared)
-        cached = len(shared) * ps
-        self.prefix_hit_tokens += cached
-        return self.table_row(slot), cached
-
-    def register_prefix(self, slot: int, prompt_ids):
-        """Publish this slot's freshly-written FULL prompt pages so later
-        requests can share them. Called once prefill completes — the pages
-        are final (decode writes land past the last full prompt page)."""
-        if not self.prefix_cache_enabled:
-            return
-        ps = self.page_size
-        keys = self._prefix_keys(prompt_ids)
-        table = self.tables[slot]
-        for i, key in enumerate(keys):
-            if i < self._shared_count[slot]:
-                continue  # was already shared at admission
-            if key in self._by_key:
-                continue  # a concurrent request published it first
-            pid = table[i]
-            self._by_key[key] = pid
-            self._key_of[pid] = key
-            self._refs[pid] = self._refs.get(pid, 0) + 1
-
-    def extend(self, slot: int, new_len: int):
-        """Ensure the slot's table covers new_len tokens; returns the row."""
-        need = -(-new_len // self.page_size)
-        while len(self.tables[slot]) < need:
-            if not self.free_pages and not self._evict_to_free(1):
-                raise MemoryError("paged KV pool exhausted during decode")
-            if len(self.tables[slot]) >= self.max_pages_per_seq:
-                raise ValueError("sequence exceeded max_pages_per_seq")
-            self.tables[slot].append(self.free_pages.pop())
-        return self.table_row(slot)
-
-    def free(self, slot: int):
-        """Return the slot's pages: cache-tracked pages decref (parking in
-        the LRU at zero, NOT the free list — a future prompt may hit them);
-        untracked pages go straight back to the free list."""
-        for pid in self.tables[slot]:
-            if pid in self._refs:
-                self._refs[pid] -= 1
-                if self._refs[pid] <= 0:
-                    if pid in self._key_of:
-                        self._refs[pid] = 0
-                        self._lru[pid] = True  # evictable, newest-last
-                    else:
-                        self._refs.pop(pid, None)
-                        self.free_pages.append(pid)
-            else:
-                self.free_pages.append(pid)
-        self.tables[slot] = []
-        self._shared_count[slot] = 0
-
-    def table_row(self, slot: int):
-        row = self.tables[slot]
-        return row + [0] * (self.max_pages_per_seq - len(row))
-
-    def table_slice(self, slot: int, start: int, n: int):
-        """Page ids covering the slot's pages [start, start+n) — the PD
-        KV-ship plane's extraction/install unit. Host-side bookkeeping is
-        authoritative here, so suffix-delta shipping never pays a device
-        sync just to learn which pool rows hold a chunk's pages."""
-        row = self.tables[slot][start:start + n]
-        if len(row) != n:
-            raise IndexError(
-                f"slot {slot} holds {len(self.tables[slot])} pages, "
-                f"requested [{start}, {start + n})")
-        return list(row)
-
-    def shared_page_count(self, slot: int) -> int:
-        """Leading pages this slot borrowed from the prefix cache (their
-        KV is already resident — a PD decode replica needs only the
-        suffix pages shipped, a PD prefill replica skips recomputing
-        them)."""
-        return self._shared_count[slot]
-
-    @property
-    def pages_in_use(self) -> int:
-        return (self.num_pages - 1) - len(self.free_pages)
-
-    @property
-    def cached_pages(self) -> int:
-        return len(self._by_key)

@@ -52,17 +52,9 @@ def kv_ship_enabled() -> bool:
     return os.environ.get("RAY_TPU_KV_SHIP", "1") != "0"
 
 
-def kv_demote_enabled() -> bool:
-    """Radix-evicted KV pages demote into object-store segments instead of
-    being discarded (ISSUE 19 tiering); RAY_TPU_SPILL_KV=0 restores
-    discard-on-evict."""
-    return os.environ.get("RAY_TPU_SPILL_KV", "1") != "0"
-
-
-def stash_budget_bytes() -> int:
-    """shm budget for demoted KV pages before the stash spills its oldest
-    segments to the disk tier (RAY_TPU_SPILL_STASH_BYTES)."""
-    return int(os.environ.get("RAY_TPU_SPILL_STASH_BYTES", 256 << 20))
+# shm a stash holds in demoted KV pages before it spills its oldest segments
+# to the disk tier (what a KVPageStash built without `budget_bytes` gets)
+STASH_BUDGET_BYTES = 256 << 20
 
 
 def local_attach_enabled() -> bool:
@@ -120,12 +112,12 @@ class ShipWriter:
         self._ship_oids: Dict[str, List[str]] = {}  # ship -> storage oids
 
     def publish(self, ship_id: str, seg_index: int, blocks,
-                page_start: int, page_axis: int = 2) -> Dict[str, Any]:
+                page_start: int, n_pages: int) -> Dict[str, Any]:
         """Seal one segment: `blocks` is one array of every per-page pool of
-        the cache (k, v, and an indexer's keys where it has them), each in
-        its pool's own layout with the segment's n pages along `page_axis`
-        ([L,Kh,n,ps,D] for the dense layout), written C-contiguous one after
-        another. Returns the segment's wire metadata."""
+        the cache, each in its pool's own form with the segment's `n_pages`
+        pages where the pool has its pages ([L,Kh,n,ps,D] for the dense
+        layout), written C-contiguous one after another. Returns the
+        segment's wire metadata."""
         blocks = [np.ascontiguousarray(b) for b in blocks]
         nbytes = sum(b.nbytes for b in blocks)
         oid = storage_oid(ship_id, seg_index)
@@ -138,7 +130,6 @@ class ShipWriter:
         handle.seal()
         self._sizes[oid] = nbytes
         self._ship_oids.setdefault(ship_id, []).append(oid)
-        n_pages = int(blocks[0].shape[page_axis])
         _counter("kv_ship_bytes", "KV bytes sealed for PD shipment").inc(
             nbytes)
         _counter("kv_ship_pages", "KV pages sealed for PD shipment").inc(
@@ -146,7 +137,7 @@ class ShipWriter:
         _counter("kv_ship_segments", "KV shipment segments sealed").inc()
         return {"seg": seg_index, "oid": oid,
                 "wire": wire_oid(ship_id, seg_index), "nbytes": nbytes,
-                "page_start": int(page_start), "n_pages": n_pages}
+                "page_start": int(page_start), "n_pages": int(n_pages)}
 
     def read_segment(self, oid: str) -> bytes:
         """Raw bytes for the RPC fetch fallback (the one path that puts
@@ -200,10 +191,9 @@ def _read_blocks(buf, specs) -> Tuple[Tuple[np.ndarray, ...], int]:
 
 def _blocks_of(handle: Dict[str, Any]) -> List[Tuple[tuple, np.dtype]]:
     """(shape, dtype) of each block a stash handle's segment holds, in
-    order: k, v, then the handle's `extra` ones."""
-    kv = (tuple(handle["shape"]), _np_dtype(handle["dtype"]))
-    return [kv, kv] + [(tuple(e["shape"]), _np_dtype(e["dtype"]))
-                       for e in handle.get("extra", ())]
+    order."""
+    return [(tuple(b["shape"]), _np_dtype(b["dtype"]))
+            for b in handle["blocks"]]
 
 
 class KVPageStash:
@@ -216,7 +206,7 @@ class KVPageStash:
     the node restores the bytes into a fresh HBM page rather than
     recomputing prefill. Restore walks the same rung order as ShipReader's
     pull ladder: same-host shm attach first, then the DISK tier — under
-    shm pressure (`stash_budget_bytes`) the stash demotes its oldest
+    shm pressure (`STASH_BUDGET_BYTES`) the stash demotes its oldest
     segments with ``StoreClient.spill`` (atomic temp+rename files), and a
     hit on a disk-resident handle promotes it back through
     ``StoreClient.restore``. Per-tier occupancy is exported on the
@@ -235,15 +225,14 @@ class KVPageStash:
     its KV), so a handle stays valid across any number of demote/restore
     round trips, and is valid from `new_handle` on."""
 
-    def __init__(self, budget_bytes: Optional[int] = None):
+    def __init__(self, budget_bytes: int = STASH_BUDGET_BYTES):
         import collections
         self.store = StoreClient(backend="pershm")
         self._seq = itertools.count(1)
         self._shm: "collections.OrderedDict[str, int]" = \
             collections.OrderedDict()          # oid -> nbytes, oldest first
         self._disk: Dict[str, Tuple[str, int]] = {}   # oid -> (path, nbytes)
-        self.budget = (stash_budget_bytes() if budget_bytes is None
-                       else budget_bytes)
+        self.budget = budget_bytes
         self.shm_bytes = 0
         self.disk_bytes = 0
         self.spilled_pages = 0     # segments the budget moved to disk, ever
@@ -270,33 +259,30 @@ class KVPageStash:
             pass
 
     # -- the caller's side: each submits to the worker ----------------------
-    def new_handle(self, page_shape, dtype, extra=()) -> Dict[str, Any]:
-        """The restore handle of a page that `put` will be given later: a
-        k and a v block of `page_shape` / `dtype`, then one block for each
-        (shape, dtype) of `extra` (a cache's further per-page arrays, as an
-        indexer's keys). The handle records every block's shape and type."""
-        dtype = np.dtype(dtype)
+    def new_handle(self, layout) -> Dict[str, Any]:
+        """The restore handle of a page that `put` will be given later.
+        `layout` describes the page (`ops.paged_attention.page_layout`: one
+        entry a block with its `shape` and `dtype` name); the handle records
+        it."""
         handle = {"oid": f"kvd{_proc_tag}{next(self._seq):08x}",
-                  "shape": list(page_shape), "dtype": dtype.name,
-                  "extra": [{"shape": list(sh), "dtype": np.dtype(dt).name}
-                            for sh, dt in extra]}
+                  "blocks": layout}
         handle["nbytes"] = sum(int(np.prod(sh)) * dt.itemsize
                                for sh, dt in _blocks_of(handle))
         return handle
 
     def put(self, handles: List[Dict[str, Any]], *pages
             ) -> concurrent.futures.Future:
-        """Seal page i of each of `pages` (k, v and the handle's further
-        arrays, each [G, *its page shape], numpy or device arrays whose copy
-        to the host may still be in flight) under `handles[i]`; rows past
-        `len(handles)` are padding. Returns at once: the future's result is
+        """Seal page i of each of `pages` (one array a block of the
+        handles' layout, each [G, *its block shape], numpy or device arrays
+        whose copy to the host may still be in flight) under `handles[i]`;
+        rows past `len(handles)` are padding. Returns at once: the result is
         one entry a handle, None or the exception that kept that page out of
         the stash."""
         return self._worker.submit(self._put, handles, pages)
 
     def get(self, handle: Dict[str, Any]) -> Tuple[np.ndarray, ...]:
-        """Restore one page's blocks (k, v, then the further ones),
-        promoting a disk-resident segment back to shm first. Byte-exact:
+        """Restore one page's blocks, in its layout's order, promoting a
+        disk-resident segment back to shm first. Byte-exact:
         the arrays round-trip untouched."""
         return self._worker.submit(self._get, handle).result()
 
@@ -337,8 +323,8 @@ class KVPageStash:
             return errors
 
     def _seal(self, handle, *blocks) -> None:
-        """One evicted page's blocks (k, v, then the further ones, each
-        C-contiguous) one after another into a sealed segment."""
+        """One evicted page's blocks (each C-contiguous) one after another
+        into a sealed segment."""
         oid, nbytes = handle["oid"], handle["nbytes"]
         if sum(b.nbytes for b in blocks) != nbytes:
             raise ValueError(f"page {oid}: {len(blocks)} blocks of "
@@ -553,19 +539,10 @@ class AttachedSegment:
         _drain_pending_close()
 
 
-def pool_layout(pools, page_axis: int) -> List[Dict[str, Any]]:
-    """What a shipment's header says of the cache it was cut from, and what
-    the receiving cache must equal: each per-page pool's shape without its
-    page dimension, where that dimension sits, and its type."""
-    return [{"shape": [int(d) for i, d in enumerate(p.shape)
-                       if i != page_axis],
-             "axis": page_axis, "dtype": str(p.dtype)} for p in pools]
-
-
 def _carve(buf, seg: Dict[str, Any], layout) -> Tuple[np.ndarray, ...]:
     """Split one segment's bytes into its blocks, one a pool of `layout`
-    (`pool_layout`), each with the segment's n pages at the pool's page
-    axis."""
+    (the header's `ops.paged_attention.page_layout`), each with the
+    segment's n pages at the entry's `axis`."""
     n = seg["n_pages"]
     out, at = _read_blocks(buf, [
         (pool["shape"][:pool["axis"]] + [n] + pool["shape"][pool["axis"]:],
@@ -586,7 +563,7 @@ class ShipReader:
     async def fetch(self, seg: Dict[str, Any], layout,
                     data_addr: Optional[str] = None,
                     rpc_fetch=None) -> AttachedSegment:
-        """Materialize one segment (`layout`: the header's `pool_layout`):
+        """Materialize one segment (`layout`: the header's `page_layout`):
         shm attach → parallel_fetch → RPC."""
         if local_attach_enabled():
             att = self._attach(seg["oid"], seg, layout, delete=False)

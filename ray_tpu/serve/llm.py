@@ -62,8 +62,10 @@ NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
 # reserved placeholder page 0, and a longer pass calls it again. Gathered
 # pages are staged (on the device and, once copied, on the host) until the
 # stash's thread has sealed them: over STAGED_CAP_BYTES (or the deployment's
-# own `staged_cap_bytes`) the loop waits for the oldest hand-off. Constants, in pages and bytes, so every page size is
-# covered by one path.
+# own `staged_cap_bytes`) the loop waits for the oldest hand-off. Restored
+# pages go back in by the same group size. Constants, in pages and bytes, so
+# every page size is covered by one path; the mechanism's other two are
+# `radix_cache.DEMOTE_CAP` and `kv_transfer.STASH_BUDGET_BYTES`.
 DEMOTE_GROUP = 8
 STAGED_CAP_BYTES = 128 << 20
 
@@ -306,7 +308,7 @@ class LLMServer:
                 "cache's third pool")
         if cfg.paged:
             from ray_tpu.ops.paged_attention import PagedKVCache
-            from ray_tpu.serve import radix_cache as _radix
+            from ray_tpu.serve.radix_cache import PageManager
             mc = self.model_cfg
             max_pages = -(-cfg.max_seq_len // cfg.page_size)
             num_pages = cfg.num_pages or (B * max_pages + 1)
@@ -316,19 +318,16 @@ class LLMServer:
             self._kv_stash = None
             self._pending_restores = []
             hooks = {}
-            if cfg.prefix_cache and _radix.radix_enabled():
-                from ray_tpu.serve.kv_transfer import (KVPageStash,
-                                                       kv_demote_enabled)
-                hooks = dict(phases=self._phases)
-                if kv_demote_enabled():
-                    self._kv_stash = KVPageStash()
-                    hooks.update(demote_cb=self._demote_page,
-                                 demote_flush_cb=self._demote_pass,
-                                 restore_cb=self._restore_page,
-                                 drop_cb=self._drop_page)
-            self.page_mgr = _radix.make_page_manager(
+            if cfg.prefix_cache:
+                from ray_tpu.serve.kv_transfer import KVPageStash
+                self._kv_stash = KVPageStash()
+                hooks = dict(demote_cb=self._demote_page,
+                             demote_flush_cb=self._demote_pass,
+                             restore_cb=self._restore_page,
+                             drop_cb=self._drop_page)
+            self.page_mgr = PageManager(
                 num_pages, cfg.page_size, B, max_pages,
-                prefix_cache=cfg.prefix_cache, **hooks)
+                prefix_cache=cfg.prefix_cache, phases=self._phases, **hooks)
             # the cache follows the model's schema: a model with an indexer
             # gets the third per-page pool (and the token-major layout)
             self.cache = PagedKVCache.init(
@@ -746,29 +745,22 @@ class LLMServer:
                 donate_argnums=(0, 1))
             self._write_table_row(0, 0, 0)
         if self._kv_stash is not None:
-            axis = self.cache.page_axis
-
-            def gather_pages(pools, idx):
-                """Pages `idx` of every per-page pool (along its page axis)
-                as buffers of their own, page-major ([G, *page shape]) so
-                that each page is contiguous on the host."""
-                if axis == 1:
-                    # [L, P, ...] pools: the layer rides in the gather and
-                    # the pages come out first. A take along axis 1 and a
-                    # moveaxis copied both 3.2 GB pools a group of 8 pages
-                    # (20 ms, HLO and trace on the v5e, PR 28)
-                    return tuple(
-                        pool[jnp.arange(pool.shape[0])[None, :], idx[:, None]]
-                        for pool in pools)
-                return tuple(jnp.moveaxis(
-                    jnp.take(pool, idx, axis=axis, mode="clip"), axis, 0)
-                    for pool in pools)
-
+            from ray_tpu.ops.paged_attention import (gather_pages, page_layout,
+                                                     scatter_pages)
+            self._page_layout = page_layout(self.cache)
+            # demotion takes pages out and restore puts them back by groups
+            # of DEMOTE_GROUP ids, page-major, the restore into the donated
+            # cache. Both compiled here and not at the first eviction:
+            # nothing may compile once a replica serves (the warm-up writes
+            # zeros to the placeholder page 0)
             self._gather_pages = jax.jit(gather_pages)
-            # compiled here and not at the first eviction: nothing may
-            # compile once a replica serves
-            self._gather_pages(self.cache.pools(),
-                               np.zeros((DEMOTE_GROUP,), np.int32))
+            self._restore_group = jax.jit(scatter_pages, donate_argnums=(0,))
+            idx = np.zeros((DEMOTE_GROUP,), np.int32)
+            self._gather_pages(self.cache, idx)
+            self.cache = self._restore_group(self.cache, idx, tuple(
+                np.zeros((DEMOTE_GROUP, *block["shape"]), pool.dtype)
+                for block, pool in zip(self._page_layout,
+                                       self.cache.pools())))
         # first token goes through the SAME sampling policy as later ones
         self._sample_first = jax.jit(
             lambda logits, key, t, p, k, want_logp=True: tuple(
@@ -1181,10 +1173,7 @@ class LLMServer:
         its node the handle its blocks (one of every per-page pool: k, v,
         and an indexer's keys where the cache has them) will be stashed
         under. Nothing leaves the device here."""
-        axis = self.cache.page_axis
-        specs = [(p.shape[:axis] + p.shape[axis + 1:], p.dtype)
-                 for p in self.cache.pools()]
-        handle = self._kv_stash.new_handle(*specs[0], extra=specs[2:])
+        handle = self._kv_stash.new_handle(self._page_layout)
         self._evicting.append((pid, node, handle))
         return handle
 
@@ -1210,7 +1199,7 @@ class LLMServer:
                     part = pages[i:i + DEMOTE_GROUP]
                     idx = np.zeros((DEMOTE_GROUP,), np.int32)
                     idx[:len(part)] = [pid for pid, _, _ in part]
-                    blocks = self._gather_pages(self.cache.pools(), idx)
+                    blocks = self._gather_pages(self.cache, idx)
                     for block in blocks:
                         block.copy_to_host_async()
                     groups.append((part, blocks))
@@ -1266,10 +1255,10 @@ class LLMServer:
         per-page pool (bit-exact — the stash round-trips raw bytes, and a
         page still on its way there is read from its staged copy, waiting
         for the transfer if it must) and
-        STAGE it; _flush_restored_pages() lands every staged page in one
-        batched scatter right after the allocation. A per-page .at[].set
-        would rewrite the whole pool buffer per page, making restore cost
-        rival the prefill it avoids."""
+        STAGE it; _flush_restored_pages() lands the staged pages by groups
+        right after the allocation. A per-page eager .at[].set would
+        rewrite the whole pool buffer per page, making restore cost rival
+        the prefill it avoids."""
         with phase(self._phases, "restore"):
             staged = self._staged.get(handle["oid"])
             if staged is not None:
@@ -1282,21 +1271,24 @@ class LLMServer:
         return True
 
     def _flush_restored_pages(self) -> None:
-        """Land all staged restores in one scatter along the page axis.
-        Must run before prefill reads the pool (called from the allocate
-        path); the radix manager already counts these pages as cached."""
+        """Land all staged restores, a group of DEMOTE_GROUP pages a call of
+        the one donated scatter program (a short group is padded with zeros
+        for the placeholder page 0). Must run before prefill reads the pool
+        (called from the allocate path); the page manager already counts
+        these pages as cached."""
         if not self._pending_restores:
             return
-        import jax.numpy as jnp
         with phase(self._phases, "restore"):
             staged, self._pending_restores = self._pending_restores, []
-            pids = np.array([p for p, _ in staged], dtype=np.int32)
-            axis = self.cache.page_axis
-            at = (slice(None),) * axis + (pids,)
-            self.cache = self.cache.with_pools([
-                pool.at[at].set(jnp.moveaxis(jnp.asarray(
-                    np.stack([blocks[i] for _, blocks in staged])), 0, axis))
-                for i, pool in enumerate(self.cache.pools())])
+            for i in range(0, len(staged), DEMOTE_GROUP):
+                part = staged[i:i + DEMOTE_GROUP]
+                pad = DEMOTE_GROUP - len(part)
+                idx = np.zeros((DEMOTE_GROUP,), np.int32)
+                idx[:len(part)] = [pid for pid, _ in part]
+                self.cache = self._restore_group(self.cache, idx, tuple(
+                    np.stack([blocks[j] for _, blocks in part]
+                             + [np.zeros_like(part[0][1][j])] * pad)
+                    for j in range(len(self._page_layout))))
 
     def _drop_page(self, handle: Dict[str, Any]) -> None:
         self._kv_stash.drop(handle)
@@ -1693,10 +1685,10 @@ class LLMServer:
     def prefix_digest(self, max_bytes: int = None) -> Optional[Dict]:
         """Hot-prefix digest for the affinity router (ISSUE 20): the radix
         tree's resident-or-restorable chains, hashed + hit-counted, packed
-        <= 4 KiB. None for dense/flat-cache engines (nothing to advertise).
-        The serve Replica wrapper piggybacks this on its stats() frame."""
-        from ray_tpu.serve.radix_cache import RadixPageManager
-        if isinstance(self.page_mgr, RadixPageManager):
+        <= 4 KiB. None for engines without a prefix cache (nothing to
+        advertise). The serve Replica wrapper piggybacks this on its
+        stats() frame."""
+        if self.page_mgr is not None and self.config.prefix_cache:
             return self.page_mgr.prefix_digest(max_bytes)
         return None
 
@@ -1793,12 +1785,10 @@ class LLMServer:
             "kv_page_util": _metrics.histogram_summary("serve_kv_page_util"),
             "spill_restore_ms": _metrics.histogram_summary("spill_restore_ms"),
         }
-        from ray_tpu.serve.radix_cache import RadixPageManager
-        if isinstance(self.page_mgr, RadixPageManager):
+        if self.page_mgr is not None and self.config.prefix_cache:
             mgr = self.page_mgr
             s["radix"] = mgr.node_stats()
-            if self._kv_stash is not None:
-                s["radix"]["stash"] = self._kv_stash.tier_stats()
+            s["radix"]["stash"] = self._kv_stash.tier_stats()
             s["slo"]["radix"] = {
                 "prefix_nodes": mgr.prefix_nodes,
                 "prefix_hit_tokens": mgr.prefix_hit_tokens,

@@ -45,11 +45,13 @@ from .llm import LLMConfig, LLMServer
 
 def _pages_of(cache, rows) -> list:
     """Pages `rows` of every per-page pool of `cache`, as host arrays in the
-    pool's own layout (the pages along its page axis)."""
+    pool's own form (a shipment's bytes)."""
     import jax
     import jax.numpy as jnp
-    return [np.asarray(jax.device_get(jnp.take(p, rows, axis=cache.page_axis)))
-            for p in cache.pools()]
+
+    from ray_tpu.ops.paged_attention import gather_pages
+    return list(jax.device_get(
+        gather_pages(cache, jnp.asarray(rows), page_major=False)))
 
 
 def _require_paged(server: LLMServer, who: str):
@@ -119,6 +121,8 @@ class PrefillServer(LLMServer):
         (segment metadata follows via prefill_wait). `skip_pages` leading
         pages are never shipped — the decode side already holds them in
         its prefix cache (suffix-only delta)."""
+        from ray_tpu.ops.paged_attention import page_layout
+
         _require_paged(self, "PrefillServer")
         self._ship_plane()
         cfg = self.config
@@ -146,8 +150,7 @@ class PrefillServer(LLMServer):
             ship_id, job, slot_idx, prompt, cached, skip_pages, trace_id,
             temperature, top_p, top_k, logprobs))
         return {"ship": True, "ship_id": ship_id,
-                "layout": kv_transfer.pool_layout(self.cache.pools(),
-                                                  self.cache.page_axis),
+                "layout": page_layout(self.cache),
                 "prompt_len": P, "page_size": ps,
                 "skip_pages": skip_pages, "total_pages": total_pages,
                 "prefill_cached_tokens": cached,
@@ -228,7 +231,7 @@ class PrefillServer(LLMServer):
         rows = np.asarray(self.page_mgr.table_slice(
             slot_idx, page_start, n_pages), np.int32)
         return writer.publish(ship_id, seg_index, _pages_of(self.cache, rows),
-                              page_start, self.cache.page_axis)
+                              page_start, n_pages)
 
     async def prefill_wait(self, ship_id: str,
                            have: int = 0) -> Dict[str, Any]:
@@ -322,20 +325,12 @@ class PrefillServer(LLMServer):
         way its pool packs a page's tokens)."""
         import jax
 
-        ps = self.config.page_size
-        n = -(-P // ps)
+        from ray_tpu.ops.paged_attention import pages_to_tokens
+
+        n = -(-P // self.config.page_size)
         rows = np.asarray(jax.device_get(
             self.cache.block_tables[slot_idx]))[:n]
-        axis = self.cache.page_axis
-        out = []
-        for block in _pages_of(self.cache, rows):
-            sh = block.shape
-            per_token = sh[axis + 2:]
-            if int(np.prod(sh[axis + 1:])) != ps * int(np.prod(per_token)):
-                per_token = (int(np.prod(sh[axis + 1:])) // ps,)   # packed
-            tokens = block.reshape(sh[:axis] + (n * ps,) + per_token)
-            out.append(np.take(tokens, np.arange(P), axis=axis))
-        return out
+        return pages_to_tokens(self.cache, _pages_of(self.cache, rows), P)
 
 
 class ShipSource:
@@ -428,6 +423,8 @@ class DecodeServer(LLMServer):
         """Streaming admission: reserve prefix-aware, ask prefill for the
         non-cached suffix only, install segments as they seal (pull of
         chunk i overlaps prefill of chunk i+1)."""
+        from ray_tpu.ops.paged_attention import page_layout
+
         P = len(prompt)
         ps = self.config.page_size
         trace_id = tracing.new_trace_id()
@@ -448,8 +445,7 @@ class DecodeServer(LLMServer):
                                         temperature, top_p, top_k, logprobs)
             ship_id = header["ship_id"]
             layout = header["layout"]
-            mine = kv_transfer.pool_layout(self.cache.pools(),
-                                           self.cache.page_axis)
+            mine = page_layout(self.cache)
             if layout != mine or header["prompt_len"] != P:
                 raise ValueError(
                     f"shipment layout {layout} does not match this decode "
@@ -587,21 +583,13 @@ class DecodeServer(LLMServer):
         page-count `n`, the same bucketing cost profile as chunked prefill."""
         import jax
 
-        ps = self.config.page_size
-        n = -(-P // ps)
-        axis = self.cache.page_axis
+        from ray_tpu.ops.paged_attention import tokens_to_pages
+
+        n = -(-P // self.config.page_size)
         rows = np.asarray(jax.device_get(
             self.cache.block_tables[slot_idx]))[:n]
-
-        def to_pages(x, pool):
-            x = np.asarray(x)
-            pad = [(0, 0)] * x.ndim
-            pad[axis] = (0, n * ps - P)
-            x = np.pad(x, pad)
-            return x.reshape(x.shape[:axis] + (n,) + pool.shape[axis + 1:])
-
-        self._scatter_pages(slot_idx, rows, [
-            to_pages(b, p) for b, p in zip(blocks, self.cache.pools())], P)
+        self._scatter_pages(slot_idx, rows,
+                            tokens_to_pages(self.cache, blocks), P)
 
     def _install_pages(self, slot_idx: int, page_start: int, n_pages: int,
                        blocks, plen: int) -> None:
@@ -617,34 +605,28 @@ class DecodeServer(LLMServer):
     def _scatter_pages(self, slot_idx: int, rows, blocks, plen: int) -> None:
         """Pages `rows` of every pool <- `blocks`, and the slot's length.
 
-        The scatter runs jitted with the pools DONATED, so XLA updates the
-        page arrays in place — an un-jitted `.at[].set` here would copy
-        every full pool per admitted request (a transient 2x-KV-pool HBM
-        spike on the hot path; r5 review)."""
+        The one scatter body runs jitted with the cache DONATED, so XLA
+        updates the page arrays in place — an un-jitted `.at[].set` here
+        would copy every full pool per admitted request (a transient
+        2x-KV-pool HBM spike on the hot path; r5 review)."""
         import jax
         import jax.numpy as jnp
 
-        pools = self.cache.pools()
-        if len(blocks) != len(pools):
-            raise ValueError(f"hand-off carries {len(blocks)} arrays a page, "
-                             f"this cache holds {len(pools)}")
-        if getattr(self, "_scatter_jit", None) is None:
-            at = (slice(None),) * self.cache.page_axis
+        from ray_tpu.ops.paged_attention import scatter_pages
 
-            def scatter(pools, lengths, new, rows, slot, plen):
-                return (tuple(p.at[at + (rows,)].set(n)
-                              for p, n in zip(pools, new)),
-                        lengths.at[slot].set(plen))
-            self._scatter_jit = jax.jit(scatter, donate_argnums=(0, 1))
-        pools, lengths = self._scatter_jit(
-            pools, self.cache.lengths,
-            tuple(jnp.asarray(np.asarray(b), p.dtype)
-                  for b, p in zip(blocks, pools)),
+        if getattr(self, "_scatter_jit", None) is None:
+            def scatter(cache, new, rows, slot, plen):
+                cache = scatter_pages(cache, rows, new, page_major=False)
+                return cache.replace(
+                    lengths=cache.lengths.at[slot].set(plen))
+            self._scatter_jit = jax.jit(scatter, donate_argnums=(0,))
+        self.cache = self._scatter_jit(
+            self.cache,
+            tuple(jnp.asarray(np.asarray(b)) for b in blocks),
             jnp.asarray(rows), jnp.int32(slot_idx), jnp.int32(plen))
         # the upload may alias the shm segment (CPU zero-copy device_put);
         # wait for the scatter so the caller can close the segment safely
-        jax.block_until_ready(pools)
-        self.cache = self.cache.with_pools(pools).replace(lengths=lengths)
+        jax.block_until_ready(self.cache)
 
 
 class PDServer(DecodeServer):

@@ -4,8 +4,8 @@ radix-cache prefixes (ISSUE 20 tentpole, part 1).
 Reference: sglang's cache-aware router advertises per-worker radix trees;
 vLLM's prefix-aware routing hashes token blocks. Here each serving replica
 publishes {chained page hash -> hit count} for its resident-or-restorable
-radix nodes (`RadixPageManager.prefix_digest`), the serve controller caches
-the digests off its existing replica-stats refresh, and `DeploymentHandle`
+radix nodes (`radix_cache.PageManager.prefix_digest`), the serve controller
+caches the digests off its existing replica-stats refresh, and `DeploymentHandle`
 scores candidate replicas by deepest matched prefix — the same
 bytes-already-there locality scoring the task scheduler applies to object
 arguments, applied to KV pages.

@@ -1,15 +1,9 @@
-"""Radix prefix index over token-block KV pages (ISSUE 19 tentpole, half 1).
+"""The serving engine's page manager: free list, per-slot block tables and
+a radix prefix index over token-block KV pages, with a demotion tier.
 
-Reference: sglang's RadixAttention tree cache and vLLM's automatic prefix
-caching. The flat `PageManager` prefix cache (ops/paged_attention.py) content-
-addresses full prompt pages by a chained hash, which already shares one
-common prefix — but the chain is invisible to eviction: the LRU can free
-page i while pages i+1.. stay cached yet unreachable (a prefix walk breaks at
-the hole), and an evicted page is simply gone, so the next same-prefix
-request re-pays its prefill.
-
-This module generalizes the index into an explicit radix tree over token
-blocks:
+Reference: vLLM's BlockSpaceManager for the allocator surface, sglang's
+RadixAttention tree cache and vLLM's automatic prefix caching for the index.
+The prefix index is an explicit radix tree over token blocks:
 
   * one trie node per FULL page of tokens; two prompts share nodes up to
     their exact divergence point, so sharing works at arbitrary branch
@@ -22,31 +16,30 @@ blocks:
     tree size / hit-token / evicted-page tallies are exported as registry
     metrics (`radix_*`, see util.metrics.radix_counters).
   * LRU-by-leaf eviction: only nodes with no RESIDENT children are eviction
-    candidates, so the tree never creates unreachable descendants.
+    candidates, so the tree never creates unreachable descendants (a plain
+    LRU can free page i while pages i+1.. stay cached yet unreachable: a
+    prefix walk breaks at the hole).
   * demotion instead of discard: an evicted page's KV can be extracted into
     a sealed object-store segment (`demote_cb`); the node stays in the tree
     marked demoted, and a later request matching it restores the bytes into
     a fresh pool page (`restore_cb` — the serve engine wires this through
-    the PR 12 ShipWriter/ShipReader pull ladder) instead of recomputing
-    prefill. That is the HBM edge of the spill ladder: HBM page → shm
-    segment → (object-store spill policy) → disk.
+    `kv_transfer.KVPageStash`) instead of recomputing prefill. That is the
+    HBM edge of the spill ladder: HBM page → shm segment → (object-store
+    spill policy) → disk.
 
-`RadixPageManager` is a drop-in `PageManager`: the allocator surface used by
-serve/llm.py and serve/pd.py (`can_fit*`, `allocate*`, `register_prefix`,
-`extend`, `free`, `table_*`, `shared_page_count`, properties) is preserved
-exactly. `RAY_TPU_RADIX=0` falls back to the flat manager.
+It knows page ids and token ids only. What a page holds on the device, and
+how pages are moved, is `ops/paged_attention.py`.
 """
 
 import collections
-import os
 
-from ray_tpu.ops.paged_attention import PageManager
 from ray_tpu.util.tracing import PhaseTotals, phase
 
-
-def radix_enabled() -> bool:
-    return os.environ.get("RAY_TPU_RADIX", "1").lower() not in (
-        "0", "false", "off")
+# Demoted nodes the tree keeps handles for (a second-chance tier, capped so
+# the handle table cannot grow without bound). With `llm.py`'s DEMOTE_GROUP
+# and STAGED_CAP_BYTES and `kv_transfer.py`'s STASH_BUDGET_BYTES, the
+# constants of the demotion mechanism.
+DEMOTE_CAP = 4096
 
 
 def _count(name: str, value: float = 1.0):
@@ -78,8 +71,20 @@ class _Node:
         return sum(1 for c in self.children.values() if c.page is not None)
 
 
-class RadixPageManager(PageManager):
-    """PageManager whose prefix cache is a radix tree with a demotion tier.
+class PageManager:
+    """Host-side page allocator of the paged KV pool, and its prefix cache.
+
+    Admission asks `can_fit(n_tokens)` / `can_fit_prompt`, `allocate(slot,
+    n_tokens)` / `allocate_prefix` assign pool pages and return the table
+    row, `extend(slot)` grabs the next page when a decode crosses a page
+    boundary, `free(slot)` returns pages to the pool. `register_prefix`
+    publishes a freshly-prefilled prompt's FULL pages as tree nodes;
+    `allocate_prefix` links a new request's table to every already-published
+    leading page (refcounted). Released published pages with refcount 0
+    park in an LRU and are evicted back to the free list only under pool
+    pressure, so repeated prompts keep hitting until memory actually runs
+    out. With `prefix_cache=False` nothing is ever published: the tree stays
+    empty and every request gets private pages.
 
     Hooks (all optional; without them the tree still branch-shares and
     evicts leaf-first, it just discards instead of demoting):
@@ -111,22 +116,27 @@ class RadixPageManager(PageManager):
     def __init__(self, num_pages: int, page_size: int, batch_slots: int,
                  max_pages_per_seq: int, prefix_cache: bool = True,
                  demote_cb=None, restore_cb=None, drop_cb=None,
-                 demote_cap: int = None, phases: PhaseTotals = None,
-                 demote_flush_cb=None):
-        super().__init__(num_pages, page_size, batch_slots,
-                         max_pages_per_seq, prefix_cache)
+                 phases: PhaseTotals = None, demote_flush_cb=None):
+        self.num_pages = num_pages
+        self.page_size = page_size
+        self.max_pages_per_seq = max_pages_per_seq
+        # page 0 is reserved as the masked placeholder for unused table slots
+        self.free_pages = list(range(num_pages - 1, 0, -1))
+        self.tables = [[] for _ in range(batch_slots)]
+        self._shared_count = [0] * batch_slots  # leading shared pages per slot
+        self.prefix_cache_enabled = prefix_cache
         self._root = _Node((), None)
-        self._node_of = {}  # page id -> resident published _Node
+        self._node_of = {}  # page id -> its resident published _Node
+        self._refs = {}     # published page id -> live borrower count
+        # refcount-0 published pages (evictable), oldest first
+        self._lru = collections.OrderedDict()
         self.demote_cb = demote_cb
         self.demote_flush_cb = demote_flush_cb
         self.restore_cb = restore_cb
         self.drop_cb = drop_cb
-        # demoted nodes, oldest-first (a second-chance tier, capped so the
-        # handle table can't grow without bound)
-        self._demoted = collections.OrderedDict()
-        if demote_cap is None:
-            demote_cap = int(os.environ.get("RAY_TPU_RADIX_DEMOTE_CAP", 4096))
-        self._demote_cap = max(0, demote_cap)
+        self._demoted = collections.OrderedDict()  # demoted nodes, oldest first
+        self.prefix_hit_tokens = 0
+        self.prefix_query_tokens = 0
         self.prefix_nodes = 0          # live tree nodes (resident + demoted)
         self.evicted_pages = 0         # pages taken off the tree by the LRU
         self.demoted_pages = 0         # of those, extracted to the store
@@ -189,7 +199,6 @@ class RadixPageManager(PageManager):
         list either way."""
         self._lru.pop(pid, None)
         self._refs.pop(pid, None)
-        self._key_of.pop(pid, None)
         self._node_of.pop(pid, None)
         node.page = None
         self.evicted_pages += 1
@@ -206,7 +215,7 @@ class RadixPageManager(PageManager):
             _count("radix_demoted_pages")
             self._demoted[node] = True
             self._demoted.move_to_end(node)
-            while len(self._demoted) > self._demote_cap:
+            while len(self._demoted) > DEMOTE_CAP:
                 old, _ = self._demoted.popitem(last=False)
                 self._drop_handle(old)
         else:
@@ -235,16 +244,15 @@ class RadixPageManager(PageManager):
         self._maybe_remove(node)
 
     def _evict_to_free(self, need: int) -> bool:
-        """Leaf-first LRU eviction: among refcount-0 resident pages, only
-        those whose node has no resident children are candidates, so an
-        interior page is never freed while a descendant still depends on
-        it for prefix matching."""
+        """Leaf-first LRU eviction until `need` pages are free: among
+        refcount-0 published pages, only those whose node has no resident
+        children are candidates, so an interior page is never freed while a
+        descendant still depends on it for prefix matching."""
         with phase(self._phases, "evict"):
             while len(self.free_pages) < need and self._lru:
                 victim = None
                 for pid in self._lru:  # oldest first
-                    node = self._node_of.get(pid)
-                    if node is None or node.resident_children == 0:
+                    if self._node_of[pid].resident_children == 0:
                         victim = pid
                         break
                 if victim is None:
@@ -253,25 +261,30 @@ class RadixPageManager(PageManager):
                     # reaching here means the invariant broke — fail safe by
                     # taking the oldest (its node becomes a hole, walks stop
                     # there, nothing dangles).
-                    victim, _ = next(iter(self._lru.items()))
-                node = self._node_of.get(victim)
-                if node is not None:
-                    self._evict_node(victim, node)
-                else:  # flat-cache page (shouldn't be, under radix): discard
-                    self._lru.pop(victim, None)
-                    key = self._key_of.pop(victim, None)
-                    if key is not None:
-                        self._by_key.pop(key, None)
-                    self._refs.pop(victim, None)
-                    self.free_pages.append(victim)
+                    victim = next(iter(self._lru))
+                self._evict_node(victim, self._node_of[victim])
             if self.demote_flush_cb is not None:
                 self.demote_flush_cb()
             return len(self.free_pages) >= need
 
+    def _take_page(self):
+        if not self.free_pages:
+            self._evict_to_free(1)
+        return self.free_pages.pop()
+
+    def _available(self) -> int:
+        return len(self.free_pages) + len(self._lru)
+
     # ------------------------------------------------------------- admission
+    def can_fit(self, n_tokens: int) -> bool:
+        need = -(-n_tokens // self.page_size)
+        return need <= self._available() and need <= self.max_pages_per_seq
+
     def can_fit_prompt(self, prompt_ids, n_tokens: int) -> bool:
-        if not self.prefix_cache_enabled:
-            return self.can_fit(n_tokens)
+        """can_fit that credits the prompt's cached-prefix pages: a
+        prefix-hit request borrows those (refcounted, costing no free
+        pages), so it must not stall in admission behind the full page
+        bill while the pool is busy serving the very prompts it shares."""
         ps = self.page_size
         P = len(prompt_ids)
         matched = self._walk(prompt_ids)
@@ -287,14 +300,29 @@ class RadixPageManager(PageManager):
         return (need_new <= self._available() - lru_matched
                 and need_total <= self.max_pages_per_seq)
 
+    def allocate(self, slot: int, n_tokens: int):
+        need = -(-n_tokens // self.page_size)
+        if need > self._available():
+            raise MemoryError(
+                f"paged KV pool exhausted: need {need} pages, "
+                f"{self._available()} free/evictable")
+        if need > self.max_pages_per_seq:
+            raise ValueError(
+                f"sequence needs {need} pages > max_pages_per_seq "
+                f"{self.max_pages_per_seq}")
+        assert not self.tables[slot], f"slot {slot} already allocated"
+        self.tables[slot] = [self._take_page() for _ in range(need)]
+        self._shared_count[slot] = 0
+        return self.table_row(slot)
+
     def allocate_prefix(self, slot: int, prompt_ids, n_tokens: int):
         """Borrow the prompt's resident chain, restore its demoted links,
         and allocate fresh pages for the rest. Returns
         (table_row, cached_token_count); prefill starts at
         cached_token_count — restored pages are cached tokens too (that is
-        the win: a disk/shm round trip instead of a prefill recompute)."""
-        if not self.prefix_cache_enabled:
-            return self.allocate(slot, n_tokens), 0
+        the win: a disk/shm round trip instead of a prefill recompute). At
+        least one prompt token is always left to prefill (the final-chunk
+        logits come from running it)."""
         ps = self.page_size
         P = len(prompt_ids)
         self.prefix_query_tokens += P
@@ -339,7 +367,6 @@ class RadixPageManager(PageManager):
                     break
                 n.page = pid
                 self._node_of[pid] = n
-                self._key_of[pid] = n
                 self._refs[pid] = 1
                 self._demoted.pop(n, None)  # handle kept: re-demotion is free
                 restored.append(n)
@@ -365,7 +392,6 @@ class RadixPageManager(PageManager):
                 pid = n.page
                 n.page = None
                 self._node_of.pop(pid, None)
-                self._key_of.pop(pid, None)
                 self._refs.pop(pid, None)
                 self._demoted[n] = True
                 self.free_pages.append(pid)
@@ -413,12 +439,65 @@ class RadixPageManager(PageManager):
             pid = table[i]
             node.page = pid
             self._node_of[pid] = node
-            self._key_of[pid] = node
             self._refs[pid] = self._refs.get(pid, 0) + 1
             self._demoted.pop(node, None)
         self._set_nodes_gauge()
 
+    # ------------------------------------------------------- a slot's pages
+    def extend(self, slot: int, new_len: int):
+        """Ensure the slot's table covers new_len tokens; returns the row."""
+        need = -(-new_len // self.page_size)
+        while len(self.tables[slot]) < need:
+            if not self.free_pages and not self._evict_to_free(1):
+                raise MemoryError("paged KV pool exhausted during decode")
+            if len(self.tables[slot]) >= self.max_pages_per_seq:
+                raise ValueError("sequence exceeded max_pages_per_seq")
+            self.tables[slot].append(self.free_pages.pop())
+        return self.table_row(slot)
+
+    def free(self, slot: int):
+        """Return the slot's pages: published pages decref (parking in the
+        LRU at zero, NOT the free list — a future prompt may hit them);
+        private pages go straight back to the free list."""
+        for pid in self.tables[slot]:
+            if pid in self._node_of:
+                self._refs[pid] -= 1
+                if self._refs[pid] <= 0:
+                    self._refs[pid] = 0
+                    self._lru[pid] = True  # evictable, newest-last
+            else:
+                self.free_pages.append(pid)
+        self.tables[slot] = []
+        self._shared_count[slot] = 0
+
+    def table_row(self, slot: int):
+        row = self.tables[slot]
+        return row + [0] * (self.max_pages_per_seq - len(row))
+
+    def table_slice(self, slot: int, start: int, n: int):
+        """Page ids covering the slot's pages [start, start+n) — the PD
+        KV-ship plane's extraction/install unit. Host-side bookkeeping is
+        authoritative here, so suffix-delta shipping never pays a device
+        sync just to learn which pool rows hold a chunk's pages."""
+        row = self.tables[slot][start:start + n]
+        if len(row) != n:
+            raise IndexError(
+                f"slot {slot} holds {len(self.tables[slot])} pages, "
+                f"requested [{start}, {start + n})")
+        return list(row)
+
+    def shared_page_count(self, slot: int) -> int:
+        """Leading pages this slot borrowed from the prefix cache (their
+        KV is already resident — a PD decode replica needs only the
+        suffix pages shipped, a PD prefill replica skips recomputing
+        them)."""
+        return self._shared_count[slot]
+
     # ------------------------------------------------------------ inspection
+    @property
+    def pages_in_use(self) -> int:
+        return (self.num_pages - 1) - len(self.free_pages)
+
     @property
     def cached_pages(self) -> int:
         return len(self._node_of)
@@ -457,16 +536,3 @@ class RadixPageManager(PageManager):
                 "evicted_pages": self.evicted_pages,
                 "demoted_pages": self.demoted_pages,
                 "restored_pages": self.restored_pages}
-
-
-def make_page_manager(num_pages: int, page_size: int, batch_slots: int,
-                      max_pages_per_seq: int, prefix_cache: bool = True,
-                      **hooks) -> PageManager:
-    """Build the serving page manager: the radix tree by default, the flat
-    chained-hash PageManager when `RAY_TPU_RADIX=0` (escape hatch — flat
-    mode also disables demotion, since only the tree tracks handles)."""
-    if prefix_cache and radix_enabled():
-        return RadixPageManager(num_pages, page_size, batch_slots,
-                                max_pages_per_seq, prefix_cache, **hooks)
-    return PageManager(num_pages, page_size, batch_slots,
-                       max_pages_per_seq, prefix_cache)

@@ -349,10 +349,10 @@ def test_stash_handle_records_every_array(tmp_path):
         rng = np.random.default_rng(0)
         k = rng.normal(size=(3, 2, 8, 2, 16)).astype(np.float32)
         idx = rng.integers(0, 255, (3, 2, 1, 64)).astype(np.uint8)
-        handles = [stash.new_handle(k.shape[1:], k.dtype,
-                                    extra=[(idx.shape[1:], idx.dtype)])
-                   for _ in range(2)]
-        assert handles[0]["extra"] == [{"shape": [2, 1, 64], "dtype": "uint8"}]
+        layout = [{"shape": list(k.shape[1:]), "dtype": "float32"}] * 2 + [
+            {"shape": [2, 1, 64], "dtype": "uint8"}]
+        handles = [stash.new_handle(layout) for _ in range(2)]
+        assert handles[0]["blocks"] == layout
         assert handles[0]["nbytes"] == 2 * k[0].nbytes + idx[0].nbytes
         assert stash.put(handles, k, k + 1, idx).result(60) == [None, None]
         for i, h in enumerate(handles):

@@ -251,8 +251,17 @@ def test_pass_of_any_size_compiles_nothing_and_is_exact(
         assert d["demote_bytes"] == sum(h["nbytes"] for h in handles)
         for handle, (k, v) in zip(handles, want):
             got_k, got_v = stash.get(handle)
-            assert got_k.dtype == dtype and list(got_k.shape) == handle["shape"]
+            assert got_k.dtype == dtype
+            assert list(got_k.shape) == handle["blocks"][0]["shape"]
             assert (got_k.tobytes(), got_v.tobytes()) == (k, v)
+        # and back in, to other pages, by the one restore program
+        srv.cache = srv.cache.replace(k_pages=fill(), v_pages=fill())
+        before = len(compile_events)
+        for handle, pid in zip(handles, reversed(pids)):
+            assert srv._restore_page(handle, pid)
+        srv._flush_restored_pages()
+        assert [e.page_bytes(pid) for pid in reversed(pids)] == want
+        assert len(compile_events) == before
     finally:
         e.close()
 
@@ -290,7 +299,8 @@ def test_close_with_puts_queued_leaves_no_segment_and_no_spill_file(
     handles, puts = [], []
     for _ in range(3):
         k = rng.normal(size=(4,) + shape).astype(np.float32)
-        group = [stash.new_handle(shape, k.dtype) for _ in range(3)]
+        layout = [{"shape": list(shape), "dtype": "float32"}] * 2
+        group = [stash.new_handle(layout) for _ in range(3)]
         puts.append(stash.put(group, k, k + 1))      # row 3 is padding
         handles += group
     assert not any(p.done() for p in puts)

@@ -136,7 +136,7 @@ def test_prefix_cache_shared_prefix_divergent_tails():
 def test_prefix_cache_eviction_under_pressure():
     """A small pool evicts LRU refcount-0 cached pages instead of failing
     admission; live borrowers are never evicted."""
-    from ray_tpu.ops.paged_attention import PageManager
+    from ray_tpu.serve.radix_cache import PageManager
     mgr = PageManager(num_pages=9, page_size=4, batch_slots=2,
                       max_pages_per_seq=8, prefix_cache=True)
     # slot 0: prompt of 12 tokens (3 pages, all full→2 registerable... use 13)
@@ -159,7 +159,7 @@ def test_prefix_cache_eviction_under_pressure():
 
 
 def test_prefix_cache_never_shares_partial_pages():
-    from ray_tpu.ops.paged_attention import PageManager
+    from ray_tpu.serve.radix_cache import PageManager
     mgr = PageManager(num_pages=16, page_size=8, batch_slots=2,
                       max_pages_per_seq=8, prefix_cache=True)
     row, cached = mgr.allocate_prefix(0, list(range(8)), 16)
@@ -229,7 +229,7 @@ def test_lru_eviction_spares_borrowed_prefix_pages():
     only; prefix pages a live slot borrowed are pinned — off the LRU —
     and must survive the eviction intact (the PD decode path depends on
     this: shipped-suffix installs scatter around borrowed leading pages)."""
-    from ray_tpu.ops.paged_attention import PageManager
+    from ray_tpu.serve.radix_cache import PageManager
     mgr = PageManager(num_pages=11, page_size=4, batch_slots=3,
                       max_pages_per_seq=8, prefix_cache=True)
     a = list(range(9))             # 2 full pages registerable

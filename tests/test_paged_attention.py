@@ -8,10 +8,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import decode_attention
-from ray_tpu.ops.paged_attention import (PagedKVCache, PageManager,
-                                         paged_attention,
+from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          paged_attention_reference,
                                          write_tokens)
+from ray_tpu.serve.radix_cache import PageManager
 
 
 def _random_paged(b, kh, g, d, page, max_pages, lengths, seed=0):

@@ -7,20 +7,18 @@
     restored chain counts as cached tokens (prefill skips it)
   * KVPageStash (the serve-side shm→disk rung) round-trips k/v pages
     bit-identically through both tiers
-  * RAY_TPU_RADIX=0 falls back to the flat PageManager
+  * prefix_cache=False is the same manager with nothing published
 """
 
 import numpy as np
-import pytest
 
-from ray_tpu.serve.radix_cache import (RadixPageManager, make_page_manager,
-                                       radix_enabled)
+from ray_tpu.serve.radix_cache import PageManager
 
 PS = 4  # tokens per page
 
 
 def _mgr(num_pages=16, slots=8, max_seq=16, **hooks):
-    return RadixPageManager(num_pages, PS, slots, max_seq, True, **hooks)
+    return PageManager(num_pages, PS, slots, max_seq, True, **hooks)
 
 
 def _prompt(*pages, tail=1):
@@ -201,10 +199,11 @@ def test_kv_page_stash_roundtrip_two_tiers(monkeypatch):
         rng = np.random.default_rng(0)
         k1 = rng.normal(size=(2, 3, PS, 8)).astype(np.float32)
         v1 = rng.normal(size=(2, 3, PS, 8)).astype(np.float32)
-        h1 = stash.new_handle(k1.shape, k1.dtype)
+        layout = [{"shape": list(k1.shape), "dtype": "float32"}] * 2
+        h1 = stash.new_handle(layout)
         stash.put([h1], k1[None], v1[None])
         k2, v2 = k1 * 2, v1 * 2
-        h2 = stash.new_handle(k2.shape, k2.dtype)
+        h2 = stash.new_handle(layout)
         # budget: h1 spills to disk, on the stash's own thread
         assert stash.put([h2], k2[None], v2[None]).result(60) == [None]
         ts = stash.tier_stats()
@@ -222,18 +221,22 @@ def test_kv_page_stash_roundtrip_two_tiers(monkeypatch):
         stash.close()
 
 
-# ------------------------------------------------------------- escape hatch
+# ------------------------------------------------------ prefix cache off
 
-def test_radix_escape_hatch(monkeypatch):
-    from ray_tpu.ops.paged_attention import PageManager
-
-    monkeypatch.setenv("RAY_TPU_RADIX", "0")
-    assert not radix_enabled()
-    m = make_page_manager(16, PS, 8, 16)
-    assert type(m) is PageManager
-    monkeypatch.setenv("RAY_TPU_RADIX", "1")
-    m2 = make_page_manager(16, PS, 8, 16)
-    assert isinstance(m2, RadixPageManager)
-    # prefix_cache=False always means the flat manager
-    m3 = make_page_manager(16, PS, 8, 16, prefix_cache=False)
-    assert type(m3) is PageManager
+def test_prefix_cache_off_is_the_same_manager_with_nothing_published():
+    m = PageManager(16, PS, 8, 16, prefix_cache=False)
+    assert type(m) is type(_mgr())
+    a = _prompt(1, 2, 3)
+    for slot in (0, 1):
+        assert m.can_fit_prompt(a, len(a))
+        _, cached = m.allocate_prefix(slot, a, len(a))
+        assert cached == 0
+        m.register_prefix(slot, a)
+        assert m.shared_page_count(slot) == 0
+    assert not set(m.tables[0]) & set(m.tables[1])   # nothing shared
+    assert m.cached_pages == 0 and m.prefix_nodes == 0
+    assert m.prefix_digest() == _mgr().prefix_digest()    # an empty tree's
+    m.free(0)
+    m.free(1)
+    assert m.pages_in_use == 0 and len(m.free_pages) == 15
+    assert not m._lru and not m._refs and not m._node_of

@@ -16,13 +16,13 @@ from ray_tpu.serve.controller import (aggregate_slo, decide_num_replicas_slo)
 from ray_tpu.serve.deployment import AutoscalingConfig
 from ray_tpu.serve.handle import DeploymentHandle
 from ray_tpu.serve.multiplex import should_rebalance_pin
-from ray_tpu.serve.radix_cache import RadixPageManager
+from ray_tpu.serve.radix_cache import PageManager
 
 PS = 4  # tokens per page
 
 
 def _mgr(num_pages=64, slots=16, max_seq=16, **hooks):
-    return RadixPageManager(num_pages, PS, slots, max_seq, True, **hooks)
+    return PageManager(num_pages, PS, slots, max_seq, True, **hooks)
 
 
 def _prompt(*pages, tail=1):
