@@ -196,8 +196,9 @@ def _paged_kernel_check(sizes: Sizes) -> dict:
     lengths = np.linspace(1, max_pages * page, sizes.slots).astype(np.int32)
     rng = np.random.default_rng(0)
     pool = sizes.slots * max_pages + 1
-    kp, vp = (jnp.asarray(rng.normal(size=(kh, pool, page, d)), jnp.bfloat16)
-              for _ in range(2))
+    layers = 2                       # a stacked pool; the kernel reads the last
+    kp, vp = (jnp.asarray(rng.normal(size=(layers, kh, pool, page, d)),
+                          jnp.bfloat16) for _ in range(2))
     q = jnp.asarray(rng.normal(size=(sizes.slots, kh * g, d)), jnp.bfloat16)
     perm = rng.permutation(np.arange(1, pool))      # scrambled page order
     tables = np.zeros((sizes.slots, max_pages), np.int32)
@@ -205,7 +206,7 @@ def _paged_kernel_check(sizes: Sizes) -> dict:
     for i, n in enumerate(-(-lengths // page)):
         tables[i, :n] = perm[used:used + n]
         used += n
-    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths))
+    args = (q, kp, vp, layers - 1, jnp.asarray(tables), jnp.asarray(lengths))
     got = jax.jit(lambda *a: paged_attention(*a, interpret=sizes.interpret))(*args)
     want = paged_attention_reference(*args)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)

@@ -305,18 +305,19 @@ class Attention(nn.Module):
         elif isinstance(cache, PagedKVCache):
             # Paged decode/prefill (vLLM memory model, ops/paged_attention):
             # write this layer's K/V into its page slice, then attend. The
-            # cache threads through the block stack; decode writes use
-            # per-row dynamic_update_slice (in-place on the donated pool —
-            # see write_layer_tokens: the batched scatter COPIED the pool).
+            # cache threads through the block stack; every write and read
+            # addresses the stacked pool by (layer, page), in place on the
+            # donated pool (write_layer_tokens: a layer taken out of it, or a
+            # scatter into it, moved a pool-sized array).
             cache = write_layer_tokens(cache, layer_idx, k, v, positions)
             if t == 1:
-                # decode: pallas kernel walks the block table (XLA gather
-                # reference off-TPU, same numerics)
+                # decode: pallas kernel walks the block table of the stacked
+                # pool, whole as the cache holds it (XLA gather reference
+                # off-TPU, same arguments, same numerics)
                 impl = (paged_attention if jax.default_backend() == "tpu"
                         else paged_attention_reference)
-                out = impl(q[:, 0], cache.k_pages[layer_idx],
-                           cache.v_pages[layer_idx], cache.block_tables,
-                           positions[:, -1] + 1)[:, None]
+                out = impl(q[:, 0], cache.k_pages, cache.v_pages, layer_idx,
+                           cache.block_tables, positions[:, -1] + 1)[:, None]
             elif paged_chunk_local:
                 # FIRST chunk of a fresh row (start==0, no cached prefix —
                 # the caller asserts this statically): chunk-local causal

@@ -4,14 +4,19 @@ Reference contrast: the reference serves LLMs by wrapping vLLM, whose paged
 attention is a CUDA kernel walking a per-sequence page table
 (vllm PagedAttention; ray serve LLM integration). The TPU-native form:
 
-- KV pages live as one pool `[Kh, P, page, D]` in HBM.
+- KV pages live as one stacked pool `[L, Kh, P, page, D]` in HBM, every
+  layer's pages in one array. Whatever reads or writes it addresses it by
+  (layer, page): a layer taken out first (`pool[layer]`) is a buffer of its
+  own to XLA, 151 MB copied a layer a decode step at Mixtral's sizes, and a
+  scatter into it re-tiles all of it (PR 31).
 - A block table `[B, max_pages]` maps each sequence's logical pages to pool
   slots; `lengths[B]` counts valid tokens.
-- The kernel runs a grid `(B, max_pages)` with the block table and lengths
-  as SCALAR-PREFETCH args (pltpu.PrefetchScalarGridSpec): the index_map
-  reads `table[b, p]` to DMA exactly that page (all kv heads of it) into
-  VMEM while the previous page computes — the pallas pipeline does the job
-  of vLLM's manual gather, and pages never materialize contiguously.
+- The kernel runs a grid `(B, max_pages)` with the block table, the lengths
+  and the layer as SCALAR-PREFETCH args (pltpu.PrefetchScalarGridSpec): the
+  index_map reads `(layer, table[b, p])` to DMA exactly that page (all kv
+  heads of it) into VMEM while the previous page computes — the pallas
+  pipeline does the job of vLLM's manual gather, and pages never
+  materialize contiguously.
 - Online-softmax accumulation across pages (same recurrence as
   ops/flash_attention.py); every kv head folds per step via batched dots
   ([Kh, G, D] × [Kh, page, D]) so the MXU sees one sizable matmul instead
@@ -36,7 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 _LANES = 128
 
 
-def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale, page_size, max_pages,
                    gsize, n_kv):
     """One (b, p) step: fold page p of sequence b into the accumulator for
@@ -44,7 +49,9 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     left it mostly idle at decode shapes).
 
     q_ref: [1, Kh, G, D]; k_ref/v_ref: [Kh, 1, page, D] — every kv head's
-    copy of the one table-selected page; o_ref: [1, Kh, G, D]. Scratch rows
+    copy of the one table-selected page of the layer (the pool's layer
+    dimension is squeezed by the BlockSpec; `layer_ref` is read by the
+    index_map only); o_ref: [1, Kh, G, D]. Scratch rows
     are max(Kh*G, 8) — row-wise math pads up to the fp32 sublane tile and
     the finish slices back down.
     """
@@ -107,21 +114,27 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention(
     q: jax.Array,             # [B, H, D] — one decode token per sequence
-    k_pages: jax.Array,       # [Kh, P, page, D] — global page pool
-    v_pages: jax.Array,       # [Kh, P, page, D]
+    k_pages: jax.Array,       # [L, Kh, P, page, D] — the stacked page pool
+    v_pages: jax.Array,       # [L, Kh, P, page, D]
+    layer,                    # int or int32 scalar — whose pages to read
     block_tables: jax.Array,  # [B, max_pages] int32 — pool slot per page
     lengths: jax.Array,       # [B] int32 — valid tokens per sequence (>= 1)
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Paged decode attention; returns [B, H, D].
+    """Paged decode attention over layer `layer` of the pool; returns
+    [B, H, D].
 
-    Unused table entries must be valid pool indices (0 is fine) — they are
-    DMA'd but masked out. Sequences attend to their first `lengths` tokens.
+    The pools go in whole, as the cache holds them, and the layer rides in
+    the pages' index_map as a third prefetched scalar: a `pallas_call`
+    operand is a buffer of its own, so `k_pages[layer]` handed in would be
+    materialised, a layer's whole pool a call. Unused table entries must be
+    valid pool indices (0 is fine) — they are DMA'd but masked out.
+    Sequences attend to their first `lengths` tokens.
     """
     b, h, d = q.shape
-    kh, _pool, page_size, _d = k_pages.shape
+    _layers, kh, _pool, page_size, _d = k_pages.shape
     g = h // kh
     max_pages = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -131,33 +144,31 @@ def paged_attention(
         _decode_kernel, scale=scale, page_size=page_size,
         max_pages=max_pages, gsize=g, n_kv=kh)
     q3 = q.reshape(b, kh, g, d)
+
+    def page_of(b_, p_, tbl, lens, lyr):
+        # Pages past the sequence's end map to its LAST valid page instead
+        # of placeholder page 0: pallas skips the copy when the block index
+        # repeats between consecutive steps, so short sequences in a long
+        # table stop paying DMA bandwidth for pages they never read
+        # (VERDICT r3 weak #3).
+        last = jnp.maximum(lens[b_] - 1, 0) // page_size
+        return (lyr[0], 0, tbl[b_, jnp.minimum(p_, last)], 0, 0)
+
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, kh, g, d),
-                             lambda b_, p_, tbl, lens: (b_, 0, 0, 0)),
-                # Every kv head's copy of the table-selected page in one
-                # block. Pages past the sequence's end map to its LAST valid
-                # page instead of placeholder page 0: pallas skips the copy
-                # when the block index repeats between consecutive steps, so
-                # short sequences in a long table stop paying DMA bandwidth
-                # for pages they never read (VERDICT r3 weak #3).
-                pl.BlockSpec((kh, 1, page_size, d),
-                             lambda b_, p_, tbl, lens: (0, tbl[
-                                 b_, jnp.minimum(
-                                     p_, jnp.maximum(lens[b_] - 1, 0)
-                                     // page_size)], 0, 0)),
-                pl.BlockSpec((kh, 1, page_size, d),
-                             lambda b_, p_, tbl, lens: (0, tbl[
-                                 b_, jnp.minimum(
-                                     p_, jnp.maximum(lens[b_] - 1, 0)
-                                     // page_size)], 0, 0)),
+                             lambda b_, p_, tbl, lens, lyr: (b_, 0, 0, 0)),
+                # every kv head's copy of the table-selected page of the
+                # layer in one block; the layer dimension is squeezed
+                pl.BlockSpec((None, kh, 1, page_size, d), page_of),
+                pl.BlockSpec((None, kh, 1, page_size, d), page_of),
             ],
             out_specs=pl.BlockSpec(
-                (1, kh, g, d), lambda b_, p_, tbl, lens: (b_, 0, 0, 0)),
+                (1, kh, g, d), lambda b_, p_, tbl, lens, lyr: (b_, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((max(h, 8), _LANES), jnp.float32),
                 pltpu.VMEM((max(h, 8), _LANES), jnp.float32),
@@ -167,24 +178,32 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables, lengths, q3, k_pages, v_pages)
+    )(block_tables, lengths, jnp.asarray(layer, jnp.int32).reshape(1),
+      q3, k_pages, v_pages)
     return out.reshape(b, h, d)
 
 
-def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
-                              *, scale: Optional[float] = None) -> jax.Array:
-    """XLA equivalent (gather pages → masked attention): numerics oracle for
-    the kernel and the CPU-backend fallback."""
+def _row_pages(pool, layer, block_tables):
+    """XLA's gather of every row's pages of `layer`: [B, Kh, mp, page, D].
+    The layer rides in the gather. Off the TPU only: on it the compiler
+    re-tiles the whole pool for this gather (`row_keys_values`)."""
+    return pool[layer, :, block_tables].swapaxes(1, 2)
+
+
+def paged_attention_reference(q, k_pages, v_pages, layer, block_tables,
+                              lengths, *,
+                              scale: Optional[float] = None) -> jax.Array:
+    """XLA equivalent of `paged_attention`, same arguments (gather pages →
+    masked attention): numerics oracle for the kernel and the CPU-backend
+    fallback."""
     b, h, d = q.shape
-    kh, _pool, page_size, _d = k_pages.shape
+    _layers, kh, _pool, page_size, _d = k_pages.shape
     g = h // kh
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    # [B, Kh, max_pages, page, D] → [B, Kh, S, D]
-    k_seq = jnp.swapaxes(k_pages[:, block_tables], 0, 1)
-    v_seq = jnp.swapaxes(v_pages[:, block_tables], 0, 1)
     s_max = block_tables.shape[1] * page_size
-    k_seq = k_seq.reshape(b, kh, s_max, d)
-    v_seq = v_seq.reshape(b, kh, s_max, d)
+
+    k_seq, v_seq = (_row_pages(pool, layer, block_tables).reshape(
+        b, kh, s_max, d) for pool in (k_pages, v_pages))
     qg = q.reshape(b, kh, g, d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bksd->bkgs", qg, k_seq.astype(jnp.float32)) * scale
     mask = jnp.arange(s_max)[None, None, None, :] < lengths[:, None, None, None]
@@ -326,27 +345,25 @@ def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
     """Write ONE layer's new tokens into every per-page pool (jit-safe).
 
     k_new/v_new: [B, T, Kh, D]; idx_new: [B, T, Di], given exactly when the
-    cache has an indexer pool; positions: [B, T]. Layers touch disjoint
-    pool slices, so the decoder threads the cache through its blocks.
+    cache has an indexer pool; positions: [B, T], a row's T positions
+    consecutive (the decoder's: the row's length onward). Layers touch
+    disjoint pool slices, so the decoder threads the cache through its
+    blocks.
 
-    Decode (T == 1) uses per-row dynamic_update_slice, UNROLLED over B:
-    XLA reliably aliases DUS on the donated pool. Alternatives measured on
-    v5e (16 layers, 269 MB pool, ms/step | compile s):
+    Every form here writes into the stacked pool in place on the donated
+    pool. The token-major layout's scatters write whole (Kh, D) tiles and
+    stay in place (PR 28); the dense layout's T > 1 scatter made the v5e's
+    compiler re-tile the whole pool and went (PR 31: `_write_chunk_rows`).
 
-        unrolled DUS   B=8: 1.0 | 4.3   B=32: 2.8 | 17   B=64: 5.0 | 42
-        fori_loop DUS  B=8: 5.1 | 2.8   B=32: 17  | 3.0  B=64: 30  | 2.9
-        batched scatter (.at[..].set): 28 ms — copies the whole pool
-
-    (Those figures predate this installation and are not re-measured; a
-    pallas in-place write kernel with input_output_aliases has not been
-    tried on it.)
-
-    The fori_loop's flat compile cost is not worth 6x slower steady-state
-    decode — per-iteration loop overhead (~32 us) dominates the tiny
-    writes. Unrolled compile cost is one-time per (B, shape) and amortizes
-    over the server's lifetime (VERDICT r3 weak #3: measured, documented,
-    unrolled wins). Prefill (T > 1) keeps the batched scatter — it runs
-    once per request, not once per generated token.
+    Decode (T == 1) uses per-row dynamic_update_slice, UNROLLED over B: XLA
+    aliases it on the donated pool. A fori_loop over the rows compiles
+    faster and ran several times slower a step (per-iteration loop overhead
+    dominates the tiny writes; a figure from before this installation, not
+    re-measured), so the unrolled form stays: its compile cost is one-time
+    per (B, shape). Measured in PR 31, `mixtral8x7b-batch` on the v5e (4
+    layers, 32 slots, a 604 MB pool, a decode step of 17.0-18.0 ms): the
+    256 row writes of a step are `dynamic_update_slice_bf16_4_8_1152_64_128_`,
+    0.043 s of 3.45 busy seconds, about 0.25 ms a step.
 
     The T == 1 path is also the write primitive inside serve/llm's fused
     multi-token decode chunk: the whole PagedKVCache is carried through a
@@ -385,34 +402,83 @@ def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
                     row = row[None, :, None, None, :]
                 pools[i] = jax.lax.dynamic_update_slice(pools[i], row, start)
         return cache.with_pools(pools)
+    if not token_major:
+        return cache.with_pools(_write_chunk_rows(
+            pools, layer_idx, news, positions[:, 0], cache.block_tables))
     pos = positions.reshape(-1)
     rows = jnp.repeat(jnp.arange(bsz), t)
     page_ids = cache.block_tables[rows, pos // ps]
     offs = pos % ps
     flat = [n.reshape((bsz * t,) + n.shape[2:]) for n in news]
-    if token_major:
-        di = flat[2].shape[-1]
-        r = pools[2].shape[-1] // di
-        # a token's Di values into its share of a row: a windowed scatter
-        # (layer, page, row, first lane), which indexing cannot spell
-        where = jnp.stack([jnp.full_like(offs, layer_idx), page_ids,
-                           offs // r, (offs % r) * di], axis=-1)
-        idx_pool = jax.lax.scatter(
-            pools[2], where, flat[2],
-            jax.lax.ScatterDimensionNumbers(
-                update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
-                scatter_dims_to_operand_dims=(0, 1, 2, 3)),
-            indices_are_sorted=False, unique_indices=False,
-            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
-        return cache.with_pools([
-            p.at[layer_idx, page_ids, offs].set(n)
-            for p, n in zip(pools[:2], flat)] + [idx_pool])
-    # index tuple (scalar, :, ids, offs): the advanced indices are separated
-    # by a slice, so numpy/jax moves the broadcast dim FIRST → values must be
-    # [B*T, Kh, D] (contrast write_tokens, whose adjacent indices keep order)
+    di = flat[2].shape[-1]
+    r = pools[2].shape[-1] // di
+    # a token's Di values into its share of a row: a windowed scatter
+    # (layer, page, row, first lane), which indexing cannot spell
+    where = jnp.stack([jnp.full_like(offs, layer_idx), page_ids,
+                       offs // r, (offs % r) * di], axis=-1)
+    idx_pool = jax.lax.scatter(
+        pools[2], where, flat[2],
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2, 3)),
+        indices_are_sorted=False, unique_indices=False,
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
     return cache.with_pools([
-        p.at[layer_idx, :, page_ids, offs].set(n)
-        for p, n in zip(pools, flat)])
+        p.at[layer_idx, page_ids, offs].set(n)
+        for p, n in zip(pools[:2], flat)] + [idx_pool])
+
+
+def _write_chunk_rows(pools, layer_idx: int, news, first_pos, tables) -> list:
+    """The dense layout's prefill write: rows `news` ([B, T, Kh, D] a pool)
+    at positions first_pos[b] .. first_pos[b] + T - 1 of row b, page by page.
+
+    T consecutive tokens touch at most ceil((T + page - 1) / page) pages, the
+    first and the last in part, so each page is read out of the stacked pool
+    with the layer and the page in `start`, the chunk's rows are laid over
+    it, and it is written back to the same `start`: a dynamic_slice and a
+    dynamic_update_slice of one page, which XLA does in place on the donated
+    pool as it does decode's single rows. Where a page's slot holds no token
+    of the chunk (before the first position, past the last, or past the
+    table's end) the old row goes back.
+
+    What decided the form (PR 31, HLO compiled for the v5e at Mixtral's
+    sizes): the scatter this replaces (`.at[layer, :, pages, offs].set`) had
+    the compiler re-tile the whole pool into the scatter's layout
+    (`{4,1,3,2,0}`) and back, four copies of all layers' pool a chunk
+    (`copy_bf16_4_8_1152_64_128_`, 1.47 ms each, 0.15-0.21 s of a 3.7 s
+    trace); this form compiles to 72 in-place `dynamic-update-slice` fusions
+    a 512-token chunk (9 pages x K, V x 4 layers) and no other instruction
+    the size of a pool, and on the chip no pool-sized copy is left in the
+    trace. A prefill chunk of the 16-layer Mistral-7B replica (a 2.4 GB
+    pool) went from 46.1 to 14.5 ms of device time (`step.prefill_chunk_ms`,
+    one traced run a side).
+    """
+    bsz, t = news[0].shape[:2]
+    kh, _, ps, d = pools[0].shape[1:]
+    mp = tables.shape[1]
+    n_pages = (t + 2 * ps - 2) // ps
+    # [B, T, Kh, D] -> [B, Kh, page + n_pages * page, D]: every page's window
+    # of the chunk is one dynamic_slice along the padded token dimension
+    padded = [jnp.pad(n.swapaxes(1, 2),
+                      ((0, 0), (0, 0), (ps, n_pages * ps - t), (0, 0)))
+              for n in news]
+    slot = jnp.arange(ps)
+    pools = list(pools)
+    for b in range(bsz):  # B and the page count are static: one program
+        first, off0 = first_pos[b] // ps, first_pos[b] % ps
+        for j in range(n_pages):
+            token = j * ps - off0 + slot         # the chunk's token a slot
+            keep = (token >= 0) & (token < t) & (first + j < mp)
+            page_id = tables[b, jnp.minimum(first + j, mp - 1)]
+            start = (layer_idx, 0, page_id, 0, 0)
+            for i, rows in enumerate(padded):
+                new = jax.lax.dynamic_slice_in_dim(
+                    rows[b], j * ps - off0 + ps, ps, axis=1)   # [Kh, page, D]
+                old = jax.lax.dynamic_slice(pools[i], start,
+                                            (1, kh, 1, ps, d))
+                page = jnp.where(keep[:, None], new[None, :, None], old)
+                pools[i] = jax.lax.dynamic_update_slice(pools[i], page, start)
+    return pools
 
 
 # ---------------------------------------------------------------------------
@@ -479,18 +545,54 @@ def scatter_pages(cache: PagedKVCache, idx, blocks,
         for pool, block in zip(pools, blocks)])
 
 
-def row_keys_values(cache: PagedKVCache, layer_idx: int):
+def _copy_pages_kernel(tbl_ref, layer_ref, k_ref, v_ref, ko_ref, vo_ref):
+    ko_ref[...] = k_ref[...]
+    vo_ref[...] = v_ref[...]
+
+
+def row_keys_values(cache: PagedKVCache, layer_idx: int,
+                    interpret: Optional[bool] = None):
     """Layer `layer_idx`'s keys and values of every row's pages, contiguous
     by position ([B, mp * page, Kh, D] each; the dense layout): slot s is
     absolute position s, and the padded table's placeholder pages sit past
-    every valid position."""
-    kp = cache.k_pages[layer_idx]      # [Kh, P, ps, D]
-    vp = cache.v_pages[layer_idx]
+    every valid position.
+
+    On the TPU a kernel on `paged_decode`'s plan copies the pages out: grid
+    (B, mp), the table and the layer prefetched, the page's block chosen by
+    (layer, table[b, p]). A `pallas_call` holds its operand to the layout
+    the pool lies in. In XLA's own hands the pool did not stay there: with
+    the layer taken out first it copied the layer's pool, and for a gather
+    or per-page dynamic_slices of the stacked pool, however spelt, the
+    v5e's compiler re-tiled ALL of it pages-major to suit the attention
+    that consumes the rows, and back for the result (four copies of all
+    layers' pool a continuation chunk: PR 31, HLO compiled for the v5e).
+    `interpret` None: the kernel on the TPU, XLA's gather elsewhere.
+    """
     tb = cache.block_tables            # [B, mp]
-    b, kh, d = tb.shape[0], kp.shape[0], kp.shape[-1]
-    k_all = kp[:, tb].transpose(1, 2, 3, 0, 4).reshape(b, -1, kh, d)
-    v_all = vp[:, tb].transpose(1, 2, 3, 0, 4).reshape(b, -1, kh, d)
-    return k_all, v_all
+    b, mp = tb.shape
+    kh, _, ps, d = cache.k_pages.shape[1:]
+    if interpret is None and jax.default_backend() != "tpu":
+        k, v = (_row_pages(pool, layer_idx, tb)
+                for pool in (cache.k_pages, cache.v_pages))
+    else:
+        page = pl.BlockSpec(
+            (None, kh, 1, ps, d),
+            lambda b_, p_, tbl, lyr: (lyr[0], 0, tbl[b_, p_], 0, 0))
+        out = pl.BlockSpec((None, kh, 1, ps, d),
+                           lambda b_, p_, tbl, lyr: (b_, 0, p_, 0, 0))
+        shape = jax.ShapeDtypeStruct((b, kh, mp, ps, d), cache.k_pages.dtype)
+        k, v = pl.pallas_call(
+            _copy_pages_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b, mp),
+                in_specs=[page, page], out_specs=[out, out]),
+            out_shape=[shape, shape],
+            interpret=bool(interpret),
+            name="paged_row_pages",
+        )(tb, jnp.asarray(layer_idx, jnp.int32).reshape(1),
+          cache.k_pages, cache.v_pages)
+    to_rows = lambda x: x.reshape(b, kh, mp * ps, d).swapaxes(1, 2)
+    return to_rows(k), to_rows(v)
 
 
 def pages_to_tokens(cache: PagedKVCache, blocks, n_tokens: int) -> list:

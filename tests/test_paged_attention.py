@@ -14,12 +14,12 @@ from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
 from ray_tpu.serve.radix_cache import PageManager
 
 
-def _random_paged(b, kh, g, d, page, max_pages, lengths, seed=0):
-    """Build a pool + tables where each row's pages hold random K/V."""
+def _random_paged(b, kh, g, d, page, max_pages, lengths, seed=0, layers=1):
+    """Build a stacked pool + tables where each row's pages hold random K/V."""
     rng = np.random.default_rng(seed)
     pool = b * max_pages + 1
-    k_pages = rng.normal(size=(kh, pool, page, d)).astype(np.float32)
-    v_pages = rng.normal(size=(kh, pool, page, d)).astype(np.float32)
+    k_pages = rng.normal(size=(layers, kh, pool, page, d)).astype(np.float32)
+    v_pages = rng.normal(size=(layers, kh, pool, page, d)).astype(np.float32)
     # deliberately scrambled page assignment (fragmentation)
     perm = rng.permutation(np.arange(1, pool))
     tables = np.zeros((b, max_pages), np.int32)
@@ -38,8 +38,8 @@ def test_kernel_matches_reference_fragmented(g):
     b, kh, d, page, max_pages = 3, 2, 64, 8, 4
     lengths = np.array([1, 13, 32])
     q, kp, vp, tbl, lens = _random_paged(b, kh, g, d, page, max_pages, lengths)
-    out_k = paged_attention(q, kp, vp, tbl, lens, interpret=True)
-    out_r = paged_attention_reference(q, kp, vp, tbl, lens)
+    out_k = paged_attention(q, kp, vp, 0, tbl, lens, interpret=True)
+    out_r = paged_attention_reference(q, kp, vp, 0, tbl, lens)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
 
@@ -56,19 +56,19 @@ def test_reference_matches_dense_decode():
 
     # lay the same cache out as contiguous per-row pages
     pool = b * max_pages + 1
-    k_pages = np.zeros((kh, pool, page, d), np.float32)
-    v_pages = np.zeros((kh, pool, page, d), np.float32)
+    k_pages = np.zeros((1, kh, pool, page, d), np.float32)
+    v_pages = np.zeros((1, kh, pool, page, d), np.float32)
     tables = np.zeros((b, max_pages), np.int32)
     nxt = 1
     for i in range(b):
         for p in range(max_pages):
-            k_pages[:, nxt] = k_cache[i, p * page:(p + 1) * page].transpose(1, 0, 2)
-            v_pages[:, nxt] = v_cache[i, p * page:(p + 1) * page].transpose(1, 0, 2)
+            k_pages[0, :, nxt] = k_cache[i, p * page:(p + 1) * page].transpose(1, 0, 2)
+            v_pages[0, :, nxt] = v_cache[i, p * page:(p + 1) * page].transpose(1, 0, 2)
             tables[i, p] = nxt
             nxt += 1
 
     out_p = paged_attention_reference(
-        jnp.array(q), jnp.array(k_pages), jnp.array(v_pages),
+        jnp.array(q), jnp.array(k_pages), jnp.array(v_pages), 0,
         jnp.array(tables), jnp.array(lengths, dtype=jnp.int32))
     # decode_attention takes tokens-BEFORE-the-chunk and attends <= L;
     # paged lengths are inclusive counts, hence the -1
