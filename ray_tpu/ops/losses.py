@@ -62,6 +62,20 @@ def chunked_cross_entropy(
     Supports the dense-LM subset of `cross_entropy`: no mask / z_loss /
     label_smoothing (use `cross_entropy` on full logits for those). Returns
     (mean_loss, {"loss", "accuracy", "tokens"}).
+
+    Across chips: `w_head` is invariant over the scan, so its gradient is
+    summed over the chunks in `w_head`'s dtype in the backward scan's carry.
+    This function holds no collective, and a caller whose `w_head` is
+    SHARDED (fsdp) must not hand it in as it is: the partitioner then
+    carries the shard through both loops and gathers it inside every trip
+    (`all-gather bf16[D, V]` in `wide.region_*_spmd.sunk`, 2 x T/chunk a
+    step), gathers every chip's logit cotangents of a chunk
+    (`all-gather bf16[B, chunk, V]`) and reduce-scatters the f32 gradient in
+    every backward trip (a `kCustom` fusion calling `all-reduce-scatter`,
+    `fusion_f32_<D/n>_<V>_` on a trace's operation line): a seventh of a
+    Mistral-7B step on four v5e chips. `parallel.sharding.rows_gathered_once`
+    wraps the call so that the head is gathered once before the loops and
+    its gradient reduced once after them; `train.lm.make_lm_train_step` does.
     """
     b, t, d = hidden.shape
     assert t % chunk_size == 0, (t, chunk_size)

@@ -7,8 +7,9 @@ NamedShardings that pjit consumes; XLA then emits all-gathers/reduce-scatters
 (FSDP) or keeps weights resident (TP) as the specs dictate.
 """
 
+import math
 import re
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -124,3 +125,40 @@ def batch_spec(extra_seq_axis: bool = False) -> P:
 
 def data_sharding(mesh: Mesh, extra_seq_axis: bool = False) -> NamedSharding:
     return NamedSharding(mesh, _filter_axes(batch_spec(extra_seq_axis), mesh))
+
+
+def rows_gathered_once(loss: Callable, mesh: Mesh, w_spec: P, batch: int):
+    """`loss(hidden[B, ...], w[D, V], labels[B, ...]) -> scalar mean over B`,
+    run per shard of the mesh's data axes (`batch_spec`), with `w`'s rows
+    all-gathered ONCE on the way in over those of them that shard the rows
+    (fsdp).
+
+    Left to the partitioner, a loss that loops over chunks carries `w`'s
+    SHARD through the loop and puts the collectives where the dots are,
+    inside every trip (`ops.losses.chunked_cross_entropy` has the HLO names).
+    Under a shard_map they are where they are written: one `all_gather`
+    before the loop (XLA moves the cast to the compute dtype in front of it,
+    so the wire carries bf16) and its transpose, one `psum_scatter` after it
+    of the gradient summed over the loop on the chip in `w`'s own dtype; the
+    hidden state's cotangent leaves sharded over the batch, as the forward
+    is. Each shard takes the mean of its own rows and the shards' means are
+    averaged, so only the order of summation differs from the bare loss.
+
+    Returns `loss` itself where there is nothing to gather over: no axis of
+    `w_spec`'s rows is a data axis of this mesh, or `batch` does not split."""
+    rows = tuple(w_spec)[0] if tuple(w_spec) else None
+    rows = (rows,) if isinstance(rows, str) else tuple(rows or ())
+    data = tuple(a for a in batch_spec()[0] if mesh.shape.get(a, 1) > 1)
+    axes = tuple(a for a in rows if a in data)
+    if not axes or batch % math.prod(mesh.shape[a] for a in data):
+        return loss
+
+    def per_shard(hidden, w, labels):
+        w = jax.lax.all_gather(w, axes, axis=0, tiled=True)
+        return jax.lax.pmean(loss(hidden, w, labels), data)
+
+    # check_vma off: the loss's scan starts its sums from constants, which
+    # the check reads as not varying over `data` where the body's sums do
+    return jax.shard_map(per_shard, mesh=mesh,
+                         in_specs=(P(data), P(axes), P(data)), out_specs=P(),
+                         axis_names=set(data), check_vma=False)

@@ -15,6 +15,21 @@ def make_lm_train_step(cfg, optimizer, key, *, mesh=None, loss_chunk: int = 512)
     step keeps them so; feed tokens placed with `data_sharding(mesh)` and
     call (or lower) the step under `jax.set_mesh(mesh)`, which is also what
     lets the flash kernel run per shard.
+
+    The head under a mesh: where `lm_head`'s rows are sharded over a data
+    axis (fsdp), the loss runs per shard through
+    `parallel.sharding.rows_gathered_once`: ONE `all_gather` of the head
+    before the loss loops (bf16 on the wire, asynchronous in the compiled
+    step: `async-collective-start` over `all-gather bf16[D, V]` in the entry
+    computation), the gradient summed over the chunks on the chip in f32,
+    and ONE f32 `reduce-scatter` to the parameter's shard after the backward
+    loop (`reduce-scatter f32[D/n, V]`, `jit(step)/transpose(jvp())/
+    shard_map/reduce_scatter`). Neither `while` body holds a collective over
+    an array with the vocabulary in it; tests/test_train_head_collectives.py
+    reads that from the step compiled for `v5e:2x2`. Left to the
+    partitioner the same loss gathers the head 2 x T/chunk times a step and
+    reduce-scatters its gradient T/chunk times (see `chunked_cross_entropy`).
+    Without a mesh nothing is wrapped and the program is the plain one.
     """
     import jax
     import jax.numpy as jnp
@@ -22,17 +37,29 @@ def make_lm_train_step(cfg, optimizer, key, *, mesh=None, loss_chunk: int = 512)
 
     from ray_tpu.models.llama import Llama
     from ray_tpu.ops.losses import chunked_cross_entropy
+    from ray_tpu.parallel.sharding import llama_rules, rows_gathered_once
 
     model = Llama(cfg)
     dummy = jnp.zeros((2, 8), jnp.int32)
+    if mesh is not None:
+        rules = llama_rules()
+        param_sh = rules.tree_shardings(
+            jax.eval_shape(model.init, key, dummy), mesh)
+
+    def head_loss(hidden, w_head, labels):
+        return chunked_cross_entropy(
+            hidden, w_head, labels,
+            chunk_size=min(loss_chunk, labels.shape[1]))[0]
 
     def loss_fn(params, tokens):
         hidden, _ = model.apply(params, tokens[:, :-1], return_hidden=True)
         w_head = params["params"]["lm_head"]["kernel"]
-        loss, _ = chunked_cross_entropy(
-            hidden, w_head, tokens[:, 1:],
-            chunk_size=min(loss_chunk, tokens.shape[1] - 1))
-        return loss
+        loss = head_loss
+        if mesh is not None:
+            loss = rows_gathered_once(
+                head_loss, mesh, param_sh["params"]["lm_head"]["kernel"].spec,
+                hidden.shape[0])
+        return loss(hidden, w_head, tokens[:, 1:])
 
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
@@ -44,9 +71,6 @@ def make_lm_train_step(cfg, optimizer, key, *, mesh=None, loss_chunk: int = 512)
         opt_state = optimizer.init(params)
         return params, opt_state, jax.jit(step, donate_argnums=(0, 1))
 
-    from ray_tpu.parallel.sharding import llama_rules
-    rules = llama_rules()
-    param_sh = rules.tree_shardings(jax.eval_shape(model.init, key, dummy), mesh)
     params = jax.jit(model.init, out_shardings=param_sh)(key, dummy)
     opt_sh = rules.tree_shardings(jax.eval_shape(optimizer.init, params), mesh)
     opt_state = jax.jit(optimizer.init, out_shardings=opt_sh)(params)
