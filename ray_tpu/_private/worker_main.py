@@ -299,10 +299,18 @@ def main():
             break
         pool.submit(_execute, ws, p)
         _warm_next(ws)
-    pool.shutdown(wait=True)
-    # drain any still-buffered refcount deltas before dropping the socket
-    # (best effort: if the controller is already gone the flush is a no-op)
-    client.close()
+    # The controller's socket closed: the driver is gone (killed, or it shut
+    # down and took its controller along), and whatever still runs here runs
+    # for nobody. Do not wait for it: an actor's method that never returns, or
+    # a thread of its own, would keep this process, and the chip it holds,
+    # for ever (`pool.shutdown(wait=True)` did, and the interpreter's exit
+    # joins every non-daemon thread besides). Flush what is buffered (best
+    # effort: with the controller gone it is a no-op) and end the process.
+    pool.shutdown(wait=False, cancel_futures=True)
+    try:
+        client.close()
+    finally:
+        os._exit(0)
 
 
 if __name__ == "__main__":

@@ -26,8 +26,10 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.moe import MoEMLP
-from ray_tpu.ops.attention import apply_rope, decode_attention, mha_reference
+from ray_tpu.ops.attention import (apply_rope, blockwise_prefill_attention,
+                                   decode_attention, mha_reference)
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.linear_attention import causal_conv, kda_chunked, kda_step
 from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          paged_attention_reference,
                                          row_keys_values,
@@ -80,6 +82,42 @@ class LlamaConfig:
     index_heads: int = 0
     index_dim: int = 64
     index_topk: int = 0
+    # ---- a hybrid of full and linear attention (Kimi Linear's layout, which
+    # Solar-Open2 takes): layer i has full softmax attention iff
+    # i % full_attn_every == 0, the others the gated delta rule
+    # (ops/linear_attention.py) over a fixed state a head. 0 = every layer
+    # full. Only the full layers have keys and values in the paged cache.
+    full_attn_every: int = 0
+    linear_heads: int = 0           # heads of the linear layers
+    linear_key_dim: int = 128       # a head's key (and query) width
+    linear_value_dim: int = 128
+    linear_conv: int = 4            # width of the short convolution
+    linear_rank: int = 128          # of the decay's and the gate's low-rank pair
+    use_rope: bool = True           # False: no positions anywhere (NoPE)
+    attn_gate: bool = False         # full layers: out * sigmoid(x Wgate)
+    # ---- what the DeepSeek-V3 family's router and banks add to the MoE
+    router_score: str = "softmax"   # softmax | sigmoid (gates renormalised)
+    n_shared_experts: int = 0       # SwiGLU experts every token takes
+    # a chip's SHARE of the routed experts: the router scores all n_experts,
+    # the bank holds `experts_held` of them from `experts_first` on and
+    # computes the pairs that fall on those (0 = the bank holds them all)
+    experts_held: int = 0
+    experts_first: int = 0
+
+    def is_linear(self, layer_idx: int) -> bool:
+        return bool(self.full_attn_every) and layer_idx % self.full_attn_every != 0
+
+    @property
+    def n_linear_layers(self) -> int:
+        return sum(self.is_linear(i) for i in range(self.n_layers))
+
+    def kv_layer(self, layer_idx: int) -> int:
+        """Where a full-attention layer's keys and values lie in the pools."""
+        return layer_idx // self.full_attn_every if self.full_attn_every else layer_idx
+
+    def linear_index(self, layer_idx: int) -> int:
+        """Which of the cache's states a linear layer owns."""
+        return layer_idx - layer_idx // self.full_attn_every - 1
 
     # ---- presets (sizes follow the Llama family; test config is `tiny`).
     # kwargs override the preset's own values (e.g. tiny(max_seq_len=64)).
@@ -123,6 +161,34 @@ class LlamaConfig:
             rope_theta=1e7, norm_eps=1e-6, n_experts=128, moe_top_k=8,
             expert_dim=768, qk_norm=True, rope_sections=(16, 24, 24),
             index_heads=16, index_dim=64, index_topk=2048), **kw})
+
+    @staticmethod
+    def solar_tiny(**kw):
+        """Test-scale Solar-Open2: one full-attention layer in four (no
+        rotary, an output gate) beside gated delta-rule layers, 16 experts
+        of 32 scored by a sigmoid with 2 a token, one shared expert."""
+        return LlamaConfig(**{**dict(
+            vocab_size=256, d_model=64, n_layers=4, n_heads=4,
+            n_kv_heads=2, head_dim=16, ffn_dim=128, max_seq_len=128,
+            n_experts=16, moe_top_k=2, expert_dim=32, full_attn_every=4,
+            linear_heads=4, linear_key_dim=16, linear_value_dim=16,
+            linear_rank=16, use_rope=False, attn_gate=True,
+            router_score="sigmoid", n_shared_experts=1), **kw})
+
+    @staticmethod
+    def solar_open2_250b(**kw):
+        """Solar-Open2-250B: 48 layers of which every fourth is GQA 64 / 8
+        of 128 without positions and with an output gate, the others KDA
+        with 64 heads of 128 x 128; 320 experts of 1280 scored by a sigmoid,
+        8 a token, one shared. `ffn_dim` is the config's unused
+        `intermediate_size`."""
+        return LlamaConfig(**{**dict(
+            vocab_size=196608, d_model=4096, n_layers=48, n_heads=64,
+            n_kv_heads=8, head_dim=128, ffn_dim=10240, max_seq_len=262144,
+            n_experts=320, moe_top_k=8, expert_dim=1280, full_attn_every=4,
+            linear_heads=64, linear_key_dim=128, linear_value_dim=128,
+            linear_rank=128, use_rope=False, attn_gate=True,
+            router_score="sigmoid", n_shared_experts=1), **kw})
 
     @staticmethod
     def mixtral_8x7b(**kw):
@@ -263,8 +329,11 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_sections)
+        if cfg.use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_sections)
+        if cfg.full_attn_every:      # the pools hold the full layers only
+            layer_idx = cfg.kv_layer(layer_idx)
         if positions.ndim == 3:
             # three-component rotary; the order in the sequence (where the
             # cache is written, what is causal) is the row's own count
@@ -337,7 +406,14 @@ class Attention(nn.Module):
                 # B is 1 here (row view), so the gather is one row's
                 # capacity per layer.
                 k_all, v_all = row_keys_values(cache, layer_idx)
-                out = decode_attention(q, k_all, v_all, positions[:, 0])
+                if cfg.full_attn_every:
+                    # rows of tens of thousands of keys: by key blocks, as
+                    # far as the chunk reaches (the scores of a whole row's
+                    # capacity at once would be gigabytes)
+                    out = blockwise_prefill_attention(q, k_all, v_all,
+                                                      positions[:, 0])
+                else:
+                    out = decode_attention(q, k_all, v_all, positions[:, 0])
             new_cache_kv = cache
         elif cache is not None:
             # Decode: write current K/V at `length`, attend over the cache.
@@ -361,7 +437,91 @@ class Attention(nn.Module):
                 out = mha_reference(q, k, v, causal=True)
 
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            out = out * nn.sigmoid(
+                dense(cfg.n_heads * cfg.head_dim, name="w_gate")(x))
         return dense(cfg.d_model, name="wo")(out), new_cache_kv
+
+
+def _decay_bias_init(lo: float, hi: float):
+    """`dt_bias` such that softplus(dt_bias), a channel's decay rate with
+    `A_log` 0, is log-uniform in [lo, hi]: alpha = exp(-rate) then spans
+    (exp(-hi), exp(-lo))."""
+    def init(key, shape, dtype=jnp.float32):
+        rate = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, jnp.log(lo), jnp.log(hi)))
+        return jnp.log(jnp.expm1(rate)).astype(dtype)
+    return init
+
+
+class LinearAttention(nn.Module):
+    """A gated delta-rule layer (KDA): q, k, v through a short causal
+    convolution and a silu, q and k normalised a head; a per-channel decay
+    from a low-rank pair; a write strength in (0, 2); the recurrence of
+    `ops/linear_attention.py`; a per-head RMS norm and a low-rank sigmoid
+    gate before the output projection. With a paged cache the layer's state
+    and the convolution's last inputs are the cache's, a slot each; without
+    one (training, the uncached forward) the sequence starts from zeros."""
+    cfg: LlamaConfig
+    layer_idx: int = 0
+
+    @nn.compact
+    def __call__(self, x, cache, n_valid=None, fresh: bool = False):
+        cfg = self.cfg
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02))
+        b, t, _ = x.shape
+        h, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+        f32 = jnp.float32
+        qkv = jnp.concatenate([dense(h * dk, name="wq")(x),
+                               dense(h * dk, name="wk")(x),
+                               dense(h * dv, name="wv")(x)], axis=-1)
+        conv_w = self.param("conv", nn.initializers.normal(0.02),
+                            (cfg.linear_conv, qkv.shape[-1]), cfg.param_dtype)
+        a_log = self.param("A_log", nn.initializers.zeros, (h,), f32)
+        dt_bias = self.param("dt_bias", _decay_bias_init(1e-4, 0.105),
+                             (h * dk,), f32)
+        rate = dense(h * dk, name="wf_b")(dense(cfg.linear_rank, name="wf_a")(x))
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            rate.astype(f32) + dt_bias).reshape(b, t, h, dk)
+        beta = 2.0 * nn.sigmoid(dense(h, name="wb")(x).astype(f32))
+
+        li = cfg.linear_index(self.layer_idx)
+        paged = isinstance(cache, PagedKVCache)
+        if cache is not None and not paged:
+            raise NotImplementedError(
+                "a linear-attention layer decodes through the paged cache "
+                "(its state is the cache's, a slot each)")
+        if paged and not fresh:
+            state, carried = cache.state[li], cache.conv[li]
+        else:
+            state = jnp.zeros((b, h, dk, dv), f32)
+            carried = jnp.zeros((b, cfg.linear_conv - 1, qkv.shape[-1]),
+                                cfg.dtype)
+        qkv, carried = causal_conv(qkv, carried, conv_w, n_valid)
+        qkv = nn.silu(qkv)
+        q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+        q = unit(q.reshape(b, t, h, dk).astype(f32)) * dk ** -0.5
+        k = unit(k.reshape(b, t, h, dk).astype(f32))
+        v = v.reshape(b, t, h, dv)
+        if t == 1 and paged:
+            valid = None if n_valid is None else n_valid > 0
+            o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], state, valid)
+            o = o[:, None]
+        else:
+            o, state = kda_chunked(q, k, v, g, beta, state, n_valid)
+        if paged:
+            put = lambda old, new: old[:li] + (new,) + old[li + 1:]
+            cache = cache.replace(state=put(cache.state, state),
+                                  conv=put(cache.conv, carried))
+        o = RMSNorm(cfg.norm_eps, cfg.dtype, name="o_norm")(o)
+        gate = dense(h * dv, name="wg_b")(dense(cfg.linear_rank, name="wg_a")(x))
+        o = o.reshape(b, t, h * dv) * nn.sigmoid(gate)
+        return dense(cfg.d_model, name="wo")(o), (cache if paged else None)
 
 
 class MLP(nn.Module):
@@ -383,18 +543,24 @@ class Block(nn.Module):
     layer_idx: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, cache, paged_chunk_local=False):
+    def __call__(self, x, positions, cache, paged_chunk_local=False,
+                 n_valid=None):
         cfg = self.cfg
-        h, new_kv = Attention(cfg, self.layer_idx, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x),
-            positions, cache, paged_chunk_local)
-        x = x + h
-        if cfg.n_experts > 0 and self.layer_idx % cfg.moe_every == 0:
-            ffn = MoEMLP(cfg, name="moe")
+        normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
+        if cfg.is_linear(self.layer_idx):
+            h, new_kv = LinearAttention(cfg, self.layer_idx, name="kda")(
+                normed, cache, n_valid, paged_chunk_local)
         else:
-            ffn = MLP(cfg, name="mlp")
-        x = x + ffn(RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(x))
-        return x, new_kv
+            h, new_kv = Attention(cfg, self.layer_idx, name="attn")(
+                normed, positions, cache, paged_chunk_local)
+        x = x + h
+        normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(x)
+        if cfg.n_experts > 0 and self.layer_idx % cfg.moe_every == 0:
+            # which rows are real tokens, for the bank's own counts
+            real = (None if n_valid is None else
+                    jnp.arange(x.shape[1])[None] < n_valid[:, None])
+            return x + MoEMLP(cfg, name="moe")(normed, real), new_kv
+        return x + MLP(cfg, name="mlp")(normed), new_kv
 
 
 class Llama(nn.Module):
@@ -402,7 +568,8 @@ class Llama(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, cache: Optional[KVCache] = None,
-                 return_hidden: bool = False, paged_chunk_local: bool = False):
+                 return_hidden: bool = False, paged_chunk_local: bool = False,
+                 n_valid=None):
         """tokens [B, T] int32 → logits [B, T, V] (f32), new cache (or None).
 
         Prefill/train: cache=None, full causal attention. Decode: pass a
@@ -412,6 +579,11 @@ class Llama(nn.Module):
         the FIRST tokens of a fresh row (start==0, no cached prefix), so
         chunk-local causal attention is exact and skips the full-row page
         gather — the hot cold-prompt path.
+
+        `n_valid` [B] (a model with linear-attention layers): how many of
+        the T tokens of each row are real. The rest (a prefill bucket's
+        padding, a slot that does not decode this step) leave the row's
+        recurrent state where it was.
 
         `return_hidden=True` returns the final-norm hidden states [B, T, D]
         instead of logits — callers fuse the lm_head into a chunked loss
@@ -438,7 +610,7 @@ class Llama(nn.Module):
         new_k, new_v = [], []
         for i in range(cfg.n_layers):
             x, new_kv = block_cls(cfg, i, name=f"layers_{i}")(
-                x, positions, cache, paged_chunk_local)
+                x, positions, cache, paged_chunk_local, n_valid)
             if paged:
                 cache = new_kv  # thread the updated page pools layer→layer
             elif new_kv is not None:
@@ -483,12 +655,24 @@ def _attn_params(cfg: LlamaConfig) -> int:
     a layout change (biases, MLA, ...) can't desynchronize reported MFU
     from the real parameter count."""
     n = cfg.d_model * cfg.head_dim * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
+    if cfg.attn_gate:
+        n += cfg.d_model * cfg.head_dim * cfg.n_heads
     if cfg.qk_norm:
         n += 2 * cfg.head_dim
     if cfg.index_topk:   # wq, wk, w, and the key's LayerNorm
         n += cfg.d_model * (cfg.index_heads * cfg.index_dim + cfg.index_dim
                             + cfg.index_heads) + 2 * cfg.index_dim
     return n
+
+
+def _linear_attn_params(cfg: LlamaConfig) -> int:
+    """One gated delta-rule layer: q, k, v, o, the two low-rank pairs, beta,
+    the convolution, A_log, dt_bias and the per-head norm."""
+    h, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    wide = h * (2 * dk + dv)
+    return (cfg.d_model * (wide + h * dv + h)
+            + cfg.linear_rank * (2 * cfg.d_model + h * dk + h * dv)
+            + cfg.linear_conv * wide + h + h * dk + dv)
 
 
 def _mlp_params(cfg: LlamaConfig) -> int:
@@ -502,11 +686,16 @@ def _expert_params(cfg: LlamaConfig) -> int:
 
 
 def llama_param_count(cfg: LlamaConfig) -> int:
+    """Parameters the model HOLDS: a bank with a share of the experts
+    (`experts_held`) counts those, its router all it scores."""
     per_layer = _attn_params(cfg) + _mlp_params(cfg) + 2 * cfg.d_model
     total = cfg.n_layers * per_layer
+    total += cfg.n_linear_layers * (_linear_attn_params(cfg)
+                                    - _attn_params(cfg))
     # MoE blocks swap the dense FFN for E experts + a router
     n_moe = _n_moe_layers(cfg)
-    total += n_moe * (cfg.n_experts * _expert_params(cfg) - _mlp_params(cfg)
+    held = (cfg.experts_held or cfg.n_experts) + cfg.n_shared_experts
+    total += n_moe * (held * _expert_params(cfg) - _mlp_params(cfg)
                       + cfg.d_model * cfg.n_experts)
     embed = cfg.vocab_size * cfg.d_model
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
@@ -520,11 +709,15 @@ def llama_compute_flops(cfg: LlamaConfig, batch: int, seq: int) -> float:
     n_moe = _n_moe_layers(cfg)
     n_dense = cfg.n_layers - n_moe
     n_active = (cfg.n_layers * _attn_params(cfg)
+                + cfg.n_linear_layers * (_linear_attn_params(cfg)
+                                         - _attn_params(cfg))
                 + n_dense * _mlp_params(cfg)
-                + n_moe * (cfg.moe_top_k * _expert_params(cfg)
+                + n_moe * ((cfg.moe_top_k + cfg.n_shared_experts)
+                           * _expert_params(cfg)
                            + cfg.d_model * cfg.n_experts))
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
     n_active += head
     tokens = batch * seq
-    attn = 6 * cfg.n_layers * cfg.n_heads * cfg.head_dim * batch * seq * seq  # fwd 2 matmuls + bwd, halved for causal
+    full = cfg.n_layers - cfg.n_linear_layers
+    attn = 6 * full * cfg.n_heads * cfg.head_dim * batch * seq * seq  # fwd 2 matmuls + bwd, halved for causal
     return 6.0 * n_active * tokens + attn

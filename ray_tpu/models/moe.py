@@ -47,7 +47,9 @@ class MoEMLP(nn.Module):
     cfg: "LlamaConfig"  # noqa: F821 - llama.py owns the config class
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, real=None):
+        """`real` [B, T] bool (optional): the rows that are tokens of a
+        request, for the counts a bank with a share of the experts sows."""
         cfg = self.cfg
         E, K = cfg.n_experts, cfg.moe_top_k
         B, T, D = x.shape
@@ -61,7 +63,8 @@ class MoEMLP(nn.Module):
                           param_dtype=jnp.float32,
                           kernel_init=nn.initializers.normal(0.02),
                           name="router")(xf.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)            # [S, E]
+        probs = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))     # [S, E]
         gate_vals, gate_idx = jax.lax.top_k(probs, K)       # [S, K]
         gate_vals = gate_vals / jnp.maximum(
             gate_vals.sum(-1, keepdims=True), 1e-9)         # renormalize
@@ -73,6 +76,8 @@ class MoEMLP(nn.Module):
         self.sow("losses", "moe_aux", E * jnp.sum(f_e * p_e))
 
         init = nn.initializers.normal(0.02)
+        if cfg.experts_held or cfg.n_shared_experts:
+            return self._share(xf, gate_vals, gate_idx, real).reshape(B, T, D)
         w_gate = self.param("w_gate", init, (E, D, F), cfg.param_dtype)
         w_up = self.param("w_up", init, (E, D, F), cfg.param_dtype)
         w_down = self.param("w_down", init, (E, F, D), cfg.param_dtype)
@@ -125,6 +130,52 @@ class MoEMLP(nn.Module):
         return y.reshape(B, T, D)
 
 
+    def _share(self, xf, gate_vals, gate_idx, real):
+        """A chip's share of the bank: `experts_held` experts from
+        `experts_first` on, through the grouped product. The router has
+        scored all E and kept K a token; the pairs that fall on a held expert
+        are computed here under their gates (normalised over all K), the
+        others are another chip's and add nothing. Every shared expert is
+        whole on every chip. Nothing stands in for the absent chips: what
+        this returns is this chip's part of the layer's sum."""
+        cfg = self.cfg
+        D = xf.shape[-1]
+        F = cfg.expert_dim or cfg.ffn_dim
+        held = cfg.experts_held or cfg.n_experts
+        init = nn.initializers.normal(0.02)
+        w_gate = self.param("w_gate", init, (held, D, F), cfg.param_dtype)
+        w_up = self.param("w_up", init, (held, D, F), cfg.param_dtype)
+        w_down = self.param("w_down", init, (held, F, D), cfg.param_dtype)
+        local = gate_idx - cfg.experts_first
+        mine = (local >= 0) & (local < held)                # [S, K]
+        local = jnp.where(mine, local, held)       # the others sort last
+        keep = lambda _, new: new
+        self.sow("moe_stats", "experts_touched", jnp.zeros(
+            (held + 1,), bool).at[local.reshape(-1)].set(True)[:held].sum(),
+            reduce_fn=keep, init_fn=lambda: jnp.int32(0))
+        # pairs on a held expert: of real tokens, and of every row the call
+        # multiplies (a bucket's padding and idle slots route too)
+        counted = mine if real is None else mine & real.reshape(-1, 1)
+        self.sow("moe_stats", "held_pairs",
+                 jnp.stack([counted.sum(), mine.sum()]).astype(jnp.int32),
+                 reduce_fn=keep, init_fn=lambda: jnp.zeros((2,), jnp.int32))
+        x = xf.astype(cfg.dtype)
+        with jax.named_scope("moe_grouped"):
+            y = grouped_experts(x, gate_vals, local, w_gate.astype(cfg.dtype),
+                                w_up.astype(cfg.dtype),
+                                w_down.astype(cfg.dtype), held=mine)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe_shared"):
+                Fs = F * cfg.n_shared_experts
+                dense = lambda n, name: nn.Dense(
+                    n, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, kernel_init=init, name=name)
+                y = y + dense(D, "shared_down")(
+                    nn.silu(dense(Fs, "shared_gate")(x))
+                    * dense(Fs, "shared_up")(x))
+        return y
+
+
 def grouped_product(n_experts: int, top_k: int) -> bool:
     """Whether a bank of these shapes takes the grouped product: where the
     dropless one-hot dispatch would compute more than 4 times the routed
@@ -135,33 +186,60 @@ def grouped_product(n_experts: int, top_k: int) -> bool:
 _GMM_RHS_TILE_BYTES = 4 << 20      # one expert's [k, n] matrix in fast memory
 
 
+def _gmm_tiling(m: int, k: int, n: int, itemsize: int):
+    """megablox tiles (rows, k, n) for a product of [m, k] rows with [k, n]
+    matrices: an expert's matrix whole where it fits fast memory's budget
+    (`_GMM_RHS_TILE_BYTES`), else its longer side halved until a tile does (a
+    4096 x 1280 matrix goes through as four tiles of 1024 x 1280, still read
+    once a group); None where the rows or the sides do not divide."""
+    if m % 8:
+        return None
+    tk, tn = k, n
+    while tk * tn * itemsize > _GMM_RHS_TILE_BYTES:
+        if tk >= tn and tk % 256 == 0:
+            tk //= 2
+        elif tn % 256 == 0:
+            tn //= 2
+        else:
+            return None
+    return next(t for t in (64, 32, 16, 8) if m % t == 0), tk, tn
+
+
 def _grouped_dot(lhs, rhs, sizes):
     """lhs [M, k] (rows in group order) x rhs [G, k, n] -> [M, n], each group
     of `sizes` by its own matrix. `jax.lax.ragged_dot`, except where the chip
-    measured better: on the TPU, rows a multiple of 8 and a matrix that fits
-    fast memory whole take megablox's pallas `gmm` tiled (64 rows, k, n), one
-    matrix read a group: 0.62-0.66 ms a product of 128 experts of 2048 x 768
-    at 192 and 4096 rows against `ragged_dot`'s 0.95 and 1.94 (v5e, PR 28; the
-    weights alone are 0.49 ms at the chip's bandwidth)."""
+    measured better: on the TPU, rows a multiple of 8 take megablox's pallas
+    `gmm` tiled by `_gmm_tiling`, one matrix read a group: 0.62-0.66 ms a
+    product of 128 experts of 2048 x 768 at 192 and 4096 rows against
+    `ragged_dot`'s 0.95 and 1.94 (v5e, PR 28; the weights alone are 0.49 ms
+    at the chip's bandwidth). Rows past the groups' sum (a bank that holds a
+    share of the experts sorts the others' pairs there) are no group's: `gmm`
+    visits no tile for them and leaves their output rows unwritten, so the
+    caller must not read them."""
     m, k = lhs.shape
     n = rhs.shape[-1]
-    if (jax.default_backend() == "tpu" and m % 8 == 0
-            and k * n * rhs.dtype.itemsize <= _GMM_RHS_TILE_BYTES):
+    tiling = (_gmm_tiling(m, k, n, rhs.dtype.itemsize)
+              if jax.default_backend() == "tpu" else None)
+    if tiling is not None:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        tm = next(t for t in (64, 32, 16, 8) if m % t == 0)
         return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-                   tiling=(tm, k, n))
+                   tiling=tiling)
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
-def grouped_experts(x, gate_vals, gate_idx, w_gate, w_up, w_down):
+def grouped_experts(x, gate_vals, gate_idx, w_gate, w_up, w_down, held=None):
     """Sorted, dropless grouped SwiGLU experts. x [S, D]; gate_vals (f32)
     and gate_idx [S, K]: each token's gates and experts; w_gate / w_up
     [E, D, F], w_down [E, F, D]. Returns [S, D] in x.dtype.
 
     The S * K routed rows are put in expert order (a stable sort, so a run
     is deterministic), each expert multiplies its contiguous group, and the
-    rows go back to token order to be summed under their gates in f32."""
+    rows go back to token order to be summed under their gates in f32.
+
+    `held` [S, K] bool (a bank that holds a share of the experts): the pairs
+    to compute. The others carry the index E, past the last group: they sort
+    behind every group, no group multiplies them (what the product leaves in
+    their rows is not read), and they add nothing to the sum."""
     S, K = gate_idx.shape
     E = w_gate.shape[0]
     flat = gate_idx.reshape(-1)
@@ -173,7 +251,10 @@ def grouped_experts(x, gate_vals, gate_idx, w_gate, w_up, w_down):
     out = _grouped_dot(nn.silu(h) * u, w_down, sizes)         # [S*K, D]
     back = jnp.zeros_like(order).at[order].set(jnp.arange(S * K))
     out = out[back].reshape(S, K, -1).astype(jnp.float32)
-    return (out * gate_vals[..., None]).sum(1).astype(x.dtype)
+    out = out * gate_vals[..., None]
+    if held is not None:
+        out = jnp.where(held[..., None], out, 0.0)
+    return out.sum(1).astype(x.dtype)
 
 
 def moe_aux_loss(losses_collection, weight: float) -> jnp.ndarray:

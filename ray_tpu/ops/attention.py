@@ -133,3 +133,55 @@ def decode_attention(
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", p.astype(v_cache.dtype), v_cache)
     return out.reshape(b, t, h, d).astype(q.dtype)
+
+
+def blockwise_prefill_attention(
+    q: jax.Array,        # [B, T, H, D]: a prefill chunk's queries
+    k_cache: jax.Array,  # [B, Smax, Kh, D]: the row's keys, the chunk's written
+    v_cache: jax.Array,  # [B, Smax, Kh, D]
+    lengths: jax.Array,  # [B] int32: tokens in the row BEFORE this chunk
+    scale: Optional[float] = None,
+    key_block: int = 512,
+) -> jax.Array:
+    """`decode_attention` for rows too long to score at once: the same
+    absolute-position causal mask, by key blocks under an online softmax, as
+    far as the last query reaches (a loop with a dynamic bound, so a short
+    row in a long table pays for its own keys only). Nothing of size
+    [T, heads, Smax] is held."""
+    b, t, h, d = q.shape
+    smax, kh = k_cache.shape[1], k_cache.shape[2]
+    g = h // kh
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    kb = min(key_block, smax)
+    if smax % kb:
+        pad = kb - smax % kb
+        k_cache, v_cache = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                            for x in (k_cache, v_cache))
+    qg = q.reshape(b, t, kh, g, d)
+    pos = lengths[:, None] + jnp.arange(t)[None]                  # [B, T]
+    n_blocks = (jnp.max(pos) + kb) // kb
+    col = jnp.arange(kb)
+
+    def block(i, carry):
+        m, l, acc = carry
+        k = jax.lax.dynamic_slice_in_dim(k_cache, i * kb, kb, 1)
+        v = jax.lax.dynamic_slice_in_dim(v_cache, i * kb, kb, 1)
+        s = jnp.einsum("btkgd,bskd->bkgts", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        keep = ((i * kb + col)[None, None] <= pos[:, :, None])[:, None, None]
+        s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bkgts,bskd->bkgtd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    init = (jnp.full((b, kh, g, t), NEG_INF, jnp.float32),
+            jnp.zeros((b, kh, g, t), jnp.float32),
+            jnp.zeros((b, kh, g, t, d), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]                  # [B,Kh,G,T,D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d).astype(q.dtype)

@@ -243,6 +243,23 @@ class PagedKVCache(flax.struct.PyTreeNode):
       HLO compiled for the v5e). `index_keys` reads pages back as
       [tokens, Di].
 
+    A model with linear-attention layers (`LlamaConfig.full_attn_every`)
+    holds two kinds of cache in this one tree. Its pools cover the
+    full-attention layers only (L counts those). Each linear layer has, a
+    SLOT and not a token, a recurrent state `state[i]` [B, H, dk, dv] in f32
+    and the short convolution's last inputs `conv[i]` [B, W - 1, channels],
+    carried through prefill chunks and decode steps in place. `snap_state` /
+    `snap_conv` ([N, ...] a layer) are a pool of SNAPSHOTS of those, taken
+    where a prompt's prefill crosses its last page boundary: a prefix hit on
+    such a model is a hit only as far as a snapshot, since the pages' keys
+    and values say nothing of what the linear layers had seen
+    (`serve/radix_cache.py`). A state is not a page: `pools()` does not list
+    these, and what moves pages (demotion, restore, the P/D hand-off) does
+    not carry them. `held_pairs` [2] counts, on the device, the (token, expert)
+    pairs that fell on this chip's share of the experts: those of real
+    tokens, and all that the programs multiplied. All None for every
+    other model: their tree and programs are what they were.
+
     block_tables: [B, max_pages]; lengths: [B]. Rows whose slot is free have
     length 0 and table entries 0. `page_axis` is where a pool's page index
     sits: the functions under "Moving whole pages" below are its only
@@ -254,6 +271,11 @@ class PagedKVCache(flax.struct.PyTreeNode):
     block_tables: jax.Array
     lengths: jax.Array
     idx_pages: Optional[jax.Array] = None
+    state: Optional[tuple] = None
+    conv: Optional[tuple] = None
+    snap_state: Optional[tuple] = None
+    snap_conv: Optional[tuple] = None
+    held_pairs: Optional[jax.Array] = None
 
     @property
     def page_axis(self) -> int:
@@ -286,7 +308,11 @@ class PagedKVCache(flax.struct.PyTreeNode):
     @staticmethod
     def init(n_layers: int, n_kv_heads: int, head_dim: int, num_pages: int,
              page_size: int, batch_slots: int, max_pages_per_seq: int,
-             dtype=jnp.bfloat16, index_dim: int = 0) -> "PagedKVCache":
+             dtype=jnp.bfloat16, index_dim: int = 0,
+             linear: Optional[dict] = None) -> "PagedKVCache":
+        """`n_layers` counts the layers with keys and values. `linear`: the
+        linear layers' sizes (`layers`, `heads`, `key_dim`, `value_dim`,
+        `conv` inputs carried, `channels`) and `snapshots`, the pool's size."""
         tables = dict(
             block_tables=jnp.zeros((batch_slots, max_pages_per_seq), jnp.int32),
             lengths=jnp.zeros((batch_slots,), jnp.int32))
@@ -298,9 +324,49 @@ class PagedKVCache(flax.struct.PyTreeNode):
                 idx_pages=jnp.zeros(
                     (n_layers, num_pages, page_size // r, r * index_dim),
                     dtype), **tables)
+        if linear:
+            per = lambda n, *shape, dt: tuple(
+                jnp.zeros((n,) + shape, dt) for _ in range(linear["layers"]))
+            s_shape = (linear["heads"], linear["key_dim"], linear["value_dim"])
+            c_shape = (linear["conv"], linear["channels"])
+            tables.update(
+                state=per(batch_slots, *s_shape, dt=jnp.float32),
+                conv=per(batch_slots, *c_shape, dt=dtype),
+                snap_state=per(linear["snapshots"], *s_shape, dt=jnp.float32),
+                snap_conv=per(linear["snapshots"], *c_shape, dt=dtype),
+                held_pairs=jnp.zeros((2,), jnp.int32))
         shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
                             v_pages=jnp.zeros(shape, dtype), **tables)
+
+
+def copy_slot_state(cache: PagedKVCache, slot, snapshot, save: bool
+                    ) -> PagedKVCache:
+    """A slot's recurrent state and convolution inputs, every linear layer:
+    into snapshot `snapshot` (`save`), or out of it into the slot. Under
+    `jit` with the cache donated each is a row read and a row written in
+    place. A negative `snapshot` on restore zeroes the slot: a sequence that
+    starts from nothing."""
+    def move(slots, snaps):
+        out_slots, out_snaps = [], []
+        for sl, sn in zip(slots, snaps):
+            if save:
+                row = jax.lax.dynamic_index_in_dim(sl, slot, 0, keepdims=True)
+                sn = jax.lax.dynamic_update_slice_in_dim(sn, row, snapshot, 0)
+            else:
+                row = jax.lax.dynamic_index_in_dim(
+                    sn, jnp.maximum(snapshot, 0), 0, keepdims=True)
+                row = jnp.where(snapshot < 0, jnp.zeros_like(row), row)
+                sl = jax.lax.dynamic_update_slice_in_dim(sl, row, slot, 0)
+            out_slots.append(sl)
+            out_snaps.append(sn)
+        return tuple(out_slots), tuple(out_snaps)
+
+    with jax.named_scope("state_snapshot" if save else "state_restore"):
+        state, snap_state = move(cache.state, cache.snap_state)
+        conv, snap_conv = move(cache.conv, cache.snap_conv)
+    return cache.replace(state=state, conv=conv, snap_state=snap_state,
+                         snap_conv=snap_conv)
 
 
 def index_pack(page_size: int, index_dim: int) -> int:

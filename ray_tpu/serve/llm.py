@@ -56,6 +56,11 @@ LOOP_PHASES = ("decode_build", "decode_dispatch", "decode_sync",
                "yield")
 NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
                  "restore")
+# A model with linear-attention layers only: the copy of a slot's recurrent
+# state into a snapshot where its prefill crosses the prompt's last page
+# boundary (inside prefill_dispatch), and out of one at admission (inside
+# admit_allocate).
+STATE_PHASES = ("state_save", "state_restore")
 
 # Demotion of evicted prefix pages. One gather program whatever the pass
 # size: its index vector always has DEMOTE_GROUP entries, padded with the
@@ -68,6 +73,7 @@ NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
 # `radix_cache.DEMOTE_CAP` and `kv_transfer.STASH_BUDGET_BYTES`.
 DEMOTE_GROUP = 8
 STAGED_CAP_BYTES = 128 << 20
+SNAPSHOTS_PER_SLOT = 4
 
 
 class SlotState(NamedTuple):
@@ -255,7 +261,11 @@ class LLMServer:
                 self.model_cfg = _dc.replace(self.model_cfg,
                                              capacity_factor=dropless)
         self.model = Llama(self.model_cfg)
-        self._phases = PhaseTotals("engine", LOOP_PHASES + NESTED_PHASES)
+        # recurrent state a slot beside the pages (linear-attention layers)
+        self._stateful = self.model_cfg.n_linear_layers > 0
+        self._phases = PhaseTotals(
+            "engine", LOOP_PHASES + NESTED_PHASES
+            + (STATE_PHASES if self._stateful else ()))
         B = cfg.max_batch_slots
         key = jax.random.PRNGKey(cfg.seed)
         if cfg.tp > 1:
@@ -306,6 +316,11 @@ class LLMServer:
                 "a model with learned sparse attention (index_topk > 0) "
                 "needs paged=True: its indexer keys live in the paged "
                 "cache's third pool")
+        if self._stateful and not cfg.paged:
+            raise ValueError(
+                "a model with linear-attention layers (full_attn_every > 0) "
+                "needs paged=True: a slot's recurrent state and its "
+                "snapshots live in the paged cache")
         if cfg.paged:
             from ray_tpu.ops.paged_attention import PagedKVCache
             from ray_tpu.serve.radix_cache import PageManager
@@ -321,19 +336,44 @@ class LLMServer:
             if cfg.prefix_cache:
                 from ray_tpu.serve.kv_transfer import KVPageStash
                 self._kv_stash = KVPageStash()
-                hooks = dict(demote_cb=self._demote_page,
-                             demote_flush_cb=self._demote_pass,
-                             restore_cb=self._restore_page,
-                             drop_cb=self._drop_page)
+                # A model with state demotes nothing: eviction is leaf first,
+                # so the node that holds a chain's snapshot goes before the
+                # pages above it, and pages with no snapshot below them can
+                # serve no later prompt (radix_cache.py): extracting them
+                # would fill the stash, and its disk, with bytes nothing reads
+                if not self._stateful:
+                    hooks = dict(demote_cb=self._demote_page,
+                                 demote_flush_cb=self._demote_pass,
+                                 restore_cb=self._restore_page,
+                                 drop_cb=self._drop_page)
+            # a model with state: every request in flight saves one snapshot
+            # and the newest of as many conversations again have to outlive
+            # them, so four a slot (one snapshot is a few thousand tokens'
+            # worth of keys and values: one a page is out of the question,
+            # one a prompt is cheap)
+            snapshots = (SNAPSHOTS_PER_SLOT * B
+                         if self._stateful and cfg.prefix_cache else 0)
             self.page_mgr = PageManager(
                 num_pages, cfg.page_size, B, max_pages,
-                prefix_cache=cfg.prefix_cache, phases=self._phases, **hooks)
+                prefix_cache=cfg.prefix_cache, phases=self._phases,
+                snapshots=snapshots, **hooks)
             # the cache follows the model's schema: a model with an indexer
-            # gets the third per-page pool (and the token-major layout)
+            # gets the third per-page pool (and the token-major layout), one
+            # with linear layers pools for its full layers only, a state a
+            # slot and the snapshot pool
+            linear = None
+            if self._stateful:
+                linear = dict(
+                    layers=mc.n_linear_layers, heads=mc.linear_heads,
+                    key_dim=mc.linear_key_dim, value_dim=mc.linear_value_dim,
+                    conv=mc.linear_conv - 1, snapshots=max(snapshots, 1),
+                    channels=mc.linear_heads * (2 * mc.linear_key_dim
+                                                + mc.linear_value_dim))
             self.cache = PagedKVCache.init(
-                mc.n_layers, mc.n_kv_heads, mc.head_dim, num_pages,
-                cfg.page_size, B, max_pages, dtype=mc.dtype,
-                index_dim=mc.index_dim if mc.index_topk else 0)
+                mc.n_layers - mc.n_linear_layers, mc.n_kv_heads, mc.head_dim,
+                num_pages, cfg.page_size, B, max_pages, dtype=mc.dtype,
+                index_dim=mc.index_dim if mc.index_topk else 0,
+                **({"linear": linear} if linear else {}))
         else:
             self.page_mgr = None
             self._kv_stash = None
@@ -399,6 +439,7 @@ class LLMServer:
         self._moe_stats = {"routed_rows": 0, "computed_rows": 0,
                            "decode_layer_calls": 0,
                            "decode_experts_touched": 0}
+        self._state_stats = {"snapshot_copies": 0, "restore_copies": 0}
         from ray_tpu.models.llama import _n_moe_layers
         from ray_tpu.models.moe import grouped_product
         mc = self.model_cfg
@@ -476,6 +517,7 @@ class LLMServer:
         # reach; how many that is only the device knows, so the decode chunk
         # hands the count back with its tokens (the same sync)
         count_touched = self._moe_grouped
+        stateful = self._stateful
 
         def sample(logits, key, temps, top_ps, top_ks, want_logp):
             """Per-request greedy / temperature / top-k / top-p (nucleus)
@@ -534,11 +576,45 @@ class LLMServer:
             row_tables = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1, 0)
             row_view = cache.replace(block_tables=row_tables,
                                      lengths=start_len[None])
+            if stateful:
+                return prefill_stateful(params, cache, row_view, tokens, slot,
+                                        start_len, true_end, chunk_local)
             logits, new_row = model.apply(params, tokens, cache=row_view,
                                           paged_chunk_local=chunk_local)
             new_cache = cache.with_pools(new_row.pools()).replace(
                 lengths=cache.lengths.at[slot].set(true_end))
             return new_cache, logits[0, true_end - start_len - 1]
+
+        def prefill_stateful(params, cache, row_view, tokens, slot, start_len,
+                             true_end, chunk_local):
+            """`prefill_paged` for a model with linear layers: the row view
+            holds the slot's own state and convolution inputs (a fresh row
+            starts from zeros whatever the slot holds), the bucket's padding
+            past `true_end` leaves them where the last real token put them,
+            and both go back into the slot in place."""
+            take = lambda xs: tuple(
+                jax.lax.dynamic_slice_in_dim(x, slot, 1, 0) for x in xs)
+            put = lambda xs, rows: tuple(
+                jax.lax.dynamic_update_slice_in_dim(x, r, slot, 0)
+                for x, r in zip(xs, rows))
+            row_view = row_view.replace(state=take(cache.state),
+                                        conv=take(cache.conv))
+            (logits, new_row), seen = model.apply(
+                params, tokens, cache=row_view, paged_chunk_local=chunk_local,
+                n_valid=(true_end - start_len)[None], mutable=["moe_stats"])
+            new_cache = cache.with_pools(new_row.pools()).replace(
+                lengths=cache.lengths.at[slot].set(true_end),
+                state=put(cache.state, new_row.state),
+                conv=put(cache.conv, new_row.conv),
+                held_pairs=cache.held_pairs + sown(seen, "held_pairs"))
+            return new_cache, logits[0, true_end - start_len - 1]
+
+        def sown(seen, name):
+            """What the expert banks sowed under `name` this call, summed
+            over the layers."""
+            flat = jax.tree_util.tree_flatten_with_path(seen)[0]
+            return sum(v for path, v in flat
+                       if any(getattr(k, "key", None) == name for k in path))
 
         def prefill_row(params, cache, tokens, slot, start_len, true_end):
             """Write one CHUNK of a (padded) prompt's KV into `slot`'s row;
@@ -601,7 +677,18 @@ class LLMServer:
                 cache, last, active, budget, room, emitted, key = carry
                 key, sub = jax.random.split(key)
                 touched = ()
-                if count_touched:
+                if stateful:
+                    # a slot that does not decode this step (idle, finished,
+                    # or still prefilling) keeps its recurrent state
+                    (logits, new_cache), seen = model.apply(
+                        params, last[:, None], cache=cache,
+                        n_valid=active.astype(jnp.int32),
+                        mutable=["moe_stats"])
+                    if count_touched:
+                        touched = (sown(seen, "experts_touched"),)
+                    new_cache = new_cache.replace(
+                        held_pairs=cache.held_pairs + sown(seen, "held_pairs"))
+                elif count_touched:
                     (logits, new_cache), seen = model.apply(
                         params, last[:, None], cache=cache,
                         mutable=["moe_stats"])
@@ -744,6 +831,17 @@ class LLMServer:
                     lengths.at[ints[0]].set(ints[1])),
                 donate_argnums=(0, 1))
             self._write_table_row(0, 0, 0)
+        if stateful:
+            from ray_tpu.ops.paged_attention import copy_slot_state
+            # [slot, snapshot]: a slot's state into a snapshot or out of one,
+            # one donated program each, compiled here as the others are
+            self._state_copy = jax.jit(
+                lambda cache, ints, save: copy_slot_state(
+                    cache, ints[0], ints[1], save),
+                donate_argnums=(0,), static_argnums=(2,))
+            for save in (True, False):
+                self.cache = self._state_copy(
+                    self.cache, np.zeros((2,), np.int32), save)
         if self._kv_stash is not None:
             from ray_tpu.ops.paged_attention import (gather_pages, page_layout,
                                                      scatter_pages)
@@ -881,6 +979,8 @@ class LLMServer:
                     E * max(1, math.ceil(
                         mc.capacity_factor * K * call_tokens / E)))
         self._moe_stats["routed_rows"] += routed_tokens * K * layers
+        # (a bank with a share of the experts counts what it multiplied on
+        # the device: stats() reads it)
         self._moe_stats["computed_rows"] += calls * per_call * layers
 
     def _count_sparse(self, first_context: int, steps: int) -> None:
@@ -1044,6 +1144,11 @@ class LLMServer:
                     # 1 overwrites it (same contract as the uncached pos-0
                     # write).
                     self._write_table_row(slot_idx, row, cached)
+                    if self._stateful and cached:
+                        with phase(self._phases, "state_restore"):
+                            self._copy_state(slot_idx,
+                                             mgr.resume_snapshot(slot_idx),
+                                             save=False)
         except BaseException:
             self._release_slot(slot_idx)
             raise
@@ -1056,12 +1161,20 @@ class LLMServer:
         P = len(job.prompt)
         start = job.pos
         n = min(self.config.prefill_chunk, P - start)
+        # a model with state stops at the prompt's last page boundary (what
+        # a later prompt can match of this one ends there), saves the state
+        # and runs the tail, at most a page, as the final chunk
+        ps = self.config.page_size
+        boundary = (P - 1) // ps * ps if self._stateful else 0
+        if start < boundary:
+            n = min(n, boundary - start)
         final = start + n >= P
         # clamp the padded bucket to the row capacity: a write spanning past
         # max_seq_len would be CLAMPED by dynamic_update_slice and land
         # shifted over earlier prompt KV (llama.py documents the clamp)
         bucket = (min(self._bucket(n), self.config.max_seq_len - start)
-                  if final else self.config.prefill_chunk)
+                  if final or n < self.config.prefill_chunk
+                  else self.config.prefill_chunk)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = job.prompt[start:start + n]
         # host values go up with the call itself
@@ -1075,6 +1188,11 @@ class LLMServer:
         else:
             self.cache, last_logits = self._prefill(*args)
         job.pos += n
+        if job.pos == boundary and n:
+            sid = self.page_mgr.reserve_snapshot(job.slot_idx, boundary // ps)
+            if sid is not None:
+                with phase(self._phases, "state_save"):
+                    self._copy_state(job.slot_idx, sid, save=True)
         st = self._decode_stats
         st["prefill_chunks"] += 1
         st["prefill_tokens"] += n
@@ -1292,6 +1410,15 @@ class LLMServer:
 
     def _drop_page(self, handle: Dict[str, Any]) -> None:
         self._kv_stash.drop(handle)
+
+    def _copy_state(self, slot_idx: int, snapshot: int, save: bool) -> None:
+        """A slot's recurrent state into snapshot `snapshot`, or out of it,
+        behind whatever is in flight (the manager may hand the id to another
+        request at once: programs run in the order they were dispatched)."""
+        self.cache = self._state_copy(
+            self.cache, np.array([slot_idx, snapshot], np.int32), save)
+        self._state_stats["snapshot_copies" if save
+                          else "restore_copies"] += 1
 
     def _write_table_row(self, slot_idx: int, row, length: int) -> None:
         """The slot's block-table row and its length on the newest cache, in
@@ -1762,6 +1889,20 @@ class LLMServer:
         if self.model_cfg.n_experts > 0:
             s["moe"] = dict(self._moe_stats,
                             recent_decode_syncs=list(self._moe_recent))
+        if self._stateful:
+            # pairs that fell on this chip's share of the experts, counted
+            # on the device by the programs themselves (read here: a sync)
+            if "moe" in s:
+                held, computed = (int(x) for x in self.cache.held_pairs)
+                s["moe"].update(held_pairs=held, computed_rows=computed)
+            per_row = lambda xs: sum(int(x.nbytes) // x.shape[0] for x in xs)
+            c = self.cache
+            s["state"] = dict(
+                self.page_mgr.state_stats(), **self._state_stats,
+                snapshot_pool_bytes=(per_row(c.snap_state + c.snap_conv)
+                                     * self.page_mgr.snapshots),
+                slot_state_bytes=(per_row(c.state + c.conv)
+                                  * self.config.max_batch_slots))
         if self.config.speculate > 0:
             st = dict(self._spec_stats)
             st["accept_rate"] = round(

@@ -58,6 +58,12 @@ def _require_paged(server: LLMServer, who: str):
     if server.page_mgr is None:
         raise ValueError(f"{who} needs LLMConfig(paged=True): KV pages are "
                          "the prefill→decode transfer unit")
+    if server.model_cfg.n_linear_layers:
+        raise NotImplementedError(
+            f"{who}: the prefill→decode hand-off carries KV pages and not "
+            "the recurrent state of linear-attention layers "
+            "(LlamaConfig.full_attn_every); serve such a model on one "
+            "colocated LLMServer")
 
 
 class _ShipJob:

@@ -27,7 +27,25 @@ The prefix index is an explicit radix tree over token blocks:
     HBM edge of the spill ladder: HBM page → shm segment → (object-store
     spill policy) → disk.
 
-It knows page ids and token ids only. What a page holds on the device, and
+Two kinds of cache in the one manager. A model with linear-attention layers
+keeps, beside the pages of its full-attention layers, one recurrent state a
+slot, and the pages say nothing of it: a matched chain of pages is worth
+only as far as a node that holds a SNAPSHOT of that state. With `snapshots`
+> 0 the manager owns a pool of that many snapshot ids. A prompt's prefill
+stops at its last page boundary, `reserve_snapshot` hands the engine an id
+to copy the state into, and `register_prefix` records it on that page's
+node; `allocate_prefix` then cuts a match back to the deepest node with a
+live snapshot, and `resume_snapshot` tells the engine which id to copy into
+the slot. Policy: a snapshot lives while its node's page is resident (it is
+dropped when the page is evicted, and is not carried to the demotion tier;
+since eviction is leaf first, the pages above an evicted snapshot can serve
+no later prompt, so the engine wires no demotion hooks for such a model and
+evicted pages are discarded), and when the pool is full the least recently
+used goes first; a request that resumed from one and
+saves a deeper one on the same path moves the older to the cold end, since
+the next turn of that conversation will match the deeper one.
+
+It knows page and snapshot ids and token ids only. What a page holds on the device, and
 how pages are moved, is `ops/paged_attention.py`.
 """
 
@@ -56,7 +74,8 @@ def _count(name: str, value: float = 1.0):
 class _Node:
     """One full page of tokens in the radix tree."""
 
-    __slots__ = ("tokens", "parent", "children", "page", "handle", "hits")
+    __slots__ = ("tokens", "parent", "children", "page", "handle", "hits",
+                 "snap")
 
     def __init__(self, tokens, parent):
         self.tokens = tokens      # tuple of page_size token ids
@@ -65,6 +84,7 @@ class _Node:
         self.page = None          # pool page id while resident
         self.handle = None        # opaque demoted-KV handle (store segment)
         self.hits = 0
+        self.snap = None          # snapshot id of the state after this page
 
     @property
     def resident_children(self) -> int:
@@ -116,7 +136,8 @@ class PageManager:
     def __init__(self, num_pages: int, page_size: int, batch_slots: int,
                  max_pages_per_seq: int, prefix_cache: bool = True,
                  demote_cb=None, restore_cb=None, drop_cb=None,
-                 phases: PhaseTotals = None, demote_flush_cb=None):
+                 phases: PhaseTotals = None, demote_flush_cb=None,
+                 snapshots: int = 0):
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -144,6 +165,16 @@ class PageManager:
         self.demote_failed = 0         # demotion raised: page discarded
         self.demote_last_error = None  # repr of the last such exception
         self._phases = phases or PhaseTotals("engine", ("evict",))
+        # the state cache of a model with linear-attention layers
+        self.snapshots = snapshots
+        self._snap_free = list(range(snapshots - 1, -1, -1))
+        self._snap_lru = collections.OrderedDict()  # nodes, coldest first
+        self._pending_snap = {}    # slot -> (snapshot id, pages before it)
+        self._resumed = {}         # slot -> node it resumed from (or None)
+        self.snapshots_saved = 0
+        self.snapshots_evicted = 0
+        self.snapshot_hits = 0
+        self.resume_gap_tokens = 0
 
     # ------------------------------------------------------------- tree walk
     def _page_tuples(self, prompt_ids) -> list:
@@ -201,6 +232,7 @@ class PageManager:
         self._refs.pop(pid, None)
         self._node_of.pop(pid, None)
         node.page = None
+        self._drop_snapshot(node)
         self.evicted_pages += 1
         _count("radix_evicted_pages")
         if node.handle is None and self.demote_cb is not None:
@@ -267,6 +299,46 @@ class PageManager:
                 self.demote_flush_cb()
             return len(self.free_pages) >= need
 
+    # ------------------------------------------------------------ snapshots
+    def _drop_snapshot(self, node):
+        if node.snap is not None:
+            self._snap_free.append(node.snap)
+            node.snap = None
+            self._snap_lru.pop(node, None)
+            self.snapshots_evicted += 1
+
+    def _usable(self, prompt_ids) -> tuple:
+        """The chain a request for `prompt_ids` can start from, and how many
+        matched pages it has to give up: the walk, less what would leave no
+        token to prefill (the final chunk's logits come from running one),
+        and for a model with state cut back to the deepest live snapshot."""
+        matched = self._walk(prompt_ids)
+        while matched and len(matched) * self.page_size >= len(prompt_ids):
+            matched.pop()
+        if not self.snapshots:
+            return matched, 0
+        deep = max((i + 1 for i, n in enumerate(matched)
+                    if n.snap is not None), default=0)
+        return matched[:deep], len(matched) - deep
+
+    def resume_snapshot(self, slot: int) -> int:
+        """The snapshot the slot's last `allocate_prefix` resumed from, -1
+        where it starts from nothing."""
+        node = self._resumed.get(slot)
+        return -1 if node is None or node.snap is None else node.snap
+
+    def reserve_snapshot(self, slot: int, n_pages: int):
+        """An id for the state after the slot's first `n_pages` pages (the
+        coldest snapshot gives way when none is free), to be recorded by
+        `register_prefix`; None where the prefix cache keeps none."""
+        if not (self.snapshots and self.prefix_cache_enabled and n_pages):
+            return None
+        if not self._snap_free:
+            self._drop_snapshot(next(iter(self._snap_lru)))
+        sid = self._snap_free.pop()
+        self._pending_snap[slot] = (sid, n_pages)
+        return sid
+
     def _take_page(self):
         if not self.free_pages:
             self._evict_to_free(1)
@@ -286,10 +358,7 @@ class PageManager:
         pages), so it must not stall in admission behind the full page
         bill while the pool is busy serving the very prompts it shares."""
         ps = self.page_size
-        P = len(prompt_ids)
-        matched = self._walk(prompt_ids)
-        while matched and len(matched) * ps >= P:
-            matched.pop()  # mirror allocate_prefix: one token must prefill
+        matched, _ = self._usable(prompt_ids)   # mirror allocate_prefix
         live = [n for n in matched if n.page is not None]
         need_total = -(-n_tokens // ps)
         # demoted matches restore into a fresh page each, so only LIVE
@@ -327,9 +396,8 @@ class PageManager:
         P = len(prompt_ids)
         self.prefix_query_tokens += P
         _count("radix_query_tokens", P)
-        matched = self._walk(prompt_ids)
-        while matched and len(matched) * ps >= P:
-            matched.pop()  # a fully covered prompt still prefills its tail
+        # a fully covered prompt still prefills its tail
+        matched, gap = self._usable(prompt_ids)
         need_total = -(-n_tokens // ps)
         if need_total > self.max_pages_per_seq:
             raise ValueError(
@@ -409,6 +477,18 @@ class PageManager:
             self.restored_pages += len(restored)
             _count("radix_restored_pages", len(restored))
         cached = len(matched) * ps
+        if self.snapshots:
+            # a restore that failed cut the chain short of its snapshot: the
+            # pages stay borrowed (the prefill writes them what they hold),
+            # the request starts from nothing
+            node = matched[-1] if matched else None
+            if node is not None and node.snap is None:
+                node, cached = None, 0
+            self._resumed[slot] = node
+            if node is not None:
+                self._snap_lru.move_to_end(node)
+                self.snapshot_hits += 1
+                self.resume_gap_tokens += gap * ps
         self.prefix_hit_tokens += cached
         _count("radix_hit_tokens", cached)
         return self.table_row(slot), cached
@@ -442,6 +522,31 @@ class PageManager:
             self._refs[pid] = self._refs.get(pid, 0) + 1
             self._demoted.pop(node, None)
         self._set_nodes_gauge()
+        self._record_snapshot(slot, prompt_ids)
+
+    def _record_snapshot(self, slot: int, prompt_ids):
+        """The snapshot the engine saved for this slot goes onto the node of
+        the page it follows (unless another request's is there already, or
+        the page did not stay resident); the one the slot resumed from, on
+        the same path, turns cold."""
+        sid, n_pages = self._pending_snap.pop(slot, (None, 0))
+        if sid is None:
+            return
+        node = self._root
+        for tokens in self._page_tuples(prompt_ids)[:n_pages]:
+            node = node.children.get(tokens)
+            if node is None:
+                break
+        if (node is None or node is self._root or node.page is None
+                or node.snap is not None):
+            self._snap_free.append(sid)
+            return
+        node.snap = sid
+        self._snap_lru[node] = True
+        self.snapshots_saved += 1
+        old = self._resumed.get(slot)
+        if old is not None and old is not node and old in self._snap_lru:
+            self._snap_lru.move_to_end(old, last=False)
 
     # ------------------------------------------------------- a slot's pages
     def extend(self, slot: int, new_len: int):
@@ -469,6 +574,10 @@ class PageManager:
                 self.free_pages.append(pid)
         self.tables[slot] = []
         self._shared_count[slot] = 0
+        self._resumed.pop(slot, None)
+        sid, _ = self._pending_snap.pop(slot, (None, 0))
+        if sid is not None:      # the request ended before it was recorded
+            self._snap_free.append(sid)
 
     def table_row(self, slot: int):
         row = self.tables[slot]
@@ -536,3 +645,11 @@ class PageManager:
                 "evicted_pages": self.evicted_pages,
                 "demoted_pages": self.demoted_pages,
                 "restored_pages": self.restored_pages}
+
+    def state_stats(self) -> dict:
+        """The state cache's own tallies (a model with linear layers)."""
+        return {"snapshots_saved": self.snapshots_saved,
+                "snapshots_evicted": self.snapshots_evicted,
+                "snapshot_hits": self.snapshot_hits,
+                "resume_gap_tokens": self.resume_gap_tokens,
+                "snapshots_live": len(self._snap_lru)}
