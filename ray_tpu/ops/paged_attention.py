@@ -11,16 +11,29 @@ attention is a CUDA kernel walking a per-sequence page table
   scatter into it re-tiles all of it (PR 31).
 - A block table `[B, max_pages]` maps each sequence's logical pages to pool
   slots; `lengths[B]` counts valid tokens.
-- The kernel runs a grid `(B, max_pages)` with the block table, the lengths
-  and the layer as SCALAR-PREFETCH args (pltpu.PrefetchScalarGridSpec): the
-  index_map reads `(layer, table[b, p])` to DMA exactly that page (all kv
-  heads of it) into VMEM while the previous page computes — the pallas
-  pipeline does the job of vLLM's manual gather, and pages never
-  materialize contiguously.
-- Online-softmax accumulation across pages (same recurrence as
-  ops/flash_attention.py); every kv head folds per step via batched dots
-  ([Kh, G, D] × [Kh, page, D]) so the MXU sees one sizable matmul instead
-  of Kh tiny ones (a per-head grid ran ~2× slower at decode shapes).
+- The kernel `paged_decode` takes the block table, the lengths, the layer
+  and its WALK as SCALAR-PREFETCH args (pltpu.PrefetchScalarGridSpec). A
+  grid step folds a BLOCK of several pages of one row (`pages_per_block`:
+  what a VMEM budget holds of the pages' bytes). The pages of a block lie
+  anywhere in the pool, so the pool is named as operand once for every page
+  of a block, each with an index_map that reads `(layer, table[row, entry])`
+  to DMA exactly that page (all kv heads of it) into VMEM while the block
+  before is folded: the pallas pipeline does the job of vLLM's manual
+  gather, and pages never materialize contiguously.
+- The grid is the walk (`_blocks_in_use`): one step for every block a row
+  holds keys in, row after row, and its LENGTH is a value of the call, the
+  sum of those. A table entry past a row's end gets no step. A grid of
+  (row, table entry), a page a step, is 10,240 steps a call at 16 rows of
+  640 entries whatever the rows hold, and a page of 64 tokens too small a
+  step to hide its own cost; a static grid of (row, block) pays every
+  operand's share of a step's cost for the blocks no row uses (v5e, PR 36:
+  3.78 and 2.04 ms a call at that shape with rows of 6k-34k keys, against
+  1.54).
+- Online-softmax accumulation across blocks (same recurrence as
+  ops/flash_attention.py) in f32; every kv head folds per block via batched
+  dots ([Kh, G, D] x [Kh, block, D]) so the MXU sees one sizable matmul
+  instead of Kh tiny ones (a per-head grid ran ~2x slower at decode
+  shapes).
 
 Decode is HBM-bandwidth-bound: the win is that only referenced pages move,
 so fragmented long-context batches stream at full bandwidth regardless of
@@ -39,77 +52,106 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+# VMEM that `paged_decode` gives the pages of a block, K's and V's, each held
+# twice (the block being folded and the one in flight): 8 pages a block at
+# the 131 KB a page of 8 kv heads x 64 tokens x 128 in bf16. On the v5e
+# (PR 36) 16 rows of 6k-34k keys took 2.10 / 1.63 / 1.54 / 1.55 ms a call at
+# 2 / 4 / 8 / 16 pages a block (the HBM floor is 1.40), and a row of a few
+# pages costs what a whole block's arithmetic costs: 32 rows of one to six
+# pages 0.044 / 0.053 / 0.077 / 0.133 ms. Every page of a block is an operand
+# of the kernel, K's and V's, so a block has at most `_MAX_PAGES_PER_BLOCK`
+# however small a page is.
+_KV_BLOCK_BYTES = 4 * 2 ** 20
+_MAX_PAGES_PER_BLOCK = 16
 
 
-def _decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page_size, max_pages,
-                   gsize, n_kv):
-    """One (b, p) step: fold page p of sequence b into the accumulator for
-    ALL kv heads at once (batched dots keep the MXU busy; a per-head grid
-    left it mostly idle at decode shapes).
+def pages_per_block(page_bytes: int, max_pages: int) -> int:
+    """Pages of one row that a step of `paged_decode` folds: as many as the
+    VMEM budget holds twice for K and twice for V, at least one, at most the
+    table's width and `_MAX_PAGES_PER_BLOCK`. `page_bytes`: every kv head's
+    share of one page."""
+    return max(1, min(max_pages, _MAX_PAGES_PER_BLOCK,
+                      _KV_BLOCK_BYTES // (4 * page_bytes)))
 
-    q_ref: [1, Kh, G, D]; k_ref/v_ref: [Kh, 1, page, D] — every kv head's
-    copy of the one table-selected page of the layer (the pool's layer
-    dimension is squeezed by the BlockSpec; `layer_ref` is read by the
-    index_map only); o_ref: [1, Kh, G, D]. Scratch rows
-    are max(Kh*G, 8) — row-wise math pads up to the fp32 sublane tile and
-    the finish slices back down.
+
+def _held(length, room: int):
+    """The keys a row is read as holding: a free slot (length 0) reads as
+    one, so that every row has a first block and its softmax a finite
+    maximum; no more than the table has room for (the reference's mask ends
+    there too)."""
+    return jnp.clip(length, 1, room)
+
+
+def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
+                   *refs, scale, page_size, ppb):
+    """Step s of the grid: fold block blk[s] of row row[s], `ppb` pages, into
+    the row's accumulators, for all kv heads at once. The steps are the
+    blocks the rows hold keys in, a row's in order (`_blocks_in_use`).
+
+    q_ref, o_ref: [1, Kh, G, D], the row's. refs: the block's `ppb` pages of
+    K, then of V, each [Kh, 1, page, D] (the pipeline has copied each into
+    its operand: `paged_attention`'s `page_of`), o_ref, and the scratch: the
+    running maximum and sum [Kh, G, 128] and the weighted values [Kh, G, D],
+    f32.
+
+    A page the row has no key on is whatever its operand held last, a real
+    page of the layer; its columns are masked, and 0 x a finite value adds
+    nothing.
     """
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    seq_len = len_ref[b]
-    h = n_kv * gsize
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
+    s_ = pl.program_id(0)
+    i = blk_ref[s_]
+    block = ppb * page_size
+    seq_len = _held(len_ref[row_ref[s_]], tbl_ref.shape[1] * page_size)
 
-    @pl.when(p == 0)
+    @pl.when(i == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # pages past the sequence's last token carry no data; their table entry
-    # is a placeholder (0), so skip both compute and accumulator updates
-    @pl.when(p * page_size < seq_len)
-    def _fold():
-        q = q_ref[0].astype(jnp.float32)                   # [Kh, G, D]
-        k = k_ref[:, 0].astype(jnp.float32)                # [Kh, page, D]
-        v = v_ref[:, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(                           # [Kh, G, page]
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        cols = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        s = jnp.where(cols < seq_len, s, -jnp.inf)
+    q = q_ref[0].astype(jnp.float32)                           # [Kh, G, D]
+    k, v = (jnp.concatenate([r[:, 0] for r in pages], axis=1).astype(
+        jnp.float32) for pages in (k_refs, v_refs))            # [Kh, block, D]
+    s = jax.lax.dot_general(                                   # [Kh, G, block]
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale
+    cols = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(cols < seq_len, s, -jnp.inf)
+    m_prev, l_prev = m_scr[:, :, :1], l_scr[:, :, :1]
+    # every block walked holds a key, so m_new is finite and exp() NaN-free
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+    pv = jax.lax.dot_general(                                  # [Kh, G, D]
+        p, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    acc = acc_scr[...] * alpha + pv
+    acc_scr[...] = acc
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-        s2 = s.reshape(h, page_size)                       # [H, page]
-        hp = m_scr.shape[0]
-        if hp != h:  # pad tiny head counts up to the sublane tile
-            s2 = jnp.concatenate(
-                [s2, jnp.zeros((hp - h, page_size), s2.dtype)])
-        m_prev = m_scr[:, :1]                              # [Hp, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s2, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # p==0 always holds >=1 valid token (lengths >= 1 in decode), so
-        # m_new > -inf from the first fold on and exp() stays NaN-free
-        pmat = jnp.exp(s2 - m_new)                         # [Hp, page]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(pmat, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(                          # [Kh, G, D]
-            pmat[:h].reshape(n_kv, gsize, page_size), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        pv2 = pv.reshape(h, pv.shape[-1])
-        if hp != h:
-            pv2 = jnp.concatenate(
-                [pv2, jnp.zeros((hp - h, pv2.shape[-1]), pv2.dtype)])
-        acc_scr[:] = acc_scr[:] * alpha + pv2
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(p == max_pages - 1)
+    @pl.when((i + 1) * block >= seq_len)
     def _finish():
-        o_ref[0] = (acc_scr[:h] / l_scr[:h, :1]).reshape(
-            n_kv, gsize, acc_scr.shape[-1]).astype(o_ref.dtype)
+        o_ref[0] = (acc / l_new).astype(o_ref.dtype)
+
+
+def _blocks_in_use(lengths, room: int, block: int, n_blocks: int):
+    """The walk of `paged_decode`: (how many steps [1], the row [S] and the
+    block within the row [S] of every step), S = B * n_blocks, the steps
+    past the count never run. Row after row, of each the blocks of `block`
+    keys it holds keys in. Small arrays and no gather: a row's first step is
+    found by comparing and summing."""
+    rows = lengths.shape[0]
+    ends = jnp.cumsum(-(-_held(lengths, room) // block))               # [B]
+    steps = jnp.arange(rows * n_blocks, dtype=jnp.int32)
+    row = jnp.minimum(jnp.sum(steps[:, None] >= ends[None], axis=1), rows - 1)
+    first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    first = jnp.sum(jnp.where(row[:, None] == jnp.arange(rows)[None],
+                              first[None], 0), axis=1)
+    return ends[-1:].astype(jnp.int32), row.astype(jnp.int32), steps - first
 
 
 def paged_attention(
@@ -126,60 +168,68 @@ def paged_attention(
     """Paged decode attention over layer `layer` of the pool; returns
     [B, H, D].
 
-    The pools go in whole, as the cache holds them, and the layer rides in
-    the pages' index_map as a third prefetched scalar: a `pallas_call`
-    operand is a buffer of its own, so `k_pages[layer]` handed in would be
-    materialised, a layer's whole pool a call. Unused table entries must be
-    valid pool indices (0 is fine) — they are DMA'd but masked out.
-    Sequences attend to their first `lengths` tokens.
+    The pools go in whole, as the cache holds them: a `pallas_call` operand
+    is a buffer of its own, so `k_pages[layer]` handed in would be
+    materialised, a layer's whole pool a call. The layer, the block table,
+    the lengths and the walk (`_blocks_in_use`) ride as prefetched scalars;
+    the grid is as long as the walk, which `lengths` decides: a call's time
+    follows the keys the rows hold and not the table's width. A table entry
+    past a row's end is never read, so it may hold anything. Sequences
+    attend to their first `lengths` tokens.
     """
     b, h, d = q.shape
     _layers, kh, _pool, page_size, _d = k_pages.shape
     g = h // kh
     max_pages = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    # the bytes a page takes in VMEM: rows are whole lanes there
+    lanes = -(-d // _LANES) * _LANES
+    ppb = pages_per_block(kh * page_size * lanes * k_pages.dtype.itemsize,
+                          max_pages)
+    n_blocks = -(-max_pages // ppb)
+    count, row, blk = _blocks_in_use(lengths, max_pages * page_size,
+                                     ppb * page_size, n_blocks)
 
-    grid = (b, max_pages)
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, page_size=page_size,
-        max_pages=max_pages, gsize=g, n_kv=kh)
-    q3 = q.reshape(b, kh, g, d)
+    def row_of(s, row, blk, tbl, lens, lyr):
+        return (row[s], 0, 0, 0)
 
-    def page_of(b_, p_, tbl, lens, lyr):
-        # Pages past the sequence's end map to its LAST valid page instead
-        # of placeholder page 0: pallas skips the copy when the block index
-        # repeats between consecutive steps, so short sequences in a long
-        # table stop paying DMA bandwidth for pages they never read
-        # (VERDICT r3 weak #3).
-        last = jnp.maximum(lens[b_] - 1, 0) // page_size
-        return (lyr[0], 0, tbl[b_, jnp.minimum(p_, last)], 0, 0)
+    def page_of(j, s, row, blk, tbl, lens, lyr):
+        # operand j holds entry blk * ppb + j of the row's table. Past the
+        # row's end it names what it named a step before, the same place of
+        # the row's block before: the pipeline copies a block only when its
+        # index changes from one step to the next, so such an entry costs no
+        # copy. (In a row's first block there is no step before in the row:
+        # page 0, the placeholder, one copy for all the short rows in a run.)
+        r = row[s]
+        last = (_held(lens[r], max_pages * page_size) - 1) // page_size
+        entry = blk[s] * ppb + j
+        entry = jnp.where(entry <= last, entry, entry - ppb)
+        page = jnp.where(entry >= 0, tbl[r, jnp.maximum(entry, 0)], 0)
+        return (lyr[0], 0, page, 0, 0)
 
+    rows = pl.BlockSpec((1, kh, g, d), row_of)
+    # every kv head's copy of one page of the layer, the layer dimension
+    # squeezed; the pool is named once for every page of a block
+    pages = [pl.BlockSpec((None, kh, 1, page_size, d),
+                          functools.partial(page_of, j)) for j in range(ppb)]
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, scale=scale, page_size=page_size,
+                          ppb=ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, kh, g, d),
-                             lambda b_, p_, tbl, lens, lyr: (b_, 0, 0, 0)),
-                # every kv head's copy of the table-selected page of the
-                # layer in one block; the layer dimension is squeezed
-                pl.BlockSpec((None, kh, 1, page_size, d), page_of),
-                pl.BlockSpec((None, kh, 1, page_size, d), page_of),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, kh, g, d), lambda b_, p_, tbl, lens, lyr: (b_, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((max(h, 8), _LANES), jnp.float32),
-                pltpu.VMEM((max(h, 8), _LANES), jnp.float32),
-                pltpu.VMEM((max(h, 8), d), jnp.float32),
-            ],
+            num_scalar_prefetch=5,
+            grid=(count[0],),
+            in_specs=[rows] + pages + pages,
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((kh, g, _LANES), jnp.float32),
+                            pltpu.VMEM((kh, g, _LANES), jnp.float32),
+                            pltpu.VMEM((kh, g, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables, lengths, jnp.asarray(layer, jnp.int32).reshape(1),
-      q3, k_pages, v_pages)
+    )(row, blk, block_tables, lengths,
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(b, kh, g, d),
+      *([k_pages] * ppb), *([v_pages] * ppb))
     return out.reshape(b, h, d)
 
 
@@ -623,7 +673,7 @@ def row_keys_values(cache: PagedKVCache, layer_idx: int,
     absolute position s, and the padded table's placeholder pages sit past
     every valid position.
 
-    On the TPU a kernel on `paged_decode`'s plan copies the pages out: grid
+    On the TPU the kernel `paged_row_pages` copies the pages out: grid
     (B, mp), the table and the layer prefetched, the page's block chosen by
     (layer, table[b, p]). A `pallas_call` holds its operand to the layout
     the pool lies in. In XLA's own hands the pool did not stay there: with

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import decode_attention
-from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
+from ray_tpu.ops.paged_attention import (PagedKVCache, pages_per_block,
+                                         paged_attention,
                                          paged_attention_reference,
                                          write_tokens)
 from ray_tpu.serve.radix_cache import PageManager
@@ -42,6 +43,118 @@ def test_kernel_matches_reference_fragmented(g):
     out_r = paged_attention_reference(q, kp, vp, 0, tbl, lens)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
+
+
+# What the kernel's plan can get wrong: a grid step folds a block of
+# `pages_per_block` pages of one row, the grid walks the blocks the rows hold
+# keys in, and an operand past a row's end names what it named a step before.
+# Pages of 64 tokens of 128 at 131,072 B for all kv heads, the cells' page,
+# make a block 8 pages = 512 tokens. Lengths a row; `shared`: rows 0 and 1
+# have their first pages in common; `layers` / `layer`: a stacked pool whose
+# other layers are NaN (`traced`: the layer a traced value under `jit`);
+# `pool` / `query`: the types (8 kv heads in bf16, 4 in f32); `d`: the heads'
+# size (at 64 a page takes the room of 128 in VMEM: 4 pages a block).
+_BLOCK = 512
+_PLAN_CASES = {
+    "width_5_narrower_than_a_block": dict(width=5, lengths=[1, 256, 320, 257]),
+    "width_20_no_multiple_of_the_block": dict(width=20, lengths=[1280, 1, 1025,
+                                                                 513]),
+    "width_36_mixtrals_four_and_a_half_blocks": dict(
+        width=36, lengths=[2304, 1, 2049, 1024]),
+    "width_64_a_multiple_of_the_block": dict(width=64, lengths=[4096, 1, 3000,
+                                                                513]),
+    "rows_of_one_token": dict(width=8, lengths=[1, 1, 1]),
+    "rows_that_end_on_a_blocks_edge": dict(width=24, lengths=[512, 1024,
+                                                              1536]),
+    "rows_that_fill_their_table": dict(width=16, lengths=[1024, 1024]),
+    "rows_one_token_into_a_new_block": dict(width=24, lengths=[513, 1025, 1]),
+    "inactive_rows_between_long_ones": dict(width=24, lengths=[1400, 1, 1536,
+                                                               1, 600]),
+    "short_rows_between_long_ones": dict(width=24, lengths=[100, 1400, 130,
+                                                            65, 1536, 300]),
+    "two_rows_share_their_first_pages": dict(width=24, lengths=[1200, 1300,
+                                                                40],
+                                             shared=12),
+    "g_1": dict(width=18, lengths=[1, 600, 1152], g=1),
+    "g_4": dict(width=18, lengths=[1, 600, 1152], g=4),
+    "g_8": dict(width=18, lengths=[1, 600, 1152], g=8),
+    "layer_0_of_a_stack_the_others_nan": dict(width=12, lengths=[70, 768, 513],
+                                              layers=3, layer=0),
+    "layer_2_of_a_stack_the_others_nan": dict(width=12, lengths=[70, 768, 513],
+                                              layers=3, layer=2),
+    "layer_1_traced_under_jit": dict(width=12, lengths=[70, 768, 513],
+                                     layers=3, layer=1, traced=True),
+    "bf16_pools_f32_query": dict(width=18, lengths=[1, 600, 1152, 1025], g=4,
+                                 pool=jnp.bfloat16),
+    "bf16_pools_bf16_query": dict(width=18, lengths=[1, 600, 1152, 1025], g=8,
+                                  pool=jnp.bfloat16, query=jnp.bfloat16),
+    "heads_of_64": dict(width=10, lengths=[1, 256, 257, 640, 100], d=64),
+    "heads_of_64_bf16_layer_traced": dict(
+        width=10, lengths=[1, 256, 257, 640, 100], d=64, g=4, layers=2,
+        layer=1, traced=True, pool=jnp.bfloat16, query=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", list(_PLAN_CASES))
+def test_kernel_walks_blocks_as_the_reference_reads_pages(name):
+    case = dict(g=2, layers=1, layer=0, shared=0, pool=jnp.float32,
+                query=jnp.float32, d=128, traced=False)
+    case.update(_PLAN_CASES[name])
+    width, lengths, g = case["width"], np.array(case["lengths"]), case["g"]
+    page, d = 64, case["d"]
+    kh = 131072 // (page * d * jnp.dtype(case["pool"]).itemsize)
+    in_vmem = 131072 * 128 // d
+    assert pages_per_block(in_vmem, 64) * page == _BLOCK * d // 128
+    if name.startswith("width"):
+        block = pages_per_block(in_vmem, width) * page
+        assert (width * page % block != 0) == ("no_multiple" in name
+                                               or "and_a_half" in name)
+
+    rng = np.random.default_rng(len(name))
+    need = -(-lengths // page)
+    n_pool = int(need.sum()) + 1
+    perm = rng.permutation(np.arange(1, n_pool))
+    tables = np.zeros((len(lengths), width), np.int32)
+    used = 0
+    for i, n in enumerate(need):
+        tables[i, :n] = perm[used:used + n]
+        used += n
+    tables[1, :case["shared"]] = tables[0, :case["shared"]]
+    shape = (case["layers"], kh, n_pool, page, d)
+    k, v = (rng.normal(size=shape).astype(np.float32) for _ in range(2))
+    for pool in (k, v):
+        pool[np.arange(case["layers"]) != case["layer"]] = np.nan
+    q = rng.normal(size=(len(lengths), kh * g, d)).astype(np.float32)
+    args = (jnp.array(q, case["query"]), jnp.array(k, case["pool"]),
+            jnp.array(v, case["pool"]), case["layer"], jnp.array(tables),
+            jnp.array(lengths, jnp.int32))
+
+    if case["traced"]:     # one program for every layer
+        got = jax.jit(lambda *a: paged_attention(*a, interpret=True))(
+            *args[:3], jnp.int32(case["layer"]), *args[4:])
+    else:
+        got = paged_attention(*args, interpret=True)
+    want = paged_attention_reference(*args)
+    assert got.dtype == want.dtype == case["query"]
+    # an f32 result is held to the f32 tests' tolerance whatever the pools'
+    # type (the arithmetic is f32); a bf16 result to its rounding
+    tol = 2e-5 if case["query"] == jnp.float32 else 8e-3
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol,
+                               rtol=tol)
+
+
+def test_walk_has_a_step_for_every_block_in_use_and_no_other():
+    from ray_tpu.ops.paged_attention import _blocks_in_use
+
+    # 4 rows of a table of 20 pages of 64: blocks of 512 keys, 3 a row at most
+    lengths = jnp.array([1025, 0, 512, 5000], jnp.int32)
+    count, row, blk = _blocks_in_use(lengths, 20 * 64, 512, 3)
+    n = int(count[0])
+    assert n == 3 + 1 + 1 + 3     # a free row reads as one key; 5000 > room
+    assert row.shape == blk.shape == (12,)
+    assert list(zip(np.asarray(row)[:n], np.asarray(blk)[:n])) == [
+        (0, 0), (0, 1), (0, 2), (1, 0), (2, 0), (3, 0), (3, 1), (3, 2)]
 
 
 def test_reference_matches_dense_decode():

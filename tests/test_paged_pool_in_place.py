@@ -340,21 +340,29 @@ def v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("name,rows,tokens,chunk_local", [
-    ("decode", 4, 1, False), ("first_chunk", 1, 128, True),
-    ("continuation", 1, 128, False)])
-def test_compiled_for_the_v5e_no_program_moves_a_pool(v5e, name, rows,
-                                                      tokens, chunk_local):
+def _pool_sized_results(hlo: str, pool: str) -> set:
+    """(opcode, name) of every instruction of the compiled program whose
+    result has the type `pool` (a regular expression: a layer's pool or the
+    whole of it) and that neither passes it on nor writes into it in place."""
     import re
 
-    from ray_tpu.models.llama import Llama, LlamaConfig
+    passive = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    moved = set()
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if m and re.search(pool, m.group(2)) and m.group(3) not in passive:
+            if "dynamic-update-slice" not in m.group(3) + m.group(1):
+                moved.add((m.group(3), m.group(1)))
+    return moved
 
-    # a small model over a pool as deep as the cells' (1152 pages of 64 x 128):
-    # a pool of a few MB is moved between memory spaces whole, which is no fault
-    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=2, n_heads=4,
-                      n_kv_heads=2, head_dim=128, ffn_dim=512,
-                      max_seq_len=512, dtype=jnp.bfloat16,
-                      param_dtype=jnp.bfloat16)
+
+def _step_compiled_for(v5e, cfg, pages, rows, width, tokens=1,
+                       chunk_local=False) -> str:
+    """The text of the program the v5e's compiler makes of one step of a
+    `Llama(cfg)` over a donated paged cache of `pages` pages of 64 (`rows`
+    rows, `width` table entries a row): `tokens` new tokens a row."""
+    from ray_tpu.models.llama import Llama
+
     model = Llama(cfg)
 
     def on_chip(tree):
@@ -365,7 +373,7 @@ def test_compiled_for_the_v5e_no_program_moves_a_pool(v5e, name, rows,
         lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
         jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(lambda: PagedKVCache.init(
-        2, 2, 128, 1152, 64, rows, 8)))
+        cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, pages, 64, rows, width)))
 
     def step(params, cache, toks):
         logits, cache = model.apply(params, toks, cache=cache,
@@ -373,19 +381,59 @@ def test_compiled_for_the_v5e_no_program_moves_a_pool(v5e, name, rows,
         return cache, logits[:, -1]
 
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        hlo = jax.jit(step, donate_argnums=(1,)).lower(
+        return jax.jit(step, donate_argnums=(1,)).lower(
             params, cache, jax.ShapeDtypeStruct((rows, tokens), jnp.int32,
                                                 sharding=v5e)
         ).compile().as_text()
-    pool = re.compile(r"bf16\[(2,)?2,1152,64,128\]")
-    passive = ("parameter", "get-tuple-element", "tuple", "bitcast")
-    moved = set()
-    for line in hlo.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
-        if m and pool.search(m.group(2)) and m.group(3) not in passive:
-            if "dynamic-update-slice" not in m.group(3) and (
-                    "dynamic-update-slice" not in m.group(1)):
-                moved.add((m.group(3), m.group(1)))
+
+
+@pytest.mark.parametrize("name,rows,tokens,chunk_local", [
+    ("decode", 4, 1, False), ("first_chunk", 1, 128, True),
+    ("continuation", 1, 128, False)])
+def test_compiled_for_the_v5e_no_program_moves_a_pool(v5e, name, rows,
+                                                      tokens, chunk_local):
+    from ray_tpu.models.llama import LlamaConfig
+
+    # a small model over a pool as deep as the cells' (1152 pages of 64 x 128):
+    # a pool of a few MB is moved between memory spaces whole, which is no fault
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=2, n_heads=4,
+                      n_kv_heads=2, head_dim=128, ffn_dim=512,
+                      max_seq_len=512, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    hlo = _step_compiled_for(v5e, cfg, 1152, rows, 8, tokens, chunk_local)
+    moved = _pool_sized_results(hlo, r"bf16\[(2,)?2,1152,64,128\]")
     assert not moved, f"{name}: pool-sized results of {sorted(moved)}"
     assert "paged_decode" in hlo or tokens > 1
     assert ("paged_row_pages" in hlo) == (name == "continuation")
+
+
+@pytest.mark.parametrize("cell,rows,width,heads,head_dim", [
+    ("mixtral8x7b-batch", 32, 36, 32, 128),
+    ("solar250b-agentloop-batch", 16, 640, 64, 128),
+    ("mistral7b-chat", 32, 128, 32, 128),
+    ("llama_1b-heads-of-64", 8, 16, 32, 64)])
+def test_decode_compiled_for_the_v5e_at_the_cells_shapes(
+        v5e, cell, rows, width, heads, head_dim):
+    """The decode step at a cell's rows, table width and heads (8 kv heads,
+    pages of 64 in bf16): the v5e's compiler takes `paged_decode` with a
+    pool operand for every page of a block and a grid as long as the walk
+    (the interpreter checks neither a block's tiling nor the VMEM the
+    operands take), it is in the program once a layer under the name the
+    benchmark's readers look for, and nothing pool-sized is produced but the
+    writes. (A pool of heads of 64 XLA lays out pages-minor at the program's
+    edge and copies whole, in and out: not held.)"""
+    from ray_tpu.models.llama import LlamaConfig
+
+    layers, pages = 2, 1152
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=layers,
+                      n_heads=heads, n_kv_heads=8, head_dim=head_dim,
+                      ffn_dim=512, max_seq_len=width * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    hlo = _step_compiled_for(v5e, cfg, pages, rows, width)
+    calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert calls == ["paged_decode"] * layers, f"{cell}: custom calls {calls}"
+    moved = _pool_sized_results(
+        hlo, rf"bf16\[({layers},)?8,{pages},64,{head_dim}\]")
+    assert not moved or head_dim % 128, (
+        f"{cell}: pool-sized results of {sorted(moved)}")
