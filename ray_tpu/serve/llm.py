@@ -36,13 +36,17 @@ import asyncio
 import collections
 import concurrent.futures
 import dataclasses
+import json
+import logging
 import math
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
-from ray_tpu.util.tracing import PhaseTotals, phase
+from ray_tpu.util.tracing import PhaseTotals, StallWatch, phase
+
+logger = logging.getLogger(__name__)
 
 # Every stretch of host time on the engine's event-loop thread belongs to one
 # of these phases (util.tracing.phase: a profiler annotation `engine.<key>`
@@ -56,6 +60,10 @@ LOOP_PHASES = ("decode_build", "decode_dispatch", "decode_sync",
                "yield")
 NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
                  "restore")
+# The two phases that hold a `device_get`: an entry that outlasts its work is
+# counted as a stall, and a watchdog samples the process while it lasts
+# (util.tracing.StallWatch). Not `yield`: it holds the profiler's stop.
+WATCHED_PHASES = ("decode_sync", "prefill_first_token")
 # A model with linear-attention layers only: the copy of a slot's recurrent
 # state into a snapshot where its prefill crosses the prompt's last page
 # boundary (inside prefill_dispatch), and out of one at admission (inside
@@ -265,7 +273,9 @@ class LLMServer:
         self._stateful = self.model_cfg.n_linear_layers > 0
         self._phases = PhaseTotals(
             "engine", LOOP_PHASES + NESTED_PHASES
-            + (STATE_PHASES if self._stateful else ()))
+            + (STATE_PHASES if self._stateful else ()), watch=WATCHED_PHASES)
+        # the last records the loop's watchdog filed (stats()["stalls"])
+        self._stalls: "collections.deque[dict]" = collections.deque(maxlen=8)
         B = cfg.max_batch_slots
         key = jax.random.PRNGKey(cfg.seed)
         if cfg.tp > 1:
@@ -406,6 +416,7 @@ class LLMServer:
         # `seq` the dispatch count at the join (later chunks hold the slot)
         self._slots = self._idle_slots()
         self._inflight: "collections.deque[_Chunk]" = collections.deque()
+        self._reading: Optional[_Chunk] = None    # the chunk `_read_chunk` is in
         self._first_pending = collections.deque()
         self._n_dispatched = 0
         self._t_read = 0.0
@@ -1259,7 +1270,27 @@ class LLMServer:
             self._tick_task = asyncio.get_running_loop().create_task(
                 self._tick_loop())
 
+    def _stall_facts(self) -> Dict[str, Any]:
+        """The engine as the watchdog's thread finds it while a read is
+        stalled: plain reads of the loop's own state, no call into jax."""
+        reading = self._reading
+        return {"inflight": len(self._inflight),
+                "reading_seq": reading.seq if reading else None,
+                "reading_steps": reading.n if reading else None,
+                "first_pending": len(self._first_pending),
+                "active": len(self._active),
+                "queued_prompts": len(self._prefill_q),
+                "staged_bytes": self._staged_bytes,
+                "ticks": self._decode_stats["ticks"]}
+
+    def _file_stall(self, record: Dict[str, Any]) -> None:
+        """On the watchdog's thread: keep the record and log it, once."""
+        self._stalls.append(record)
+        logger.warning("%s", json.dumps(record))
+
     async def _tick_loop(self):
+        watch = StallWatch(self._phases, self._stall_facts,
+                           self._file_stall).start()
         try:
             await self._tick_loop_inner()
         except BaseException as e:  # noqa: BLE001 - fail every waiter loudly
@@ -1284,6 +1315,8 @@ class LLMServer:
             self._first_pending.clear()
             self._slots = self._idle_slots()
             raise
+        finally:
+            watch.stop()
 
     # -- tiered KV: radix demote/restore hooks (ISSUE 19) --------------------
     def _demote_page(self, pid: int, node) -> Dict[str, Any]:
@@ -1617,11 +1650,12 @@ class LLMServer:
         as host arrays."""
         import jax
 
-        chunk = self._inflight.popleft()
+        chunk = self._reading = self._inflight.popleft()
         t0 = time.perf_counter()
         out = [np.asarray(x) for x in jax.device_get(chunk.out)]
         if not self._inflight:
             self._decode_stats["read_wait_s"] += time.perf_counter() - t0
+        self._reading = None
         return chunk, out
 
     def _emit_chunk(self, chunk: _Chunk, toks, n_valid, logp, *touched):
@@ -1862,6 +1896,10 @@ class LLMServer:
             "loop_s": st["loop_s"], "ticks": st["ticks"],
             "phase_s": dict(self._phases.seconds),
             "phase_n": dict(self._phases.counts),
+            # watched reads that outlasted their work (WATCHED_PHASES)
+            "stall_s": dict(self._phases.stall_seconds),
+            "stall_n": dict(self._phases.stall_counts),
+            "stall_max_s": self._phases.stall_max_s,
             **{k: st[k] for k in (
                 "decode_steps", "active_slot_syncs", "prefill_chunks",
                 "prefill_tokens", "prefill_padded_tokens", "admitted",
@@ -1881,6 +1919,7 @@ class LLMServer:
             "stash_worker_s": (self._kv_stash.phases.seconds["put"]
                                if self._kv_stash is not None else 0.0),
         }
+        s["stalls"] = list(self._stalls)
         if self.model_cfg.index_topk:
             # decode rows only: a prefill chunk's selection is not counted
             s["sparse"] = dict(

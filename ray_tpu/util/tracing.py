@@ -49,11 +49,13 @@ from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
-    "enabled", "sample_rate", "refresh", "new_trace_id", "new_span_id",
+    "enabled", "refresh", "new_trace_id", "new_span_id",
     "trace_id_for", "stamp", "record_span", "span", "set_current",
     "get_current", "current_trace_id", "events", "drain", "clear",
-    "to_chrome", "summary", "set_process_label", "record_window",
+    "to_chrome", "summary", "record_window",
     "ship_window", "take_shipped", "bubble_stats", "PhaseTotals", "phase",
+    "StallWatch", "STALL_FLOOR_S", "STALL_FACTOR", "STALL_RECENT",
+    "STALL_POLL_S",
 ]
 
 _lock = threading.Lock()
@@ -65,7 +67,6 @@ _dropped: int = 0
 # next(_count) is a single C-level op under the GIL — no lock on the id path
 _count = itertools.count(1)
 _id_prefix: str = ""
-_process_label: str = ""
 
 # per-thread current span context: (trace_id, span_id) — set by the worker
 # around task execution so nested submits and log records inherit it.
@@ -130,16 +131,6 @@ refresh()
 
 def enabled() -> bool:
     return _enabled
-
-
-def sample_rate() -> float:
-    return _sample
-
-
-def set_process_label(label: str) -> None:
-    """Human name for this process in Chrome traces ("driver", "node:x")."""
-    global _process_label
-    _process_label = label
 
 
 def stamp(spec) -> Optional[str]:
@@ -293,18 +284,73 @@ def span(name: str, cat: str = "app", trace_id: Optional[str] = None,
                     time.monotonic() - m0, tid=tid, args=args)
 
 
+# A watched phase is a STALL when it lasted at least STALL_FLOOR_S and more
+# than STALL_FACTOR times the mean of its key's last STALL_RECENT stretches:
+# what the key has taken LATELY, not since the process began. Set-up reads
+# long and honestly (a compile; a first token behind a whole prompt of 8k-32k
+# tokens dispatched ahead of it: 0.3-3.9 s, the device busy), and a mean that
+# kept those stood at 1.5-3.6 s when the window opened (PERF.md 7 y). Inside
+# a window the host runs a chunk or two ahead of its reads: the longest
+# honest one in any benchmarked cell took 0.21 s, and the fewest stretches
+# between set-up's last long read and the window's opening were 66, so 32
+# have forgotten it twice over. The stalls this is for are 1.5-4 s; one of d
+# seconds raises its key's threshold by d / 4 for the next 32 stretches.
+# Constants: nobody has two values to give them.
+STALL_FLOOR_S = 0.25
+STALL_FACTOR = 8
+STALL_RECENT = 32       # stretches of a key that its mean is taken over
+STALL_POLL_S = 0.1      # the watchdog's wake-up
+_STALL_ROWS = 16        # thread rows a record holds at most
+_STALL_FRAMES = 4       # innermost frames a Python thread is shown by
+_CLK_TCK = os.sysconf("SC_CLK_TCK")     # /proc counts CPU time in these
+
+
 class PhaseTotals:
     """The accumulator of `phase`, owned by the caller (an engine's
     `stats()`): seconds and entries by key, every key present from
     construction at 0 so that a reader can take the delta of two snapshots.
-    A phase `key` is annotated as `<prefix>.<key>`."""
+    A phase `key` is annotated as `<prefix>.<key>`.
 
-    __slots__ = ("seconds", "counts", "names")
+    For the keys in `watch` (phases that hold a blocking read; they do not
+    nest in one another) it also counts stalls: `stall_seconds[key]`,
+    `stall_counts[key]`, `stall_max_s`; `recent[key]` holds the seconds of
+    the key's last STALL_RECENT stretches, stalls among them (after a change
+    of what is honest the mean follows within a few entries, where a mean
+    that left stalls out would call every later entry one). `open` is
+    `(key, t0, serial)` of the watched phase that is open now (`t0` on
+    `time.perf_counter()`), else None: one attribute, so another thread reads
+    it with no lock. `stalled` holds `(key, t0, dt, limit)` of the stalled
+    exits nobody has filed yet (a `StallWatch` does; with none running the
+    oldest fall out)."""
 
-    def __init__(self, prefix: str, keys):
+    __slots__ = ("seconds", "counts", "names", "watch", "stall_seconds",
+                 "stall_counts", "stall_max_s", "recent", "open", "stalled",
+                 "_serial")
+
+    def __init__(self, prefix: str, keys, watch=()):
         self.seconds: Dict[str, float] = dict.fromkeys(keys, 0.0)
         self.counts: Dict[str, int] = dict.fromkeys(keys, 0)
         self.names: Dict[str, str] = {k: f"{prefix}.{k}" for k in keys}
+        self.watch = frozenset(watch)
+        if not self.watch <= set(self.seconds):
+            raise ValueError(f"watched keys {sorted(self.watch)} are not all "
+                             f"among the keys {sorted(self.seconds)}")
+        self.stall_seconds: Dict[str, float] = dict.fromkeys(watch, 0.0)
+        self.stall_counts: Dict[str, int] = dict.fromkeys(watch, 0)
+        self.stall_max_s = 0.0
+        self.recent: Dict[str, deque] = {
+            k: deque(maxlen=STALL_RECENT) for k in watch}
+        self.open: Optional[Tuple[str, float, int]] = None
+        self.stalled: deque = deque(maxlen=8)
+        self._serial = itertools.count(1)
+
+    def stall_threshold(self, key: str) -> Optional[float]:
+        """Seconds past which an entry of `key` is a stall; None while the
+        key has no entry to take a mean from (its first one compiles)."""
+        recent = self.recent[key]
+        if not recent:
+            return None
+        return max(STALL_FLOOR_S, STALL_FACTOR * sum(recent) / len(recent))
 
 
 # jax.profiler.TraceAnnotation, resolved once and only in a process that has
@@ -333,7 +379,11 @@ class phase:
     nest; all of one `totals` run on one thread, and an `await` inside one
     means the other tasks' phases are its children. `entries=0` is a further
     stretch of an entry counted where it began (a phase whose work is
-    dispatched at one place of a loop and read at another)."""
+    dispatched at one place of a loop and read at another).
+
+    A watched key (`PhaseTotals(watch=...)`) also shows in `totals.open`
+    while it is open, and an exit that was a stall is counted and left on
+    `totals.stalled`: this thread files nothing."""
 
     __slots__ = ("_totals", "_key", "_entries", "_ann", "_t0")
 
@@ -349,17 +399,249 @@ class phase:
         else:
             self._ann = cls(self._totals.names[self._key])
             self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        totals = self._totals
+        self._t0 = t0 = time.perf_counter()
+        if self._key in totals.watch:
+            totals.open = (self._key, t0, next(totals._serial))
         return self
 
     def __exit__(self, et, ev, tb):
         dt = time.perf_counter() - self._t0
         totals, key = self._totals, self._key
+        if key in totals.watch:
+            if dt >= STALL_FLOOR_S:     # an ordinary exit stops here
+                limit = totals.stall_threshold(key)
+                if limit is not None and dt > limit:
+                    totals.stall_seconds[key] += dt
+                    totals.stall_counts[key] += 1
+                    totals.stall_max_s = max(totals.stall_max_s, dt)
+                    totals.stalled.append((key, self._t0, dt, limit))
+            totals.recent[key].append(dt)
+            totals.open = None
         totals.seconds[key] += dt
         totals.counts[key] += self._entries
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
         return False
+
+
+def _read(path: str) -> Optional[str]:
+    try:
+        with open(path) as f:
+            return f.read()
+    except OSError:         # no such kernel file, or the thread has ended
+        return None
+
+
+def _thread_sample(tid: str, first: bool) -> Optional[Dict[str, Any]]:
+    """One native thread of this process as /proc shows it now: its name,
+    state and CPU seconds from `stat`; run-queue seconds and context switches
+    where the kernel has `schedstat` and counts switches (a sandbox's may
+    not)."""
+    base = f"/proc/self/task/{tid}/"
+    stat = _read(base + "stat")
+    if not stat:
+        return None
+    # the name may hold spaces and brackets: fields are counted from its end
+    fields = stat[stat.rindex(")") + 2:].split()
+    row = {"name": stat[stat.index("(") + 1:stat.rindex(")")],
+           "state": fields[0],
+           "cpu_s": (int(fields[11]) + int(fields[12])) / _CLK_TCK}
+    sched = _read(base + "schedstat")
+    if sched:
+        row["runq_s"] = int(sched.split()[1]) / 1e9
+    for ln in (_read(base + "status") or "").splitlines():
+        if "ctxt_switches" in ln:       # voluntary_, nonvoluntary_
+            row["invol" if ln.startswith("non") else "vol"] = int(
+                ln.split()[1])
+    if first:   # where the thread sleeps, while the stall is going on
+        wchan = (_read(base + "wchan") or "").strip()
+        if wchan not in ("", "0"):
+            row["wchan"] = wchan
+    return row
+
+
+def _totals_of(path: str, keys) -> Dict[str, str]:
+    """`key value...` lines of a /proc file, the named keys only."""
+    out = {}
+    for ln in (_read(path) or "").splitlines():
+        key, _, rest = ln.partition(" ")
+        if key.rstrip(":") in keys:
+            out[key.rstrip(":")] = rest
+    return out
+
+
+def _short(path: str) -> str:
+    """The last two parts of a source path."""
+    head, tail = os.path.split(path)
+    return os.path.join(os.path.basename(head), tail)
+
+
+def _process_sample(first: bool) -> Dict[str, Any]:
+    """What this process and its machine look like now, read from /proc and
+    the interpreter alone: no jax and no call into the runtime, which may be
+    what is stuck. Whatever the kernel does not show is left out. The first
+    sample of a stall begins with every Python thread's innermost frames."""
+    own = threading.get_native_id()
+    sample: Dict[str, Any] = {"at": time.perf_counter()}
+    python = {}     # native id -> name, of the Python threads
+    if first:
+        names = {t.ident: (t.name, t.native_id)
+                 for t in threading.enumerate()}
+        frames = sample["py_frames"] = {}
+        for ident, frame in sys._current_frames().items():
+            name, native = names.get(ident, (f"thread-{ident}", None))
+            if native == own:
+                continue
+            rows = []
+            while frame is not None and len(rows) < _STALL_FRAMES:
+                code = frame.f_code
+                rows.append(f"{_short(code.co_filename)}:{frame.f_lineno} "
+                            f"{code.co_name}")
+                frame = frame.f_back
+            if name in frames:      # two threads of one name
+                name = f"{name}#{native}"
+            frames[name] = rows
+            python[str(native)] = name
+    threads = sample["threads"] = {}
+    try:
+        tids = os.listdir("/proc/self/task")
+    except OSError:
+        tids = []
+    for tid in tids:
+        row = None if tid == str(own) else _thread_sample(tid, first)
+        if row is not None:
+            if tid in python:
+                row["py"] = python[tid]
+            threads[tid] = row
+    pressure = {}
+    for what in ("cpu", "io", "memory"):
+        rows = _totals_of(f"/proc/pressure/{what}", ("some", "full"))
+        if rows:    # microseconds some (all) tasks stood still for it
+            pressure[what] = {k: int(v.rsplit("total=", 1)[1]) / 1e6
+                              for k, v in rows.items()}
+    if pressure:
+        sample["pressure"] = pressure
+    io = _totals_of("/proc/self/io", ("read_bytes", "write_bytes"))
+    if io:
+        sample["proc_io"] = {k: int(v) for k, v in io.items()}
+    return sample
+
+
+def _delta(a: dict, b: dict) -> dict:
+    """b - a, key by key and group by group, for the keys both have."""
+    return {k: _delta(v, b[k]) if isinstance(v, dict) else round(b[k] - v, 6)
+            for k, v in a.items() if k in b}
+
+
+class StallWatch:
+    """The watchdog of a `PhaseTotals` with watched keys: a daemon thread
+    that lives between `start()` and `stop()` (the loop whose phases it
+    watches starts it on entry and stops it in a `finally`). Every
+    STALL_POLL_S it reads `totals.open`. When a watched phase has been open
+    for longer than its threshold it takes a FIRST sample of the process
+    (`_process_sample`, with `describe()`, the owner's plain facts); when it
+    finds that entry's stalled exit on `totals.stalled` it takes a SECOND and
+    hands `file` one record of the differences. A stalled exit it never
+    sampled (it ended inside a poll) is filed with `sampled` False, and so is
+    one whose sample failed, with `sample_error`. With no stall it is ten
+    wake-ups a second that read one attribute.
+
+    A record: `t` (`time.time()` at the phase's entry), `phase`, `dur_s`,
+    `limit_s` (the threshold it passed), `sampled`, and of a sampled one
+    `seen_after_s` (entry to first sample: a watchdog that could not run
+    sooner was itself kept from the interpreter), `between_s` (first sample
+    to second), `engine` (`describe()`), `py_frames` (every Python thread's
+    innermost frames at the first sample), `pressure`, `proc_io`
+    (differences) and `threads`: at most 16 rows, every native thread seen in
+    state D or R at either sample first, then by CPU seconds between the
+    samples."""
+
+    def __init__(self, totals: PhaseTotals, describe, file):
+        self._totals = totals
+        self._describe = describe
+        self._file = file
+        self._done = threading.Event()
+        # (entry, first sample or the error that kept it from being taken)
+        self._first: Optional[Tuple[Tuple[str, float, int], Any]] = None
+        self.samples = 0
+        self._thread = threading.Thread(
+            target=self._run, name="stall-watch", daemon=True)
+
+    def start(self) -> "StallWatch":
+        self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        """Ends the thread, which first files what is still on the queue."""
+        self._done.set()
+        self._thread.join()
+
+    def _run(self) -> None:
+        while not self._done.wait(STALL_POLL_S):
+            self._look()
+        self._look()
+
+    def _look(self) -> None:
+        totals = self._totals
+        # a record is on the queue only once its entry has ended: whether the
+        # sampled entry has closed is read from here, not from `open`, which
+        # the engine's thread clears a moment after it appends
+        t0 = None
+        while totals.stalled:
+            key, t0, dt, limit = totals.stalled.popleft()
+            record = {"t": time.time() - (time.perf_counter() - t0),
+                      "phase": key, "dur_s": round(dt, 6),
+                      "limit_s": round(limit, 6), "sampled": False}
+            if self._first is not None and self._first[0][1] == t0:
+                first, self._first = self._first[1], None
+                try:
+                    if isinstance(first, Exception):
+                        raise first
+                    record.update(self._differences(
+                        t0, first, _process_sample(first=False)))
+                except Exception as e:  # noqa: BLE001 - the count stands
+                    record["sample_error"] = repr(e)
+            self._file(record)
+        entry = totals.open
+        if (entry is None or entry[1] == t0     # just filed, not yet cleared
+                or (self._first is not None and self._first[0] == entry)):
+            return
+        key, t0, _ = entry
+        limit = totals.stall_threshold(key)
+        if limit is not None and time.perf_counter() - t0 > limit:
+            try:
+                sample = _process_sample(first=True)
+                sample["engine"] = self._describe()
+            except Exception as e:  # noqa: BLE001 - a /proc line, describe()
+                sample = e
+            self._first = (entry, sample)
+            self.samples += 1
+
+    @staticmethod
+    def _differences(t0: float, a: dict, b: dict) -> Dict[str, Any]:
+        rows = []
+        for tid, x in a["threads"].items():
+            y = b["threads"].get(tid)
+            if y is None:
+                continue
+            row = {"tid": int(tid), "name": x["name"],
+                   "state": x["state"] + y["state"],
+                   **_delta({k: x[k] for k in ("cpu_s", "runq_s", "vol",
+                                               "invol") if k in x}, y),
+                   **{k: x[k] for k in ("py", "wchan") if k in x}}
+            rows.append(row)
+        rows.sort(key=lambda r: (not set(r["state"]) & {"D", "R"},
+                                 -r["cpu_s"], -r.get("runq_s", 0.0),
+                                 "py" not in r))
+        out = {"sampled": True, "seen_after_s": round(a["at"] - t0, 6),
+               "between_s": round(b["at"] - a["at"], 6),
+               "engine": a["engine"], "py_frames": a["py_frames"],
+               "n_threads": len(a["threads"]), "threads": rows[:_STALL_ROWS]}
+        for group in ("pressure", "proc_io"):
+            if group in a and group in b:
+                out[group] = _delta(a[group], b[group])
+        return out
 
 
 def _format(raw) -> Dict[str, Any]:
@@ -421,9 +703,6 @@ def to_chrome(evts: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
         if ar:
             ev["args"] = ar
         out.append(ev)
-    if _process_label:
-        out.append({"name": "process_name", "ph": "M", "pid": os.getpid(),
-                    "tid": 0, "args": {"name": _process_label}})
     return out
 
 
