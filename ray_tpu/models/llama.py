@@ -28,11 +28,12 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import MoEMLP
 from ray_tpu.ops.attention import (apply_rope, blockwise_prefill_attention,
                                    decode_attention, mha_reference)
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import (continuation_blocks, flash_attention,
+                                         flash_continuation)
 from ray_tpu.ops.linear_attention import causal_conv, kda_chunked, kda_step
 from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          paged_attention_reference,
-                                         row_keys_values,
+                                         row_keys_values, row_pages,
                                          sparse_attention_reference,
                                          sparse_paged_decode,
                                          sparse_paged_prefill,
@@ -307,6 +308,37 @@ def _chunk_local_attention(cfg: LlamaConfig, q, k, v):
             else mha_reference(q, k, v, causal=True))
 
 
+# f32 scores of a chunk against its row's whole capacity that XLA may hold at
+# once (`decode_attention`); past it the row goes by key blocks. Mixtral's
+# chunk of 512 x 32 heads over 2304 keys is 151 MB; Solar's of 1024 x 64
+# over 40,960 would be 10.7 GB.
+_SCORES_AT_ONCE_BYTES = 2 ** 30
+
+
+def _continuation_attention(q, cache: PagedKVCache, layer_idx, positions):
+    """Attention of a prefill chunk (B is 1: the row view) whose tokens sit
+    at `positions` [B, T] over the row's pages, its own keys written: the row's
+    pages copied out contiguous (slot s = absolute position s; the padded
+    table's placeholder pages sit past every valid query position and are
+    masked) under `decode_attention`'s absolute-position causal mask.
+
+    On the TPU one flash kernel over the pages head-major as the pool holds
+    them, as far as the chunk's last query reaches; a chunk its blocks do not
+    tile (a bucket clamped to what the row has left) and every chunk off the
+    TPU take the XLA forms: all keys at once where the scores fit, by key
+    blocks where they would be gigabytes."""
+    _, t, h, _ = q.shape
+    g = h // cache.k_pages.shape[1]
+    if (jax.default_backend() == "tpu"
+            and continuation_blocks(t, g, q.dtype) is not None):
+        return flash_continuation(q, *row_pages(cache, layer_idx),
+                                  positions[:, 0])
+    k_all, v_all = row_keys_values(cache, layer_idx)
+    at_once = 4 * t * h * k_all.shape[1] <= _SCORES_AT_ONCE_BYTES
+    return (decode_attention if at_once else blockwise_prefill_attention)(
+        q, k_all, v_all, positions[:, 0])
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
     layer_idx: int = 0
@@ -399,21 +431,8 @@ class Attention(nn.Module):
                 # prefix-cache hits start mid-prompt), not just their own
                 # chunk — chunk-local causal attention here was the r4 bug
                 # that made multi-chunk paged prefill numerically wrong.
-                # Gather the row's pages into contiguous KV (slot s =
-                # absolute position s; the padded table's placeholder pages
-                # sit past every valid query position and are masked) and
-                # reuse decode_attention's absolute-position causal mask.
-                # B is 1 here (row view), so the gather is one row's
-                # capacity per layer.
-                k_all, v_all = row_keys_values(cache, layer_idx)
-                if cfg.full_attn_every:
-                    # rows of tens of thousands of keys: by key blocks, as
-                    # far as the chunk reaches (the scores of a whole row's
-                    # capacity at once would be gigabytes)
-                    out = blockwise_prefill_attention(q, k_all, v_all,
-                                                      positions[:, 0])
-                else:
-                    out = decode_attention(q, k_all, v_all, positions[:, 0])
+                out = _continuation_attention(q, cache, layer_idx,
+                                              positions)
             new_cache_kv = cache
         elif cache is not None:
             # Decode: write current K/V at `length`, attend over the cache.

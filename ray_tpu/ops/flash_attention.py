@@ -19,6 +19,10 @@ Block sizes default to 1024 (per-grid-step overhead dominates small blocks);
 fwd, dq and dkv all compile at 1024x1024 on a v5e under libtpu 0.0.34 and
 match `mha_reference` at B=1,T=2048,H=32,Kh=8,D=64 (chip_smoke.py checks this
 on every run). Their speed is not measured.
+
+Serving has a forward kernel of its own at the end of the file,
+`flash_continuation`: a prefill chunk that starts past position 0 against
+its row's cached keys, with the start and the key bound values of the call.
 """
 
 import functools
@@ -354,3 +358,156 @@ def flash_attention(
                                out_specs=spec, axis_names=axes,
                                check_vma=False)
     return kernel(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# A prefill continuation chunk over its row's cached prefix (serving; forward
+# only). The training kernels above are not its callers and share nothing
+# with it.
+# ---------------------------------------------------------------------------
+
+# Rows of one step's score matrix: the G query heads of a kv head stacked on
+# `block_q` queries each, so that a K/V block is read once a kv head and the
+# MXU sees a tall operand; and its columns, the keys of a block. f32 scores
+# and weights of [1024, 1024] are 4 MiB each, inside the default VMEM limit.
+# On the v5e (PR 38) a chunk of 1024 x 64 heads of 128 over a prefix of 30k
+# took 7.9 ms at 1024 x 1024 (66% of the bf16 peak), 7.6 at 2048 x 1024 (which
+# needs the limit raised), 8.0 at 1024 x 2048, and 16.1 at 1024 x 512 and
+# 24.7 at 1024 x 256: a step's fixed work (the accumulators rescaled, the
+# row statistics read and written back lane-wide) wants a wide block to
+# spread over.
+_CONT_ROWS = 1024
+_CONT_BLOCK_KV = 1024
+
+
+def continuation_blocks(t: int, g: int, dtype) -> Optional[int]:
+    """The query block `flash_continuation` takes a chunk of `t` queries of
+    `g` heads a kv head with, or None for a chunk it cannot take: its blocks
+    have to tile the chunk, in whole sublane tiles of the operands' type (a
+    bucket clamped to what a row has left can be any length)."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    block_q = min(t, max(tile, _CONT_ROWS // g))
+    return None if t % block_q or block_q % tile else block_q
+
+
+def _continuation_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
+                         m_scr, l_scr, acc_scr, *, scale, block_q, block_kv):
+    """Step (b, kh, iq, ik): fold key block ik into the accumulators of query
+    block iq, for the G query heads of kv head kh at once. q_ref, o_ref:
+    [G, block_q, D]; k_ref, v_ref: [block_kv, D]; scratch: running maximum
+    and sum [G * block_q, 128] and weighted values [G * block_q, D], f32.
+
+    Query j of the chunk sits at position start + j and sees key s iff
+    s <= start + j. A key block wholly at or before the block's first query
+    is folded unmasked; one the block's last query does not reach is not
+    folded at all (its operand names the last block that is, and is not
+    copied); the one or two between are masked, and their values past the
+    last query zeroed: whatever lies past the chunk's end in the row, or past
+    the row's end in the operand, is read as nothing, not as 0 x something.
+    """
+    b_, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    g, _, d = q_ref.shape
+    rows = g * block_q
+    first = start_ref[b_] + iq * block_q      # the block's first query's position
+    last = first + block_q - 1
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(masked: bool):
+        q = q_ref[...].reshape(rows, d)
+        k, v = k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            at = lambda shape, dim: jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+            three = (g, block_q, block_kv)
+            seen = (ik * block_kv + at(three, 2) <= first + at(three, 1))
+            s = jnp.where(seen.reshape(rows, block_kv), s, -jnp.inf)
+            v = jnp.where(ik * block_kv + at(v.shape, 0) <= last, v,
+                          jnp.zeros_like(v))
+        # key 0 is seen by every query, so from the first block on the
+        # maximum is finite and exp() NaN-free
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    whole = (ik + 1) * block_kv - 1 <= first
+    pl.when(whole)(lambda: fold(False))
+    pl.when(jnp.logical_not(whole) & (ik * block_kv <= last))(
+        lambda: fold(True))
+
+    @pl.when(ik == last // block_kv)
+    def _finish():
+        o_ref[...] = (acc_scr[...] / l_scr[:, :1]).reshape(
+            g, block_q, d).astype(o_ref.dtype)
+
+
+def flash_continuation(
+    q: jax.Array,      # [B, T, H, D]: a prefill chunk's queries
+    k: jax.Array,      # [B, Kh, S, D]: the row's keys by position, HEAD-MAJOR
+    v: jax.Array,      # (or [B, Kh, mp, page, D], `paged_attention.row_pages`)
+    start: jax.Array,  # [B] int32: the position of the chunk's first token
+    *,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention of a prefill chunk that starts at position `start` of its
+    row over the row's keys up to its own (`decode_attention`'s mask: query j
+    sees key s iff s <= start + j), one flash program; returns [B, T, H, D].
+
+    `start` is a value of the call, prefetched, and so is the grid's length
+    along the keys: no key block past start + T - 1 is computed or copied,
+    and a row of 9k keys in a table of 40k pays for 9k. Each key block is
+    scored once (scores, running maximum and sum and the weighted values in
+    one step: bf16 products, f32 accumulation and softmax, the weights cast
+    to the values' type), for all query heads of its kv head. The chunk has
+    to tile (`continuation_blocks`)."""
+    b, t, h, d = q.shape
+    kh = k.shape[1]
+    k, v = k.reshape(b, kh, -1, d), v.reshape(b, kh, -1, d)
+    s_max, g = k.shape[2], h // kh
+    block_q = continuation_blocks(t, g, q.dtype)
+    if block_q is None:
+        raise ValueError(f"flash_continuation: no query block tiles a chunk "
+                         f"of {t} x {g} heads of {q.dtype}")
+    block_kv = min(_CONT_BLOCK_KV, s_max)
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    start = start.astype(jnp.int32)
+    n_kv = (jnp.max(start) + t + block_kv - 1) // block_kv
+
+    def keys_of(b_, kh_, iq, ik, start):
+        # past the last block the query block reaches: that block again
+        reach = (start[b_] + (iq + 1) * block_q - 1) // block_kv
+        return (b_, kh_, jnp.minimum(ik, reach), 0)
+
+    heads = pl.BlockSpec((None, None, g, block_q, d),
+                         lambda b_, kh_, iq, ik, start: (b_, kh_, 0, iq, 0))
+    keys = pl.BlockSpec((None, None, block_kv, d), keys_of)
+    out = pl.pallas_call(
+        functools.partial(_continuation_kernel, scale=scale, block_q=block_q,
+                          block_kv=block_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kh, t // block_q, n_kv),
+            in_specs=[heads, keys, keys],
+            out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((g * block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((g * block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((g * block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, t, d), q.dtype),
+        interpret=interpret,
+        name="flash_continuation",
+    )(start, q.reshape(b, t, kh, g, d).transpose(0, 2, 3, 1, 4), k, v)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)

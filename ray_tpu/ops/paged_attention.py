@@ -233,10 +233,10 @@ def paged_attention(
     return out.reshape(b, h, d)
 
 
-def _row_pages(pool, layer, block_tables):
+def _gather_row_pages(pool, layer, block_tables):
     """XLA's gather of every row's pages of `layer`: [B, Kh, mp, page, D].
     The layer rides in the gather. Off the TPU only: on it the compiler
-    re-tiles the whole pool for this gather (`row_keys_values`)."""
+    re-tiles the whole pool for this gather (`row_pages`)."""
     return pool[layer, :, block_tables].swapaxes(1, 2)
 
 
@@ -252,7 +252,7 @@ def paged_attention_reference(q, k_pages, v_pages, layer, block_tables,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     s_max = block_tables.shape[1] * page_size
 
-    k_seq, v_seq = (_row_pages(pool, layer, block_tables).reshape(
+    k_seq, v_seq = (_gather_row_pages(pool, layer, block_tables).reshape(
         b, kh, s_max, d) for pool in (k_pages, v_pages))
     qg = q.reshape(b, kh, g, d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bksd->bkgs", qg, k_seq.astype(jnp.float32)) * scale
@@ -666,10 +666,11 @@ def _copy_pages_kernel(tbl_ref, layer_ref, k_ref, v_ref, ko_ref, vo_ref):
     vo_ref[...] = v_ref[...]
 
 
-def row_keys_values(cache: PagedKVCache, layer_idx: int,
-                    interpret: Optional[bool] = None):
+def row_pages(cache: PagedKVCache, layer_idx: int,
+              interpret: Optional[bool] = None):
     """Layer `layer_idx`'s keys and values of every row's pages, contiguous
-    by position ([B, mp * page, Kh, D] each; the dense layout): slot s is
+    by position and HEAD-MAJOR as the pool lies ([B, Kh, mp, page, D] each,
+    what `flash_continuation` takes): token s of a row's (mp, page) is
     absolute position s, and the padded table's placeholder pages sit past
     every valid position.
 
@@ -688,7 +689,7 @@ def row_keys_values(cache: PagedKVCache, layer_idx: int,
     b, mp = tb.shape
     kh, _, ps, d = cache.k_pages.shape[1:]
     if interpret is None and jax.default_backend() != "tpu":
-        k, v = (_row_pages(pool, layer_idx, tb)
+        k, v = (_gather_row_pages(pool, layer_idx, tb)
                 for pool in (cache.k_pages, cache.v_pages))
     else:
         page = pl.BlockSpec(
@@ -707,6 +708,15 @@ def row_keys_values(cache: PagedKVCache, layer_idx: int,
             name="paged_row_pages",
         )(tb, jnp.asarray(layer_idx, jnp.int32).reshape(1),
           cache.k_pages, cache.v_pages)
+    return k, v
+
+
+def row_keys_values(cache: PagedKVCache, layer_idx: int,
+                    interpret: Optional[bool] = None):
+    """`row_pages` in the dense layout ([B, mp * page, Kh, D] each), for the
+    XLA forms of attention."""
+    k, v = row_pages(cache, layer_idx, interpret)
+    b, kh, mp, ps, d = k.shape
     to_rows = lambda x: x.reshape(b, kh, mp * ps, d).swapaxes(1, 2)
     return to_rows(k), to_rows(v)
 

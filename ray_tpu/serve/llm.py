@@ -433,7 +433,14 @@ class LLMServer:
             "chunk_sizes": {}, "loop_s": 0.0, "ticks": 0,
             "decode_steps": 0, "active_slot_syncs": 0,
             "prefill_chunks": 0, "prefill_tokens": 0,
-            "prefill_padded_tokens": 0, "admitted": 0, "slot_wait_s": 0.0,
+            "prefill_padded_tokens": 0,
+            # prefill chunks that started past position 0, the sum over them
+            # of the keys the chunk's last real query reaches (start + n),
+            # and of the (query, key) pairs their real queries see: times
+            # 4 x heads x head_dim, what their attention had to compute
+            "continuation_chunks": 0, "continuation_reach_keys": 0,
+            "continuation_query_keys": 0,
+            "admitted": 0, "slot_wait_s": 0.0,
             "slot_wait_max_s": 0.0, "demote_bytes": 0, "demote_passes": 0,
             "demote_wait_s": 0.0, "demote_inflight_max_bytes": 0,
             "restored_in_flight": 0,
@@ -1208,6 +1215,10 @@ class LLMServer:
         st["prefill_chunks"] += 1
         st["prefill_tokens"] += n
         st["prefill_padded_tokens"] += bucket
+        if start:
+            st["continuation_chunks"] += 1
+            st["continuation_reach_keys"] += start + n
+            st["continuation_query_keys"] += n * start + n * (n + 1) // 2
         self._count_moe(n, bucket)
         return last_logits if final else None
 
@@ -1902,7 +1913,9 @@ class LLMServer:
             "stall_max_s": self._phases.stall_max_s,
             **{k: st[k] for k in (
                 "decode_steps", "active_slot_syncs", "prefill_chunks",
-                "prefill_tokens", "prefill_padded_tokens", "admitted",
+                "prefill_tokens", "prefill_padded_tokens",
+                "continuation_chunks", "continuation_reach_keys",
+                "continuation_query_keys", "admitted",
                 "slot_wait_s", "slot_wait_max_s", "demote_bytes",
                 "demote_passes", "demote_wait_s",
                 "demote_inflight_max_bytes", "restored_in_flight",

@@ -128,6 +128,8 @@ def test_counters_are_all_there_at_zero_from_construction():
                             paged=False)).stats()["decode"]
     for key in ("loop_s", "ticks", "decode_steps", "active_slot_syncs",
                 "prefill_chunks", "prefill_tokens", "prefill_padded_tokens",
+                "continuation_chunks", "continuation_reach_keys",
+                "continuation_query_keys",
                 "admitted", "slot_wait_s", "slot_wait_max_s", "evicted_pages",
                 "demoted_pages", "restored_pages", "demote_failed",
                 "demote_bytes", "stash_spilled_pages", "host_syncs", "tokens",

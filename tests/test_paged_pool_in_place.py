@@ -5,6 +5,7 @@ of a prefill chunk produces an array the size of a layer's pool or of the
 whole pool other than the in-place writes."""
 
 import asyncio
+import math
 from unittest import mock
 
 import jax
@@ -405,6 +406,46 @@ def test_compiled_for_the_v5e_no_program_moves_a_pool(v5e, name, rows,
     assert not moved, f"{name}: pool-sized results of {sorted(moved)}"
     assert "paged_decode" in hlo or tokens > 1
     assert ("paged_row_pages" in hlo) == (name == "continuation")
+
+
+@pytest.mark.parametrize("cell,tokens,width,heads", [
+    ("solar250b-agentloop-batch", 1024, 640, 64),
+    ("mixtral8x7b-batch", 512, 36, 32)])
+def test_continuation_compiled_for_the_v5e_at_the_cells_shapes(
+        v5e, cell, tokens, width, heads):
+    """A continuation chunk at a cell's chunk length, row capacity and heads
+    (8 kv heads of 128, pages of 64 in bf16): the v5e's compiler takes
+    `flash_continuation` with its prefetched start and a grid as long as the
+    keys the chunk reaches, once a layer beside the page copy that feeds it;
+    no score matrix is left in XLA's hands (no f32 array of the program is
+    larger than the chunk's queries [T, H, D], which the rotary turns in f32:
+    the scores of ONE key block of 512 were four times that), the
+    row's K and V go from the copy to the kernel head-major as the pool
+    holds them (nothing else has a row-sized result: no transpose), and
+    nothing pool-sized is produced but the writes."""
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    layers, pages, kv_heads, d = 2, 1152, 8, 128
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=layers,
+                      n_heads=heads, n_kv_heads=kv_heads, head_dim=d,
+                      ffn_dim=512, max_seq_len=width * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    hlo = _step_compiled_for(v5e, cfg, pages, 1, width, tokens)
+    calls = sorted(line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                   for line in hlo.splitlines() if "tpu_custom_call" in line)
+    assert calls == (["flash_continuation"] * layers
+                     + ["paged_row_pages"] * layers), f"{cell}: {calls}"
+    largest = max(math.prod(map(int, dims.split(",")))
+                  for dims in re.findall(r"f32\[([\d,]+)\]", hlo))
+    assert largest <= tokens * heads * d, f"{cell}: an f32 of {largest}"
+    row = rf"bf16\[1,({kv_heads},{width},64|{kv_heads},{width * 64}|{width * 64},{kv_heads}),{d}\]"
+    moved = {m for m in _pool_sized_results(hlo, row) if m[0] != "custom-call"}
+    assert not moved, f"{cell}: row-sized results of {sorted(moved)}"
+    moved = _pool_sized_results(
+        hlo, rf"bf16\[({layers},)?{kv_heads},{pages},64,{d}\]")
+    assert not moved, f"{cell}: pool-sized results of {sorted(moved)}"
 
 
 @pytest.mark.parametrize("cell,rows,width,heads,head_dim", [
