@@ -16,6 +16,7 @@ serve LLM replicas):
   of activation checkpointing, trading HBM for recompute.
 """
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Optional, Tuple
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import MoEMLP
 from ray_tpu.ops.attention import (apply_rope, blockwise_prefill_attention,
                                    decode_attention, mha_reference)
+from ray_tpu.ops.flash_attention import _CONT_BLOCK_KV as CONT_BLOCK_KV
 from ray_tpu.ops.flash_attention import (continuation_blocks, flash_attention,
                                          flash_continuation)
 from ray_tpu.ops.linear_attention import causal_conv, kda_chunked, kda_step
@@ -104,6 +106,32 @@ class LlamaConfig:
     # computes the pairs that fall on those (0 = the bank holds them all)
     experts_held: int = 0
     experts_first: int = 0
+    # ---- sliding-window layers beside full ones (Cohere's Command family):
+    # the kind of layer i is layer_types[i % len(layer_types)], "sliding"
+    # (rotary positions; query t sees key s iff t - sliding_window < s <= t;
+    # its keys and values live in the paged cache's WINDOW pool) or "full"
+    # (no positions at all, causal, the full pool). None = every layer as
+    # the fields above say.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    rope_interleaved: bool = False  # rotary turns the pairs (2i, 2i + 1)
+    # attention and FFN both read ONE norm of the block's input and are
+    # summed into the residual
+    parallel_block: bool = False
+    norm: str = "rms"               # rms | layer (mean-centred, no bias)
+    logit_scale: float = 1.0
+    shared_average: bool = False    # the shared experts' MEAN, not their sum
+
+    def layer_kind(self, layer_idx: int) -> Optional[str]:
+        """"sliding" or "full" under a `layer_types` pattern, else None."""
+        if not self.layer_types:
+            return None
+        return self.layer_types[layer_idx % len(self.layer_types)]
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(self.layer_kind(i) == "sliding"
+                   for i in range(self.n_layers))
 
     def is_linear(self, layer_idx: int) -> bool:
         return bool(self.full_attn_every) and layer_idx % self.full_attn_every != 0
@@ -113,7 +141,11 @@ class LlamaConfig:
         return sum(self.is_linear(i) for i in range(self.n_layers))
 
     def kv_layer(self, layer_idx: int) -> int:
-        """Where a full-attention layer's keys and values lie in the pools."""
+        """Where a layer's keys and values lie in its pool (under a
+        `layer_types` pattern: among the layers of its own kind)."""
+        if self.layer_types:
+            kind = self.layer_kind(layer_idx)
+            return sum(self.layer_kind(i) == kind for i in range(layer_idx))
         return layer_idx // self.full_attn_every if self.full_attn_every else layer_idx
 
     def linear_index(self, layer_idx: int) -> int:
@@ -190,6 +222,41 @@ class LlamaConfig:
             linear_heads=64, linear_key_dim=128, linear_value_dim=128,
             linear_rank=128, use_rope=False, attn_gate=True,
             router_score="sigmoid", n_shared_experts=1), **kw})
+
+    @staticmethod
+    def command_tiny(**kw):
+        """Test-scale Command A+: three sliding layers (window 16,
+        interleaved rotary) to one full layer without positions, two periods,
+        a parallel block under a mean-centred norm, 4 query heads a kv head,
+        16 experts of 32 scored by a sigmoid with 2 a token beside 2 shared
+        experts that are averaged, a tied embedding."""
+        return LlamaConfig(**{**dict(
+            vocab_size=256, d_model=64, n_layers=8, n_heads=8,
+            n_kv_heads=2, head_dim=16, ffn_dim=32, max_seq_len=128,
+            rope_theta=50000.0, tie_embeddings=True, n_experts=16,
+            moe_top_k=2, expert_dim=32, router_score="sigmoid",
+            n_shared_experts=2, shared_average=True,
+            layer_types=("sliding", "sliding", "sliding", "full"),
+            sliding_window=16, rope_interleaved=True, parallel_block=True,
+            norm="layer"), **kw})
+
+    @staticmethod
+    def command_a_plus(**kw):
+        """Command A+ (command-a-plus-05-2026, `cohere2_moe`, 218B-A25B): 32
+        layers, three sliding (window 4096, interleaved rotary, theta 50000)
+        to one full without positions; 128 query heads of 128 on a hidden
+        size of 4096 over 8 kv heads; a parallel block under a LayerNorm
+        without bias; 128 experts of 4096 scored by a sigmoid, 8 a token,
+        beside 4 shared experts that are averaged; a tied embedding."""
+        return LlamaConfig(**{**dict(
+            vocab_size=262144, d_model=4096, n_layers=32, n_heads=128,
+            n_kv_heads=8, head_dim=128, ffn_dim=4096, max_seq_len=131072,
+            rope_theta=50000.0, norm_eps=1e-5, tie_embeddings=True,
+            n_experts=128, moe_top_k=8, expert_dim=4096,
+            router_score="sigmoid", n_shared_experts=4, shared_average=True,
+            layer_types=("sliding", "sliding", "sliding", "full"),
+            sliding_window=4096, rope_interleaved=True, parallel_block=True,
+            norm="layer"), **kw})
 
     @staticmethod
     def mixtral_8x7b(**kw):
@@ -274,6 +341,25 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(self.dtype)
 
 
+class LayerNorm(nn.Module):
+    """Mean-centred norm with a scale and no bias, in f32."""
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        xf = x.astype(jnp.float32)
+        xf = xf - jnp.mean(xf, -1, keepdims=True)
+        normed = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + self.eps)
+        return (normed * scale).astype(self.dtype)
+
+
+def _norm(cfg: "LlamaConfig", name: str):
+    return (LayerNorm if cfg.norm == "layer" else RMSNorm)(
+        cfg.norm_eps, cfg.dtype, name=name)
+
+
 class Indexer(nn.Module):
     """The lightning indexer's projections: `index_heads` query heads, one
     key head (LayerNorm, cached in the third pool) and a weight a head, all
@@ -298,9 +384,21 @@ class Indexer(nn.Module):
         return qi, ki, wi
 
 
-def _chunk_local_attention(cfg: LlamaConfig, q, k, v):
+def _band(t: int, window: int):
+    """[1, T, T] True where query t sees key s through a causal window."""
+    at = jnp.arange(t)
+    return ((at[None, :] <= at[:, None])
+            & (at[None, :] > at[:, None] - window))[None]
+
+
+def _chunk_local_attention(cfg: LlamaConfig, q, k, v, window=None):
     """Causal attention of a chunk over itself (a fresh row's first chunk
-    into the paged cache); honors attn_impl like the cache=None branch."""
+    into the paged cache); honors attn_impl like the cache=None branch. A
+    sliding layer's chunk longer than its window (test sizes: the real
+    chunk is a quarter of the window) takes the masked XLA form."""
+    if window is not None and q.shape[1] > window:
+        return mha_reference(q, k, v, causal=False,
+                             mask=_band(q.shape[1], window))
     impl = cfg.attn_impl
     if impl in ("auto", "ring"):
         impl = "flash" if jax.default_backend() == "tpu" else "xla"
@@ -315,7 +413,8 @@ def _chunk_local_attention(cfg: LlamaConfig, q, k, v):
 _SCORES_AT_ONCE_BYTES = 2 ** 30
 
 
-def _continuation_attention(q, cache: PagedKVCache, layer_idx, positions):
+def _continuation_attention(q, cache: PagedKVCache, layer_idx, positions,
+                            window=None):
     """Attention of a prefill chunk (B is 1: the row view) whose tokens sit
     at `positions` [B, T] over the row's pages, its own keys written: the row's
     pages copied out contiguous (slot s = absolute position s; the padded
@@ -326,17 +425,35 @@ def _continuation_attention(q, cache: PagedKVCache, layer_idx, positions):
     them, as far as the chunk's last query reaches; a chunk its blocks do not
     tile (a bucket clamped to what the row has left) and every chunk off the
     TPU take the XLA forms: all keys at once where the scores fit, by key
-    blocks where they would be gigabytes."""
+    blocks where they would be gigabytes.
+
+    `window` (a sliding layer; `cache` is then the window view): only the
+    pages from the key block of the first visible key on are copied out, and
+    every form masks what lies before a query's window."""
     _, t, h, _ = q.shape
     g = h // cache.k_pages.shape[1]
-    if (jax.default_backend() == "tpu"
-            and continuation_blocks(t, g, q.dtype) is not None):
+    on_tpu = (jax.default_backend() == "tpu"
+              and continuation_blocks(t, g, q.dtype) is not None)
+    # (the start is taken after the pages, where it always was: the other
+    # configurations' lowered programs are held to their text)
+    if on_tpu and window is None:
         return flash_continuation(q, *row_pages(cache, layer_idx),
                                   positions[:, 0])
+    if on_tpu:
+        ps = cache.page_size
+        per_block = max(1, CONT_BLOCK_KV // ps)     # pages a key block
+        start = positions[:, 0]
+        first = (jnp.maximum(start - window + 1, 0) // (per_block * ps)
+                 * per_block)
+        n_pages = (window + t - 2) // ps + 2 + per_block
+        return flash_continuation(
+            q, *row_pages(cache, layer_idx, first=first, n_pages=n_pages),
+            start, window=window)
     k_all, v_all = row_keys_values(cache, layer_idx)
     at_once = 4 * t * h * k_all.shape[1] <= _SCORES_AT_ONCE_BYTES
     return (decode_attention if at_once else blockwise_prefill_attention)(
-        q, k_all, v_all, positions[:, 0])
+        q, k_all, v_all, positions[:, 0],
+        **({} if window is None else {"window": window}))
 
 
 class Attention(nn.Module):
@@ -361,11 +478,20 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
-        if cfg.use_rope:
-            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections)
-            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_sections)
-        if cfg.full_attn_every:      # the pools hold the full layers only
+        kind = cfg.layer_kind(layer_idx)
+        # a sliding layer's mask, and the argument that carries it
+        window = cfg.sliding_window if kind == "sliding" else None
+        win = {} if window is None else {"window": window}
+        if cfg.use_rope and kind != "full":
+            turn = ({"interleaved": True} if cfg.rope_interleaved else {})
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections,
+                           **turn)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_sections,
+                           **turn)
+        if cfg.full_attn_every or kind:   # a pool holds its kind's layers
             layer_idx = cfg.kv_layer(layer_idx)
+        scope = (jax.named_scope("attn_window" if window else "attn_full")
+                 if kind else contextlib.nullcontext())
         if positions.ndim == 3:
             # three-component rotary; the order in the sequence (where the
             # cache is written, what is causal) is the row's own count
@@ -409,30 +535,38 @@ class Attention(nn.Module):
             # cache threads through the block stack; every write and read
             # addresses the stacked pool by (layer, page), in place on the
             # donated pool (write_layer_tokens: a layer taken out of it, or a
-            # scatter into it, moved a pool-sized array).
-            cache = write_layer_tokens(cache, layer_idx, k, v, positions)
-            if t == 1:
-                # decode: pallas kernel walks the block table of the stacked
-                # pool, whole as the cache holds it (XLA gather reference
-                # off-TPU, same arguments, same numerics)
-                impl = (paged_attention if jax.default_backend() == "tpu"
-                        else paged_attention_reference)
-                out = impl(q[:, 0], cache.k_pages, cache.v_pages, layer_idx,
-                           cache.block_tables, positions[:, -1] + 1)[:, None]
-            elif paged_chunk_local:
-                # FIRST chunk of a fresh row (start==0, no cached prefix —
-                # the caller asserts this statically): chunk-local causal
-                # attention is exact, no page gather. The hot cold-prompt
-                # TTFT path; honors attn_impl like the cache=None branch.
-                out = _chunk_local_attention(cfg, q, k, v)
-            else:
-                # chunked prefill continuation: queries must see the row's
-                # CACHED prefix (chunks 2+ of a long prompt, and
-                # prefix-cache hits start mid-prompt), not just their own
-                # chunk — chunk-local causal attention here was the r4 bug
-                # that made multi-chunk paged prefill numerically wrong.
-                out = _continuation_attention(q, cache, layer_idx,
-                                              positions)
+            # scatter into it, moved a pool-sized array). A sliding layer
+            # does all of it on the window pool and its table.
+            whole = cache
+            if window is not None:
+                cache = cache.window_view()
+            with scope:
+                cache = write_layer_tokens(cache, layer_idx, k, v, positions)
+                if t == 1:
+                    # decode: pallas kernel walks the block table of the
+                    # stacked pool, whole as the cache holds it (XLA gather
+                    # reference off-TPU, same arguments, same numerics)
+                    impl = (paged_attention if jax.default_backend() == "tpu"
+                            else paged_attention_reference)
+                    out = impl(q[:, 0], cache.k_pages, cache.v_pages,
+                               layer_idx, cache.block_tables,
+                               positions[:, -1] + 1, **win)[:, None]
+                elif paged_chunk_local:
+                    # FIRST chunk of a fresh row (start==0, no cached prefix —
+                    # the caller asserts this statically): chunk-local causal
+                    # attention is exact, no page gather. The hot cold-prompt
+                    # TTFT path; honors attn_impl like the cache=None branch.
+                    out = _chunk_local_attention(cfg, q, k, v, **win)
+                else:
+                    # chunked prefill continuation: queries must see the row's
+                    # CACHED prefix (chunks 2+ of a long prompt, and
+                    # prefix-cache hits start mid-prompt), not just their own
+                    # chunk — chunk-local causal attention here was the r4 bug
+                    # that made multi-chunk paged prefill numerically wrong.
+                    out = _continuation_attention(q, cache, layer_idx,
+                                                  positions, **win)
+            if window is not None:
+                cache = whole.merge_window(cache)
             new_cache_kv = cache
         elif cache is not None:
             # Decode: write current K/V at `length`, attend over the cache.
@@ -442,13 +576,18 @@ class Attention(nn.Module):
             v_cache = jax.vmap(
                 lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(c, u, i, 0)
             )(cache.v[layer_idx], v, cache.length)
-            out = decode_attention(q, k_cache, v_cache, cache.length)
+            out = decode_attention(q, k_cache, v_cache, cache.length, **win)
             new_cache_kv = (k_cache, v_cache)
         else:
             impl = cfg.attn_impl
             if impl == "auto":
                 impl = "flash" if jax.default_backend() == "tpu" else "xla"
-            if impl == "flash":
+            if window is not None:
+                # no flash kernel takes a window mask through its backward
+                # pass: the uncached forward of a sliding layer is XLA's
+                out = mha_reference(q, k, v, causal=False,
+                                    mask=_band(t, window))
+            elif impl == "flash":
                 out = flash_attention(q, k, v, causal=True)
             elif impl == "ring":
                 out = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True)
@@ -565,6 +704,17 @@ class Block(nn.Module):
     def __call__(self, x, positions, cache, paged_chunk_local=False,
                  n_valid=None):
         cfg = self.cfg
+        if cfg.parallel_block:
+            # attention and FFN both read ONE norm of the input and are
+            # summed into the residual
+            normed = _norm(cfg, "attn_norm")(x)
+            h, new_kv = Attention(cfg, self.layer_idx, name="attn")(
+                normed, positions, cache, paged_chunk_local)
+            if cfg.n_experts > 0 and self.layer_idx % cfg.moe_every == 0:
+                real = (None if n_valid is None else
+                        jnp.arange(x.shape[1])[None] < n_valid[:, None])
+                return x + h + MoEMLP(cfg, name="moe")(normed, real), new_kv
+            return x + h + MLP(cfg, name="mlp")(normed), new_kv
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
         if cfg.is_linear(self.layer_idx):
             h, new_kv = LinearAttention(cfg, self.layer_idx, name="kda")(
@@ -636,7 +786,7 @@ class Llama(nn.Module):
                 new_k.append(new_kv[0])
                 new_v.append(new_kv[1])
 
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        x = _norm(cfg, "final_norm")(x)
         if return_hidden:
             new_cache = None
             if paged:
@@ -653,6 +803,8 @@ class Llama(nn.Module):
                               kernel_init=nn.initializers.normal(0.02),
                               name="lm_head")(x)
         logits = logits.astype(jnp.float32)
+        if cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
 
         new_cache = None
         if paged:
@@ -707,7 +859,8 @@ def _expert_params(cfg: LlamaConfig) -> int:
 def llama_param_count(cfg: LlamaConfig) -> int:
     """Parameters the model HOLDS: a bank with a share of the experts
     (`experts_held`) counts those, its router all it scores."""
-    per_layer = _attn_params(cfg) + _mlp_params(cfg) + 2 * cfg.d_model
+    norms = 1 if cfg.parallel_block else 2
+    per_layer = _attn_params(cfg) + _mlp_params(cfg) + norms * cfg.d_model
     total = cfg.n_layers * per_layer
     total += cfg.n_linear_layers * (_linear_attn_params(cfg)
                                     - _attn_params(cfg))

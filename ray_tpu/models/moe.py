@@ -136,7 +136,8 @@ class MoEMLP(nn.Module):
         scored all E and kept K a token; the pairs that fall on a held expert
         are computed here under their gates (normalised over all K), the
         others are another chip's and add nothing. Every shared expert is
-        whole on every chip. Nothing stands in for the absent chips: what
+        whole on every chip (one concatenated bank: its product is their sum,
+        or with `shared_average` their mean). Nothing stands in for the absent chips: what
         this returns is this chip's part of the layer's sum."""
         cfg = self.cfg
         D = xf.shape[-1]
@@ -170,9 +171,13 @@ class MoEMLP(nn.Module):
                 dense = lambda n, name: nn.Dense(
                     n, use_bias=False, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, kernel_init=init, name=name)
-                y = y + dense(D, "shared_down")(
+                shared = dense(D, "shared_down")(
                     nn.silu(dense(Fs, "shared_gate")(x))
                     * dense(Fs, "shared_up")(x))
+                if cfg.shared_average:
+                    # the concatenated bank's product is the experts' SUM
+                    shared = shared * (1.0 / cfg.n_shared_experts)
+                y = y + shared
         return y
 
 

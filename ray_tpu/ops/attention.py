@@ -31,8 +31,9 @@ def rope_table(max_len: int, head_dim: int, theta: float = 10000.0):
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-               sections=None):
-    """Rotate-half RoPE. x: [B, T, H, D], positions: [B, T] int32, or
+               sections=None, interleaved: bool = False):
+    """Rotate-half RoPE (`interleaved`: frequency i turns the pair
+    (2i, 2i + 1) of a head, GPT-J's layout, and not (i, i + D/2)). x: [B, T, H, D], positions: [B, T] int32, or
     [3, B, T] with `sections` (s_t, s_h, s_w) summing to D/2: of the D/2
     frequencies the first s_t turn by positions[0] (temporal), the next s_h
     by positions[1] (height), the rest by positions[2] (width). Text has all
@@ -54,6 +55,11 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
         angles = positions[..., None].astype(jnp.float32) * freqs
     sin = jnp.sin(angles)[:, :, None, :]  # [B, T, 1, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -111,11 +117,13 @@ def decode_attention(
     v_cache: jax.Array,  # [B, Smax, Kh, D]
     lengths: jax.Array,  # [B] int32 — tokens in cache BEFORE this chunk
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Decode/chunked-prefill attention against a static-shape cache.
 
     Query j sits at absolute position lengths+j and attends cache slots
-    ≤ that position. The whole cache is read and invalid slots masked — on
+    ≤ that position (with `window`, the last `window` of them, its own
+    included). The whole cache is read and invalid slots masked — on
     TPU a masked dense read of a static cache beats dynamic-shape gathers,
     which would force recompilation per step.
     """
@@ -129,6 +137,8 @@ def decode_attention(
     s = s * scale
     pos = lengths[:, None, None] + jnp.arange(t)[None, :, None]    # [B, T, 1]
     valid = jnp.arange(smax)[None, None, :] <= pos                 # [B, T, Smax]
+    if window is not None:
+        valid &= jnp.arange(smax)[None, None, :] > pos - window
     s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", p.astype(v_cache.dtype), v_cache)
@@ -142,11 +152,13 @@ def blockwise_prefill_attention(
     lengths: jax.Array,  # [B] int32: tokens in the row BEFORE this chunk
     scale: Optional[float] = None,
     key_block: int = 512,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """`decode_attention` for rows too long to score at once: the same
     absolute-position causal mask, by key blocks under an online softmax, as
     far as the last query reaches (a loop with a dynamic bound, so a short
-    row in a long table pays for its own keys only). Nothing of size
+    row in a long table pays for its own keys only; with `window` it starts
+    at the block of the first key the first query sees). Nothing of size
     [T, heads, Smax] is held."""
     b, t, h, d = q.shape
     smax, kh = k_cache.shape[1], k_cache.shape[2]
@@ -168,7 +180,10 @@ def blockwise_prefill_attention(
         v = jax.lax.dynamic_slice_in_dim(v_cache, i * kb, kb, 1)
         s = jnp.einsum("btkgd,bskd->bkgts", qg, k,
                        preferred_element_type=jnp.float32) * scale
-        keep = ((i * kb + col)[None, None] <= pos[:, :, None])[:, None, None]
+        keep = (i * kb + col)[None, None] <= pos[:, :, None]
+        if window is not None:
+            keep &= (i * kb + col)[None, None] > pos[:, :, None] - window
+        keep = keep[:, None, None]
         s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
@@ -182,6 +197,8 @@ def blockwise_prefill_attention(
     init = (jnp.full((b, kh, g, t), NEG_INF, jnp.float32),
             jnp.zeros((b, kh, g, t), jnp.float32),
             jnp.zeros((b, kh, g, t, d), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+    first = (0 if window is None
+             else jnp.maximum(jnp.min(pos) - window + 1, 0) // kb)
+    _, l, acc = jax.lax.fori_loop(first, n_blocks, block, init)
     out = acc / jnp.maximum(l, 1e-30)[..., None]                  # [B,Kh,G,T,D]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d).astype(q.dtype)

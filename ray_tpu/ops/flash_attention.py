@@ -390,8 +390,15 @@ def continuation_blocks(t: int, g: int, dtype) -> Optional[int]:
     return None if t % block_q or block_q % tile else block_q
 
 
+def _window_first_block(first, window: int, block_kv: int):
+    """The key block that holds the first key a query at position `first`
+    sees through a window of `window` keys, its own included."""
+    return jnp.maximum(first - window + 1, 0) // block_kv
+
+
 def _continuation_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, block_q, block_kv):
+                         m_scr, l_scr, acc_scr, *, scale, block_q, block_kv,
+                         window=None):
     """Step (b, kh, iq, ik): fold key block ik into the accumulators of query
     block iq, for the G query heads of kv head kh at once. q_ref, o_ref:
     [G, block_q, D]; k_ref, v_ref: [block_kv, D]; scratch: running maximum
@@ -404,14 +411,24 @@ def _continuation_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
     copied); the one or two between are masked, and their values past the
     last query zeroed: whatever lies past the chunk's end in the row, or past
     the row's end in the operand, is read as nothing, not as 0 x something.
+
+    With `window` (a sliding layer: query t sees key s iff t - window < s <= t)
+    the grid's key steps start at the block of the first key the block's first
+    query sees (`_window_first_block`), a block is whole only if the block's
+    LAST query still sees its first key, and the one or two on the window's
+    trailing edge are masked as the diagonal's are, their values before the
+    first query's window zeroed.
     """
     b_, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     g, _, d = q_ref.shape
     rows = g * block_q
     first = start_ref[b_] + iq * block_q      # the block's first query's position
     last = first + block_q - 1
+    step = ik
+    if window is not None:
+        ik = ik + _window_first_block(first, window, block_kv)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -426,15 +443,27 @@ def _continuation_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
             at = lambda shape, dim: jax.lax.broadcasted_iota(jnp.int32, shape, dim)
             three = (g, block_q, block_kv)
             seen = (ik * block_kv + at(three, 2) <= first + at(three, 1))
+            if window is not None:
+                seen &= (ik * block_kv + at(three, 2)
+                         > first + at(three, 1) - window)
             s = jnp.where(seen.reshape(rows, block_kv), s, -jnp.inf)
-            v = jnp.where(ik * block_kv + at(v.shape, 0) <= last, v,
-                          jnp.zeros_like(v))
+            col = ik * block_kv + at(v.shape, 0)
+            in_reach = col <= last
+            if window is not None:
+                in_reach &= col > first - window
+            v = jnp.where(in_reach, v, jnp.zeros_like(v))
         # key 0 is seen by every query, so from the first block on the
         # maximum is finite and exp() NaN-free
         m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
+        m_safe = m_new
+        if window is not None:
+            # a late query of the block may see no key of the window's first
+            # block: its maximum stays -inf there, and exp() must not see
+            # -inf - -inf
+            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
         l_scr[...] = jnp.broadcast_to(
             alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -443,6 +472,8 @@ def _continuation_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32)
 
     whole = (ik + 1) * block_kv - 1 <= first
+    if window is not None:
+        whole &= ik * block_kv > last - window
     pl.when(whole)(lambda: fold(False))
     pl.when(jnp.logical_not(whole) & (ik * block_kv <= last))(
         lambda: fold(True))
@@ -461,6 +492,7 @@ def flash_continuation(
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention of a prefill chunk that starts at position `start` of its
     row over the row's keys up to its own (`decode_attention`'s mask: query j
@@ -472,7 +504,13 @@ def flash_continuation(
     scored once (scores, running maximum and sum and the weighted values in
     one step: bf16 products, f32 accumulation and softmax, the weights cast
     to the values' type), for all query heads of its kv head. The chunk has
-    to tile (`continuation_blocks`)."""
+    to tile (`continuation_blocks`).
+
+    `window` (a sliding layer): query j sees the last `window` keys up to its
+    own. The grid along the keys then starts, for each query block, at the
+    block that holds the first key its first query sees, so a block wholly
+    before the window gets no copy and no arithmetic and the operand may hold
+    anything there; the kernel's name is `flash_continuation_window`."""
     b, t, h, d = q.shape
     kh = k.shape[1]
     k, v = k.reshape(b, kh, -1, d), v.reshape(b, kh, -1, d)
@@ -485,10 +523,16 @@ def flash_continuation(
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     start = start.astype(jnp.int32)
     n_kv = (jnp.max(start) + t + block_kv - 1) // block_kv
+    if window is not None:
+        # no query block's keys span more blocks than this
+        n_kv = jnp.minimum(n_kv, (window + block_q - 2) // block_kv + 2)
 
     def keys_of(b_, kh_, iq, ik, start):
         # past the last block the query block reaches: that block again
         reach = (start[b_] + (iq + 1) * block_q - 1) // block_kv
+        if window is not None:
+            ik = ik + _window_first_block(start[b_] + iq * block_q, window,
+                                          block_kv)
         return (b_, kh_, jnp.minimum(ik, reach), 0)
 
     heads = pl.BlockSpec((None, None, g, block_q, d),
@@ -496,7 +540,8 @@ def flash_continuation(
     keys = pl.BlockSpec((None, None, block_kv, d), keys_of)
     out = pl.pallas_call(
         functools.partial(_continuation_kernel, scale=scale, block_q=block_q,
-                          block_kv=block_kv),
+                          block_kv=block_kv,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, kh, t // block_q, n_kv),
@@ -508,6 +553,7 @@ def flash_continuation(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, t, d), q.dtype),
         interpret=interpret,
-        name="flash_continuation",
+        name=("flash_continuation" if window is None
+              else "flash_continuation_window"),
     )(start, q.reshape(b, t, kh, g, d).transpose(0, 2, 3, 1, 4), k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)

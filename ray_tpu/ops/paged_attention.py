@@ -83,7 +83,7 @@ def _held(length, room: int):
 
 
 def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
-                   *refs, scale, page_size, ppb):
+                   *refs, scale, page_size, ppb, window=None):
     """Step s of the grid: fold block blk[s] of row row[s], `ppb` pages, into
     the row's accumulators, for all kv heads at once. The steps are the
     blocks the rows hold keys in, a row's in order (`_blocks_in_use`).
@@ -97,6 +97,10 @@ def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
     A page the row has no key on is whatever its operand held last, a real
     page of the layer; its columns are masked, and 0 x a finite value adds
     nothing.
+
+    With `window` (a sliding layer) the row's walk starts at the block of
+    its first visible key, `held - window`, and that block's columns before
+    it are masked as the last block's past the row's end are.
     """
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
@@ -104,8 +108,9 @@ def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
     i = blk_ref[s_]
     block = ppb * page_size
     seq_len = _held(len_ref[row_ref[s_]], tbl_ref.shape[1] * page_size)
+    lo = 0 if window is None else jnp.maximum(seq_len - window, 0)
 
-    @pl.when(i == 0)
+    @pl.when(i == lo // block)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -118,7 +123,10 @@ def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale
     cols = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(cols < seq_len, s, -jnp.inf)
+    seen = cols < seq_len
+    if window is not None:
+        seen &= cols >= lo
+    s = jnp.where(seen, s, -jnp.inf)
     m_prev, l_prev = m_scr[:, :, :1], l_scr[:, :, :1]
     # every block walked holds a key, so m_new is finite and exp() NaN-free
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -138,17 +146,26 @@ def _decode_kernel(row_ref, blk_ref, tbl_ref, len_ref, layer_ref, q_ref,
         o_ref[0] = (acc / l_new).astype(o_ref.dtype)
 
 
-def _blocks_in_use(lengths, room: int, block: int, n_blocks: int):
+def _blocks_in_use(lengths, room: int, block: int, n_blocks: int,
+                   window: Optional[int] = None):
     """The walk of `paged_decode`: (how many steps [1], the row [S] and the
     block within the row [S] of every step), S = B * n_blocks, the steps
     past the count never run. Row after row, of each the blocks of `block`
-    keys it holds keys in. Small arrays and no gather: a row's first step is
-    found by comparing and summing."""
+    keys it holds keys in (with `window`: visible keys, from the block of
+    key `held - window` on). Small arrays and no gather: a row's first step
+    is found by comparing and summing."""
     rows = lengths.shape[0]
-    ends = jnp.cumsum(-(-_held(lengths, room) // block))               # [B]
+    held = _held(lengths, room)
+    if window is None:
+        ends = jnp.cumsum(-(-held // block))                           # [B]
+    else:
+        low = jnp.maximum(held - window, 0) // block   # a row's first block
+        ends = jnp.cumsum(-(-held // block) - low)
     steps = jnp.arange(rows * n_blocks, dtype=jnp.int32)
     row = jnp.minimum(jnp.sum(steps[:, None] >= ends[None], axis=1), rows - 1)
     first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    if window is not None:
+        first = first - low
     first = jnp.sum(jnp.where(row[:, None] == jnp.arange(rows)[None],
                               first[None], 0), axis=1)
     return ends[-1:].astype(jnp.int32), row.astype(jnp.int32), steps - first
@@ -164,9 +181,12 @@ def paged_attention(
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Paged decode attention over layer `layer` of the pool; returns
-    [B, H, D].
+    [B, H, D]. With `window` a row attends to its last `window` tokens, and
+    the walk neither copies nor scores a block that lies wholly before them
+    (the kernel's name is then `paged_decode_window`).
 
     The pools go in whole, as the cache holds them: a `pallas_call` operand
     is a buffer of its own, so `k_pages[layer]` handed in would be
@@ -187,8 +207,9 @@ def paged_attention(
     ppb = pages_per_block(kh * page_size * lanes * k_pages.dtype.itemsize,
                           max_pages)
     n_blocks = -(-max_pages // ppb)
-    count, row, blk = _blocks_in_use(lengths, max_pages * page_size,
-                                     ppb * page_size, n_blocks)
+    count, row, blk = _blocks_in_use(
+        lengths, max_pages * page_size, ppb * page_size, n_blocks,
+        **({} if window is None else {"window": window}))
 
     def row_of(s, row, blk, tbl, lens, lyr):
         return (row[s], 0, 0, 0)
@@ -214,7 +235,8 @@ def paged_attention(
                           functools.partial(page_of, j)) for j in range(ppb)]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=page_size,
-                          ppb=ppb),
+                          ppb=ppb,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(count[0],),
@@ -226,7 +248,7 @@ def paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "paged_decode_window",
     )(row, blk, block_tables, lengths,
       jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(b, kh, g, d),
       *([k_pages] * ppb), *([v_pages] * ppb))
@@ -241,8 +263,8 @@ def _gather_row_pages(pool, layer, block_tables):
 
 
 def paged_attention_reference(q, k_pages, v_pages, layer, block_tables,
-                              lengths, *,
-                              scale: Optional[float] = None) -> jax.Array:
+                              lengths, *, scale: Optional[float] = None,
+                              window: Optional[int] = None) -> jax.Array:
     """XLA equivalent of `paged_attention`, same arguments (gather pages →
     masked attention): numerics oracle for the kernel and the CPU-backend
     fallback."""
@@ -257,6 +279,9 @@ def paged_attention_reference(q, k_pages, v_pages, layer, block_tables,
     qg = q.reshape(b, kh, g, d).astype(jnp.float32)
     s = jnp.einsum("bkgd,bksd->bkgs", qg, k_seq.astype(jnp.float32)) * scale
     mask = jnp.arange(s_max)[None, None, None, :] < lengths[:, None, None, None]
+    if window is not None:
+        mask &= (jnp.arange(s_max)[None, None, None, :]
+                 >= lengths[:, None, None, None] - window)
     s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bksd->bkgd", p, v_seq.astype(jnp.float32))
@@ -310,6 +335,23 @@ class PagedKVCache(flax.struct.PyTreeNode):
     tokens, and all that the programs multiplied. All None for every
     other model: their tree and programs are what they were.
 
+    A model with sliding-window layers holds two kinds of PAGE in this one
+    tree. `k_pages` / `v_pages` and `block_tables` are the FULL pool: the
+    layers that see every key (L counts those), a page for every token of a
+    row. `win_k_pages` / `win_v_pages` [Lw, Kh, Pw, page, D] are the WINDOW
+    pool of the Lw sliding layers, with a table of its own, `win_tables`
+    [B, max_pages], indexed by a position's page as the full table is: a row
+    holds window pages only for positions a later query of it can still see,
+    an entry before them names whatever page it named last (the kernels
+    neither copy nor score a block that lies wholly before the window, and
+    mask what lies before it inside the first block), an entry past what the
+    row has been given is 0, the placeholder. `window_view()` is the same
+    tree with the window pool and table in the full pool's places, so that
+    the functions below serve a sliding layer unchanged; `merge_window` puts
+    a view's pools back. `pools()` lists the full pool only: a window page is
+    not carried by what moves pages, and the engine refuses those paths for
+    such a model. All None for every other model.
+
     block_tables: [B, max_pages]; lengths: [B]. Rows whose slot is free have
     length 0 and table entries 0. `page_axis` is where a pool's page index
     sits: the functions under "Moving whole pages" below are its only
@@ -326,6 +368,17 @@ class PagedKVCache(flax.struct.PyTreeNode):
     snap_state: Optional[tuple] = None
     snap_conv: Optional[tuple] = None
     held_pairs: Optional[jax.Array] = None
+    win_k_pages: Optional[jax.Array] = None
+    win_v_pages: Optional[jax.Array] = None
+    win_tables: Optional[jax.Array] = None
+
+    def window_view(self) -> "PagedKVCache":
+        """The sliding layers' pool and table where the full layers' are."""
+        return self.replace(k_pages=self.win_k_pages, v_pages=self.win_v_pages,
+                            block_tables=self.win_tables)
+
+    def merge_window(self, view: "PagedKVCache") -> "PagedKVCache":
+        return self.replace(win_k_pages=view.k_pages, win_v_pages=view.v_pages)
 
     @property
     def page_axis(self) -> int:
@@ -359,10 +412,13 @@ class PagedKVCache(flax.struct.PyTreeNode):
     def init(n_layers: int, n_kv_heads: int, head_dim: int, num_pages: int,
              page_size: int, batch_slots: int, max_pages_per_seq: int,
              dtype=jnp.bfloat16, index_dim: int = 0,
-             linear: Optional[dict] = None) -> "PagedKVCache":
-        """`n_layers` counts the layers with keys and values. `linear`: the
-        linear layers' sizes (`layers`, `heads`, `key_dim`, `value_dim`,
-        `conv` inputs carried, `channels`) and `snapshots`, the pool's size."""
+             linear: Optional[dict] = None,
+             window: Optional[dict] = None) -> "PagedKVCache":
+        """`n_layers` counts the layers with keys and values (with `window`,
+        those that see every key). `linear`: the linear layers' sizes
+        (`layers`, `heads`, `key_dim`, `value_dim`, `conv` inputs carried,
+        `channels`) and `snapshots`, the pool's size. `window`: the sliding
+        layers' pool (`layers`, `num_pages`)."""
         tables = dict(
             block_tables=jnp.zeros((batch_slots, max_pages_per_seq), jnp.int32),
             lengths=jnp.zeros((batch_slots,), jnp.int32))
@@ -384,6 +440,15 @@ class PagedKVCache(flax.struct.PyTreeNode):
                 conv=per(batch_slots, *c_shape, dt=dtype),
                 snap_state=per(linear["snapshots"], *s_shape, dt=jnp.float32),
                 snap_conv=per(linear["snapshots"], *c_shape, dt=dtype),
+                held_pairs=jnp.zeros((2,), jnp.int32))
+        if window:
+            shape = (window["layers"], n_kv_heads, window["num_pages"],
+                     page_size, head_dim)
+            tables.update(
+                win_k_pages=jnp.zeros(shape, dtype),
+                win_v_pages=jnp.zeros(shape, dtype),
+                win_tables=jnp.zeros((batch_slots, max_pages_per_seq),
+                                     jnp.int32),
                 held_pairs=jnp.zeros((2,), jnp.int32))
         shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
@@ -667,7 +732,8 @@ def _copy_pages_kernel(tbl_ref, layer_ref, k_ref, v_ref, ko_ref, vo_ref):
 
 
 def row_pages(cache: PagedKVCache, layer_idx: int,
-              interpret: Optional[bool] = None):
+              interpret: Optional[bool] = None, first=None,
+              n_pages: Optional[int] = None):
     """Layer `layer_idx`'s keys and values of every row's pages, contiguous
     by position and HEAD-MAJOR as the pool lies ([B, Kh, mp, page, D] each,
     what `flash_continuation` takes): token s of a row's (mp, page) is
@@ -684,6 +750,12 @@ def row_pages(cache: PagedKVCache, layer_idx: int,
     that consumes the rows, and back for the result (four copies of all
     layers' pool a continuation chunk: PR 31, HLO compiled for the v5e).
     `interpret` None: the kernel on the TPU, XLA's gather elsewhere.
+
+    `first` [B] and `n_pages` (a sliding layer's continuation): the kernel
+    (`paged_row_pages_window`) copies only the `n_pages` pages from table
+    entry `first` on, to the same places of the result; what the result holds
+    elsewhere is not written and must not be read. XLA's gather takes every
+    entry as ever.
     """
     tb = cache.block_tables            # [B, mp]
     b, mp = tb.shape
@@ -691,6 +763,26 @@ def row_pages(cache: PagedKVCache, layer_idx: int,
     if interpret is None and jax.default_backend() != "tpu":
         k, v = (_gather_row_pages(pool, layer_idx, tb)
                 for pool in (cache.k_pages, cache.v_pages))
+    elif first is not None:
+        at = lambda b_, p_, first: jnp.minimum(first[b_] + p_, mp - 1)
+        page = pl.BlockSpec(
+            (None, kh, 1, ps, d),
+            lambda b_, p_, tbl, lyr, first: (
+                lyr[0], 0, tbl[b_, at(b_, p_, first)], 0, 0))
+        out = pl.BlockSpec(
+            (None, kh, 1, ps, d),
+            lambda b_, p_, tbl, lyr, first: (b_, 0, at(b_, p_, first), 0, 0))
+        shape = jax.ShapeDtypeStruct((b, kh, mp, ps, d), cache.k_pages.dtype)
+        k, v = pl.pallas_call(
+            lambda tbl, lyr, first, *refs: _copy_pages_kernel(tbl, lyr, *refs),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(b, min(n_pages, mp)),
+                in_specs=[page, page], out_specs=[out, out]),
+            out_shape=[shape, shape],
+            interpret=bool(interpret),
+            name="paged_row_pages_window",
+        )(tb, jnp.asarray(layer_idx, jnp.int32).reshape(1),
+          first.astype(jnp.int32), cache.k_pages, cache.v_pages)
     else:
         page = pl.BlockSpec(
             (None, kh, 1, ps, d),

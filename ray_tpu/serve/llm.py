@@ -69,6 +69,13 @@ WATCHED_PHASES = ("decode_sync", "prefill_first_token")
 # boundary (inside prefill_dispatch), and out of one at admission (inside
 # admit_allocate).
 STATE_PHASES = ("state_save", "state_restore")
+# A model with sliding-window layers only: the hand-back of window pages a row
+# has moved past and the taking of its next ones, before a prefill chunk
+# (inside prefill_dispatch) and before a decode chunk (inside decode_dispatch).
+# Host bookkeeping and one small table write; nothing is read from the device.
+WINDOW_PHASES = ("window_release",)
+# entries of the device's window table one write carries
+WINDOW_WRITE = 64
 
 # Demotion of evicted prefix pages. One gather program whatever the pass
 # size: its index vector always has DEMOTE_GROUP entries, padded with the
@@ -135,6 +142,11 @@ class LLMConfig:
     # waste less HBM per request)
     page_size: int = 64
     num_pages: Optional[int] = None  # default: full (B·ceil(Smax/page)) + 1
+    # a model with sliding-window layers: the pages of ITS pool (their keys
+    # and values lie apart from the full layers', a row holds a window's
+    # worth at most). Default: every slot's budget, and as much again to
+    # cache finished prompts' tails.
+    num_window_pages: Optional[int] = None
     # Chunked prefill (ref: vLLM chunked prefill / the reference's
     # prefill-decode disaggregation, python/ray/llm/_internal/serve/
     # serving_patterns/prefill_decode/pd_server.py): prompts are fed through
@@ -271,9 +283,13 @@ class LLMServer:
         self.model = Llama(self.model_cfg)
         # recurrent state a slot beside the pages (linear-attention layers)
         self._stateful = self.model_cfg.n_linear_layers > 0
+        # sliding-window layers: a second pool of pages, with its own table
+        self._windowed = self.model_cfg.n_window_layers > 0
         self._phases = PhaseTotals(
             "engine", LOOP_PHASES + NESTED_PHASES
-            + (STATE_PHASES if self._stateful else ()), watch=WATCHED_PHASES)
+            + (STATE_PHASES if self._stateful else ())
+            + (WINDOW_PHASES if self._windowed else ()),
+            watch=WATCHED_PHASES)
         # the last records the loop's watchdog filed (stats()["stalls"])
         self._stalls: "collections.deque[dict]" = collections.deque(maxlen=8)
         B = cfg.max_batch_slots
@@ -331,6 +347,12 @@ class LLMServer:
                 "a model with linear-attention layers (full_attn_every > 0) "
                 "needs paged=True: a slot's recurrent state and its "
                 "snapshots live in the paged cache")
+        if self._windowed and not cfg.paged:
+            raise ValueError(
+                "a model with sliding-window layers (layer_types) needs "
+                "paged=True: their keys and values live in the paged "
+                "cache's window pool (so it cannot speculate either: "
+                "speculate needs paged=False)")
         if cfg.paged:
             from ray_tpu.ops.paged_attention import PagedKVCache
             from ray_tpu.serve.radix_cache import PageManager
@@ -351,7 +373,10 @@ class LLMServer:
                 # pages above it, and pages with no snapshot below them can
                 # serve no later prompt (radix_cache.py): extracting them
                 # would fill the stash, and its disk, with bytes nothing reads
-                if not self._stateful:
+                # nor does one with a window pool: a sliding layer's pages are
+                # not carried to the stash, and a chain restored without them
+                # could not be resumed from
+                if not self._stateful and not self._windowed:
                     hooks = dict(demote_cb=self._demote_page,
                                  demote_flush_cb=self._demote_pass,
                                  restore_cb=self._restore_page,
@@ -363,10 +388,21 @@ class LLMServer:
             # one a prompt is cheap)
             snapshots = (SNAPSHOTS_PER_SLOT * B
                          if self._stateful and cfg.prefix_cache else 0)
+            window = {}
+            if self._windowed:
+                # what a row holds at most: the pages under a window's keys
+                # and under what one program adds to them (a prefill chunk,
+                # or the decode steps dispatched and not yet read)
+                ahead = max(cfg.prefill_chunk, 3 * cfg.decode_chunk + 2)
+                budget = (mc.sliding_window + ahead - 2) // cfg.page_size + 2
+                window = dict(
+                    window=mc.sliding_window, window_budget=budget,
+                    window_pages=(cfg.num_window_pages
+                                  or 2 * B * min(budget, max_pages) + 1))
             self.page_mgr = PageManager(
                 num_pages, cfg.page_size, B, max_pages,
                 prefix_cache=cfg.prefix_cache, phases=self._phases,
-                snapshots=snapshots, **hooks)
+                snapshots=snapshots, **window, **hooks)
             # the cache follows the model's schema: a model with an indexer
             # gets the third per-page pool (and the token-major layout), one
             # with linear layers pools for its full layers only, a state a
@@ -379,11 +415,15 @@ class LLMServer:
                     conv=mc.linear_conv - 1, snapshots=max(snapshots, 1),
                     channels=mc.linear_heads * (2 * mc.linear_key_dim
                                                 + mc.linear_value_dim))
+            pools = {"linear": linear} if linear else {}
+            if self._windowed:
+                pools["window"] = dict(layers=mc.n_window_layers,
+                                       num_pages=window["window_pages"])
             self.cache = PagedKVCache.init(
-                mc.n_layers - mc.n_linear_layers, mc.n_kv_heads, mc.head_dim,
+                mc.n_layers - mc.n_linear_layers - mc.n_window_layers,
+                mc.n_kv_heads, mc.head_dim,
                 num_pages, cfg.page_size, B, max_pages, dtype=mc.dtype,
-                index_dim=mc.index_dim if mc.index_topk else 0,
-                **({"linear": linear} if linear else {}))
+                index_dim=mc.index_dim if mc.index_topk else 0, **pools)
         else:
             self.page_mgr = None
             self._kv_stash = None
@@ -458,6 +498,18 @@ class LLMServer:
                            "decode_layer_calls": 0,
                            "decode_experts_touched": 0}
         self._state_stats = {"snapshot_copies": 0, "restore_copies": 0}
+        # sliding layers: the (query, key) pairs the real queries of
+        # continuation chunks see in ONE of them, and the keys decode rows
+        # see in one of them and in one full layer, summed over rows and steps
+        self._window_stats = {"window_query_keys": 0, "decode_window_keys": 0,
+                              "decode_full_keys": 0, "window_table_writes": 0}
+        # the last decode syncs and continuation chunks, (time.monotonic(),
+        # what the sync or chunk added to the sliding layers' count, to the
+        # full layers'): a reader that times a slice of a run needs the
+        # slice's own counts (as `_moe_recent`)
+        self._window_recent = {
+            "recent_decode_syncs": collections.deque(maxlen=4096),
+            "recent_continuations": collections.deque(maxlen=4096)}
         from ray_tpu.models.llama import _n_moe_layers
         from ray_tpu.models.moe import grouped_product
         mc = self.model_cfg
@@ -536,6 +588,7 @@ class LLMServer:
         # hands the count back with its tokens (the same sync)
         count_touched = self._moe_grouped
         stateful = self._stateful
+        windowed = self._windowed
 
         def sample(logits, key, temps, top_ps, top_ks, want_logp):
             """Per-request greedy / temperature / top-k / top-p (nucleus)
@@ -597,6 +650,9 @@ class LLMServer:
             if stateful:
                 return prefill_stateful(params, cache, row_view, tokens, slot,
                                         start_len, true_end, chunk_local)
+            if windowed:
+                return prefill_windowed(params, cache, row_view, tokens, slot,
+                                        start_len, true_end, chunk_local)
             logits, new_row = model.apply(params, tokens, cache=row_view,
                                           paged_chunk_local=chunk_local)
             new_cache = cache.with_pools(new_row.pools()).replace(
@@ -624,6 +680,22 @@ class LLMServer:
                 lengths=cache.lengths.at[slot].set(true_end),
                 state=put(cache.state, new_row.state),
                 conv=put(cache.conv, new_row.conv),
+                held_pairs=cache.held_pairs + sown(seen, "held_pairs"))
+            return new_cache, logits[0, true_end - start_len - 1]
+
+        def prefill_windowed(params, cache, row_view, tokens, slot, start_len,
+                             true_end, chunk_local):
+            """`prefill_paged` for a model with sliding layers: the row view
+            has the slot's row of the window table too, both pools come back,
+            and the bank's count of the pairs it held is kept."""
+            row_view = row_view.replace(win_tables=jax.lax.dynamic_slice_in_dim(
+                cache.win_tables, slot, 1, 0))
+            (logits, new_row), seen = model.apply(
+                params, tokens, cache=row_view, paged_chunk_local=chunk_local,
+                n_valid=(true_end - start_len)[None], mutable=["moe_stats"])
+            new_cache = cache.with_pools(new_row.pools()).merge_window(
+                new_row.window_view()).replace(
+                lengths=cache.lengths.at[slot].set(true_end),
                 held_pairs=cache.held_pairs + sown(seen, "held_pairs"))
             return new_cache, logits[0, true_end - start_len - 1]
 
@@ -695,9 +767,10 @@ class LLMServer:
                 cache, last, active, budget, room, emitted, key = carry
                 key, sub = jax.random.split(key)
                 touched = ()
-                if stateful:
+                if stateful or windowed:
                     # a slot that does not decode this step (idle, finished,
-                    # or still prefilling) keeps its recurrent state
+                    # or still prefilling) keeps its recurrent state, and its
+                    # row's pairs are not counted as a real token's
                     (logits, new_cache), seen = model.apply(
                         params, last[:, None], cache=cache,
                         n_valid=active.astype(jnp.int32),
@@ -848,6 +921,18 @@ class LLMServer:
                     tables.at[ints[0]].set(ints[2:]),
                     lengths.at[ints[0]].set(ints[1])),
                 donate_argnums=(0, 1))
+            if windowed:
+                # a slot's whole row of the window table (at admission and
+                # release), and single entries as a row moves on: [slot, *row]
+                # and [slots, page indices, page ids], a slot past the last
+                # where the write carries fewer than WINDOW_WRITE entries
+                self._set_win_row_fn = jax.jit(
+                    lambda tables, ints: tables.at[ints[0]].set(ints[1:]),
+                    donate_argnums=(0,))
+                self._set_win_entries_fn = jax.jit(
+                    lambda tables, ints: tables.at[ints[0], ints[1]].set(
+                        ints[2], mode="drop"), donate_argnums=(0,))
+                self._write_window_entries([])
             self._write_table_row(0, 0, 0)
         if stateful:
             from ray_tpu.ops.paged_attention import copy_slot_state
@@ -1119,6 +1204,11 @@ class LLMServer:
                     f"{min(mgr.num_pages - 1, mgr.max_pages_per_seq)} "
                     f"per sequence (num_pages={mgr.num_pages}, "
                     f"page_size={mgr.page_size})")
+            if mgr.win_num_pages and (
+                    min(need, mgr.window_budget) > mgr.win_num_pages - 1):
+                raise ValueError(
+                    f"request needs {min(need, mgr.window_budget)} window "
+                    f"pages but that pool holds {mgr.win_num_pages - 1}")
 
         def fits():
             if mgr is None:
@@ -1193,6 +1283,8 @@ class LLMServer:
         bucket = (min(self._bucket(n), self.config.max_seq_len - start)
                   if final or n < self.config.prefill_chunk
                   else self.config.prefill_chunk)
+        if self._windowed:
+            self._window_advance([(job.slot_idx, start, start + n)])
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = job.prompt[start:start + n]
         # host values go up with the call itself
@@ -1219,6 +1311,14 @@ class LLMServer:
             st["continuation_chunks"] += 1
             st["continuation_reach_keys"] += start + n
             st["continuation_query_keys"] += n * start + n * (n + 1) // 2
+            if self._windowed:
+                # query j of the chunk sees min(start + j + 1, window) keys
+                w = self.model_cfg.sliding_window
+                ramp = min(n, max(0, w - start - 1))    # queries still short
+                seen = ramp * start + ramp * (ramp + 1) // 2 + (n - ramp) * w
+                self._window_stats["window_query_keys"] += seen
+                self._window_recent["recent_continuations"].append(
+                    (time.monotonic(), seen, n * start + n * (n + 1) // 2))
         self._count_moe(n, bucket)
         return last_logits if final else None
 
@@ -1474,6 +1574,37 @@ class LLMServer:
         tables, lengths = self._set_row_fn(
             self.cache.block_tables, self.cache.lengths, ints)
         self.cache = self.cache.replace(block_tables=tables, lengths=lengths)
+        if self._windowed:
+            ints = np.empty((1 + self.cache.win_tables.shape[1],), np.int32)
+            ints[0], ints[1:] = slot_idx, self.page_mgr.win_table_row(slot_idx)
+            self.cache = self.cache.replace(win_tables=self._set_win_row_fn(
+                self.cache.win_tables, ints))
+
+    def _write_window_entries(self, entries) -> None:
+        """New (slot, page index, page id) entries of the window table onto
+        the device, behind whatever is in flight."""
+        B = self.config.max_batch_slots
+        for i in range(0, max(len(entries), 1), WINDOW_WRITE):
+            part = np.asarray(entries[i:i + WINDOW_WRITE], np.int32)
+            ints = np.zeros((3, WINDOW_WRITE), np.int32)
+            ints[0] = B                       # past the last slot: dropped
+            ints[:, :len(part)] = part.reshape(-1, 3).T
+            self.cache = self.cache.replace(win_tables=self._set_win_entries_fn(
+                self.cache.win_tables, ints))
+            self._window_stats["window_table_writes"] += 1
+
+    def _window_advance(self, rows) -> None:
+        """Before a program is dispatched, for each (slot, t_min, upto) of
+        `rows`: the slot's first query in it sits at `t_min` or later and it
+        writes positions below `upto`. The window pages that no later query
+        of the slot sees go back, the ones it writes are taken, and their
+        table entries are sent in one write."""
+        with phase(self._phases, "window_release"):
+            new = [(slot, i, pid) for slot, t_min, upto in rows
+                   for i, pid in self.page_mgr.window_advance(
+                       slot, t_min, upto)]
+            if new:
+                self._write_window_entries(new)
 
     def _release_slot(self, i: int):
         """Return slot i to the pool; paged mode also frees its pages and
@@ -1625,6 +1756,15 @@ class LLMServer:
         t0 = time.perf_counter()
         slots = list(self._active.items())
         any_logp = any(s.want_logprobs for _, s in slots)
+        if self._windowed:
+            # of the steps dispatched for a slot the host has read
+            # len(generated) - 1 (the first token came from the prefill):
+            # the next query it has not seen the result of sits there, and
+            # this chunk writes no further than `ahead + n` past it
+            self._window_advance([
+                (i, s.prompt_len + max(len(s.generated), 1) - 1,
+                 s.prompt_len + len(s.generated) + s.ahead + n + 1)
+                for i, s in slots])
         if drafts is not None:
             # speculative tick: one [B, K+1] verify forward
             n = None
@@ -1683,12 +1823,24 @@ class LLMServer:
             sp["decode_ticks"] += 1
         emitted = 0
         finished = []
+        seen_before = (self._window_stats["decode_window_keys"],
+                       self._window_stats["decode_full_keys"])
         for i, slot in chunk.slots:
             slot.ahead -= n or 1
             if slot.done_event.is_set():    # the host ended it meanwhile
                 continue
             cnt = int(n_valid[i])
             self._count_sparse(slot.prompt_len + len(slot.generated), cnt)
+            if self._windowed and cnt > 0:
+                # step j's query sees first + j keys, its own included
+                first = slot.prompt_len + len(slot.generated)
+                w = self.model_cfg.sliding_window
+                short = max(0, min(cnt, w - first))     # steps under the window
+                ws = self._window_stats
+                ws["decode_full_keys"] += cnt * first + cnt * (cnt - 1) // 2
+                ws["decode_window_keys"] += (
+                    short * first + short * (short - 1) // 2
+                    + (cnt - short) * w)
             if drafts is not None and i in drafts:
                 # clip: a short draft's zero-padding can "accidentally"
                 # match argmax (still exact output) but must not count as
@@ -1704,6 +1856,11 @@ class LLMServer:
         self._note_sync(emitted, now - max(self._t_read, chunk.t_dispatch),
                         chunk=n)
         self._t_read = now
+        if self._windowed:
+            self._window_recent["recent_decode_syncs"].append((
+                time.monotonic(),
+                self._window_stats["decode_window_keys"] - seen_before[0],
+                self._window_stats["decode_full_keys"] - seen_before[1]))
         if n is None:    # one verify forward of K + 1 positions
             self._count_moe(emitted, B * (K + 1))
         else:
@@ -1941,12 +2098,12 @@ class LLMServer:
         if self.model_cfg.n_experts > 0:
             s["moe"] = dict(self._moe_stats,
                             recent_decode_syncs=list(self._moe_recent))
-        if self._stateful:
+        if (self._stateful or self._windowed) and "moe" in s:
             # pairs that fell on this chip's share of the experts, counted
             # on the device by the programs themselves (read here: a sync)
-            if "moe" in s:
-                held, computed = (int(x) for x in self.cache.held_pairs)
-                s["moe"].update(held_pairs=held, computed_rows=computed)
+            held, computed = (int(x) for x in self.cache.held_pairs)
+            s["moe"].update(held_pairs=held, computed_rows=computed)
+        if self._stateful:
             per_row = lambda xs: sum(int(x.nbytes) // x.shape[0] for x in xs)
             c = self.cache
             s["state"] = dict(
@@ -1955,6 +2112,23 @@ class LLMServer:
                                      * self.page_mgr.snapshots),
                 slot_state_bytes=(per_row(c.state + c.conv)
                                   * self.config.max_batch_slots))
+        if self._windowed:
+            c, mgr = self.cache, self.page_mgr
+            page_bytes = lambda pool: 2 * int(pool.nbytes) // pool.shape[2]
+            full, win = page_bytes(c.k_pages), page_bytes(c.win_k_pages)
+            live = mgr.window_stats()
+            s["window"] = dict(
+                live, **self._window_stats,
+                **{k: list(v) for k, v in self._window_recent.items()},
+                page_size=self.config.page_size,
+                window=self.model_cfg.sliding_window,
+                window_pool_bytes=2 * int(c.win_k_pages.nbytes),
+                full_pool_bytes=2 * int(c.k_pages.nbytes),
+                window_page_bytes=win, full_page_bytes=full,
+                live_bytes=(live["window_pages_live"] * win
+                            + live["full_pages_live"] * full),
+                # what the live rows would hold if every layer kept every key
+                live_bytes_one_pool=live["full_pages_live"] * (full + win))
         if self.config.speculate > 0:
             st = dict(self._spec_stats)
             st["accept_rate"] = round(

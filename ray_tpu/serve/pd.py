@@ -64,6 +64,12 @@ def _require_paged(server: LLMServer, who: str):
             "the recurrent state of linear-attention layers "
             "(LlamaConfig.full_attn_every); serve such a model on one "
             "colocated LLMServer")
+    if server.model_cfg.n_window_layers:
+        raise NotImplementedError(
+            f"{who}: the prefill→decode hand-off carries the full pool's "
+            "pages and not the window pool's of sliding-window layers "
+            "(LlamaConfig.layer_types); serve such a model on one "
+            "colocated LLMServer")
 
 
 class _ShipJob:

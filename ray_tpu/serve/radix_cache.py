@@ -45,6 +45,25 @@ used goes first; a request that resumed from one and
 saves a deeper one on the same path moves the older to the cold end, since
 the next turn of that conversation will match the deeper one.
 
+Two kinds of PAGE in the one manager. A model with sliding-window layers
+keeps their keys and values in a pool of its own (`window_pages` > 0), with
+its own free list, table a slot, refcounts and LRU; everything above, and
+`num_pages` / `free_pages`, describes the FULL pool, the one every token of a
+row takes a page of. A row holds window pages only for positions a later
+query of it can still see: `window_advance` gives back each page that has
+fallen wholly behind `t_min - window` (to the LRU if a tree node holds it,
+else to the free list) and takes the pages the row's next program writes.
+Admission reserves a BUDGET of window pages a row (`window_budget` at most,
+however long the row), a count and not pages, and admits only while the sum
+of what rows may still take fits what is free or evictable, so a row's next
+page is always there. A tree node may hold a window page beside its full one
+(`_Node.win`): a finished prompt's last `window` tokens' pages stay with
+their nodes. A match may be served at depth d only if the nodes of
+(d - window, d] all hold one (there is no exact way to rebuild a sliding
+layer's keys from the full layers'); else it is cut back to the deepest depth
+that is whole, or lost. Window pages are never demoted: a manager with a
+window pool takes no demotion hooks.
+
 It knows page and snapshot ids and token ids only. What a page holds on the device, and
 how pages are moved, is `ops/paged_attention.py`.
 """
@@ -75,7 +94,7 @@ class _Node:
     """One full page of tokens in the radix tree."""
 
     __slots__ = ("tokens", "parent", "children", "page", "handle", "hits",
-                 "snap")
+                 "snap", "win")
 
     def __init__(self, tokens, parent):
         self.tokens = tokens      # tuple of page_size token ids
@@ -85,6 +104,7 @@ class _Node:
         self.handle = None        # opaque demoted-KV handle (store segment)
         self.hits = 0
         self.snap = None          # snapshot id of the state after this page
+        self.win = None           # window-pool page id of the same tokens
 
     @property
     def resident_children(self) -> int:
@@ -137,7 +157,13 @@ class PageManager:
                  max_pages_per_seq: int, prefix_cache: bool = True,
                  demote_cb=None, restore_cb=None, drop_cb=None,
                  phases: PhaseTotals = None, demote_flush_cb=None,
-                 snapshots: int = 0):
+                 snapshots: int = 0, window_pages: int = 0, window: int = 0,
+                 window_budget: int = 0):
+        if window_pages and (demote_cb or restore_cb):
+            raise ValueError(
+                "a page manager with a window pool takes no demotion hooks: "
+                "a sliding layer's pages are not carried to the host stash "
+                "or back (an evicted page is discarded)")
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -175,6 +201,24 @@ class PageManager:
         self.snapshots_evicted = 0
         self.snapshot_hits = 0
         self.resume_gap_tokens = 0
+        # the window pool of a model with sliding layers (page 0 reserved)
+        self.window = window              # keys a sliding query sees
+        self.win_num_pages = window_pages
+        self.window_budget = window_budget
+        self.win_free = list(range(window_pages - 1, 0, -1))
+        # a slot's window table by page index: a page id, or None where the
+        # page was given back (or the row resumed past it)
+        self.win_tables = [[] for _ in range(batch_slots)]
+        self._win_budget = [0] * batch_slots   # pages the row may hold
+        self._win_held = [0] * batch_slots     # pages it holds
+        self._win_dead = [0] * batch_slots     # entries before this are gone
+        self._win_node_of = {}   # published window page -> its node
+        self._win_refs = {}      # published window page -> live borrowers
+        self._win_lru = collections.OrderedDict()   # refcount 0, oldest first
+        self.window_pages_released = 0
+        self.window_pages_evicted = 0
+        self.prefix_cut_by_window = 0
+        self.prefix_lost_to_window = 0
 
     # ------------------------------------------------------------- tree walk
     def _page_tuples(self, prompt_ids) -> list:
@@ -233,6 +277,10 @@ class PageManager:
         self._node_of.pop(pid, None)
         node.page = None
         self._drop_snapshot(node)
+        if node.win is not None:
+            # a window page is borrowed only beside its node's full page, so
+            # none is borrowed here
+            self._win_unpublish(node)
         self.evicted_pages += 1
         _count("radix_evicted_pages")
         if node.handle is None and self.demote_cb is not None:
@@ -311,15 +359,136 @@ class PageManager:
         """The chain a request for `prompt_ids` can start from, and how many
         matched pages it has to give up: the walk, less what would leave no
         token to prefill (the final chunk's logits come from running one),
-        and for a model with state cut back to the deepest live snapshot."""
+        and for a model with state cut back to the deepest live snapshot, for
+        one with a window pool to the deepest depth whose window is whole."""
         matched = self._walk(prompt_ids)
         while matched and len(matched) * self.page_size >= len(prompt_ids):
             matched.pop()
+        if self.win_num_pages:
+            deep = self._window_depth(matched)
+            return matched[:deep], len(matched) - deep
         if not self.snapshots:
             return matched, 0
         deep = max((i + 1 for i, n in enumerate(matched)
                     if n.snap is not None), default=0)
         return matched[:deep], len(matched) - deep
+
+    # ---------------------------------------------------------- window pool
+    def _window_first(self, position: int) -> int:
+        """The first page a query at `position` still sees in a sliding
+        layer."""
+        return max(0, position - self.window + 1) // self.page_size
+
+    def _window_depth(self, matched) -> int:
+        """The deepest d <= len(matched) at which a request may resume: the
+        nodes of (d - window, d] all hold a window page."""
+        best = run = 0
+        for i, node in enumerate(matched):
+            run = run + 1 if node.win is not None else 0
+            d = i + 1
+            if run >= d - self._window_first(d * self.page_size):
+                best = d
+        return best
+
+    def _win_owed(self) -> int:
+        """Window pages the live rows may still take under their budgets."""
+        return sum(self._win_budget) - sum(self._win_held)
+
+    def _win_budget_for(self, n_tokens: int) -> int:
+        return min(-(-n_tokens // self.page_size), self.window_budget)
+
+    def _win_fits(self, n_tokens: int, borrowed=()) -> bool:
+        """Whether a row of `n_tokens` that borrows the window pages of the
+        nodes `borrowed` can be promised its budget."""
+        if not self.win_num_pages:
+            return True
+        parked = sum(1 for n in borrowed if n.win in self._win_lru)
+        return (self._win_owed() + self._win_budget_for(n_tokens)
+                - len(borrowed)
+                <= len(self.win_free) + len(self._win_lru) - parked)
+
+    def _win_unpublish(self, node):
+        pid, node.win = node.win, None
+        self._win_node_of.pop(pid, None)
+        self._win_lru.pop(pid, None)
+        if not self._win_refs.pop(pid, 0):
+            self.win_free.append(pid)
+            self.window_pages_evicted += 1
+
+    def _win_take_page(self) -> int:
+        if not self.win_free:
+            if not self._win_lru:
+                raise MemoryError("window page pool exhausted")
+            self._win_unpublish(self._win_node_of[next(iter(self._win_lru))])
+        return self.win_free.pop()
+
+    def _win_give_back(self, pid: int):
+        """A row lets go of a window page: a published one parks in the LRU
+        when its last borrower has, a private one is free."""
+        if pid in self._win_node_of:
+            self._win_refs[pid] -= 1
+            if self._win_refs[pid] <= 0:
+                self._win_refs[pid] = 0
+                self._win_lru[pid] = True
+        else:
+            self.win_free.append(pid)
+
+    def _win_open(self, slot: int, n_tokens: int, nodes=(), first: int = 0):
+        """Start a slot's window table: `first` entries it will never see,
+        then the borrowed pages of `nodes`."""
+        for n in nodes:
+            self._win_refs[n.win] = self._win_refs.get(n.win, 0) + 1
+            self._win_lru.pop(n.win, None)
+        self.win_tables[slot] = [None] * first + [n.win for n in nodes]
+        self._win_budget[slot] = self._win_budget_for(n_tokens)
+        self._win_held[slot] = len(nodes)
+        self._win_dead[slot] = first
+
+    def window_advance(self, slot: int, t_min: int, upto: int) -> list:
+        """The slot's next program has its first query at position `t_min`
+        or later and writes positions below `upto`: give back every window
+        page that lies wholly before `t_min - window + 1` (no later query of
+        the row sees it; programs already dispatched run before whatever is
+        dispatched for the page's next owner), and take the pages up to
+        `upto`. Returns the new (page index, page id) entries, for the
+        device's table."""
+        table = self.win_tables[slot]
+        dead = min(self._window_first(t_min), len(table))
+        for i in range(self._win_dead[slot], dead):
+            if table[i] is not None:
+                self._win_give_back(table[i])
+                table[i] = None
+                self._win_held[slot] -= 1
+                self.window_pages_released += 1
+        self._win_dead[slot] = max(self._win_dead[slot], dead)
+        need = min(-(-upto // self.page_size), len(self.tables[slot]))
+        new = []
+        while len(table) < need:
+            if self._win_held[slot] >= self._win_budget[slot]:
+                raise MemoryError(
+                    f"slot {slot} needs more than its budget of "
+                    f"{self._win_budget[slot]} window pages")
+            pid = self._win_take_page()
+            new.append((len(table), pid))
+            table.append(pid)
+            self._win_held[slot] += 1
+        return new
+
+    def win_table_row(self, slot: int):
+        row = [p or 0 for p in self.win_tables[slot]]
+        return row + [0] * (self.max_pages_per_seq - len(row))
+
+    def window_stats(self) -> dict:
+        """The window pool's tallies and what the live rows hold now."""
+        return {"window_pages_released": self.window_pages_released,
+                "window_pages_evicted": self.window_pages_evicted,
+                "full_pages_evicted": self.evicted_pages,
+                "prefix_cut_by_window": self.prefix_cut_by_window,
+                "prefix_lost_to_window": self.prefix_lost_to_window,
+                "window_pages_live": sum(self._win_held),
+                "full_pages_live": sum(len(t) for t in self.tables),
+                "window_pages_free": len(self.win_free),
+                "window_pages_cached": len(self._win_node_of)}
 
     def resume_snapshot(self, slot: int) -> int:
         """The snapshot the slot's last `allocate_prefix` resumed from, -1
@@ -350,7 +519,8 @@ class PageManager:
     # ------------------------------------------------------------- admission
     def can_fit(self, n_tokens: int) -> bool:
         need = -(-n_tokens // self.page_size)
-        return need <= self._available() and need <= self.max_pages_per_seq
+        return (need <= self._available() and need <= self.max_pages_per_seq
+                and self._win_fits(n_tokens))
 
     def can_fit_prompt(self, prompt_ids, n_tokens: int) -> bool:
         """can_fit that credits the prompt's cached-prefix pages: a
@@ -367,10 +537,21 @@ class PageManager:
         need_new = need_total - len(live)
         lru_matched = sum(1 for n in live if n.page in self._lru)
         return (need_new <= self._available() - lru_matched
-                and need_total <= self.max_pages_per_seq)
+                and need_total <= self.max_pages_per_seq
+                and self._win_fits(n_tokens, self._win_borrowed(matched)))
+
+    def _win_borrowed(self, matched) -> list:
+        """The nodes whose window pages a request resuming after `matched`
+        borrows: those of the last `window` tokens."""
+        if not self.win_num_pages:
+            return []
+        return matched[self._window_first(len(matched) * self.page_size):]
 
     def allocate(self, slot: int, n_tokens: int):
         need = -(-n_tokens // self.page_size)
+        if not self._win_fits(n_tokens):
+            raise MemoryError("window page pool exhausted: the live rows' "
+                              "budgets leave no room for another")
         if need > self._available():
             raise MemoryError(
                 f"paged KV pool exhausted: need {need} pages, "
@@ -382,6 +563,8 @@ class PageManager:
         assert not self.tables[slot], f"slot {slot} already allocated"
         self.tables[slot] = [self._take_page() for _ in range(need)]
         self._shared_count[slot] = 0
+        if self.win_num_pages:
+            self._win_open(slot, n_tokens)
         return self.table_row(slot)
 
     def allocate_prefix(self, slot: int, prompt_ids, n_tokens: int):
@@ -404,6 +587,14 @@ class PageManager:
                 f"sequence needs {need_total} pages > max_pages_per_seq "
                 f"{self.max_pages_per_seq}")
         assert not self.tables[slot], f"slot {slot} already allocated"
+        if self.win_num_pages:
+            if gap and matched:       # pages matched that the window pool
+                self.prefix_cut_by_window += 1     # could not serve whole
+            elif gap:
+                self.prefix_lost_to_window += 1
+            if not self._win_fits(n_tokens, self._win_borrowed(matched)):
+                raise MemoryError("window page pool exhausted: the live "
+                                  "rows' budgets leave no room for another")
         # pin the live chain BEFORE any eviction: _evict_to_free scans the
         # LRU and could otherwise free the very pages being borrowed
         pinned = []
@@ -471,6 +662,9 @@ class PageManager:
             raise
         self.tables[slot] = [n.page for n in matched] + fresh
         self._shared_count[slot] = len(matched)
+        if self.win_num_pages:
+            nodes = self._win_borrowed(matched)
+            self._win_open(slot, n_tokens, nodes, len(matched) - len(nodes))
         for n in matched:
             n.hits += 1
         if restored:
@@ -512,6 +706,8 @@ class PageManager:
                 cur.children[tokens] = node
                 self.prefix_nodes += 1
             cur = node
+            if self.win_num_pages:
+                self._win_publish(slot, i, node, table[i])
             if node.page is not None:
                 continue  # shared at admission or concurrently published
             if i < self._shared_count[slot]:
@@ -523,6 +719,21 @@ class PageManager:
             self._demoted.pop(node, None)
         self._set_nodes_gauge()
         self._record_snapshot(slot, prompt_ids)
+
+    def _win_publish(self, slot: int, i: int, node, full_page: int):
+        """The slot's own window page of prompt page `i` goes onto the node,
+        where the node holds none and its full page is (or is about to be)
+        this slot's: whoever borrows the window page later borrows that full
+        page with it, so the full page's refcount covers both."""
+        wtable = self.win_tables[slot]
+        if (node.win is not None or i >= len(wtable) or wtable[i] is None
+                or wtable[i] in self._win_node_of
+                or node.page not in (None, full_page)
+                or (node.page is None and i < self._shared_count[slot])):
+            return
+        node.win = wtable[i]
+        self._win_node_of[node.win] = node
+        self._win_refs[node.win] = 1
 
     def _record_snapshot(self, slot: int, prompt_ids):
         """The snapshot the engine saved for this slot goes onto the node of
@@ -574,6 +785,12 @@ class PageManager:
                 self.free_pages.append(pid)
         self.tables[slot] = []
         self._shared_count[slot] = 0
+        for pid in self.win_tables[slot]:
+            if pid is not None:
+                self._win_give_back(pid)
+        self.win_tables[slot] = []
+        self._win_budget[slot] = self._win_held[slot] = 0
+        self._win_dead[slot] = 0
         self._resumed.pop(slot, None)
         sid, _ = self._pending_snap.pop(slot, (None, 0))
         if sid is not None:      # the request ended before it was recorded

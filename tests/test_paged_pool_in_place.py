@@ -358,10 +358,12 @@ def _pool_sized_results(hlo: str, pool: str) -> set:
 
 
 def _step_compiled_for(v5e, cfg, pages, rows, width, tokens=1,
-                       chunk_local=False) -> str:
+                       chunk_local=False, window_pages=0) -> str:
     """The text of the program the v5e's compiler makes of one step of a
     `Llama(cfg)` over a donated paged cache of `pages` pages of 64 (`rows`
-    rows, `width` table entries a row): `tokens` new tokens a row."""
+    rows, `width` table entries a row): `tokens` new tokens a row. With
+    `window_pages` the cache has a window pool of so many pages for the
+    model's sliding layers beside the full pool."""
     from ray_tpu.models.llama import Llama
 
     model = Llama(cfg)
@@ -373,8 +375,11 @@ def _step_compiled_for(v5e, cfg, pages, rows, width, tokens=1,
     params = on_chip(jax.eval_shape(
         lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
         jax.random.PRNGKey(0)))
+    window = ({"window": dict(layers=cfg.n_window_layers,
+                              num_pages=window_pages)} if window_pages else {})
     cache = on_chip(jax.eval_shape(lambda: PagedKVCache.init(
-        cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, pages, 64, rows, width)))
+        cfg.n_layers - cfg.n_window_layers, cfg.n_kv_heads, cfg.head_dim,
+        pages, 64, rows, width, **window)))
 
     def step(params, cache, toks):
         logits, cache = model.apply(params, toks, cache=cache,
@@ -478,3 +483,37 @@ def test_decode_compiled_for_the_v5e_at_the_cells_shapes(
         hlo, rf"bf16\[({layers},)?8,{pages},64,{head_dim}\]")
     assert not moved or head_dim % 128, (
         f"{cell}: pool-sized results of {sorted(moved)}")
+
+
+@pytest.mark.parametrize("name,rows,tokens,calls", [
+    ("decode", 48, 1, ["paged_decode"] + ["paged_decode_window"] * 3),
+    ("continuation", 1, 1024,
+     ["flash_continuation"] + ["flash_continuation_window"] * 3
+     + ["paged_row_pages"] + ["paged_row_pages_window"] * 3)])
+def test_two_pools_compiled_for_the_v5e_at_the_cells_shapes(v5e, name, rows,
+                                                            tokens, calls):
+    """`commandaplus-mixedlen-batch`'s decode step and continuation chunk (48
+    rows, a table of 800 pages, 128 query heads on 8 kv heads of 128, a window
+    of 4096, one period of three sliding layers to one full): the v5e's
+    compiler takes the window forms of the three kernels (a first block a
+    row in the walk, a key grid that starts at the window, a copy of the
+    window's pages only) under names of their own, once a sliding layer,
+    beside the full layer's, and neither pool is moved."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    pages, window_pages, d = 1152, 640, 128
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=4, n_heads=128,
+                      n_kv_heads=8, head_dim=d, ffn_dim=512,
+                      max_seq_len=800 * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16, rope_theta=50000.0,
+                      layer_types=("sliding", "sliding", "sliding", "full"),
+                      sliding_window=4096, rope_interleaved=True,
+                      parallel_block=True, norm="layer")
+    hlo = _step_compiled_for(v5e, cfg, pages, rows, 800, tokens,
+                             window_pages=window_pages)
+    got = sorted(line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                 for line in hlo.splitlines() if "tpu_custom_call" in line)
+    assert got == sorted(calls), f"{name}: custom calls {got}"
+    for layers, n in ((1, pages), (3, window_pages)):
+        moved = _pool_sized_results(hlo, rf"bf16\[({layers},)?8,{n},64,{d}\]")
+        assert not moved, f"{name}: pool-sized results of {sorted(moved)}"
