@@ -114,7 +114,18 @@ class LlamaConfig:
     # the fields above say.
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: int = 0
-    rope_interleaved: bool = False  # rotary turns the pairs (2i, 2i + 1)
+    # rotary turns the pairs (2i, 2i + 1) of a head (GPT-J's layout), which
+    # is the layout of such a model's weights as published and as given. The
+    # weights it is SERVED on have the pairs split, a head's columns of wq
+    # and wk reordered [0, 2, ..., 1, 3, ...], and run with this False:
+    # `split_rotary_pairs`, once, where `LLMServer` takes its weights. The
+    # uncached forward and training keep this form on the given weights.
+    rope_interleaved: bool = False
+    # wq's and wk's kernels on the layers that turn q and k are stored
+    # [out, in], as the v5e's compiler reads them in every serving program
+    # (given [in, out] it transposes the WEIGHT in every call). Set by
+    # `split_rotary_pairs` on the tree it rebuilds, by nothing else.
+    qk_out_major: bool = False
     # attention and FFN both read ONE norm of the block's input and are
     # summed into the residual
     parallel_block: bool = False
@@ -135,6 +146,11 @@ class LlamaConfig:
 
     def is_linear(self, layer_idx: int) -> bool:
         return bool(self.full_attn_every) and layer_idx % self.full_attn_every != 0
+
+    def turns_qk(self, layer_idx: int) -> bool:
+        """Whether the layer turns q and k by rotary positions."""
+        return (self.use_rope and not self.is_linear(layer_idx)
+                and self.layer_kind(layer_idx) != "full")
 
     @property
     def n_linear_layers(self) -> int:
@@ -456,6 +472,21 @@ def _continuation_attention(q, cache: PagedKVCache, layer_idx, positions,
         **({} if window is None else {"window": window}))
 
 
+class _OutMajorDense(nn.Module):
+    """`nn.Dense` without bias on a kernel stored [out, in]."""
+    features: int
+    dtype: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.normal(0.02),
+                            (self.features, x.shape[-1]), self.param_dtype)
+        return jax.lax.dot_general(
+            x.astype(self.dtype), kernel.astype(self.dtype),
+            (((x.ndim - 1,), (1,)), ((), ())))
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
     layer_idx: int = 0
@@ -469,8 +500,13 @@ class Attention(nn.Module):
                         param_dtype=cfg.param_dtype,
                         kernel_init=nn.initializers.normal(0.02))
         b, t, _ = x.shape
-        q = dense(cfg.n_heads * cfg.head_dim, name="wq")(x)
-        k = dense(cfg.n_kv_heads * cfg.head_dim, name="wk")(x)
+        kind = cfg.layer_kind(layer_idx)
+        turned = cfg.turns_qk(layer_idx)
+        qk = (partial(_OutMajorDense, dtype=cfg.dtype,
+                      param_dtype=cfg.param_dtype)
+              if turned and cfg.qk_out_major else dense)
+        q = qk(cfg.n_heads * cfg.head_dim, name="wq")(x)
+        k = qk(cfg.n_kv_heads * cfg.head_dim, name="wk")(x)
         v = dense(cfg.n_kv_heads * cfg.head_dim, name="wv")(x)
         q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
@@ -478,11 +514,10 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
-        kind = cfg.layer_kind(layer_idx)
         # a sliding layer's mask, and the argument that carries it
         window = cfg.sliding_window if kind == "sliding" else None
         win = {} if window is None else {"window": window}
-        if cfg.use_rope and kind != "full":
+        if turned:
             turn = ({"interleaved": True} if cfg.rope_interleaved else {})
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_sections,
                            **turn)
@@ -819,6 +854,54 @@ def _n_moe_layers(cfg: LlamaConfig) -> int:
     if cfg.n_experts <= 0:
         return 0
     return len(range(0, cfg.n_layers, cfg.moe_every))
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _pairs_split(x, head_dim: int):
+    """`x` `[..., heads * head_dim]` with every head's entries reordered
+    [0, 2, 4, ..., 1, 3, 5, ...]: a rotary pair (2i, 2i + 1) becomes the
+    pair (i, i + head_dim / 2). A kernel `[in, out]` comes back `[out, in]`
+    (`LlamaConfig.qk_out_major`)."""
+    pairs = x.reshape(x.shape[:-1] + (-1, head_dim // 2, 2))
+    return pairs.swapaxes(-1, -2).reshape(x.shape).T
+
+
+def split_rotary_pairs(params, cfg: LlamaConfig):
+    """`(params', cfg')` that compute what `(params, cfg)` compute wherever
+    q meets k of the same tree (the cached forward: a served model), with the
+    rotate-half rotary in place of the interleaved one.
+
+    Turning the pairs (2i, 2i + 1) of a head is turning the pairs
+    (i, i + D/2) of the head with its columns reordered [0, 2, ..., 1, 3,
+    ...], and q . k does not change when q and k carry the same reordering.
+    So on every layer that turns q and k (`LlamaConfig.turns_qk`) the output
+    columns of `wq` and `wk` (and a
+    q/k norm's scale) are reordered inside each head, ONCE, and `cfg'` says
+    `rope_interleaved=False`: the interleaved form's pair reshape, which the
+    compiler folds through the projection into a relayout of the WEIGHT in
+    every call, is gone from the program. The two kernels are rebuilt
+    anyway, so they are stored `[out, in]` (`qk_out_major`), which is how
+    every serving program reads them: handed `[in, out]` it transposes the
+    weight in every call as well. Values, the output projection and every
+    kernel see what they saw. Every other leaf of `params'` IS the leaf of
+    `params`; a model whose rotary is not interleaved gets both arguments
+    back as they are. Keys cached by one pair are not keys of the other."""
+    if not (cfg.use_rope and cfg.rope_interleaved):
+        return params, cfg
+    turned = {f"layers_{i}" for i in range(cfg.n_layers) if cfg.turns_qk(i)}
+    split = {("wq", "kernel"), ("wk", "kernel"),
+             ("q_norm", "scale"), ("k_norm", "scale")}
+
+    def leaf(path, x):
+        keys = tuple(getattr(k, "key", None) for k in path)
+        if (len(keys) >= 4 and keys[-4] in turned and keys[-3] == "attn"
+                and keys[-2:] in split):
+            return _pairs_split(x, cfg.head_dim)
+        return x
+
+    return (jax.tree_util.tree_map_with_path(leaf, params),
+            dataclasses.replace(cfg, rope_interleaved=False,
+                                qk_out_major=True))
 
 
 def _attn_params(cfg: LlamaConfig) -> int:

@@ -33,7 +33,13 @@ def rope_table(max_len: int, head_dim: int, theta: float = 10000.0):
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
                sections=None, interleaved: bool = False):
     """Rotate-half RoPE (`interleaved`: frequency i turns the pair
-    (2i, 2i + 1) of a head, GPT-J's layout, and not (i, i + D/2)). x: [B, T, H, D], positions: [B, T] int32, or
+    (2i, 2i + 1) of a head, GPT-J's layout, and not (i, i + D/2): the form
+    of the weights as given, for the uncached forward. The compiler folds its
+    pair reshape through the projection into a relayout of the WEIGHT in
+    every call, so a served model never takes it: `LLMServer` splits the
+    pairs of wq's and wk's columns once at load, `models/llama.py
+    split_rotary_pairs`, and its programs run the rotate-half form).
+    x: [B, T, H, D], positions: [B, T] int32, or
     [3, B, T] with `sections` (s_t, s_h, s_w) summing to D/2: of the D/2
     frequencies the first s_t turn by positions[0] (temporal), the next s_h
     by positions[1] (height), the rest by positions[2] (width). Text has all

@@ -257,7 +257,8 @@ class LLMServer:
     def __init__(self, config: Optional[LLMConfig] = None, params=None):
         import jax
         import jax.numpy as jnp
-        from ray_tpu.models.llama import KVCache, Llama, LlamaConfig
+        from ray_tpu.models.llama import (KVCache, Llama, LlamaConfig,
+                                          split_rotary_pairs)
 
         self.config = cfg = config or LLMConfig()
         preset = getattr(LlamaConfig, cfg.preset)
@@ -330,6 +331,23 @@ class LLMServer:
             if params is None:
                 params = self.model.init(key, jnp.zeros((1, 8), jnp.int32))
             self.params = jax.device_put(params)
+        # The pair the jitted programs run. `params`, `model` and `model_cfg`
+        # stay what the caller gave (a reference takes them as a pair that
+        # agrees with itself); for a model whose rotary is interleaved the
+        # programs take a tree with the rotary's pairs split once, here, and
+        # the rotate-half form (models/llama.py split_rotary_pairs): held
+        # BESIDE the given one, only the rebuilt leaves are new arrays. For
+        # every other model the same objects.
+        self._run_params, run_cfg = split_rotary_pairs(self.params,
+                                                       self.model_cfg)
+        self._run_model = (self.model if run_cfg is self.model_cfg
+                           else Llama(run_cfg))
+        # projections split at load (stats()["decode"]): tells a trace
+        # without the interleaved form's relayouts from another model's
+        self._rotary_split = sum(
+            ran is not given for ran, given in zip(
+                jax.tree_util.tree_leaves(self._run_params),
+                jax.tree_util.tree_leaves(self.params)))
         if cfg.speculate > 0 and cfg.paged:
             # checked BEFORE the page pool below: a config error must not
             # cost a multi-GB HBM allocation first
@@ -582,7 +600,7 @@ class LLMServer:
         from ray_tpu.models.llama import KVCache
 
         cfg = self.config
-        model = self.model
+        model = self._run_model
         # a grouped expert product reads the weights of the experts its rows
         # reach; how many that is only the device knows, so the decode chunk
         # hands the count back with its tokens (the same sync)
@@ -1038,7 +1056,7 @@ class LLMServer:
         compiled HLO or cost analysis. Traces only: nothing is donated or
         run."""
         return self._decode_chunk.lower(
-            self.params, self.cache, self._slots, self._sample_key, False,
+            self._run_params, self.cache, self._slots, self._sample_key, False,
             n or self.config.decode_chunk)
 
     def _note_sync(self, tokens: int, dt_s: float,
@@ -1288,7 +1306,7 @@ class LLMServer:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = job.prompt[start:start + n]
         # host values go up with the call itself
-        args = (self.params, self.cache, padded, job.slot_idx,
+        args = (self._run_params, self.cache, padded, job.slot_idx,
                 np.int32(start), np.int32(start + n))
         if self.config.paged:
             # start==0 → fresh row's first chunk: exact with chunk-local
@@ -1774,7 +1792,8 @@ class LLMServer:
                 padded[i, :len(d)] = d
             self._sample_key, sub = jax.random.split(self._sample_key)
             self.cache, self._slots, *out = self._spec(
-                self.params, self.cache, self._slots, padded, sub, any_logp)
+                self._run_params, self.cache, self._slots, padded, sub,
+                any_logp)
         else:
             # fused multi-token decode: n steps on device. The chunk fn
             # splits the sample key once per step and returns the carried
@@ -1782,7 +1801,7 @@ class LLMServer:
             # chunking never changes sampled outputs.
             (self.cache, self._slots, toks, n_valid, logp,
              self._sample_key, *touched) = self._decode_chunk(
-                self.params, self.cache, self._slots, self._sample_key,
+                self._run_params, self.cache, self._slots, self._sample_key,
                 any_logp, n)
             out = [toks, n_valid, logp, *touched]
         for x in out:
@@ -2062,6 +2081,7 @@ class LLMServer:
             # the loop's own account of its time (LOOP_PHASES sum to loop_s,
             # NESTED_PHASES are their children) and of its work
             "loop_s": st["loop_s"], "ticks": st["ticks"],
+            "rotary_split_projections": self._rotary_split,
             "phase_s": dict(self._phases.seconds),
             "phase_n": dict(self._phases.counts),
             # watched reads that outlasted their work (WATCHED_PHASES)

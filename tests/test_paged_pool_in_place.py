@@ -517,3 +517,40 @@ def test_two_pools_compiled_for_the_v5e_at_the_cells_shapes(v5e, name, rows,
     for layers, n in ((1, pages), (3, window_pages)):
         moved = _pool_sized_results(hlo, rf"bf16\[({layers},)?8,{n},64,{d}\]")
         assert not moved, f"{name}: pool-sized results of {sorted(moved)}"
+
+
+@pytest.mark.parametrize("name,rows,tokens", [("decode", 48, 1),
+                                              ("continuation", 1, 1024)])
+def test_split_rotary_compiled_for_the_v5e_moves_no_weight(v5e, name, rows,
+                                                           tokens):
+    """Command A+'s attention widths in the form `LLMServer` runs (PR 40:
+    `split_rotary_pairs`): the rotary's pairs split at load, so the
+    rotate-half form, and `wq`, `wk` of the three sliding layers stored
+    [out, in]. The v5e's compiler then makes no `[128, 64, 2, 4096]` of `wq`
+    (the interleaved form's pair reshape, folded through the projection:
+    three relayouts a call) and transposes no rebuilt kernel; what is left
+    with `wq`'s element count is ONE copy, of the full layer's `wq`, which is
+    the caller's array, [in, out] as every model's is."""
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    heads, d, d_model = 128, 128, 4096
+    cfg = LlamaConfig(vocab_size=256, d_model=d_model, n_layers=4,
+                      n_heads=heads, n_kv_heads=8, head_dim=d, ffn_dim=512,
+                      max_seq_len=800 * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16, rope_theta=50000.0,
+                      layer_types=("sliding", "sliding", "sliding", "full"),
+                      sliding_window=4096, rope_interleaved=False,
+                      qk_out_major=True, parallel_block=True, norm="layer")
+    hlo = _step_compiled_for(v5e, cfg, 1152, rows, 800, tokens,
+                             window_pages=640)
+    assert not re.search(rf"bf16\[{heads},{d // 2},2,{d_model}\]", hlo)
+    sized = [(m.group(3), m.group(1)) for m in (
+        re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* ([\w\-]+)\(",
+                 line) for line in hlo.splitlines())
+        if m and math.prod(map(int, m.group(2).split(","))) == heads * d * d_model
+        and m.group(3) in ("copy", "transpose", "reshape")]
+    assert [op for op, _ in sized] == ["copy"], f"{name}: {sized}"
+    line = next(ln for ln in hlo.splitlines() if f"%{sized[0][1]} = " in ln)
+    assert "layers_3" in line, line
