@@ -188,15 +188,38 @@ def grouped_product(n_experts: int, top_k: int) -> bool:
     return n_experts > 4 * top_k
 
 
-_GMM_RHS_TILE_BYTES = 4 << 20      # one expert's [k, n] matrix in fast memory
+_GMM_RHS_TILE_BYTES = 4 << 20      # one tile of an expert's matrix in fast memory
+_GMM_EDGE_ROWS = 128    # the row tile of a product whose groups lie on tile edges
+
+# "m x k x n" -> [tm, tk, tn] (None: `jax.lax.ragged_dot`) of every grouped
+# product traced in this process: a memo of a function of the shapes and the
+# backend, written while tracing and read by `LLMServer.stats()`.
+_TILINGS_TAKEN = {}
+
+
+def gmm_tilings() -> dict:
+    """The tiling each grouped product shape traced in this process took. A
+    tile's k under the product's k says the experts' matrices are k-tiled:
+    read once a VISIT (`_gmm_tiling`)."""
+    return dict(_TILINGS_TAKEN)
 
 
 def _gmm_tiling(m: int, k: int, n: int, itemsize: int):
     """megablox tiles (rows, k, n) for a product of [m, k] rows with [k, n]
     matrices: an expert's matrix whole where it fits fast memory's budget
     (`_GMM_RHS_TILE_BYTES`), else its longer side halved until a tile does (a
-    4096 x 1280 matrix goes through as four tiles of 1024 x 1280, still read
-    once a group); None where the rows or the sides do not divide."""
+    4096 x 1280 matrix goes through as four tiles of 1024 x 1280, 4096 x 4096
+    as eight of 1024 x 2048); None where the rows or the sides do not divide.
+
+    What a tiled matrix costs: `gmm`'s grid is (n tiles, visits, k tiles), a
+    visit being one (row tile, group) pair and k INNERMOST, and the pipeline
+    fetches a block when its index changes. The matrix's block is (group,
+    k tile, n tile): whole, or tiled along n alone, it stays put while
+    consecutive visits stay in one group, so the product reads each reached
+    matrix once. With more than one k tile it changes at every grid step and
+    every VISIT streams the group's whole matrix again: read once a group
+    only where the group lies inside one row tile, as a decode step's rows
+    do, and as `grouped_experts` lays a chunk's."""
     if m % 8:
         return None
     tk, tn = k, n
@@ -210,21 +233,24 @@ def _gmm_tiling(m: int, k: int, n: int, itemsize: int):
     return next(t for t in (64, 32, 16, 8) if m % t == 0), tk, tn
 
 
-def _grouped_dot(lhs, rhs, sizes):
+def _grouped_dot(lhs, rhs, sizes, tm=None):
     """lhs [M, k] (rows in group order) x rhs [G, k, n] -> [M, n], each group
     of `sizes` by its own matrix. `jax.lax.ragged_dot`, except where the chip
     measured better: on the TPU, rows a multiple of 8 take megablox's pallas
-    `gmm` tiled by `_gmm_tiling`, one matrix read a group: 0.62-0.66 ms a
-    product of 128 experts of 2048 x 768 at 192 and 4096 rows against
-    `ragged_dot`'s 0.95 and 1.94 (v5e, PR 28; the weights alone are 0.49 ms
-    at the chip's bandwidth). Rows past the groups' sum (a bank that holds a
-    share of the experts sorts the others' pairs there) are no group's: `gmm`
-    visits no tile for them and leaves their output rows unwritten, so the
-    caller must not read them."""
+    `gmm` tiled by `_gmm_tiling` (`tm`: the row tile the caller laid its
+    groups by): 0.62-0.66 ms a product of 128 experts of 2048 x 768 at 192
+    and 4096 rows against `ragged_dot`'s 0.95 and 1.94 (v5e, PR 28; the
+    weights alone are 0.49 ms at the chip's bandwidth). Rows past the groups'
+    sum (a bank that holds a share of the experts sorts the others' pairs
+    there) are no group's: `gmm` visits no tile for them and leaves their
+    output rows unwritten, so the caller must not read them."""
     m, k = lhs.shape
     n = rhs.shape[-1]
     tiling = (_gmm_tiling(m, k, n, rhs.dtype.itemsize)
               if jax.default_backend() == "tpu" else None)
+    if tiling is not None and tm is not None:
+        tiling = (tm,) + tiling[1:]
+    _TILINGS_TAKEN[f"{m} x {k} x {n}"] = tiling and list(tiling)
     if tiling is not None:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
         return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
@@ -244,17 +270,45 @@ def grouped_experts(x, gate_vals, gate_idx, w_gate, w_up, w_down, held=None):
     `held` [S, K] bool (a bank that holds a share of the experts): the pairs
     to compute. The others carry the index E, past the last group: they sort
     behind every group, no group multiplies them (what the product leaves in
-    their rows is not read), and they add nothing to the sum."""
+    their rows is not read), and they add nothing to the sum.
+
+    Such a bank's rows are mostly the others', so where a matrix goes to the
+    kernel in k tiles (`_gmm_tiling`: every (row tile, group) visit reads
+    all of it) and the rows are at least two tiles a group, each group is
+    laid on a row-tile edge: `sizes` rounded up to `_GMM_EDGE_ROWS`, the
+    buffer longer by a tile a group so that the worst routing (every pair
+    held) still fits. A group of up to a tile's rows is then ONE visit, where
+    some 64 sorted rows that start anywhere are two. The rows of padding hold
+    token 0 and are multiplied; no pair reads them back. (Two tiles a group,
+    not one: the gathers, `silu(h) * u` and the index scatter round the
+    products work on the padding too, and at 40 experts of 4096 x 1280 on
+    8192 rows, padding of five eighths, they took back in the cell what the
+    products saved: v5e, PR 43.)"""
     S, K = gate_idx.shape
     E = w_gate.shape[0]
     flat = gate_idx.reshape(-1)
     order = jnp.argsort(flat)                         # routed row -> sorted
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-    xs = x[order // K]                                # [S*K, D]
-    h = _grouped_dot(xs, w_gate, sizes)
-    u = _grouped_dot(xs, w_up, sizes)
-    out = _grouped_dot(nn.silu(h) * u, w_down, sizes)         # [S*K, D]
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(S * K))
+    token, rows, tm = order // K, None, None          # sorted row -> its token
+    matrix_bytes = math.prod(w_gate.shape[1:]) * w_gate.dtype.itemsize
+    if (held is not None and S * K >= 2 * E * _GMM_EDGE_ROWS
+            and matrix_bytes > _GMM_RHS_TILE_BYTES):
+        tm = _GMM_EDGE_ROWS
+        padded = (sizes + tm - 1) // tm * tm
+        # the padding ahead of each group; ahead of the others' pairs, all
+        ahead = jnp.cumsum(jnp.pad(padded - sizes, (1, 0)))
+        rows = jnp.arange(S * K) + ahead[flat[order]]   # sorted -> buffer row
+        token = jnp.zeros(-(-S * K // tm) * tm + E * tm, token.dtype).at[
+            rows].set(token, indices_are_sorted=True, unique_indices=True)
+        sizes = padded
+    xs = x[token]                                     # [S*K (+ E*tm), D]
+    h = _grouped_dot(xs, w_gate, sizes, tm)
+    u = _grouped_dot(xs, w_up, sizes, tm)
+    out = _grouped_dot(nn.silu(h) * u, w_down, sizes, tm)
+    # (the iota made HERE where no group moved: the program other banks
+    # lowered to before is the program they lower to)
+    back = jnp.zeros_like(order).at[order].set(      # routed row -> its row
+        jnp.arange(S * K) if rows is None else rows)
     out = out[back].reshape(S, K, -1).astype(jnp.float32)
     out = out * gate_vals[..., None]
     if held is not None:

@@ -2116,8 +2116,12 @@ class LLMServer:
                 self._sparse_stats, topk=self.model_cfg.index_topk,
                 index_pool_bytes=int(self.cache.idx_pages.nbytes))
         if self.model_cfg.n_experts > 0:
+            from ray_tpu.models.moe import gmm_tilings
+            # gmm_tilings: which kernel tiles each grouped product shape
+            # traced here took (a k tile under k: every visit re-reads)
             s["moe"] = dict(self._moe_stats,
-                            recent_decode_syncs=list(self._moe_recent))
+                            recent_decode_syncs=list(self._moe_recent),
+                            gmm_tilings=gmm_tilings())
         if (self._stateful or self._windowed) and "moe" in s:
             # pairs that fell on this chip's share of the experts, counted
             # on the device by the programs themselves (read here: a sync)

@@ -554,3 +554,41 @@ def test_split_rotary_compiled_for_the_v5e_moves_no_weight(v5e, name, rows,
     assert [op for op, _ in sized] == ["copy"], f"{name}: {sized}"
     line = next(ln for ln in hlo.splitlines() if f"%{sized[0][1]} = " in ln)
     assert "layers_3" in line, line
+
+
+@pytest.mark.parametrize("cell,rows,k,n,held,tm", [
+    ("commandaplus-mixedlen-batch chunk", 8192 + 16 * 128, 4096, 4096, 16, 128),
+    ("commandaplus-mixedlen-batch decode", 384, 4096, 4096, 16, None),
+    ("commandaplus-mixedlen-batch chunk of 512", 4096 + 16 * 128, 4096, 4096,
+     16, 128),
+    ("solar250b-agentloop-batch chunk gate/up", 8192, 4096, 1280, 40, None),
+    ("solar250b-agentloop-batch chunk down", 8192, 1280, 4096, 40, None),
+    ("keye30b-longdoc-batch chunk gate/up", 4096, 2048, 768, 128, None)])
+def test_grouped_product_compiled_for_the_v5e_at_the_cells_shapes(
+        v5e, cell, rows, k, n, held, tm):
+    """megablox's `gmm` under the tiles a cell's grouped expert product takes
+    (`_gmm_tiling`; `tm`: the row tile of a chunk whose groups
+    `grouped_experts` lays on tile edges, the buffer a tile a group longer:
+    PR 43): the v5e's compiler takes it (a tile of the matrix, the rows'
+    tile, the result's and the f32 accumulator fit its fast memory, which the
+    interpreter does not check) and the kernel is in the program under the
+    name the benchmark's readers look for, with the rows in its result's
+    shape."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ray_tpu.models.moe import _GMM_EDGE_ROWS, _gmm_tiling
+
+    tiling = _gmm_tiling(rows, k, n, 2)
+    if tm is not None:
+        assert tm == _GMM_EDGE_ROWS
+        tiling = (tm,) + tiling[1:]
+    shapes = [jax.ShapeDtypeStruct(s, t, sharding=v5e) for s, t in (
+        ((rows, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
+        ((held,), jnp.int32))]
+    hlo = jax.jit(lambda lhs, rhs, sizes: gmm(
+        lhs, rhs, sizes, preferred_element_type=jnp.bfloat16,
+        tiling=tiling)).lower(*shapes).compile().as_text()
+    calls = [line.split(" = ")[0].split("%")[-1].split(".")[0]
+             + " " + line.split(" = ")[1].split("{")[0]
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert calls == [f"gmm bf16[{rows},{n}]"], f"{cell}: {calls}"
