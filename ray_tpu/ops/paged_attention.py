@@ -590,23 +590,47 @@ def write_layer_tokens(cache: PagedKVCache, layer_idx: int, k_new: jax.Array,
     rows = jnp.repeat(jnp.arange(bsz), t)
     page_ids = cache.block_tables[rows, pos // ps]
     offs = pos % ps
-    flat = [n.reshape((bsz * t,) + n.shape[2:]) for n in news]
-    di = flat[2].shape[-1]
-    r = pools[2].shape[-1] // di
-    # a token's Di values into its share of a row: a windowed scatter
-    # (layer, page, row, first lane), which indexing cannot spell
-    where = jnp.stack([jnp.full_like(offs, layer_idx), page_ids,
-                       offs // r, (offs % r) * di], axis=-1)
-    idx_pool = jax.lax.scatter(
-        pools[2], where, flat[2],
-        jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
-            scatter_dims_to_operand_dims=(0, 1, 2, 3)),
-        indices_are_sorted=False, unique_indices=False,
-        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    flat = [n.reshape((bsz * t,) + n.shape[2:]) for n in news[:2]]
     return cache.with_pools([
         p.at[layer_idx, page_ids, offs].set(n)
-        for p, n in zip(pools[:2], flat)] + [idx_pool])
+        for p, n in zip(pools[:2], flat)] + [_write_index_rows(
+            pools[2], layer_idx, news[2], positions[:, 0],
+            cache.block_tables, ps)])
+
+
+def _write_index_rows(pool, layer_idx: int, new, first_pos, tables, ps: int):
+    """The indexer's keys of a prefill chunk ([B, T, Di]) into its pool
+    [L, P, page / r, r * Di] at positions first_pos[b] .. first_pos[b] + T - 1
+    of row b, page by page as `_write_chunk_rows` writes the dense layout: a
+    page is read by (layer, page), seen as [page, Di], the chunk's rows laid
+    over it and written back, in place on the donated pool; a slot that holds
+    no token of the chunk, or lies past the table's end, keeps its old row.
+
+    A token's Di values are half a row of the pool, and the scatter this
+    replaces (a window of Di lanes at (layer, page, row, first lane) a token)
+    the v5e's compiler ran as a loop of one `dynamic-update-slice` a token:
+    2048 of them a chunk of 512 x 4 layers, 9.6 ms of its 46 (PR 44, traced
+    on the chip); page by page it is 9 writes a layer."""
+    bsz, t, di = new.shape
+    rows, lanes = pool.shape[-2:]
+    mp = tables.shape[1]
+    n_pages = (t + 2 * ps - 2) // ps
+    padded = jnp.pad(new, ((0, 0), (ps, n_pages * ps - t), (0, 0)))
+    slot = jnp.arange(ps)
+    for b in range(bsz):  # B and the page count are static: one program
+        first, off0 = first_pos[b] // ps, first_pos[b] % ps
+        for j in range(n_pages):
+            token = j * ps - off0 + slot         # the chunk's token a slot
+            keep = (token >= 0) & (token < t) & (first + j < mp)
+            start = (layer_idx, tables[b, jnp.minimum(first + j, mp - 1)],
+                     0, 0)
+            fresh = jax.lax.dynamic_slice_in_dim(
+                padded[b], j * ps - off0 + ps, ps, axis=0)        # [page, Di]
+            old = jax.lax.dynamic_slice(pool, start, (1, 1, rows, lanes))
+            page = jnp.where(keep[:, None], fresh, old.reshape(ps, di))
+            pool = jax.lax.dynamic_update_slice(
+                pool, page.reshape(1, 1, rows, lanes), start)
+    return pool
 
 
 def _write_chunk_rows(pools, layer_idx: int, news, first_pos, tables) -> list:
@@ -866,28 +890,34 @@ def index_scores(qi, wi, ki):
                       wi.astype(jnp.float32))
 
 
-def kth_largest(scores, k: int):
+# Bits a pass of `kth_largest` settles. A pass compares every key with the
+# 2^bits - 1 candidates of its digit and reads the keys once: at 4 bits the
+# compares bound it (2.08 ms a layer for [512, 29696] on the v5e, PR 44), at
+# 2 bits the 16 reads do.
+_SELECT_BITS = 3
+_NEG_INF_KEY = 0x007FFFFF     # -inf in `kth_largest`'s order of the floats
+
+
+def kth_largest(scores, k: int, bits: int = _SELECT_BITS):
     """The k-th largest of each row of `scores` [T, S] (f32, -inf allowed),
-    exactly, as a radix select over the floats' bits: 8 passes of 4 bits, each
-    one read of the scores that counts, for 15 candidate prefixes, how many
-    keys reach it. Returns (keys [T, S], kth [T, 1]) as uint32 whose order is
-    the floats'. `lax.top_k` of a [512, 30720] block is a full sort on the
-    TPU: 14 ms of a prefill chunk's layer against 2 ms this way (v5e, PR 28);
-    where the positions are wanted too (decode) `lax.top_k` stays."""
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    bits = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
-    keys = jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(1 << 31)
-    digits = jnp.arange(1, 16, dtype=jnp.uint32)
-
-    def digit(i, kth):
-        shift = (28 - 4 * i).astype(jnp.uint32)
-        cands = kth | (digits[None, :] << shift)                  # [T, 15]
-        reach = (keys[:, :, None] >= cands[:, None, :]).sum(1)    # [T, 15]
+    exactly, as a radix select over the floats' bits, `bits` at a pass from
+    the top: each pass is one read of the scores that counts, for the
+    2^bits - 1 candidate prefixes, how many keys reach it. Returns (keys
+    [T, S], kth [T, 1]) as uint32 whose order is the floats'. `lax.top_k` of a
+    [512, 30720] block is a full sort on the TPU: 14 ms of a prefill chunk's
+    layer against 2 ms this way (v5e, PR 28); where the positions are wanted
+    too (decode) `lax.top_k` stays."""
+    as_int = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    as_int = jnp.where(as_int < 0, as_int ^ 0x7FFFFFFF, as_int)
+    keys = jax.lax.bitcast_convert_type(as_int, jnp.uint32) ^ jnp.uint32(1 << 31)
+    kth = jnp.zeros((scores.shape[0], 1), jnp.uint32)
+    for top in range(32, 0, -bits):
+        shift = max(top - bits, 0)
+        digits = jnp.arange(1, 1 << (top - shift), dtype=jnp.uint32)
+        cands = kth | (digits[None, :] << shift)                  # [T, 2^b-1]
+        reach = (keys[:, :, None] >= cands[:, None, :]).sum(1)
         best = (reach >= k).sum(-1, keepdims=True).astype(jnp.uint32)
-        return kth | (best << shift)   # reach falls as the candidate grows
-
-    kth = jax.lax.fori_loop(0, 8, digit,
-                            jnp.zeros((scores.shape[0], 1), jnp.uint32))
+        kth = kth | (best << shift)    # reach falls as the candidate grows
     return keys, kth
 
 
@@ -956,7 +986,7 @@ def sparse_paged_prefill(q, qi, wi, cache: PagedKVCache, layer_idx: int,
     q [B, T, H, D]; qi [B, T, J, Di]; wi [B, T, J]; positions [B, T]
     absolute. Returns [B, T, H, D].
     """
-    _, t, h, d = q.shape
+    bsz, t, h, d = q.shape
     kh = cache.k_pages.shape[-2]
     g = h // kh
     ps = cache.page_size
@@ -968,40 +998,46 @@ def sparse_paged_prefill(q, qi, wi, cache: PagedKVCache, layer_idx: int,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     k_pool, v_pool, _ = cache.pools()
 
-    def one_row(q, qi, wi, pos, table):
-        table = jnp.pad(table, (0, n_static * ppb - mp))
-        n_blocks = (jnp.max(pos) + kb) // kb   # blocks the last query reaches
-        col = jnp.arange(kb)
+    tables = jnp.pad(cache.block_tables, ((0, 0), (0, n_static * ppb - mp)))
+    n_blocks = (jnp.max(positions) + kb) // kb   # blocks the last query reaches
 
-        def block_pages(i):
-            return jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb)
+    def index_block(i, scores):
+        pages = jax.lax.dynamic_slice_in_dim(tables, i * ppb, ppb, 1)
+        ki = index_keys(cache, layer_idx, pages)                  # [B, kb, Di]
+        s = index_scores(qi, wi, ki)                              # [B, T, kb]
+        s = jnp.where((i * kb + jnp.arange(kb))[None, None]
+                      <= positions[:, :, None], s, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(scores, s, i * kb, 2)
 
-        def index_block(i, scores):
-            ki = index_keys(cache, layer_idx, block_pages(i))     # [kb, Di]
-            s = index_scores(qi, wi, ki)                          # [T, kb]
-            s = jnp.where((i * kb + col)[None] <= pos[:, None], s, -jnp.inf)
-            return jax.lax.dynamic_update_slice_in_dim(scores, s, i * kb, 1)
+    with jax.named_scope("sparse_index"):
+        scores = jax.lax.fori_loop(
+            0, n_blocks, index_block,
+            jnp.full((bsz, t, s_pad), -jnp.inf, jnp.float32))
+    with jax.named_scope("sparse_select"):
+        # lax.top_k's own set as a mask: all above the k-th value, and of
+        # those equal to it the first by position (scores tie at 0, where
+        # every head's relu is shut). The count along a row that ranks the
+        # tied is the dear part (1.7 ms a layer for [512, 29696] on the v5e,
+        # PR 44), and only a query with more keys tied at its k-th value than
+        # it has room for needs it; one whose k-th value is -inf (a row
+        # shorter than topk) does not: what is tied there is no key at all
+        k_sel = min(topk, s_pad)
+        keys, kth = kth_largest(scores.reshape(bsz * t, s_pad), k_sel)
+        above, tied = keys > kth, keys == kth
+        room = k_sel - above.sum(-1, keepdims=True)
+        surplus = (tied.sum(-1, keepdims=True) > room) & (kth > _NEG_INF_KEY)
+        selected = jax.lax.cond(
+            surplus.any(),
+            lambda: above | (tied & (jnp.cumsum(tied, -1) <= room)),
+            lambda: above | tied)
+        selected = (selected & (keys > _NEG_INF_KEY)).reshape(bsz, t, s_pad)
 
-        with jax.named_scope("sparse_index"):
-            scores = jax.lax.fori_loop(
-                0, n_blocks, index_block,
-                jnp.full((t, s_pad), -jnp.inf, jnp.float32))
-        with jax.named_scope("sparse_select"):
-            # lax.top_k's own set as a mask: all above the k-th value, and
-            # of those equal to it the first by position (scores tie at 0,
-            # where every head's relu is shut)
-            k_sel = min(topk, s_pad)
-            keys, kth = kth_largest(scores, k_sel)
-            above, tied = keys > kth, keys == kth
-            room = k_sel - above.sum(-1, keepdims=True)
-            selected = (above | (tied & (jnp.cumsum(tied, -1) <= room))) & (
-                scores > -jnp.inf)                                    # [T, S]
-
+    def attend_row(q, selected, table):
         qg = q.reshape(t, kh, g, d)
 
         def attend_block(i, carry):
             m, l, acc = carry
-            pages = block_pages(i)
+            pages = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb)
             k = k_pool[layer_idx, pages].reshape(kb, kh, d)
             v = v_pool[layer_idx, pages].reshape(kb, kh, d)
             keep = jax.lax.dynamic_slice_in_dim(selected, i * kb, kb, 1)
@@ -1017,15 +1053,15 @@ def sparse_paged_prefill(q, qi, wi, cache: PagedKVCache, layer_idx: int,
                 preferred_element_type=jnp.float32)
             return m_new, l, acc
 
-        with jax.named_scope("sparse_attend"):
-            init = (jnp.full((kh, g, t), _NEG, jnp.float32),
-                    jnp.zeros((kh, g, t), jnp.float32),
-                    jnp.zeros((kh, g, t, d), jnp.float32))
-            _, l, acc = jax.lax.fori_loop(0, n_blocks, attend_block, init)
-            out = acc / jnp.maximum(l, 1e-30)[..., None]          # [Kh,G,T,D]
+        init = (jnp.full((kh, g, t), _NEG, jnp.float32),
+                jnp.zeros((kh, g, t), jnp.float32),
+                jnp.zeros((kh, g, t, d), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, attend_block, init)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]              # [Kh,G,T,D]
         return out.transpose(2, 0, 1, 3).reshape(t, h, d).astype(q.dtype)
 
-    return jax.vmap(one_row)(q, qi, wi, positions, cache.block_tables)
+    with jax.named_scope("sparse_attend"):
+        return jax.vmap(attend_row)(q, selected, tables)
 
 
 def sparse_attention_reference(q, k, v, qi, ki, wi, topk: int, *, scale=None):

@@ -3,9 +3,11 @@ runs as on the TPU, in interpret mode on the CPU: against `decode_attention`
 (all keys at once under the same absolute-position mask) over chunk lengths,
 starts, group sizes, head sizes and both precisions, with the row's tail and
 the table's placeholder pages poisoned (the key bound), and through
-`LLMServer` at test sizes against the uncached forward."""
+`LLMServer` at test sizes against the uncached forward. And the kernel's
+trace at the configurations' shapes, held to what PR 38 measured."""
 
 import asyncio
+import hashlib
 import importlib
 from functools import partial
 from unittest import mock
@@ -26,6 +28,7 @@ fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 KV_HEADS = 2
 CAPACITY = 304          # 38 pages of 8: 9.5 key blocks of 32, the last partial
+ON_THE_CHIP = (fa._CONT_ROWS, fa._CONT_BLOCK_KV)    # as imported: PR 38's
 
 
 @pytest.fixture(autouse=True)
@@ -145,6 +148,51 @@ def test_a_chunk_the_blocks_do_not_tile_is_not_the_kernels(t, g, dtype,
         with pytest.raises(ValueError, match="no query block tiles"):
             fa.flash_continuation(q, k.swapaxes(1, 2), v.swapaxes(1, 2),
                                   jnp.array([0], jnp.int32), interpret=True)
+
+
+# -- the other configurations' trace ---------------------------------------
+
+# sha256 of `_traced_text` on PR 43's tree (commit d9cdbc9)
+AS_MEASURED = {
+    "solar250b-agentloop-batch": (
+        (1024, 64, 8, 40960, None),
+        "d2c457197b21ddafc678761b4dab7de553d3af17af37c723dc1ac2a303b40ea6"),
+    "commandaplus-mixedlen-batch full": (
+        (1024, 128, 8, 51200, None),
+        "4611ee3d7d6a6ae4d4edbd887cde18c7e30b43b6e4dc9dedbb2642ec37557b19"),
+    "commandaplus-mixedlen-batch window": (
+        (1024, 128, 8, 51200, 4096),
+        "bd4317ba8d2515890321648aa423bd74daa66ca5446a6d589b9cee0ae111a080"),
+}
+
+
+def _traced_text(t, heads, kv_heads, capacity, window) -> str:
+    """What `flash_continuation` traces to at a cell's shapes (heads of 128,
+    pages of 64, bf16) with the blocks the chip runs: the jaxpr, the kernel's
+    body, grid, operands and name in it, and the program it lowers to in
+    interpret mode. Neither holds a line number."""
+    q = jax.ShapeDtypeStruct((1, t, heads, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, kv_heads, capacity // 64, 64, 128),
+                              jnp.bfloat16)
+    at = jax.ShapeDtypeStruct((1,), jnp.int32)
+    kw = {} if window is None else {"window": window}
+    return (str(jax.make_jaxpr(partial(fa.flash_continuation, **kw))(
+        q, kv, kv, at)) + jax.jit(partial(
+            fa.flash_continuation, interpret=True, **kw)).lower(
+                q, kv, kv, at).as_text())
+
+
+@pytest.mark.parametrize("cell", sorted(AS_MEASURED))
+def test_the_kernel_traces_to_what_it_did(cell, monkeypatch):
+    """Solar's and Command A+'s continuation kernel, block sizes included,
+    is textually the one PR 38 measured: a change meant for another layer's
+    mask (Keye's selection, say) shows here if it moves them."""
+    monkeypatch.setattr(fa, "_CONT_ROWS", ON_THE_CHIP[0])
+    monkeypatch.setattr(fa, "_CONT_BLOCK_KV", ON_THE_CHIP[1])
+    shapes, was = AS_MEASURED[cell]
+    text = _traced_text(*shapes)
+    assert "name=flash_continuation" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == was
 
 
 # -- through the engine ------------------------------------------------------

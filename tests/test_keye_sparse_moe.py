@@ -25,7 +25,11 @@ import pytest
 
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEMLP, grouped_experts, grouped_product
-from ray_tpu.ops.paged_attention import PagedKVCache
+from ray_tpu.ops.paged_attention import (PagedKVCache, index_scores,
+                                         kth_largest,
+                                         sparse_attention_reference,
+                                         sparse_paged_prefill,
+                                         write_layer_tokens)
 from ray_tpu.serve.llm import LLMConfig, LLMServer
 
 TOL = 2e-4
@@ -199,6 +203,191 @@ def test_paged_prefill_then_decode_agrees_with_reference(model, first, more,
         got.append(logits[0])
     np.testing.assert_allclose(jnp.concatenate(got), want, atol=TOL)
     assert int(row.lengths[0]) == total
+
+
+KV_HEADS = 2
+PAGE = 8
+
+
+def _selecting_row(n, g, d, dtype, dead=(), live=None, seed=3):
+    """A row of `n` tokens in a token-major cache of three pools under a
+    scrambled table, and the tensors it was made of. The indexer's scores are
+    made to order: its queries, keys and weights are positive, so every score
+    is positive and distinct, but a key in a 32-key block of `dead`, or (with
+    `live`) not among every `live`-th, has all its products negative: the
+    relu is shut and it scores exactly 0."""
+    j, di = 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (1, n, KV_HEADS * g, d), dtype)
+    k = jax.random.normal(ks[1], (1, n, KV_HEADS, d), dtype)
+    v = jax.random.normal(ks[2], (1, n, KV_HEADS, d), dtype)
+    qi = jnp.abs(jax.random.normal(ks[3], (1, n, j, di), dtype)) + 0.1
+    ki = jnp.abs(jax.random.normal(ks[4], (1, n, di), dtype)) + 0.1
+    wi = jnp.abs(jax.random.normal(ks[5], (1, n, j), dtype)) + 0.1
+    at = np.arange(n)
+    shut = np.isin(at // 32, dead)
+    if live:
+        shut |= at % live != 0
+    ki = jnp.where(jnp.asarray(shut)[None, :, None], -ki, ki)
+
+    width = -(-n // PAGE) + 2                       # two placeholder entries
+    held = -(-n // PAGE)
+    table = np.zeros((1, width), np.int32)
+    table[0, :held] = 1 + np.random.default_rng(seed).permutation(held)
+    pad = held * PAGE - n
+
+    def pool(x, layers=2):
+        """[1, n, ...] by position -> [layers, pages, PAGE, ...], layer 1."""
+        x = jnp.pad(x[0], ((0, pad),) + ((0, 0),) * (x.ndim - 2))
+        pages = x.reshape((held, PAGE) + x.shape[1:])
+        out = jnp.zeros((layers, 1 + held) + pages.shape[1:], dtype)
+        return out.at[1, table[0, :held]].set(pages)
+
+    cache = PagedKVCache(k_pages=pool(k), v_pages=pool(v),
+                         idx_pages=pool(ki).reshape(2, 1 + held, 1, PAGE * di),
+                         block_tables=jnp.asarray(table),
+                         lengths=jnp.array([n], jnp.int32))
+    return cache, (q, k, v, qi, ki, wi)
+
+
+# (t, start, g, topk, made-to-order scores)
+SELECTED = {
+    "row_shorter_than_topk": (16, 16, 4, 64, {}),
+    "no_key_of_the_first_block": (64, 96, 4, 16, {"dead": (0,)}),
+    "no_key_of_a_middle_block": (64, 96, 4, 16, {"dead": (2,)}),
+    "no_key_of_the_chunks_own_blocks": (64, 96, 4, 16, {"dead": (3, 4)}),
+    # 7 keys score above 0 at most, so 9 and more of the 16 are taken from
+    # those tied at 0: the earliest positions, ranked under the `cond`
+    "tied_at_0_across_the_kth_place": (32, 64, 4, 16, {"live": 16}),
+    "G8_bucket_64": (64, 128, 8, 16, {}),
+    "G8_bucket_128": (128, 64, 8, 16, {"dead": (1,)}),
+    "G8_bucket_512": (512, 64, 8, 16, {}),
+    "G8_bfloat16": (64, 100, 8, 16, {"dtype": jnp.bfloat16}),
+    "start_inside_a_key_block": (32, 77, 4, 16, {}),
+    "start_inside_a_key_block_G1": (16, 45, 1, 16, {"live": 3}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECTED))
+def test_selected_prefill_is_the_reference(case):
+    """`sparse_paged_prefill` on a cache against the uncached reference
+    (`lax.top_k`'s own set: ties to the earlier position) on the row's
+    tensors: the same output, finite, whether or not a query has more keys
+    tied at its k-th value than room (the rank's `cond`, both sides), a row
+    shorter than `topk`, and a key block no query keeps a key of."""
+    t, start, g, topk, made = SELECTED[case]
+    made = dict(made)
+    dtype = made.pop("dtype", jnp.float32)
+    n = start + t
+    cache, (q, k, v, qi, ki, wi) = _selecting_row(n, g, 32, dtype, **made)
+    chunk = slice(start, n)
+    got = jax.jit(sparse_paged_prefill, static_argnums=(4, 6),
+                  static_argnames="key_block")(
+        q[:, chunk], qi[:, chunk], wi[:, chunk], cache, 1,
+        jnp.arange(start, n)[None], topk, key_block=32)
+    want = sparse_attention_reference(q, k, v, qi, ki, wi, topk)[:, chunk]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5 if dtype == jnp.float32
+                               else 2e-2)
+
+
+def test_the_made_to_order_scores_do_what_the_cases_say():
+    """The cases above rest on it: with a block's keys shut no query selects
+    one of them, and with one key in 16 open the rest of the selection is the
+    earliest of the keys tied at 0."""
+    def selection(n, topk, **made):
+        _, (_, _, _, qi, ki, wi) = _selecting_row(n, 4, 32, jnp.float32, **made)
+        scores = jnp.where(jnp.tril(jnp.ones((n, n), bool)),
+                           index_scores(qi, wi, ki)[0], -jnp.inf)
+        return np.asarray(jax.lax.top_k(scores, topk)[1]), np.asarray(scores)
+
+    sel, _ = selection(160, 16, dead=(2,))
+    assert not np.isin(sel[96:] // 32, [2]).any()
+    sel, scores = selection(96, 16, live=16)
+    for query in (64, 95):
+        open_keys = [s for s in range(query + 1) if s % 16 == 0]
+        assert len(open_keys) < 16
+        assert sorted(sel[query]) == sorted(
+            open_keys + [s for s in range(96) if s % 16][:16 - len(open_keys)])
+        assert (scores[query, :query + 1] == 0).sum() > 16
+
+
+def test_engine_prefills_a_prompt_in_chunks_to_the_rows_end(model, loop):
+    """50 tokens in chunks of 16 into a row of 60: the first chunk is local
+    (no more than `index_topk` tokens from position 0), the next two select
+    over the row's pages, and so does the last, 2 tokens in a bucket of 16
+    clamped to the 12 the row has left; the logprobs are the uncached
+    forward's."""
+    cfg, _, params = model
+    srv = LLMServer(_llm_cfg(num_pages=40, prefill_chunk=16, max_seq_len=60),
+                    params=params)
+    try:
+        prompt = _tokens(50, 50)
+        out = _generate(loop, srv, prompt, 6, logprobs=True)
+        d = srv.stats()["decode"]
+    finally:
+        srv._kv_stash.close()
+    assert (d["prefill_chunks"], d["continuation_chunks"]) == (4, 3)
+    assert d["prefill_padded_tokens"] == 16 * 3 + 12
+    seq = prompt + out["tokens"]
+    logp = jax.nn.log_softmax(_reference_logits(params, seq[:-1], cfg), -1)
+    want = [float(logp[49 + i, t]) for i, t in enumerate(out["tokens"])]
+    np.testing.assert_allclose(out["logprobs"], want, atol=TOL)
+
+
+@pytest.mark.parametrize("bits", [2, 3, 4])
+def test_kth_largest_is_the_sorts(bits):
+    """The radix select at the digit widths PERF.md compares on the chip (32
+    is a multiple of two of them): rows
+    with -inf past their end, a run of zeros of both signs across the k-th
+    place, and k from the first to past the valid keys."""
+    rng = np.random.default_rng(bits)
+    x = rng.normal(size=(12, 300)).astype(np.float32)
+    x[:, 250:] = -np.inf
+    x[3, :200] = 0.0
+    x[5, 10:40] = -0.0
+    x[7, :] = np.float32(1.5)
+    for k in (1, 7, 64, 250, 300):
+        keys, kth = kth_largest(jnp.asarray(x), k, bits)
+        keys, kth = np.asarray(keys), np.asarray(kth)
+        want = np.sort(x, -1)[:, ::-1][:, k - 1]
+        got = np.take_along_axis(x, np.argmax(keys == kth, -1)[:, None], -1)
+        np.testing.assert_array_equal(got[:, 0], want)    # (-0.0 == 0.0)
+        # the keys' order is the floats'
+        for row in (0, 5):
+            order = np.argsort(keys[row], kind="stable")
+            np.testing.assert_array_equal(x[row][order], np.sort(x[row]))
+
+
+@pytest.mark.parametrize("start,t", [(0, 24), (5, 16), (8, 8), (13, 40),
+                                     (63, 3), (150, 42)])
+def test_chunk_write_equals_token_writes_in_three_pools(start, t):
+    """A chunk's rows go into the three pools as its tokens would one by one
+    (the indexer's keys page by page, two tokens to a row of its pool),
+    whatever the offset of the first in its page, and nothing else moves."""
+    cfg = _cfg()
+    rng = np.random.default_rng(start)
+    cache = _row_cache(cfg)
+    cache = cache.with_pools([jnp.asarray(rng.normal(size=p.shape), p.dtype)
+                              for p in cache.pools()])
+    assert cache.idx_pages.shape[-2:] == (PS // 8, 8 * cfg.index_dim)
+    k, v = (jnp.asarray(rng.normal(size=(1, t, cfg.n_kv_heads, cfg.head_dim)),
+                        jnp.float32) for _ in range(2))
+    ki = jnp.asarray(rng.normal(size=(1, t, cfg.index_dim)), jnp.float32)
+    positions = start + jnp.arange(t)[None]
+    chunked = jax.jit(write_layer_tokens, static_argnums=(1,))(
+        cache, 1, k, v, positions, ki)
+    by_token = cache
+    for i in range(t):
+        by_token = write_layer_tokens(by_token, 1, k[:, i:i + 1],
+                                      v[:, i:i + 1], positions[:, i:i + 1],
+                                      ki[:, i:i + 1])
+    for got, want, was in zip(chunked.pools(), by_token.pools(),
+                              cache.pools()):
+        np.testing.assert_array_equal(got, want)
+        assert not np.array_equal(got, was)
 
 
 def test_control_without_the_selection_fails_above_topk(model):

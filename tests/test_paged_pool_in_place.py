@@ -377,6 +377,8 @@ def _step_compiled_for(v5e, cfg, pages, rows, width, tokens=1,
         jax.random.PRNGKey(0)))
     window = ({"window": dict(layers=cfg.n_window_layers,
                               num_pages=window_pages)} if window_pages else {})
+    if cfg.index_topk:          # the token-major layout and the third pool
+        window = {"index_dim": cfg.index_dim}
     cache = on_chip(jax.eval_shape(lambda: PagedKVCache.init(
         cfg.n_layers - cfg.n_window_layers, cfg.n_kv_heads, cfg.head_dim,
         pages, 64, rows, width, **window)))
@@ -451,6 +453,36 @@ def test_continuation_compiled_for_the_v5e_at_the_cells_shapes(
     moved = _pool_sized_results(
         hlo, rf"bf16\[({layers},)?{kv_heads},{pages},64,{d}\]")
     assert not moved, f"{cell}: pool-sized results of {sorted(moved)}"
+
+
+@pytest.mark.parametrize("tokens", [512, 64, 16, 500])
+def test_selected_prefill_compiled_for_the_v5e_at_the_cells_shapes(v5e,
+                                                                   tokens):
+    """`keye30b-longdoc-batch`'s continuation chunk (32 query heads on 4 kv
+    heads of 128, a 16-head indexer of 64 that selects 2048, a row of 464
+    pages of 64 in token-major pools of 12288), at the engine's buckets and
+    at one clamped to what a row has left: the v5e's compiler produces
+    nothing the size of a pool, the indexer's included, but the in-place
+    writes; the second pass reads the row a key block at a time."""
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    layers, pages, width = 2, 12288, 464
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=layers,
+                      n_heads=32, n_kv_heads=4, head_dim=128, ffn_dim=512,
+                      max_seq_len=width * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16, qk_norm=True, index_heads=16,
+                      index_dim=64, index_topk=2048)
+    hlo = _step_compiled_for(v5e, cfg, pages, 1, width, tokens)
+    assert "tpu_custom_call" not in hlo
+    assert not re.search(rf"bf16\[1,4,{width},64,128\]", hlo)   # no row copy
+    pool = rf"bf16\[({layers},)?{pages},(64,4,128|32,128)\]"
+    moved = {m for m in _pool_sized_results(hlo, pool) if m[0] != "scatter"}
+    written = {name for line in hlo.splitlines() if re.search(
+        r'op_name="[^"]*/scatter"', line)
+        for name in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", line)}
+    assert not {m for m in moved if m[1] not in written}, sorted(moved)
 
 
 @pytest.mark.parametrize("cell,rows,width,heads,head_dim", [
