@@ -15,8 +15,7 @@ KV. One JSON line:
    "paged": {...}, "B": .., "decode_chunk": .., "backend": ..}
 SECTIONS=dense,paged,prefix,speculative,pd selects sections (all by
 default). The `pd` section runs disaggregated prefill/decode on a
-shared-prefix workload, streaming KV plane vs the legacy KV-over-RPC
-hand-off. `--smoke` is the tier-1 CPU gate for the streaming plane:
+shared-prefix workload over the streaming KV plane. `--smoke` is the tier-1 CPU gate for the streaming plane:
 asserts the kv_ship counters moved and that no KV bytes rode the RPC
 control frames.
 """
@@ -212,7 +211,7 @@ class _WirePrefill:
 
     def __init__(self, srv):
         for name in ("prefill_begin", "prefill_wait", "prefill_fetch",
-                     "prefill_drop", "prefill_kv"):
+                     "prefill_drop"):
             setattr(self, name, _WireMethod(getattr(srv, name)))
 
 
@@ -220,10 +219,8 @@ def bench_pd():
     """Disaggregated prefill/decode on a high-prefix-overlap workload:
     every request shares a long base prompt and differs in a 3-token tail,
     with a short decode (the TTFT-bound regime disaggregation targets).
-    Runs the SAME workload twice — the streaming KV-page plane (default)
-    vs the legacy whole-KV-in-the-RPC hand-off (RAY_TPU_KV_SHIP=0) — and
-    reports tokens/s, TTFT, the counter deltas, and the fraction of pages
-    the prefix-aware ship never had to move. The hand-off crosses a
+    Reports tokens/s, TTFT, the kv_ship counter deltas, and the fraction of
+    pages the prefix-aware ship never had to move. The hand-off crosses a
     _WirePrefill pickle boundary both ways so frame payload size has its
     real cost; on CPU the tiny preset's KV is widened (model_overrides)
     to an LLM-realistic ~4 KiB/token so the hand-off isn't measurement
@@ -251,59 +248,40 @@ def bench_pd():
 
     base = list(range(1, plen - 3))
 
-    def run(ship: bool):
-        prev = os.environ.get("RAY_TPU_KV_SHIP")
-        os.environ["RAY_TPU_KV_SHIP"] = "1" if ship else "0"
-        try:
-            prefill = PrefillServer(cfg())
-            pd = PDServer(cfg(), params=prefill.params,
-                          prefill=_WirePrefill(prefill))
+    prefill = PrefillServer(cfg())
+    pd = PDServer(cfg(), params=prefill.params,
+                  prefill=_WirePrefill(prefill))
 
-            async def one(i):
-                out = await pd.generate(base + [240 + (i % 8), 249, 250],
-                                        max_tokens=gen_tokens)
-                return out["ttft_s"], len(out["tokens"])
+    async def one(i):
+        out = await pd.generate(base + [240 + (i % 8), 249, 250],
+                                max_tokens=gen_tokens)
+        return out["ttft_s"], len(out["tokens"])
 
-            async def rnd(k):
-                return await asyncio.gather(
-                    *[one(k * B + j) for j in range(B)])
+    async def rnd(k):
+        return await asyncio.gather(*[one(k * B + j) for j in range(B)])
 
-            # two warm rounds: round 0 compiles the cold-prefill programs,
-            # round 1 the warm-cache suffix-chunk variants
-            asyncio.run(rnd(0))
-            asyncio.run(rnd(1))
-            c0 = _metrics.kv_ship_counters()
-            ttfts = []
-            toks = 0
-            t0 = time.perf_counter()
-            for r in range(ROUNDS):
-                for ttft, n in asyncio.run(rnd(r + 2)):
-                    ttfts.append(ttft)
-                    toks += n
-            dt = time.perf_counter() - t0
-            c1 = _metrics.kv_ship_counters()
-            ttfts.sort()
-            rec = {"tokens_per_s": round(toks / dt, 1),
-                   "ttft_p50_ms": round(ttfts[len(ttfts) // 2] * 1e3, 1),
-                   "requests": len(ttfts)}
-            if ship:
-                rec["kv_ship"] = {k: round(c1[k] - c0[k], 1) for k in c1}
-            return rec
-        finally:
-            if prev is None:
-                os.environ.pop("RAY_TPU_KV_SHIP", None)
-            else:
-                os.environ["RAY_TPU_KV_SHIP"] = prev
-
-    stream = run(True)
-    rpc = run(False)
-    shipped = stream["kv_ship"]["pages"]
-    saved = stream["kv_ship"]["saved_pages"]
-    return {"stream": stream, "rpc": rpc,
-            "stream_over_rpc": round(
-                stream["tokens_per_s"] / max(rpc["tokens_per_s"], 1e-9), 2),
+    # two warm rounds: round 0 compiles the cold-prefill programs,
+    # round 1 the warm-cache suffix-chunk variants
+    asyncio.run(rnd(0))
+    asyncio.run(rnd(1))
+    c0 = _metrics.kv_ship_counters()
+    ttfts = []
+    toks = 0
+    t0 = time.perf_counter()
+    for r in range(ROUNDS):
+        for ttft, n in asyncio.run(rnd(r + 2)):
+            ttfts.append(ttft)
+            toks += n
+    dt = time.perf_counter() - t0
+    c1 = _metrics.kv_ship_counters()
+    ttfts.sort()
+    ship = {k: round(c1[k] - c0[k], 1) for k in c1}
+    return {"tokens_per_s": round(toks / dt, 1),
+            "ttft_p50_ms": round(ttfts[len(ttfts) // 2] * 1e3, 1),
+            "requests": len(ttfts), "kv_ship": ship,
             "saved_page_fraction": round(
-                saved / max(saved + shipped, 1.0), 3)}
+                ship["saved_pages"]
+                / max(ship["saved_pages"] + ship["pages"], 1.0), 3)}
 
 
 def smoke() -> int:

@@ -47,7 +47,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -835,39 +834,6 @@ def row_keys_values(cache: PagedKVCache, layer_idx: int,
     b, kh, mp, ps, d = k.shape
     to_rows = lambda x: x.reshape(b, kh, mp * ps, d).swapaxes(1, 2)
     return to_rows(k), to_rows(v)
-
-
-def pages_to_tokens(cache: PagedKVCache, blocks, n_tokens: int) -> list:
-    """Host blocks of n pages in the pools' own form (`gather_pages(...,
-    page_major=False)`) -> one array a pool with the first `n_tokens` tokens
-    along one dimension, where the pool has its pages ([L, Kh, T, D] k and v
-    in the dense layout; an indexer's keys [L, T, Di], whatever way its pool
-    packs a page's tokens). The legacy P/D hand-off's form."""
-    axis, ps = cache.page_axis, cache.page_size
-    out = []
-    for block in blocks:
-        sh = block.shape
-        per_token = sh[axis + 2:]
-        if int(np.prod(sh[axis + 1:])) != ps * int(np.prod(per_token)):
-            per_token = (int(np.prod(sh[axis + 1:])) // ps,)   # packed
-        tokens = block.reshape(sh[:axis] + (sh[axis] * ps,) + per_token)
-        out.append(np.take(tokens, np.arange(n_tokens), axis=axis))
-    return out
-
-
-def tokens_to_pages(cache: PagedKVCache, arrays) -> list:
-    """`pages_to_tokens` undone: each array padded to whole pages and cut
-    into them, in its pool's own form."""
-    axis, ps = cache.page_axis, cache.page_size
-    out = []
-    for x, pool in zip(arrays, cache.pools()):
-        x = np.asarray(x)
-        n = -(-x.shape[axis] // ps)
-        pad = [(0, 0)] * x.ndim
-        pad[axis] = (0, n * ps - x.shape[axis])
-        out.append(np.pad(x, pad).reshape(
-            x.shape[:axis] + (n,) + pool.shape[axis + 1:]))
-    return out
 
 
 # ---------------------------------------------------------------------------

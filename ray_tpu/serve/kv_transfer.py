@@ -1,9 +1,8 @@
-"""Zero-copy KV-page shipment plane for prefill/decode disaggregation.
+"""KV pages that leave the page pool: the prefill/decode shipment plane and
+the demotion tier.
 
-The legacy PD hand-off returned each request's full prompt KV as host
-numpy arrays inside the actor-RPC reply — a serialized copy of the
-entire prompt cache on the hot path. This module turns the hand-off
-into a streaming data plane over the object store:
+A prefill replica hands a prompt's KV to a decode replica as a STREAMING
+data plane over the object store; no RPC frame carries KV bytes:
 
   * the PREFILL side seals extracted KV pages into per-object shm
     segments (``StoreClient.create_writable`` → fill → seal, plasma
@@ -20,6 +19,10 @@ Segments are published per prefill CHUNK, so the decode pull of chunk i
 overlaps the prefill compute of chunk i+1 — the serving-side analog of
 the r8 prefetch/execute overlap.
 
+Pages the radix tree evicts go the same way out of the pool and come back
+by it: `DemotionTier` owns them from the gather on the device to the
+`KVPageStash` segment (shm, then disk) and back into a pool page.
+
 Naming: ``object_store.seg_name`` keeps only the oid's last 16 chars,
 so ship oids are exactly 16 chars — an 8-hex per-process tag, a 4-hex
 ship counter, a 3-hex segment index, and one role suffix. The storage
@@ -30,10 +33,12 @@ lands the copy under the wire id.
 """
 
 import asyncio
+import collections
 import concurrent.futures
 import itertools
 import os
 import socket as _socket
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -46,21 +51,24 @@ _proc_tag = os.urandom(4).hex()          # 8 chars, fresh per process
 _ship_counter = itertools.count(1)
 
 
-def kv_ship_enabled() -> bool:
-    """Streaming is the default; RAY_TPU_KV_SHIP=0 restores the legacy
-    KV-over-RPC hand-off (the bench's comparison baseline)."""
-    return os.environ.get("RAY_TPU_KV_SHIP", "1") != "0"
-
-
 # shm a stash holds in demoted KV pages before it spills its oldest segments
 # to the disk tier (what a KVPageStash built without `budget_bytes` gets)
 STASH_BUDGET_BYTES = 256 << 20
 
-
-def local_attach_enabled() -> bool:
-    """RAY_TPU_KV_ATTACH=0 disables the same-host zero-copy attach so
-    tests can force the parallel_fetch / RPC pull paths on one host."""
-    return os.environ.get("RAY_TPU_KV_ATTACH", "1") != "0"
+# Demotion of evicted prefix pages. One gather program whatever the pass
+# size: its index vector always has DEMOTE_GROUP entries, padded with the
+# reserved placeholder page 0, and a longer pass calls it again. Gathered
+# pages are staged (on the device and, once copied, on the host) until the
+# stash's thread has sealed them: over STAGED_CAP_BYTES (or the deployment's
+# own `LLMConfig.staged_cap_bytes`) the loop waits for the oldest hand-off.
+# Restored pages go back in by the same group size. Constants, in pages and
+# bytes, so every page size is covered by one path; the mechanism's other two
+# are `radix_cache.DEMOTE_CAP` and STASH_BUDGET_BYTES.
+DEMOTE_GROUP = 8
+STAGED_CAP_BYTES = 128 << 20
+# what a DemotionTier counts, under the names stats()["decode"] gives them
+TIER_COUNTERS = {"demote_bytes": 0, "demote_passes": 0, "demote_wait_s": 0.0,
+                 "demote_inflight_max_bytes": 0, "restored_in_flight": 0}
 
 
 def new_ship_id() -> str:
@@ -226,7 +234,6 @@ class KVPageStash:
     round trips, and is valid from `new_handle` on."""
 
     def __init__(self, budget_bytes: int = STASH_BUDGET_BYTES):
-        import collections
         self.store = StoreClient(backend="pershm")
         self._seq = itertools.count(1)
         self._shm: "collections.OrderedDict[str, int]" = \
@@ -389,6 +396,201 @@ class KVPageStash:
     def _drop_all(self) -> None:
         for oid in list(self._shm) + list(self._disk):
             self._drop({"oid": oid})
+
+
+class DemotionTier:
+    """Owner of every prefix page between the pool and the stash: the pass
+    being evicted, the gathered groups whose copy to the host or whose seal
+    is still in flight, the restores not yet landed, the two programs that
+    move whole pages (both always DEMOTE_GROUP page ids), the stash itself.
+
+    `hooks()` is what a `radix_cache.PageManager` is built with; `failed` is
+    the manager's `demotion_failed`, the one call back. The cache is never
+    kept: `read_cache()` gives the newest one when a pass is gathered (the
+    manager calls `demote_pass` from inside an allocation), `flush_restores`
+    and `warm` take it and return the donated result. Everything but
+    `staged_bytes` (a plain read) belongs to the engine loop's thread.
+
+    Build it, then `cache = tier.warm(cache)` before the first eviction:
+    both programs compile there, so nothing compiles once a replica serves.
+    `phases` must have `demote`, `demote_stash` and `restore`."""
+
+    def __init__(self, read_cache, phases: PhaseTotals, failed,
+                 staged_cap_bytes: Optional[int] = None):
+        self.stash = KVPageStash()
+        self.staged_cap_bytes = staged_cap_bytes or STAGED_CAP_BYTES
+        self._read_cache = read_cache
+        self._phases = phases
+        self._failed = failed
+        # the pass being evicted [(page id, node, handle)]; hand-offs the
+        # stash's thread has not been seen to finish, oldest first [(future,
+        # pages, nbytes)]; the staged copy of every page in them, oid -> (its
+        # group of each per-page array, row); restores fetched and not yet
+        # in the pool [(page id, blocks)]
+        self._evicting = []
+        self._handoffs = collections.deque()
+        self._staged = {}
+        self.staged_bytes = 0
+        self._pending_restores = []
+        self._layout = self._gather = self._scatter = None
+        self.counters = dict(TIER_COUNTERS)
+
+    def warm(self, cache):
+        """Take `cache`'s page layout and compile both programs against it
+        (the restore writes zeros to the placeholder page 0). Returns the
+        cache the restore was donated."""
+        import jax
+
+        from ray_tpu.ops.paged_attention import (gather_pages, page_layout,
+                                                 scatter_pages)
+        self._layout = page_layout(cache)
+        # pages out and back in by groups of DEMOTE_GROUP ids, page-major,
+        # the restore into the donated cache
+        self._gather = jax.jit(gather_pages)
+        self._scatter = jax.jit(scatter_pages, donate_argnums=(0,))
+        idx = np.zeros((DEMOTE_GROUP,), np.int32)
+        self._gather(cache, idx)
+        return self._scatter(cache, idx, tuple(
+            np.zeros((DEMOTE_GROUP, *block["shape"]), pool.dtype)
+            for block, pool in zip(self._layout, cache.pools())))
+
+    def hooks(self) -> Dict[str, Any]:
+        return dict(demote_cb=self.demote_page,
+                    demote_flush_cb=self.demote_pass,
+                    restore_cb=self.restore_page, drop_cb=self.drop_page)
+
+    def demote_page(self, pid: int, node) -> Dict[str, Any]:
+        """radix demote_cb: note page `pid` for this pass's gather and give
+        its node the handle its blocks (one of every per-page pool: k, v,
+        and an indexer's keys where the cache has them) will be stashed
+        under. Nothing leaves the device here."""
+        handle = self.stash.new_handle(self._layout)
+        self._evicting.append((pid, node, handle))
+        return handle
+
+    def demote_pass(self) -> None:
+        """radix demote_flush_cb, at the end of an eviction pass: DISPATCH
+        the gather of the pass's pages out of the pool, start their copy to
+        the host and hand them to the stash's thread, which waits for the
+        copy, seals and spills. The loop waits for none of it: the gather
+        is on the device stream before the admitting request's prefill and
+        every later decode chunk, so the pool pages are free to be written
+        at once. Whatever raises here discards the pages it had not handed
+        over and is counted; serving goes on."""
+        pages, self._evicting = self._evicting, []
+        if not pages:
+            return
+        with phase(self._phases, "demote"):
+            self.reap()
+            handed = 0
+            try:
+                cache = self._read_cache()
+                groups = []
+                for i in range(0, len(pages), DEMOTE_GROUP):
+                    part = pages[i:i + DEMOTE_GROUP]
+                    idx = np.zeros((DEMOTE_GROUP,), np.int32)
+                    idx[:len(part)] = [pid for pid, _, _ in part]
+                    blocks = self._gather(cache, idx)
+                    for block in blocks:
+                        block.copy_to_host_async()
+                    groups.append((part, blocks))
+                self.counters["demote_passes"] += 1
+                with phase(self._phases, "demote_stash"):
+                    for part, blocks in groups:
+                        self._hand_off(part, blocks)
+                        handed += len(part)
+            except Exception as e:  # noqa: BLE001 - demotion is best-effort
+                for _, node, handle in pages[handed:]:
+                    self._failed(node, handle, e)
+
+    def _hand_off(self, part, blocks) -> None:
+        """Give one gathered group (a [G, ...] array of every per-page
+        pool) to the stash's thread, first waiting for the oldest hand-offs
+        while the staged bytes are over the cap."""
+        st = self.counters
+        nbytes = sum(handle["nbytes"] for _, _, handle in part)
+        while (self._handoffs
+               and self.staged_bytes + nbytes > self.staged_cap_bytes):
+            t0 = time.perf_counter()
+            concurrent.futures.wait([self._handoffs[0][0]])
+            st["demote_wait_s"] += time.perf_counter() - t0
+            self.reap()
+        done = self.stash.put([h for _, _, h in part], *blocks)
+        self._handoffs.append((done, part, nbytes))
+        for row, (_, _, handle) in enumerate(part):
+            self._staged[handle["oid"]] = (blocks, row)
+        self.staged_bytes += nbytes
+        st["demote_bytes"] += nbytes
+        st["demote_inflight_max_bytes"] = max(
+            st["demote_inflight_max_bytes"], self.staged_bytes)
+
+    def reap(self) -> None:
+        """Let go of the staged copy of every hand-off the stash's thread
+        has finished, oldest first, and report to the page manager each
+        page that an exception over there kept out of the stash."""
+        while self._handoffs and self._handoffs[0][0].done():
+            done, part, nbytes = self._handoffs.popleft()
+            try:
+                errors = done.result()
+            except Exception as e:  # noqa: BLE001 - the transfer itself
+                errors = [e] * len(part)
+            for (_, node, handle), error in zip(part, errors):
+                del self._staged[handle["oid"]]
+                if error is not None:
+                    self._failed(node, handle, error)
+            self.staged_bytes -= nbytes
+
+    def restore_page(self, handle: Dict[str, Any], pid: int) -> bool:
+        """radix restore_cb: fetch the demoted page's blocks, one of every
+        per-page pool (bit-exact — the stash round-trips raw bytes, and a
+        page still on its way there is read from its staged copy, waiting
+        for the transfer if it must) and STAGE it; `flush_restores` lands
+        the staged pages by groups right after the allocation. A per-page
+        eager .at[].set would rewrite the whole pool buffer per page, making
+        restore cost rival the prefill it avoids."""
+        with phase(self._phases, "restore"):
+            staged = self._staged.get(handle["oid"])
+            if staged is not None:
+                groups, row = staged
+                blocks = tuple(np.asarray(g)[row] for g in groups)
+                self.counters["restored_in_flight"] += 1
+            else:
+                blocks = self.stash.get(handle)
+            self._pending_restores.append((pid, blocks))
+        return True
+
+    def flush_restores(self, cache):
+        """Land all staged restores in `cache`, a group of DEMOTE_GROUP
+        pages a call of the one donated scatter program (a short group is
+        padded with zeros for the placeholder page 0), and return the
+        result. Must run before prefill reads the pool (called from the
+        allocate path); the page manager already counts these pages as
+        cached."""
+        if not self._pending_restores:
+            return cache
+        with phase(self._phases, "restore"):
+            staged, self._pending_restores = self._pending_restores, []
+            for i in range(0, len(staged), DEMOTE_GROUP):
+                part = staged[i:i + DEMOTE_GROUP]
+                pad = DEMOTE_GROUP - len(part)
+                idx = np.zeros((DEMOTE_GROUP,), np.int32)
+                idx[:len(part)] = [pid for pid, _ in part]
+                cache = self._scatter(cache, idx, tuple(
+                    np.stack([blocks[j] for _, blocks in part]
+                             + [np.zeros_like(part[0][1][j])] * pad)
+                    for j in range(len(self._layout))))
+        return cache
+
+    def drop_page(self, handle: Dict[str, Any]) -> None:
+        self.stash.drop(handle)
+
+    def close(self) -> None:
+        """Wait for what the stash's thread was handed, let go of it, and
+        close the stash: no segment and no spill file is left. Closing
+        twice is fine."""
+        concurrent.futures.wait([h[0] for h in self._handoffs])
+        self.reap()
+        self.stash.close()
 
 
 class KVDataServer:
@@ -565,12 +767,11 @@ class ShipReader:
                     rpc_fetch=None) -> AttachedSegment:
         """Materialize one segment (`layout`: the header's `page_layout`):
         shm attach → parallel_fetch → RPC."""
-        if local_attach_enabled():
-            att = self._attach(seg["oid"], seg, layout, delete=False)
-            if att is not None:
-                _counter("kv_ship_attach_hits",
-                         "KV segments attached zero-copy same-host").inc()
-                return att
+        att = self._attach(seg["oid"], seg, layout, delete=False)
+        if att is not None:
+            _counter("kv_ship_attach_hits",
+                     "KV segments attached zero-copy same-host").inc()
+            return att
         if data_addr:
             from ray_tpu._private.node_agent import parallel_fetch
             got = await parallel_fetch([data_addr], seg["wire"],

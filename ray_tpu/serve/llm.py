@@ -2,12 +2,22 @@
 (reference: ray serve LLM examples / serve/llm vLLM integration; re-designed
 TPU-first instead of wrapping vLLM's CUDA paged attention).
 
-Design: B decode slots over a static-shape KVCache ([B, Smax] per layer,
-per-row lengths). Requests are admitted into free slots (prefill fills the
-row's cache), and ONE jitted decode call advances every active slot each
-tick — XLA sees the same program forever, no recompiles, while requests
-join/leave between ticks (continuous batching). Sampling is
-temperature/top-k on-device.
+Design: B decode slots over a PAGED cache (`LLMConfig(paged=True)`, what
+every benchmark cell runs): keys and values live in a pool of pages
+(`ops/paged_attention.py PagedKVCache`, read by the pallas kernels through
+a block table a slot), and `serve/radix_cache.py PageManager` owns which
+page belongs to which row, the radix tree of finished prompts' pages that
+later prompts share, and eviction. Requests are admitted into free slots
+with their pages reserved up front (prefill fills them by chunks), and ONE
+jitted decode call advances every active slot each tick — XLA sees the same
+program forever, no recompiles, while requests join/leave between ticks
+(continuous batching). Sampling is temperature/top-k/top-p on-device. Pages
+the tree evicts leave the pool and come back through a collaborator,
+`serve/kv_transfer.py DemotionTier`; `close()` gives back what it holds.
+
+The dense slot cache (`paged=False`: `models/llama.py KVCache`, [B, Smax] a
+layer with per-row lengths) is what `tp > 1` and `speculate` still need;
+nothing else does (ROADMAP D3).
 
 The decode tick is a fused MULTI-TOKEN chunk (Podracer/Anakin lesson —
 keep the inner loop on device): a lax.scan runs up to `decode_chunk`
@@ -26,15 +36,10 @@ is carried from chunk to chunk, a prompt's first token joins it there, and
 chunk k+1 is dispatched before chunk k is read (`_tick_loop_inner`). The
 host never blocks on the device with nothing queued behind what it waits
 for; `stats()["decode"]["read_wait_s"]` says when it did.
-
-The per-row `length` mask plays the role of vLLM's page table in round 1:
-slot rows are the "pages", eviction = slot free. A pallas paged-attention
-kernel over a real block table is the round-2 upgrade path.
 """
 
 import asyncio
 import collections
-import concurrent.futures
 import dataclasses
 import json
 import logging
@@ -44,6 +49,7 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+from ray_tpu.serve.kv_transfer import TIER_COUNTERS, DemotionTier
 from ray_tpu.util.tracing import PhaseTotals, StallWatch, phase
 
 logger = logging.getLogger(__name__)
@@ -77,17 +83,6 @@ WINDOW_PHASES = ("window_release",)
 # entries of the device's window table one write carries
 WINDOW_WRITE = 64
 
-# Demotion of evicted prefix pages. One gather program whatever the pass
-# size: its index vector always has DEMOTE_GROUP entries, padded with the
-# reserved placeholder page 0, and a longer pass calls it again. Gathered
-# pages are staged (on the device and, once copied, on the host) until the
-# stash's thread has sealed them: over STAGED_CAP_BYTES (or the deployment's
-# own `staged_cap_bytes`) the loop waits for the oldest hand-off. Restored
-# pages go back in by the same group size. Constants, in pages and bytes, so
-# every page size is covered by one path; the mechanism's other two are
-# `radix_cache.DEMOTE_CAP` and `kv_transfer.STASH_BUDGET_BYTES`.
-DEMOTE_GROUP = 8
-STAGED_CAP_BYTES = 128 << 20
 SNAPSHOTS_PER_SLOT = 4
 
 
@@ -167,7 +162,8 @@ class LLMConfig:
     decode_chunk: int = 8
     # Bytes of demoted pages that may be staged (gathered on the device and
     # copied to the host) before the stash's thread has sealed them; over it
-    # the engine loop waits for the oldest hand-off. None: STAGED_CAP_BYTES.
+    # the engine loop waits for the oldest hand-off. None:
+    # kv_transfer.STAGED_CAP_BYTES.
     # A deployment whose ONE admission evicts more than that raises it (a
     # 28k-token prompt of 557 KB pages is 250 MB), or every such admission
     # stalls the loop at the pace of the stash's disk.
@@ -371,6 +367,9 @@ class LLMServer:
                 "paged=True: their keys and values live in the paged "
                 "cache's window pool (so it cannot speculate either: "
                 "speculate needs paged=False)")
+        # pages that leave the pool and come back (kv_transfer.DemotionTier);
+        # None without a prefix cache
+        self._tier: Optional[DemotionTier] = None
         if cfg.paged:
             from ray_tpu.ops.paged_attention import PagedKVCache
             from ray_tpu.serve.radix_cache import PageManager
@@ -378,14 +377,14 @@ class LLMServer:
             max_pages = -(-cfg.max_seq_len // cfg.page_size)
             num_pages = cfg.num_pages or (B * max_pages + 1)
             # tiered KV (ISSUE 19): the radix tree demotes LRU-evicted
-            # prefix pages into the stash (shm → disk ladder) and restores
-            # them on a later match instead of recomputing prefill
-            self._kv_stash = None
-            self._pending_restores = []
+            # prefix pages into the tier's stash (shm → disk ladder) and
+            # restores them on a later match instead of recomputing prefill
             hooks = {}
             if cfg.prefix_cache:
-                from ray_tpu.serve.kv_transfer import KVPageStash
-                self._kv_stash = KVPageStash()
+                self._tier = DemotionTier(
+                    lambda: self.cache, self._phases,
+                    lambda *a: self.page_mgr.demotion_failed(*a),
+                    cfg.staged_cap_bytes)
                 # A model with state demotes nothing: eviction is leaf first,
                 # so the node that holds a chain's snapshot goes before the
                 # pages above it, and pages with no snapshot below them can
@@ -395,10 +394,7 @@ class LLMServer:
                 # not carried to the stash, and a chain restored without them
                 # could not be resumed from
                 if not self._stateful and not self._windowed:
-                    hooks = dict(demote_cb=self._demote_page,
-                                 demote_flush_cb=self._demote_pass,
-                                 restore_cb=self._restore_page,
-                                 drop_cb=self._drop_page)
+                    hooks = self._tier.hooks()
             # a model with state: every request in flight saves one snapshot
             # and the newest of as many conversations again have to outlive
             # them, so four a slot (one snapshot is a few thousand tokens'
@@ -444,8 +440,6 @@ class LLMServer:
                 index_dim=mc.index_dim if mc.index_topk else 0, **pools)
         else:
             self.page_mgr = None
-            self._kv_stash = None
-            self._pending_restores = []
             if self.mesh is not None:
                 # born sharded on the kv-head axis ([B, Smax, Kh, D]) to
                 # match the tp-sharded wk/wv projections — KV for a head
@@ -499,9 +493,7 @@ class LLMServer:
             "continuation_chunks": 0, "continuation_reach_keys": 0,
             "continuation_query_keys": 0,
             "admitted": 0, "slot_wait_s": 0.0,
-            "slot_wait_max_s": 0.0, "demote_bytes": 0, "demote_passes": 0,
-            "demote_wait_s": 0.0, "demote_inflight_max_bytes": 0,
-            "restored_in_flight": 0,
+            "slot_wait_max_s": 0.0,
             # the loop runs one program ahead of what it reads: chunks
             # dispatched while an unread one was in flight, first tokens
             # that joined their slot as device values, and seconds the host
@@ -540,15 +532,6 @@ class LLMServer:
         # times a slice of a run (a profiler's few seconds) needs the
         # slice's own count and not the run's mean
         self._moe_recent = collections.deque(maxlen=4096)
-        # demotion in flight, loop thread only: the pass being evicted
-        # [(page id, node, handle)]; hand-offs the stash's thread has not
-        # been seen to finish, oldest first [(future, pages, nbytes)]; and
-        # the staged copy of every page in them, oid -> (its group of each
-        # per-page array, row)
-        self._evicting = []
-        self._handoffs = collections.deque()
-        self._staged = {}
-        self._staged_bytes = 0
         from ray_tpu.util import metrics as _metrics
         # serving SLO histograms (TTFT / TPOT / occupancy / KV utilization),
         # tagged by engine flavor so paged and dense replicas in one process
@@ -963,23 +946,9 @@ class LLMServer:
             for save in (True, False):
                 self.cache = self._state_copy(
                     self.cache, np.zeros((2,), np.int32), save)
-        if self._kv_stash is not None:
-            from ray_tpu.ops.paged_attention import (gather_pages, page_layout,
-                                                     scatter_pages)
-            self._page_layout = page_layout(self.cache)
-            # demotion takes pages out and restore puts them back by groups
-            # of DEMOTE_GROUP ids, page-major, the restore into the donated
-            # cache. Both compiled here and not at the first eviction:
-            # nothing may compile once a replica serves (the warm-up writes
-            # zeros to the placeholder page 0)
-            self._gather_pages = jax.jit(gather_pages)
-            self._restore_group = jax.jit(scatter_pages, donate_argnums=(0,))
-            idx = np.zeros((DEMOTE_GROUP,), np.int32)
-            self._gather_pages(self.cache, idx)
-            self.cache = self._restore_group(self.cache, idx, tuple(
-                np.zeros((DEMOTE_GROUP, *block["shape"]), pool.dtype)
-                for block, pool in zip(self._page_layout,
-                                       self.cache.pools())))
+        if self._tier is not None:
+            # its two programs, compiled here and not at the first eviction
+            self.cache = self._tier.warm(self.cache)
         # first token goes through the SAME sampling policy as later ones
         self._sample_first = jax.jit(
             lambda logits, key, t, p, k, want_logp=True: tuple(
@@ -1135,6 +1104,19 @@ class LLMServer:
                 raise ValueError(f"decode_chunk must be >= 1, got {n}")
             self.config.decode_chunk = n
 
+    def close(self) -> None:
+        """Give back what the engine keeps outside the process (the shared
+        memory segments and spill files of demoted KV pages), after what is
+        on its way there. For whoever tears the server down; closing twice
+        is fine, and `stats()` still answers afterwards."""
+        if self._tier is not None:
+            self._tier.close()
+
+    @property
+    def _kv_stash(self):
+        """The tier's stash (None without one): read by perfbench."""
+        return self._tier.stash if self._tier is not None else None
+
     def _bucket(self, n: int) -> int:
         """Pad prompt lengths to power-of-two buckets: few compiled prefill
         variants instead of one per length. Clamped to the cache row size —
@@ -1258,7 +1240,7 @@ class LLMServer:
                     if use_prefix and self.config.prefix_cache:
                         row, cached = mgr.allocate_prefix(
                             slot_idx, list(prompt_ids), total_len)
-                        self._flush_restored_pages()
+                        self.cache = self._tier.flush_restores(self.cache)
                     else:
                         row = mgr.allocate(slot_idx, total_len)
                     # lengths[slot] must point PAST the shared prefix before
@@ -1409,7 +1391,8 @@ class LLMServer:
                 "first_pending": len(self._first_pending),
                 "active": len(self._active),
                 "queued_prompts": len(self._prefill_q),
-                "staged_bytes": self._staged_bytes,
+                "staged_bytes": (self._tier.staged_bytes
+                                 if self._tier is not None else 0),
                 "ticks": self._decode_stats["ticks"]}
 
     def _file_stall(self, record: Dict[str, Any]) -> None:
@@ -1446,132 +1429,6 @@ class LLMServer:
             raise
         finally:
             watch.stop()
-
-    # -- tiered KV: radix demote/restore hooks (ISSUE 19) --------------------
-    def _demote_page(self, pid: int, node) -> Dict[str, Any]:
-        """radix demote_cb: note page `pid` for this pass's gather and give
-        its node the handle its blocks (one of every per-page pool: k, v,
-        and an indexer's keys where the cache has them) will be stashed
-        under. Nothing leaves the device here."""
-        handle = self._kv_stash.new_handle(self._page_layout)
-        self._evicting.append((pid, node, handle))
-        return handle
-
-    def _demote_pass(self) -> None:
-        """radix demote_flush_cb, at the end of an eviction pass: DISPATCH
-        the gather of the pass's pages out of the pool, start their copy to
-        the host and hand them to the stash's thread, which waits for the
-        copy, seals and spills. The loop waits for none of it: the gather
-        is on the device stream before the admitting request's prefill and
-        every later decode chunk, so the pool pages are free to be written
-        at once. Whatever raises here discards the pages it had not handed
-        over and is counted; serving goes on."""
-        pages, self._evicting = self._evicting, []
-        if not pages:
-            return
-        st = self._decode_stats
-        with phase(self._phases, "demote"):
-            self._reap_handoffs()
-            handed = 0
-            try:
-                groups = []
-                for i in range(0, len(pages), DEMOTE_GROUP):
-                    part = pages[i:i + DEMOTE_GROUP]
-                    idx = np.zeros((DEMOTE_GROUP,), np.int32)
-                    idx[:len(part)] = [pid for pid, _, _ in part]
-                    blocks = self._gather_pages(self.cache, idx)
-                    for block in blocks:
-                        block.copy_to_host_async()
-                    groups.append((part, blocks))
-                st["demote_passes"] += 1
-                with phase(self._phases, "demote_stash"):
-                    for part, blocks in groups:
-                        self._hand_off(part, blocks)
-                        handed += len(part)
-            except Exception as e:  # noqa: BLE001 - demotion is best-effort
-                for _, node, handle in pages[handed:]:
-                    self.page_mgr.demotion_failed(node, handle, e)
-
-    def _hand_off(self, part, blocks) -> None:
-        """Give one gathered group (a [G, ...] array of every per-page
-        pool) to the stash's thread, first waiting for the oldest hand-offs
-        while the staged bytes are over the cap."""
-        st = self._decode_stats
-        nbytes = sum(handle["nbytes"] for _, _, handle in part)
-        while (self._handoffs
-               and self._staged_bytes + nbytes > (
-                   self.config.staged_cap_bytes or STAGED_CAP_BYTES)):
-            t0 = time.perf_counter()
-            concurrent.futures.wait([self._handoffs[0][0]])
-            st["demote_wait_s"] += time.perf_counter() - t0
-            self._reap_handoffs()
-        done = self._kv_stash.put([h for _, _, h in part], *blocks)
-        self._handoffs.append((done, part, nbytes))
-        for row, (_, _, handle) in enumerate(part):
-            self._staged[handle["oid"]] = (blocks, row)
-        self._staged_bytes += nbytes
-        st["demote_bytes"] += nbytes
-        st["demote_inflight_max_bytes"] = max(
-            st["demote_inflight_max_bytes"], self._staged_bytes)
-
-    def _reap_handoffs(self) -> None:
-        """Let go of the staged copy of every hand-off the stash's thread
-        has finished, oldest first, and report to the page manager each
-        page that an exception over there kept out of the stash."""
-        while self._handoffs and self._handoffs[0][0].done():
-            done, part, nbytes = self._handoffs.popleft()
-            try:
-                errors = done.result()
-            except Exception as e:  # noqa: BLE001 - the transfer itself
-                errors = [e] * len(part)
-            for (_, node, handle), error in zip(part, errors):
-                del self._staged[handle["oid"]]
-                if error is not None:
-                    self.page_mgr.demotion_failed(node, handle, error)
-            self._staged_bytes -= nbytes
-
-    def _restore_page(self, handle: Dict[str, Any], pid: int) -> bool:
-        """radix restore_cb: fetch the demoted page's blocks, one of every
-        per-page pool (bit-exact — the stash round-trips raw bytes, and a
-        page still on its way there is read from its staged copy, waiting
-        for the transfer if it must) and
-        STAGE it; _flush_restored_pages() lands the staged pages by groups
-        right after the allocation. A per-page eager .at[].set would
-        rewrite the whole pool buffer per page, making restore cost rival
-        the prefill it avoids."""
-        with phase(self._phases, "restore"):
-            staged = self._staged.get(handle["oid"])
-            if staged is not None:
-                groups, row = staged
-                blocks = tuple(np.asarray(g)[row] for g in groups)
-                self._decode_stats["restored_in_flight"] += 1
-            else:
-                blocks = self._kv_stash.get(handle)
-            self._pending_restores.append((pid, blocks))
-        return True
-
-    def _flush_restored_pages(self) -> None:
-        """Land all staged restores, a group of DEMOTE_GROUP pages a call of
-        the one donated scatter program (a short group is padded with zeros
-        for the placeholder page 0). Must run before prefill reads the pool
-        (called from the allocate path); the page manager already counts
-        these pages as cached."""
-        if not self._pending_restores:
-            return
-        with phase(self._phases, "restore"):
-            staged, self._pending_restores = self._pending_restores, []
-            for i in range(0, len(staged), DEMOTE_GROUP):
-                part = staged[i:i + DEMOTE_GROUP]
-                pad = DEMOTE_GROUP - len(part)
-                idx = np.zeros((DEMOTE_GROUP,), np.int32)
-                idx[:len(part)] = [pid for pid, _ in part]
-                self.cache = self._restore_group(self.cache, idx, tuple(
-                    np.stack([blocks[j] for _, blocks in part]
-                             + [np.zeros_like(part[0][1][j])] * pad)
-                    for j in range(len(self._page_layout))))
-
-    def _drop_page(self, handle: Dict[str, Any]) -> None:
-        self._kv_stash.drop(handle)
 
     def _copy_state(self, slot_idx: int, snapshot: int, save: bool) -> None:
         """A slot's recurrent state into snapshot `snapshot`, or out of it,
@@ -2064,7 +1921,9 @@ class LLMServer:
     def stats(self) -> Dict[str, Any]:
         s = {"active": len(self._active), "free_slots": len(self._free),
              "requests": self._req_counter}
-        self._reap_handoffs()
+        tier = self._tier
+        if tier is not None:
+            tier.reap()
         st = self._decode_stats
         s["decode"] = {
             "decode_chunk": self.config.decode_chunk,
@@ -2093,10 +1952,10 @@ class LLMServer:
                 "prefill_tokens", "prefill_padded_tokens",
                 "continuation_chunks", "continuation_reach_keys",
                 "continuation_query_keys", "admitted",
-                "slot_wait_s", "slot_wait_max_s", "demote_bytes",
-                "demote_passes", "demote_wait_s",
-                "demote_inflight_max_bytes", "restored_in_flight",
+                "slot_wait_s", "slot_wait_max_s",
                 "run_ahead_chunks", "joined_on_device", "read_wait_s")},
+            # the demotion tier's (0 where the engine has none)
+            **(tier.counters if tier is not None else TIER_COUNTERS),
             # the page manager's and the stash's own tallies, read and not
             # counted twice (0 where the engine has no such tier)
             **{k: getattr(self.page_mgr, k, 0) for k in (
@@ -2104,10 +1963,11 @@ class LLMServer:
                 "demote_failed")},
             "demote_last_error": getattr(self.page_mgr, "demote_last_error",
                                          None),
-            "stash_spilled_pages": getattr(self._kv_stash, "spilled_pages", 0),
+            "stash_spilled_pages": (tier.stash.spilled_pages
+                                    if tier is not None else 0),
             # the stash thread's busy seconds: its `stash.put` spans
-            "stash_worker_s": (self._kv_stash.phases.seconds["put"]
-                               if self._kv_stash is not None else 0.0),
+            "stash_worker_s": (tier.stash.phases.seconds["put"]
+                               if tier is not None else 0.0),
         }
         s["stalls"] = list(self._stalls)
         if self.model_cfg.index_topk:
@@ -2179,7 +2039,7 @@ class LLMServer:
         if self.page_mgr is not None and self.config.prefix_cache:
             mgr = self.page_mgr
             s["radix"] = mgr.node_stats()
-            s["radix"]["stash"] = self._kv_stash.tier_stats()
+            s["radix"]["stash"] = tier.stash.tier_stats()
             s["slo"]["radix"] = {
                 "prefix_nodes": mgr.prefix_nodes,
                 "prefix_hit_tokens": mgr.prefix_hit_tokens,

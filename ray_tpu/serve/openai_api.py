@@ -166,7 +166,7 @@ class OpenAIIngress:
         import asyncio
         loop = asyncio.get_running_loop()
         # DeploymentHandle: .remote() does sync controller IO — keep it off
-        # the loop (same pattern as pd.py _remote_prefill)
+        # the loop (same pattern as pd.py ShipSource._call)
         resp = await loop.run_in_executor(
             None, lambda: eng.generate.remote(prompt_ids, **kw))
         return await resp

@@ -25,9 +25,7 @@ payload:
     4-stream ranged transfer against the prefill's KVDataServer, or a
     raw-bytes RPC fetch as the last-resort fallback.
 
-RAY_TPU_KV_SHIP=0 restores the legacy whole-KV-in-the-RPC hand-off
-(the serving_bench `pd` section's comparison baseline). Requires
-paged=True (the dense cache has no page identity to ship).
+Requires paged=True (the dense cache has no page identity to ship).
 """
 
 import asyncio
@@ -161,7 +159,7 @@ class PrefillServer(LLMServer):
         job.task = asyncio.ensure_future(self._run_ship(
             ship_id, job, slot_idx, prompt, cached, skip_pages, trace_id,
             temperature, top_p, top_k, logprobs))
-        return {"ship": True, "ship_id": ship_id,
+        return {"ship_id": ship_id,
                 "layout": page_layout(self.cache),
                 "prompt_len": P, "page_size": ps,
                 "skip_pages": skip_pages, "total_pages": total_pages,
@@ -283,67 +281,6 @@ class PrefillServer(LLMServer):
             self._ship_writer.drop_ship(ship_id)
         return True
 
-    # ------------------------------------------------- legacy RPC hand-off
-    async def prefill_kv(self, prompt_ids: List[int],
-                         temperature: Optional[float] = None,
-                         top_p: Optional[float] = None,
-                         top_k: Optional[int] = None,
-                         logprobs: bool = False) -> Dict[str, Any]:
-        """Whole-KV-in-the-RPC hand-off (pre-streaming behavior; kept as
-        the RAY_TPU_KV_SHIP=0 baseline and for callers that want the raw
-        arrays)."""
-        import jax
-        import jax.numpy as jnp
-
-        from .llm import _PrefillJob
-
-        _require_paged(self, "PrefillServer")
-        cfg = self.config
-        prompt = list(prompt_ids)
-        P = len(prompt)
-        # plain allocation: extraction reads raw pages, prefix-sharing
-        # bookkeeping would complicate ownership for zero benefit here.
-        # Feasibility (max_seq_len / pool capacity) raises in _reserve.
-        slot_idx, _ = await self._reserve(prompt, P, use_prefix=False)
-        try:
-            job = _PrefillJob(slot_idx=slot_idx, slot=None,
-                              prompt=np.asarray(prompt, np.int32))
-            last_logits = None
-            while last_logits is None:
-                last_logits = self._prefill_chunk(job)
-                await asyncio.sleep(0)   # stay responsive between chunks
-            self._sample_key, sub = jax.random.split(self._sample_key)
-            first, flogp = self._sample_first(
-                last_logits, sub,
-                jnp.float32(cfg.temperature if temperature is None
-                            else temperature),
-                jnp.float32(cfg.top_p if top_p is None else top_p),
-                jnp.int32(cfg.top_k if top_k is None else top_k),
-                logprobs)
-            k, v, *extra = self._extract_kv(slot_idx, P)
-        finally:
-            self._release_slot(slot_idx)
-        out = {"k": k, "v": v, "prompt_len": P, "token": int(first)}
-        if extra:       # a cache's further per-page arrays (indexer keys)
-            out["extra"] = extra
-        if logprobs:
-            out["logprob"] = float(flogp)
-        return out
-
-    def _extract_kv(self, slot_idx: int, P: int):
-        """Slot pages → one host array a per-page pool with the P tokens
-        along one dimension, where the pool has its pages ([L, Kh, P, D] k
-        and v in the dense layout; an indexer's keys [L, P, Di], whatever
-        way its pool packs a page's tokens)."""
-        import jax
-
-        from ray_tpu.ops.paged_attention import pages_to_tokens
-
-        n = -(-P // self.config.page_size)
-        rows = np.asarray(jax.device_get(
-            self.cache.block_tables[slot_idx]))[:n]
-        return pages_to_tokens(self.cache, _pages_of(self.cache, rows), P)
-
 
 class ShipSource:
     """Decode-side endpoint bundle for one prefill replica's shipment API:
@@ -395,48 +332,20 @@ class DecodeServer(LLMServer):
     def _pd_slo_tags(self) -> Dict[str, str]:
         return {"engine": self._slo_tags["engine"], "path": "pd"}
 
-    async def _admit_with_kv(self, prompt: List[int], kv: Dict[str, Any],
+    async def _admit_with_kv(self, prompt: List[int], source: ShipSource,
                              max_tokens: int, eos_id, stream: bool,
                              temperature, top_p, top_k, logprobs,
                              t_request: Optional[float] = None):
-        """Install shipped KV into a reserved slot and hand the request to
-        the decode tick loop; returns (slot_idx, slot, finished_early).
-        `kv` is either the legacy whole-KV dict from prefill_kv or a
-        streaming descriptor {"ship": True, "source": ShipSource}."""
+        """Install the KV `source`'s prefill replica ships into a reserved
+        slot and hand the request to the decode tick loop; returns
+        (slot_idx, slot, finished_early). Reserves prefix-aware, asks
+        prefill for the non-cached suffix only, installs segments as they
+        seal (pull of chunk i overlaps prefill of chunk i+1)."""
+        from ray_tpu.ops.paged_attention import page_layout
+
         _require_paged(self, "DecodeServer")
         if t_request is None:
             t_request = time.monotonic()
-        if kv.get("ship"):
-            return await self._admit_streamed(
-                prompt, kv["source"], max_tokens, eos_id, stream,
-                temperature, top_p, top_k, logprobs, t_request)
-        P = len(prompt)
-        if kv["prompt_len"] != P:
-            raise ValueError("kv prompt_len does not match prompt")
-        slot_idx, _ = await self._reserve(prompt, P + max_tokens,
-                                          use_prefix=False)
-        try:
-            self._install_kv(slot_idx, [kv["k"], kv["v"],
-                                        *kv.get("extra", ())], P)
-        except BaseException:
-            self._release_slot(slot_idx)
-            raise
-        first = int(kv["token"])
-        logprob = float(kv["logprob"]) if logprobs and "logprob" in kv \
-            else None
-        return self._finish_admit(slot_idx, P, max_tokens, eos_id, stream,
-                                  temperature, top_p, top_k, logprobs,
-                                  first, logprob, t_request, None)
-
-    async def _admit_streamed(self, prompt: List[int], source: ShipSource,
-                              max_tokens: int, eos_id, stream: bool,
-                              temperature, top_p, top_k, logprobs,
-                              t_request: float):
-        """Streaming admission: reserve prefix-aware, ask prefill for the
-        non-cached suffix only, install segments as they seal (pull of
-        chunk i overlaps prefill of chunk i+1)."""
-        from ray_tpu.ops.paged_attention import page_layout
-
         P = len(prompt)
         ps = self.config.page_size
         trace_id = tracing.new_trace_id()
@@ -513,7 +422,7 @@ class DecodeServer(LLMServer):
                       logprobs: bool, first: int,
                       logprob: Optional[float], t_request: float,
                       trace_id: Optional[str]):
-        """Shared tail of both PD admission paths: build the slot, emit the
+        """The tail of a PD admission: build the slot, emit the
         prefill-sampled first token, observe PD TTFT, activate decode."""
         # prompt_ids=None: PD decode requires paged KV while speculation
         # requires the dense cache, so prompt-lookup drafting can never be
@@ -556,7 +465,7 @@ class DecodeServer(LLMServer):
         return slot_idx, slot, finished
 
     async def generate_with_kv(self, prompt_ids: List[int],
-                               kv: Dict[str, Any], max_tokens: int = 32,
+                               source: ShipSource, max_tokens: int = 32,
                                eos_id: Optional[int] = None,
                                temperature: Optional[float] = None,
                                top_p: Optional[float] = None,
@@ -567,7 +476,7 @@ class DecodeServer(LLMServer):
         t0 = time.perf_counter()
         prompt = list(prompt_ids)
         _idx, slot, finished = await self._admit_with_kv(
-            prompt, kv, max_tokens, eos_id, False, temperature, top_p,
+            prompt, source, max_tokens, eos_id, False, temperature, top_p,
             top_k, logprobs, t_request=t_request)
         ttft = time.perf_counter() - t0
         if not finished:
@@ -587,21 +496,6 @@ class DecodeServer(LLMServer):
         if logprobs:
             out["logprobs"] = slot.logprobs[:len(toks)]
         return out
-
-    def _install_kv(self, slot_idx: int, blocks, P: int) -> None:
-        """Scatter host KV, one array a per-page pool with the P tokens
-        contiguous (`_extract_kv`'s form: [L, Kh, P, D] k and v in the dense
-        layout), into this slot's allocated pages. One compile per
-        page-count `n`, the same bucketing cost profile as chunked prefill."""
-        import jax
-
-        from ray_tpu.ops.paged_attention import tokens_to_pages
-
-        n = -(-P // self.config.page_size)
-        rows = np.asarray(jax.device_get(
-            self.cache.block_tables[slot_idx]))[:n]
-        self._scatter_pages(slot_idx, rows,
-                            tokens_to_pages(self.cache, blocks), P)
 
     def _install_pages(self, slot_idx: int, page_start: int, n_pages: int,
                        blocks, plen: int) -> None:
@@ -660,23 +554,6 @@ class PDServer(DecodeServer):
             self._ship_src = ShipSource(self._prefill)
         return self._ship_src
 
-    async def _remote_prefill(self, prompt: List[int], **kw):
-        if isinstance(self._prefill, PrefillServer):
-            return await self._prefill.prefill_kv(prompt, **kw)
-        # serve DeploymentHandle: .remote() does sync controller IO (keep it
-        # off the loop); the DeploymentResponse itself is awaitable
-        loop = asyncio.get_running_loop()
-        resp = await loop.run_in_executor(
-            None, lambda: self._prefill.prefill_kv.remote(prompt, **kw))
-        return await resp
-
-    async def _pd_kv(self, prompt: List[int], **kw) -> Dict[str, Any]:
-        """The kv argument for this request: a streaming descriptor
-        (default), or the legacy full-KV RPC dict (RAY_TPU_KV_SHIP=0)."""
-        if kv_transfer.kv_ship_enabled():
-            return {"ship": True, "source": self._ship_source()}
-        return await self._remote_prefill(prompt, **kw)
-
     async def generate(self, prompt_ids: List[int], max_tokens: int = 32,
                        eos_id: Optional[int] = None,
                        temperature: Optional[float] = None,
@@ -691,9 +568,9 @@ class PDServer(DecodeServer):
         t_req = time.monotonic()
         kw = dict(temperature=temperature, top_p=top_p, top_k=top_k,
                   logprobs=logprobs)
-        kv = await self._pd_kv(list(prompt_ids), **kw)
         return await self.generate_with_kv(
-            list(prompt_ids), kv, max_tokens, eos_id, t_request=t_req, **kw)
+            list(prompt_ids), self._ship_source(), max_tokens, eos_id,
+            t_request=t_req, **kw)
 
     async def generate_stream(self, prompt_ids: List[int],
                               max_tokens: int = 32,
@@ -712,10 +589,8 @@ class PDServer(DecodeServer):
             return
         self.pd_requests += 1
         t_req = time.monotonic()
-        kw = dict(temperature=temperature, top_p=top_p, top_k=top_k)
-        kv = await self._pd_kv(list(prompt_ids), logprobs=False, **kw)
         _idx, slot, _fin = await self._admit_with_kv(
-            list(prompt_ids), kv, max_tokens, eos_id, True,
+            list(prompt_ids), self._ship_source(), max_tokens, eos_id, True,
             temperature, top_p, top_k, False, t_request=t_req)
         emitted = 0
         while emitted < max_tokens:

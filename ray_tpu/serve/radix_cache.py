@@ -73,9 +73,9 @@ import collections
 from ray_tpu.util.tracing import PhaseTotals, phase
 
 # Demoted nodes the tree keeps handles for (a second-chance tier, capped so
-# the handle table cannot grow without bound). With `llm.py`'s DEMOTE_GROUP
-# and STAGED_CAP_BYTES and `kv_transfer.py`'s STASH_BUDGET_BYTES, the
-# constants of the demotion mechanism.
+# the handle table cannot grow without bound). With `kv_transfer.py`'s
+# DEMOTE_GROUP, STAGED_CAP_BYTES and STASH_BUDGET_BYTES, the constants of the
+# demotion mechanism.
 DEMOTE_CAP = 4096
 
 

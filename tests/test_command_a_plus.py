@@ -45,7 +45,7 @@ def _server(**kw):
 def server():
     srv = _server()
     yield srv
-    srv._kv_stash.close()
+    srv.close()
 
 
 def _errs(srv, prompt, out, weights_as=None):

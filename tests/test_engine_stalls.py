@@ -45,7 +45,7 @@ def srv():
         preset="tiny", max_batch_slots=2, max_seq_len=64, paged=True,
         page_size=8, num_pages=15, prefill_chunk=16, decode_chunk=4, seed=0))
     yield server
-    server._kv_stash.close()
+    server.close()
 
 
 def _generate(srv, loop, n=3, seed=3):

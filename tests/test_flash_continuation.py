@@ -240,7 +240,7 @@ def test_chunked_and_resumed_prefill_is_the_uncached_forward(preset):
         try:
             a, mid, b, end = asyncio.run(run())
         finally:
-            srv._kv_stash.close()
+            srv.close()
     assert set(traced) == {16, 32}                 # the kernel was traced
 
     for prompt, out in ((first, a), (second, b)):

@@ -328,7 +328,7 @@ def test_engine_prefills_a_prompt_in_chunks_to_the_rows_end(model, loop):
         out = _generate(loop, srv, prompt, 6, logprobs=True)
         d = srv.stats()["decode"]
     finally:
-        srv._kv_stash.close()
+        srv.close()
     assert (d["prefill_chunks"], d["continuation_chunks"]) == (4, 3)
     assert d["prefill_padded_tokens"] == 16 * 3 + 12
     seq = prompt + out["tokens"]
@@ -458,11 +458,11 @@ def test_engine_builds_three_pools_from_the_schema(loop):
                                     max_seq_len=64, max_batch_slots=2))
         assert dense.cache.idx_pages is None and dense.cache.page_axis == 2
         assert len(jax.tree_util.tree_leaves(dense.cache)) == 4
-        dense._kv_stash.close()
+        dense.close()
         with pytest.raises(ValueError, match="paged=True"):
             LLMServer(_llm_cfg(paged=False))
     finally:
-        srv._kv_stash.close()
+        srv.close()
 
 
 @pytest.mark.parametrize("n_prompt", [10, 50, 100])
@@ -480,7 +480,7 @@ def test_engine_logprobs_agree_with_reference_and_chunk_equals_steps(
         try:
             outs.append(_generate(loop, srv, prompt, 11, logprobs=True))
         finally:
-            srv._kv_stash.close()
+            srv.close()
     fused, single = outs
     assert fused["tokens"] == single["tokens"]
     np.testing.assert_array_equal(fused["logprobs"], single["logprobs"])
@@ -528,7 +528,7 @@ def test_demote_and_restore_are_bit_exact_in_three_pools(model, loop):
                  for node in srv.page_mgr._walk(prompts[0])]
         assert after == list(before.values())
     finally:
-        srv._kv_stash.close()
+        srv.close()
 
 
 def test_stash_handle_records_every_array(tmp_path):
@@ -556,11 +556,10 @@ def test_stash_handle_records_every_array(tmp_path):
         stash.close()
 
 
-@pytest.mark.parametrize("ship", ["1", "0"])     # the shipment plane, the RPC
-def test_pd_hand_off_is_bit_exact_in_three_pools(model, loop, monkeypatch,
-                                                 ship):
+def test_pd_hand_off_is_bit_exact_in_three_pools(model, loop):
+    from ray_tpu.ops.paged_attention import gather_pages
+    from ray_tpu.serve.kv_transfer import ShipReader
     from ray_tpu.serve.pd import PDServer, PrefillServer
-    monkeypatch.setenv("RAY_TPU_KV_SHIP", ship)
     _, _, params = model
     kw = dict(num_pages=60, prefix_cache=False)
     plain = LLMServer(_llm_cfg(**kw), params=params)
@@ -572,16 +571,39 @@ def test_pd_hand_off_is_bit_exact_in_three_pools(model, loop, monkeypatch,
     assert got["tokens"] == want["tokens"]
     np.testing.assert_allclose(got["logprobs"], want["logprobs"], atol=1e-6)
     assert pd.stats()["pd_requests"] == 1
-    # the pages the decode replica was handed are the prefill's, bit for bit
+
+    # the pages the decode replica holds are the prefill's, bit for bit
+    def held(srv, rows):
+        return [np.asarray(b).tobytes() for b in gather_pages(
+            srv.cache, jnp.asarray(rows, jnp.int32), page_major=False)]
+
     async def both():
-        kv = await prefill.prefill_kv(prompt)
+        free = set(prefill._free)
+        header = await prefill.prefill_begin(prompt)
+        (p_slot,) = free - set(prefill._free)
+        n = header["total_pages"]
+        p_rows = prefill.page_mgr.table_slice(p_slot, 0, n)
         slot, _ = await pd._reserve(prompt, len(prompt) + 1, use_prefix=False)
-        pd._install_kv(slot, [kv["k"], kv["v"], *kv["extra"]], len(prompt))
-        return kv, PrefillServer._extract_kv(pd, slot, len(prompt)), slot
-    kv, back, slot = loop.run_until_complete(both())
-    assert len(kv["extra"]) == 1 and len(back) == 3
-    for sent, got_back in zip([kv["k"], kv["v"], *kv["extra"]], back):
-        assert sent.tobytes() == got_back.tobytes()
+        reader, have, res = ShipReader(), 0, {"done": False}
+        while not res["done"]:
+            res = await prefill.prefill_wait(header["ship_id"], have)
+            have += len(res["segments"])
+            for seg in res["segments"]:
+                att = await reader.fetch(
+                    seg, header["layout"], header["data_addr"],
+                    rpc_fetch=lambda oid: prefill.prefill_fetch(
+                        header["ship_id"], oid))
+                assert len(att.blocks) == 3
+                pd._install_pages(slot, seg["page_start"], seg["n_pages"],
+                                  att.blocks, len(prompt))
+                att.close()
+        await prefill.prefill_drop(header["ship_id"])
+        # (the prefill replica has let go of its pages by now; nothing has
+        # written them since)
+        return held(prefill, p_rows), held(pd, pd.page_mgr.table_slice(
+            slot, 0, n)), slot
+    sent, back, slot = loop.run_until_complete(both())
+    assert len(sent) == len(back) == 3 and sent == back
     pd._release_slot(slot)
 
 
@@ -606,7 +628,7 @@ def test_sparse_counters_arithmetic(loop, first, steps):
         assert got["index_pool_bytes"] == srv.cache.idx_pages.nbytes == (
             2 * 20 * PS * srv.model_cfg.index_dim * 4)
     finally:
-        srv._kv_stash.close()
+        srv.close()
 
 
 def test_moe_and_sparse_counters_follow_a_request(model, loop):
@@ -655,6 +677,6 @@ def test_moe_and_sparse_counters_follow_a_request(model, loop):
         assert moe["computed_rows"] == sum(
             dc.n_experts * s for s in calls) * dc.n_layers   # C = S: dropless
         assert moe["decode_layer_calls"] == moe["decode_experts_touched"] == 0
-        dense._kv_stash.close()
+        dense.close()
     finally:
-        srv._kv_stash.close()
+        srv.close()

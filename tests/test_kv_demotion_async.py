@@ -12,7 +12,10 @@ the loop they are first awaited on.
 
 import asyncio
 import concurrent.futures
+import functools
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -20,8 +23,8 @@ import numpy as np
 import pytest
 
 from ray_tpu._private import object_store
-from ray_tpu.serve import llm as llm_mod
-from ray_tpu.serve.kv_transfer import KVPageStash
+from ray_tpu.serve import kv_transfer
+from ray_tpu.serve.kv_transfer import DemotionTier, KVPageStash
 from ray_tpu.serve.llm import LLMConfig, LLMServer
 
 PRESETS = ("tiny", "moe_tiny")
@@ -67,11 +70,11 @@ class _Engine:
     def settle(self):
         """Wait for the stash's thread to finish what it was handed, then
         let the engine reap it; returns the engine's counters."""
-        concurrent.futures.wait([h[0] for h in self.srv._handoffs], WAIT_S)
+        concurrent.futures.wait([h[0] for h in self.srv._tier._handoffs], WAIT_S)
         return self.srv.stats()["decode"]
 
     def close(self):
-        self.srv._kv_stash.close()
+        self.srv.close()
 
 
 @pytest.fixture(scope="module")
@@ -122,13 +125,13 @@ def test_restored_page_is_the_demoted_page(engine, tier):
     assert all(engine.page_bytes(pid) != (k, v) for _, pid, k, v in before)
     if gate is None:
         d = engine.settle()
-        assert d["demote_failed"] == 0 and not srv._staged
+        assert d["demote_failed"] == 0 and not srv._tier._staged
         assert [h["oid"] in stash._disk for h in handles] == (
             [tier == "disk"] * 3)
         assert d["stash_spilled_pages"] == (
             d["demoted_pages"] - 1 if tier == "disk" else 0)
     else:
-        assert all(h["oid"] in srv._staged for h in handles)
+        assert all(h["oid"] in srv._tier._staged for h in handles)
 
     again = engine.generate(first)             # restores the three pages
     if gate is not None:
@@ -146,7 +149,7 @@ def test_restored_page_is_the_demoted_page(engine, tier):
         assert (got_k.tobytes(), got_v.tobytes()) == (k, v)
     assert d["demote_passes"] > 0 and d["stash_worker_s"] > 0
     assert d["demote_wait_s"] == 0
-    assert 0 < d["demote_inflight_max_bytes"] <= llm_mod.STAGED_CAP_BYTES
+    assert 0 < d["demote_inflight_max_bytes"] <= kv_transfer.STAGED_CAP_BYTES
 
 
 def test_exception_on_the_stash_thread_is_counted_and_serving_goes_on(engine):
@@ -194,7 +197,7 @@ def test_loop_waits_for_the_oldest_hand_off_over_the_cap(engine, monkeypatch):
 
     stash._seal = slow
     page_bytes = engine.page_nbytes()
-    monkeypatch.setattr(llm_mod, "STAGED_CAP_BYTES", 4 * page_bytes)
+    monkeypatch.setattr(srv._tier, "staged_cap_bytes", 4 * page_bytes)
     try:
         for p in engine.prompts + engine.prompts[:2]:
             engine.generate(p)
@@ -206,7 +209,7 @@ def test_loop_waits_for_the_oldest_hand_off_over_the_cap(engine, monkeypatch):
     # the cap bounds what was staged before a hand-off, so the most ever
     # staged is under the cap plus the group that was let in
     assert d["demote_inflight_max_bytes"] <= (
-        4 + llm_mod.DEMOTE_GROUP) * page_bytes
+        4 + kv_transfer.DEMOTE_GROUP) * page_bytes
 
 
 @pytest.fixture(scope="module")
@@ -224,46 +227,151 @@ def compile_events():
     return seen
 
 
+class _Bare:
+    """A `DemotionTier` over a `PagedKVCache` filled with noise: no engine,
+    no model, no event loop. `failed` collects what the tier reports back
+    where an engine's page manager would stand."""
+
+    def __init__(self, layout, seed, num_pages=24, staged_cap_bytes=None):
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.paged_attention import PagedKVCache
+        from ray_tpu.serve.llm import NESTED_PHASES
+        from ray_tpu.util.tracing import PhaseTotals
+        self.rng = np.random.default_rng(seed)
+        self.cache = PagedKVCache.init(
+            2, 2, 16, num_pages, 8, 2, 8, dtype=jnp.float32,
+            index_dim=8 if layout == "indexed" else 0)
+        self.failed = []
+        self.phases = PhaseTotals("engine", NESTED_PHASES)
+        self.tier = DemotionTier(
+            lambda: self.cache, self.phases,
+            lambda node, handle, error: self.failed.append((node, error)),
+            staged_cap_bytes)
+        self.cache = self.tier.warm(self.cache)
+        self.fill()
+
+    def fill(self):
+        import jax.numpy as jnp
+        self.cache = self.cache.with_pools(tuple(
+            jnp.asarray(self.rng.normal(size=p.shape).astype(np.float32))
+            for p in self.cache.pools()))
+
+    def page_bytes(self, pid):
+        """One page's bytes in every pool (two dense, three indexed)."""
+        import jax.numpy as jnp
+        return [np.asarray(jnp.take(p, pid, axis=self.cache.page_axis)
+                           ).tobytes() for p in self.cache.pools()]
+
+    def settle(self):
+        concurrent.futures.wait([h[0] for h in self.tier._handoffs], WAIT_S)
+        self.tier.reap()
+        return self.tier.counters
+
+
 @pytest.mark.parametrize("n_pages", [1, 7, 20])
-@pytest.mark.parametrize("preset", PRESETS)
+@pytest.mark.parametrize("layout", ["dense", "indexed"])
 def test_pass_of_any_size_compiles_nothing_and_is_exact(
-        preset, n_pages, loop, compile_events, monkeypatch):
-    import jax.numpy as jnp
+        layout, n_pages, compile_events, monkeypatch):
     monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
-    e = _Engine(preset, loop, num_pages=24)
-    srv, stash = e.srv, e.srv._kv_stash
+    b = _Bare(layout, seed=n_pages)
+    tier, stash = b.tier, b.tier.stash
     try:
-        rng = np.random.default_rng(n_pages)
-        shape, dtype = srv.cache.k_pages.shape, srv.cache.k_pages.dtype
-        fill = lambda: jnp.asarray(  # noqa: E731
-            rng.normal(size=shape).astype(np.float32)).astype(dtype)
-        srv.cache = srv.cache.replace(k_pages=fill(), v_pages=fill())
-        pids = rng.permutation(np.arange(1, 24))[:n_pages].tolist()
-        want = [e.page_bytes(pid) for pid in pids]
+        pids = b.rng.permutation(np.arange(1, 24))[:n_pages].tolist()
+        want = [b.page_bytes(pid) for pid in pids]
+        assert len(want[0]) == (3 if layout == "indexed" else 2)
         before = len(compile_events)
-        handles = [srv._demote_page(pid, object()) for pid in pids]
-        srv._demote_pass()
-        assert len(srv._handoffs) == -(-n_pages // llm_mod.DEMOTE_GROUP)
-        d = e.settle()
+        handles = [tier.demote_page(pid, object()) for pid in pids]
+        tier.demote_pass()
+        assert len(tier._handoffs) == -(-n_pages // kv_transfer.DEMOTE_GROUP)
+        d = b.settle()
         assert len(compile_events) == before
-        assert d["demote_passes"] == d["phase_n"]["demote"] == 1
-        assert d["demote_failed"] == 0
+        assert d["demote_passes"] == b.phases.counts["demote"] == 1
+        assert not b.failed
         assert d["demote_bytes"] == sum(h["nbytes"] for h in handles)
-        for handle, (k, v) in zip(handles, want):
-            got_k, got_v = stash.get(handle)
-            assert got_k.dtype == dtype
-            assert list(got_k.shape) == handle["blocks"][0]["shape"]
-            assert (got_k.tobytes(), got_v.tobytes()) == (k, v)
+        for handle, page in zip(handles, want):
+            got = stash.get(handle)
+            assert got[0].dtype == b.cache.k_pages.dtype
+            assert [list(g.shape) for g in got] == [
+                blk["shape"] for blk in handle["blocks"]]
+            assert [g.tobytes() for g in got] == page
         # and back in, to other pages, by the one restore program
-        srv.cache = srv.cache.replace(k_pages=fill(), v_pages=fill())
+        b.fill()
         before = len(compile_events)
         for handle, pid in zip(handles, reversed(pids)):
-            assert srv._restore_page(handle, pid)
-        srv._flush_restored_pages()
-        assert [e.page_bytes(pid) for pid in reversed(pids)] == want
+            assert tier.restore_page(handle, pid)
+        b.cache = tier.flush_restores(b.cache)
+        assert [b.page_bytes(pid) for pid in reversed(pids)] == want
         assert len(compile_events) == before
+        assert d["restored_in_flight"] == 0 and not tier._pending_restores
     finally:
-        e.close()
+        tier.close()
+
+
+def test_tier_alone_waits_for_the_oldest_hand_off_over_the_cap(monkeypatch):
+    """The loop-side wait, against a tier and nothing else: room for two
+    staged pages, a stash thread slower than the hand-offs, three groups."""
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    b = _Bare("dense", seed=5)
+    tier, stash, seal = b.tier, b.tier.stash, b.tier.stash._seal
+    page_bytes = tier.stash.new_handle(tier._layout)["nbytes"]
+    tier.staged_cap_bytes = 2 * page_bytes
+
+    def slow(handle, *blocks):
+        time.sleep(0.02)
+        return seal(handle, *blocks)
+
+    stash._seal = slow
+    try:
+        pids = list(range(1, 21))
+        want = [b.page_bytes(pid) for pid in pids]
+        handles = [tier.demote_page(pid, object()) for pid in pids]
+        tier.demote_pass()
+        # the first group went in over the cap (nothing to wait for); each
+        # later one waited until the one before it was sealed
+        assert len(tier._handoffs) == 1
+        d = b.settle()
+        assert d["demote_wait_s"] > 0 and not b.failed
+        assert d["demote_inflight_max_bytes"] == (
+            kv_transfer.DEMOTE_GROUP * page_bytes)
+        assert tier.staged_bytes == 0 and not tier._staged
+        assert [[g.tobytes() for g in stash.get(h)] for h in handles] == want
+    finally:
+        stash._seal = seal
+        tier.close()
+
+
+def test_tier_reports_what_the_stash_refused_and_close_leaves_nothing(
+        monkeypatch):
+    """An exception on the stash's thread reaches `failed` with the node;
+    `close()` waits for what is in flight, reports it, and leaves no
+    segment. Twice is fine."""
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    b = _Bare("indexed", seed=9)
+    tier, stash, seal = b.tier, b.tier.stash, b.tier.stash._seal
+
+    def third_fails(handle, *blocks):
+        if handle is handles[2]:
+            raise OSError("no space left on /dev/shm")
+        return seal(handle, *blocks)
+
+    stash._seal = third_fails
+    gate = _Gate(stash)
+    nodes = [object() for _ in range(5)]
+    handles = [tier.demote_page(pid, node)
+               for pid, node in zip(range(1, 6), nodes)]
+    tier.demote_pass()
+    assert tier.staged_bytes == sum(h["nbytes"] for h in handles)
+    threading.Timer(0.2, gate.open).start()
+    tier.close()                     # waits for the hand-off, then reaps it
+    assert [(node, type(e)) for node, e in b.failed] == [(nodes[2], OSError)]
+    assert tier.staged_bytes == 0 and not tier._staged
+    assert stash.tier_stats() == {"shm_objects": 0, "shm_bytes": 0,
+                                  "disk_objects": 0, "disk_bytes": 0}
+    for handle in handles:
+        name = object_store.seg_name(handle["oid"])
+        assert not os.path.exists(os.path.join("/dev/shm", name))
+    tier.close()
 
 
 @pytest.mark.parametrize("preset", PRESETS)
@@ -318,3 +426,111 @@ def test_close_with_puts_queued_leaves_no_segment_and_no_spill_file(
     stash.close()                                    # closing twice is fine
     with pytest.raises(RuntimeError):
         stash.put([], k, k)
+
+
+# stats() of a paged prefix-caching server at the parent commit (4508f22),
+# group by group: the tier's counters keep their names and their place
+_PHASE_KEYS = {"admit_allocate", "decode_build", "decode_dispatch",
+               "decode_emit", "decode_sync", "demote", "demote_stash",
+               "evict", "prefill_dispatch", "prefill_first_token", "restore",
+               "yield"}
+_STALL_KEYS = {"decode_sync", "prefill_first_token"}
+PARENT_STATS_KEYS = {
+    "": {"active", "decode", "free_slots", "pages_free", "pages_in_use",
+         "prefix_cached_pages", "prefix_hit_rate", "prefix_hit_tokens",
+         "prefix_query_tokens", "radix", "requests", "slo", "stalls"},
+    "decode": {
+        "active_slot_syncs", "admitted", "chunk_ms_avg", "chunk_s_total",
+        "chunk_sizes", "continuation_chunks", "continuation_query_keys",
+        "continuation_reach_keys", "decode_chunk", "decode_steps",
+        "demote_bytes", "demote_failed", "demote_inflight_max_bytes",
+        "demote_last_error", "demote_passes", "demote_wait_s",
+        "demoted_pages", "evicted_pages", "host_syncs",
+        "host_syncs_per_token", "joined_on_device", "loop_s", "phase_n",
+        "phase_s", "prefill_chunks", "prefill_padded_tokens",
+        "prefill_tokens", "read_wait_s", "restored_in_flight",
+        "restored_pages", "rotary_split_projections", "run_ahead_chunks",
+        "slot_wait_max_s", "slot_wait_s", "stall_max_s", "stall_n",
+        "stall_s", "stash_spilled_pages", "stash_worker_s", "ticks",
+        "tokens", "tokens_per_sync"},
+    "decode.phase_n": _PHASE_KEYS, "decode.phase_s": _PHASE_KEYS,
+    "decode.stall_n": _STALL_KEYS, "decode.stall_s": _STALL_KEYS,
+    "radix": {"demoted_nodes", "demoted_pages", "evicted_pages",
+              "prefix_nodes", "resident_pages", "restored_pages", "stash"},
+    "radix.stash": {"disk_bytes", "disk_objects", "shm_bytes",
+                    "shm_objects"},
+    "slo": {"batch_occupancy", "kv_page_util", "radix", "spill_restore_ms",
+            "tpot_ms", "ttft_s"},
+    "slo.radix": {"prefix_evicted_pages", "prefix_hit_tokens",
+                  "prefix_nodes"},
+}
+
+
+def _stats_keys(stats):
+    at = lambda path: functools.reduce(  # noqa: E731
+        lambda d, k: d[k], filter(None, path.split(".")), stats)
+    return {path: set(at(path)) for path in PARENT_STATS_KEYS}
+
+
+def test_stats_keys_and_span_names_are_the_parents(loop, monkeypatch):
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    e = _Engine("tiny", loop)
+    try:
+        assert _stats_keys(e.srv.stats()) == PARENT_STATS_KEYS
+        for p in e.prompts + e.prompts[:1]:       # evicts, demotes, restores
+            e.generate(p)
+        e.settle()
+        assert _stats_keys(e.srv.stats()) == PARENT_STATS_KEYS
+        # the tier's phases are the engine's own: the same spans in a trace
+        assert e.srv._tier._phases is e.srv._phases
+        assert {k: e.srv._phases.names[k]
+                for k in ("demote", "demote_stash", "restore")} == {
+            "demote": "engine.demote", "demote_stash": "engine.demote_stash",
+            "restore": "engine.restore"}
+        n = e.srv.stats()["decode"]["phase_n"]
+        assert n["demote"] > 0 and n["demote_stash"] > 0 and n["restore"] > 0
+    finally:
+        e.close()
+
+
+def test_server_close_gives_back_the_stash_and_stats_still_answers(
+        loop, monkeypatch):
+    monkeypatch.delenv("RAY_TPU_ARENA", raising=False)
+    e = _Engine("tiny", loop)
+    srv, stash = e.srv, e.srv._kv_stash
+    assert stash is srv._tier.stash
+    for p in e.prompts:
+        e.generate(p)
+    before = e.settle()
+    names = [object_store.seg_name(oid) for oid in stash._shm]
+    assert names and all(
+        os.path.exists(os.path.join("/dev/shm", n)) for n in names)
+    srv.close()
+    assert not any(os.path.exists(os.path.join("/dev/shm", n)) for n in names)
+    with pytest.raises(RuntimeError):
+        stash.put([], np.zeros(1))
+    srv.close()                                  # closing twice is fine
+    # a closed server still answers stats(): the counters as they stood,
+    # the stash empty
+    after = srv.stats()
+    assert _stats_keys(after) == PARENT_STATS_KEYS
+    assert after["decode"]["demote_bytes"] == before["demote_bytes"] > 0
+    assert after["radix"]["stash"] == {"shm_objects": 0, "shm_bytes": 0,
+                                       "disk_objects": 0, "disk_bytes": 0}
+    # an engine with no tier has nothing to give back, and counts zeros
+    dense = LLMServer(LLMConfig(preset="tiny", max_batch_slots=1,
+                                max_seq_len=32))
+    assert dense._tier is None and dense._kv_stash is None
+    dense.close()
+    assert all(dense.stats()["decode"][k] == 0
+               for k in kv_transfer.TIER_COUNTERS)
+
+
+def test_the_tier_module_imports_without_jax():
+    """`kv_transfer.py` (and `llm.py`, which imports it) stay importable in
+    a process that must not touch the chip: the tier takes jax in `warm`."""
+    code = ("import sys, ray_tpu.serve.kv_transfer, ray_tpu.serve.llm; "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr

@@ -75,8 +75,7 @@ def loop():
 def run(request, loop):
     r = _Run(request.param, loop)
     yield r
-    if r.srv._kv_stash is not None:
-        r.srv._kv_stash.close()
+    r.srv.close()
 
 
 def test_loop_phases_sum_to_loop_seconds(run):
@@ -250,7 +249,7 @@ def test_profiler_host_plane_holds_engine_phases(run, tmp_path):
     try:
         run.generate(prompts)
         # the stash's thread ends its last `stash.put` inside the session
-        concurrent.futures.wait([h[0] for h in run.srv._handoffs], 60.0)
+        concurrent.futures.wait([h[0] for h in run.srv._tier._handoffs], 60.0)
     finally:
         jax.profiler.stop_trace()
     names = _host_event_names(str(tmp_path))

@@ -45,7 +45,7 @@ def servers():
     yield lambda preset: made.setdefault(
         preset, LLMServer(LLMConfig(preset=preset, **ENGINE)))
     for srv in made.values():
-        srv._kv_stash.close()
+        srv.close()
 
 
 @pytest.mark.parametrize("preset,program", sorted(WAS))

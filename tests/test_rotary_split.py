@@ -41,7 +41,7 @@ def servers():
 
     yield get
     for srv, _ in made.values():
-        srv._kv_stash.close()
+        srv.close()
 
 
 def _leaf(tree, layer, name):

@@ -31,19 +31,6 @@ def servers():
     return plain, prefill, pd
 
 
-def test_prefill_kv_shapes(servers):
-    _, prefill, _ = servers
-    out = asyncio.run(prefill.prefill_kv(list(range(2, 39))))
-    mc = prefill.model_cfg
-    assert out["prompt_len"] == 37
-    assert out["k"].shape == (mc.n_layers, mc.n_kv_heads, 37, mc.head_dim)
-    assert out["v"].shape == out["k"].shape
-    assert isinstance(out["token"], int)
-    # the prefill slot was released — nothing leaks
-    assert prefill.stats()["active"] == 0
-    assert prefill.stats()["free_slots"] == 4
-
-
 def test_pd_matches_colocated_greedy(servers):
     plain, _, pd = servers
     prompts = [list(range(5, 25)), [7, 3, 11] * 9, list(range(60, 100))]
@@ -151,7 +138,7 @@ def test_pd_requires_paged():
     with pytest.raises(ValueError, match="paged"):
         server = PrefillServer(LLMConfig(preset="tiny", paged=False,
                                          max_seq_len=64))
-        asyncio.run(server.prefill_kv([1, 2, 3]))
+        asyncio.run(server.prefill_begin([1, 2, 3]))
 
 
 # ------------------- streaming data plane (zero-copy KV-page shipment) ---
@@ -182,13 +169,15 @@ def test_stream_frames_carry_no_kv_bytes(servers):
             have += len(res["segments"])
             done = res["done"]
         assert have >= 1
+        assert isinstance(res["token"], int)     # the first token, sampled
         await prefill.prefill_drop(header["ship_id"])
         return header
 
     header = asyncio.run(drive())
     assert header["total_pages"] == 3 and header["prompt_len"] == 37
-    # slot released; drop freed every segment
+    # the prefill slot was released (nothing leaks); drop freed every segment
     assert prefill.stats()["active"] == 0
+    assert prefill.stats()["free_slots"] == 4
 
 
 def test_stream_suffix_install_parity():
@@ -222,11 +211,18 @@ def test_stream_suffix_install_parity():
 
 
 def test_stream_forced_remote_pull(servers, monkeypatch):
-    """RAY_TPU_KV_ATTACH=0 forbids the same-host shm attach, forcing the
-    KVDataServer + parallel_fetch ranged-transfer path."""
+    """A reader that cannot attach the writer's segment by name (as on
+    another host) takes the next rung: the KVDataServer + parallel_fetch
+    ranged-transfer path."""
+    from ray_tpu.serve.kv_transfer import ShipReader
     from ray_tpu.util import metrics as _metrics
     plain, _, pd = servers
-    monkeypatch.setenv("RAY_TPU_KV_ATTACH", "0")
+    attach = ShipReader._attach
+    # the local copy parallel_fetch lands (`delete`) is still attached
+    monkeypatch.setattr(
+        ShipReader, "_attach",
+        lambda self, oid, seg, layout, delete: (
+            attach(self, oid, seg, layout, delete) if delete else None))
     p = list(range(11, 53))
     before = _metrics.kv_ship_counters()
     got = asyncio.run(pd.generate(p, max_tokens=10))
@@ -235,20 +231,6 @@ def test_stream_forced_remote_pull(servers, monkeypatch):
     after = _metrics.kv_ship_counters()
     assert after["stream_pulls"] - before["stream_pulls"] >= 1
     assert after["attach_hits"] == before["attach_hits"]
-
-
-def test_legacy_rpc_handoff_escape_hatch(servers, monkeypatch):
-    """RAY_TPU_KV_SHIP=0 restores the whole-KV-over-RPC hand-off."""
-    from ray_tpu.util import metrics as _metrics
-    plain, _, pd = servers
-    monkeypatch.setenv("RAY_TPU_KV_SHIP", "0")
-    p = [9, 8, 7] * 8
-    before = _metrics.kv_ship_counters()
-    got = asyncio.run(pd.generate(p, max_tokens=9))
-    ref = asyncio.run(plain.generate(p, max_tokens=9))
-    assert got["tokens"] == ref["tokens"]
-    # the streaming plane was bypassed entirely
-    assert _metrics.kv_ship_counters()["segments"] == before["segments"]
 
 
 def test_serving_bench_smoke_gate():

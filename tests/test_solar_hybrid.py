@@ -39,7 +39,7 @@ def _server(**kw):
 def server():
     srv = _server()
     yield srv
-    srv._kv_stash.close()
+    srv.close()
 
 
 def _errs(srv, prompt, out, weights_as=None):
@@ -130,7 +130,7 @@ def test_an_evicted_snapshot_is_a_miss_and_still_right():
         assert srv.page_mgr.snapshot_hits == hits      # no hit: it was evicted
         assert _errs(srv, prompts[0] + [5, 6, 7], out).max() < 1e-4
     finally:
-        srv._kv_stash.close()
+        srv.close()
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -193,4 +193,4 @@ def test_what_such_a_model_cannot_do_says_so():
         with pytest.raises(NotImplementedError, match="recurrent state"):
             asyncio.run(srv.prefill_begin([1, 2, 3]))
     finally:
-        srv._kv_stash.close()
+        srv.close()

@@ -92,7 +92,7 @@ async def run(args) -> dict:
                 (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                 for d in jax.devices())}
     finally:
-        srv._kv_stash.close()
+        srv.close()
 
 
 def main() -> int:
