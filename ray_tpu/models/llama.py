@@ -41,6 +41,7 @@ from ray_tpu.ops.paged_attention import (PagedKVCache, paged_attention,
                                          sparse_paged_prefill,
                                          write_layer_tokens)
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.ops.ssd import ssd_chunked, ssd_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,6 +133,30 @@ class LlamaConfig:
     norm: str = "rms"               # rms | layer (mean-centred, no bias)
     logit_scale: float = 1.0
     shared_average: bool = False    # the shared experts' MEAN, not their sum
+    # ---- a Mamba-2 mixer BESIDE the attention heads in EVERY block
+    # (Falcon-H1): both read one norm of the block's input, their outputs are
+    # summed into the residual, and the FFN is a sub-block of its own. Every
+    # layer then has keys and values in the paged cache AND a recurrent state
+    # a slot (ops/ssd.py). 0 = no mixer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 128
+    ssm_state: int = 256            # a channel's state
+    ssm_groups: int = 1             # groups of heads that share B and C
+    ssm_conv: int = 4               # width of the short convolution (biased)
+    ssm_chunk: int = 128            # the chunked program's chunk
+    # muP multipliers, fixed numbers of the published config: on the
+    # embedding, on attention's input, keys and output, on the mixer's input,
+    # the five segments of its projection (z, x, B, C, dt) and its output, on
+    # the FFN's gate and output (`logit_scale` is the head's)
+    embed_scale: float = 1.0
+    attn_in_scale: float = 1.0
+    key_scale: float = 1.0
+    attn_out_scale: float = 1.0
+    ssm_in_scale: float = 1.0
+    ssm_scales: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_scale: float = 1.0
+    mlp_gate_scale: float = 1.0
+    mlp_down_scale: float = 1.0
 
     def layer_kind(self, layer_idx: int) -> Optional[str]:
         """"sliding" or "full" under a `layer_types` pattern, else None."""
@@ -156,6 +181,31 @@ class LlamaConfig:
     def n_linear_layers(self) -> int:
         return sum(self.is_linear(i) for i in range(self.n_layers))
 
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that hold a recurrent state a slot: the linear ones, or
+        every layer of a model with a mixer beside its attention heads."""
+        return self.n_layers if self.ssm_heads else self.n_linear_layers
+
+    def state_schema(self) -> Optional[dict]:
+        """What one slot's state is made of, as `PagedKVCache.init` takes it
+        (`linear`, less the snapshot pool's size); None for a model without."""
+        if self.ssm_heads:
+            return dict(
+                layers=self.n_layers, heads=self.ssm_heads,
+                key_dim=self.ssm_state, value_dim=self.ssm_head_dim,
+                conv=self.ssm_conv - 1,
+                channels=(self.ssm_heads * self.ssm_head_dim
+                          + 2 * self.ssm_groups * self.ssm_state))
+        if not self.n_linear_layers:
+            return None
+        return dict(
+            layers=self.n_linear_layers, heads=self.linear_heads,
+            key_dim=self.linear_key_dim, value_dim=self.linear_value_dim,
+            conv=self.linear_conv - 1,
+            channels=self.linear_heads * (2 * self.linear_key_dim
+                                          + self.linear_value_dim))
+
     def kv_layer(self, layer_idx: int) -> int:
         """Where a layer's keys and values lie in its pool (under a
         `layer_types` pattern: among the layers of its own kind)."""
@@ -165,7 +215,9 @@ class LlamaConfig:
         return layer_idx // self.full_attn_every if self.full_attn_every else layer_idx
 
     def linear_index(self, layer_idx: int) -> int:
-        """Which of the cache's states a linear layer owns."""
+        """Which of the cache's states a layer with one owns."""
+        if self.ssm_heads:
+            return layer_idx
         return layer_idx - layer_idx // self.full_attn_every - 1
 
     # ---- presets (sizes follow the Llama family; test config is `tiny`).
@@ -275,6 +327,30 @@ class LlamaConfig:
             norm="layer"), **kw})
 
     @staticmethod
+    def falcon_h1_tiny(**kw):
+        """Test-scale Falcon-H1: in every block 5 query heads a kv head
+        beside a Mamba-2 mixer of 8 heads of 16 in 2 groups with a state of
+        32, chunks of 8, and the published multipliers."""
+        return LlamaConfig(**{**_FALCON_H1_MULTIPLIERS, **dict(
+            vocab_size=256, d_model=64, n_layers=2, n_heads=5,
+            n_kv_heads=1, head_dim=16, ffn_dim=128, max_seq_len=128,
+            rope_theta=1e11, ssm_heads=8, ssm_head_dim=16, ssm_state=32,
+            ssm_groups=2, ssm_chunk=8), **kw})
+
+    @staticmethod
+    def falcon_h1_34b(**kw):
+        """Falcon-H1-34B-Instruct (`falcon_h1`): 72 blocks alike, in each 20
+        query heads of 128 over 4 kv heads (rotary, theta 1e11) beside a
+        Mamba-2 mixer of 32 heads of 128 in 2 groups with a state of 256,
+        both on one norm and summed; an FFN of 21504; eight muP multipliers;
+        an untied head over 261,120 tokens."""
+        return LlamaConfig(**{**_FALCON_H1_MULTIPLIERS, **dict(
+            vocab_size=261120, d_model=5120, n_layers=72, n_heads=20,
+            n_kv_heads=4, head_dim=128, ffn_dim=21504, max_seq_len=262144,
+            rope_theta=1e11, norm_eps=1e-5, ssm_heads=32, ssm_head_dim=128,
+            ssm_state=256, ssm_groups=2, ssm_conv=4, ssm_chunk=128), **kw})
+
+    @staticmethod
     def mixtral_8x7b(**kw):
         """Mixtral-8x7B shape: Llama-7B trunk, 8 experts, top-2 routing."""
         return LlamaConfig(**{**dict(
@@ -305,6 +381,19 @@ class LlamaConfig:
         return LlamaConfig(**{**dict(
             d_model=8192, n_layers=80, n_heads=64,
             n_kv_heads=8, head_dim=128, ffn_dim=28672), **kw})
+
+
+# Falcon-H1-34B-Instruct's config.json: embedding_multiplier,
+# lm_head_multiplier, attention_in / key / attention_out, ssm_in,
+# ssm_multipliers (z, x, B, C, dt), ssm_out, mlp_multipliers (gate, down)
+_FALCON_H1_MULTIPLIERS = dict(
+    embed_scale=5.656854249492381, logit_scale=0.0078125,
+    attn_in_scale=1.0, key_scale=0.011048543456039804,
+    attn_out_scale=0.0375, ssm_in_scale=0.25,
+    ssm_scales=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                0.3535533905932738),
+    ssm_out_scale=0.08838834764831845,
+    mlp_gate_scale=0.1767766952966369, mlp_down_scale=0.011160714285714284)
 
 
 class KVCache(flax.struct.PyTreeNode):
@@ -508,6 +597,8 @@ class Attention(nn.Module):
         q = qk(cfg.n_heads * cfg.head_dim, name="wq")(x)
         k = qk(cfg.n_kv_heads * cfg.head_dim, name="wk")(x)
         v = dense(cfg.n_kv_heads * cfg.head_dim, name="wv")(x)
+        if cfg.key_scale != 1.0:
+            k = k * cfg.key_scale
         q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
@@ -717,6 +808,91 @@ class LinearAttention(nn.Module):
         return dense(cfg.d_model, name="wo")(o), (cache if paged else None)
 
 
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 mixer (SSD): one projection into a gate z, the inputs x and
+    the group's B and C, and a step dt a head, each segment under its own
+    multiplier; x, B and C through one short causal convolution with a bias
+    and a silu; the recurrence of `ops/ssd.py` with a scalar decay a head;
+    y * silu(z) under an RMS norm a group with a learned scale; the output
+    projection. With a paged cache the layer's state and the convolution's
+    last inputs are the cache's, a slot each; without one the sequence starts
+    from zeros."""
+    cfg: LlamaConfig
+    layer_idx: int = 0
+
+    @nn.compact
+    def __call__(self, x, cache, n_valid=None, fresh: bool = False):
+        cfg = self.cfg
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02))
+        b, t, _ = x.shape
+        h, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                      cfg.ssm_groups)
+        f32 = jnp.float32
+        inner, bc = h * p, g * n
+        segments = (inner, inner, bc, bc, h)                 # z, x, B, C, dt
+        mup = jnp.concatenate([jnp.full((w,), m, cfg.dtype)
+                               for w, m in zip(segments, cfg.ssm_scales)])
+        zxbcdt = dense(sum(segments), name="in_proj")(
+            x * cfg.ssm_in_scale) * mup
+        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], axis=-1)
+        conv_w = self.param("conv", nn.initializers.normal(0.02),
+                            (cfg.ssm_conv, inner + 2 * bc), cfg.param_dtype)
+        conv_b = self.param("conv_bias", nn.initializers.normal(0.02),
+                            (inner + 2 * bc,), cfg.param_dtype)
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, f32, 1.0, 16.0)), (h,))
+        skip = self.param("D", nn.initializers.ones, (h,), f32)
+        # softplus(dt_bias), a head's step where the projection gives 0, is
+        # log-uniform in [0.001, 0.1] (Mamba-2's own)
+        dt_bias = self.param("dt_bias", _decay_bias_init(1e-3, 0.1), (h,), f32)
+
+        li = cfg.linear_index(self.layer_idx)
+        paged = isinstance(cache, PagedKVCache)
+        if cache is not None and not paged:
+            raise NotImplementedError(
+                "a Mamba-2 mixer decodes through the paged cache (its state "
+                "is the cache's, a slot each)")
+        if paged and not fresh:
+            state, carried = cache.state[li], cache.conv[li]
+        else:
+            state = jnp.zeros((b, h, n, p), f32)
+            carried = jnp.zeros((b, cfg.ssm_conv - 1, inner + 2 * bc),
+                                cfg.dtype)
+        xbc, carried = causal_conv(xbc, carried, conv_w, n_valid,
+                                   bias=conv_b, scope="ssd_conv")
+        xbc = nn.silu(xbc)
+        xs, bm, cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        xs = xs.reshape(b, t, h, p)
+        bm, cm = bm.reshape(b, t, g, n), cm.reshape(b, t, g, n)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+        a = -jnp.exp(a_log)
+        if t == 1 and paged:
+            valid = None if n_valid is None else n_valid > 0
+            y, state = ssd_step(xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                                skip, state, valid)
+            y = y[:, None]
+        else:
+            y, state = ssd_chunked(xs, dt, a, bm, cm, skip, state, n_valid,
+                                   chunk=cfg.ssm_chunk)
+        if paged:
+            put = lambda old, new: old[:li] + (new,) + old[li + 1:]
+            cache = cache.replace(state=put(cache.state, state),
+                                  conv=put(cache.conv, carried))
+        # the gate first, then the norm a group (`mamba_norm_before_gate`
+        # false), in f32
+        scale = self.param("norm", nn.initializers.ones, (inner,), f32)
+        y = (y.reshape(b, t, inner) * nn.silu(z.astype(f32))).reshape(
+            b, t, g, inner // g)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + cfg.norm_eps)
+        y = (y.reshape(b, t, inner) * scale).astype(cfg.dtype)
+        return dense(cfg.d_model, name="out_proj")(y), (cache if paged
+                                                        else None)
+
+
 class MLP(nn.Module):
     cfg: LlamaConfig
 
@@ -728,7 +904,10 @@ class MLP(nn.Module):
                         kernel_init=nn.initializers.normal(0.02))
         gate = dense(cfg.ffn_dim, name="w_gate")(x)
         up = dense(cfg.ffn_dim, name="w_up")(x)
-        return dense(cfg.d_model, name="w_down")(nn.silu(gate) * up)
+        if cfg.mlp_gate_scale != 1.0:
+            gate = gate * cfg.mlp_gate_scale
+        out = dense(cfg.d_model, name="w_down")(nn.silu(gate) * up)
+        return out if cfg.mlp_down_scale == 1.0 else out * cfg.mlp_down_scale
 
 
 class Block(nn.Module):
@@ -750,6 +929,23 @@ class Block(nn.Module):
                         jnp.arange(x.shape[1])[None] < n_valid[:, None])
                 return x + h + MoEMLP(cfg, name="moe")(normed, real), new_kv
             return x + h + MLP(cfg, name="mlp")(normed), new_kv
+        if cfg.ssm_heads:
+            # the attention heads and the mixer read ONE norm and are summed;
+            # the FFN is a sub-block of its own. The paged cache goes through
+            # attention (keys and values) and then the mixer (the state).
+            normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
+            h, new_kv = Attention(cfg, self.layer_idx, name="attn")(
+                normed if cfg.attn_in_scale == 1.0
+                else normed * cfg.attn_in_scale,
+                positions, cache, paged_chunk_local)
+            paged = isinstance(cache, PagedKVCache)
+            m, new_cache = Mamba2Mixer(cfg, self.layer_idx, name="mamba")(
+                normed, new_kv if paged else cache, n_valid,
+                paged_chunk_local)
+            x = x + m * cfg.ssm_out_scale + h * cfg.attn_out_scale
+            normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(x)
+            return x + MLP(cfg, name="mlp")(normed), (new_cache if paged
+                                                      else new_kv)
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
         if cfg.is_linear(self.layer_idx):
             h, new_kv = LinearAttention(cfg, self.layer_idx, name="kda")(
@@ -773,7 +969,7 @@ class Llama(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, cache: Optional[KVCache] = None,
                  return_hidden: bool = False, paged_chunk_local: bool = False,
-                 n_valid=None):
+                 n_valid=None, logits_at=None):
         """tokens [B, T] int32 → logits [B, T, V] (f32), new cache (or None).
 
         Prefill/train: cache=None, full causal attention. Decode: pass a
@@ -788,6 +984,10 @@ class Llama(nn.Module):
         the T tokens of each row are real. The rest (a prefill bucket's
         padding, a slot that does not decode this step) leave the row's
         recurrent state where it was.
+
+        `logits_at` [B]: the one position of each row whose logits are
+        wanted (a prefill chunk keeps its last real token's): the final norm
+        and the head run on that row alone and the logits are [B, 1, V].
 
         `return_hidden=True` returns the final-norm hidden states [B, T, D]
         instead of logits — callers fuse the lm_head into a chunked loss
@@ -805,6 +1005,8 @@ class Llama(nn.Module):
                          embedding_init=nn.initializers.normal(0.02),
                          name="embed")
         x = embed(tokens)
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
 
         block_cls = Block
         if cfg.remat and cache is None:
@@ -821,6 +1023,8 @@ class Llama(nn.Module):
                 new_k.append(new_kv[0])
                 new_v.append(new_kv[1])
 
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = _norm(cfg, "final_norm")(x)
         if return_hidden:
             new_cache = None
@@ -929,6 +1133,18 @@ def _linear_attn_params(cfg: LlamaConfig) -> int:
             + cfg.linear_conv * wide + h + h * dk + dv)
 
 
+def _ssm_params(cfg: LlamaConfig) -> int:
+    """One Mamba-2 mixer: the two projections, the convolution with its bias,
+    A_log, D, dt_bias and the gated norm's scale."""
+    if not cfg.ssm_heads:
+        return 0
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    bc = cfg.ssm_groups * cfg.ssm_state
+    return (cfg.d_model * (2 * inner + 2 * bc + cfg.ssm_heads)
+            + inner * cfg.d_model + (cfg.ssm_conv + 1) * (inner + 2 * bc)
+            + 3 * cfg.ssm_heads + inner)
+
+
 def _mlp_params(cfg: LlamaConfig) -> int:
     """One dense SwiGLU FFN."""
     return 3 * cfg.d_model * cfg.ffn_dim
@@ -943,7 +1159,8 @@ def llama_param_count(cfg: LlamaConfig) -> int:
     """Parameters the model HOLDS: a bank with a share of the experts
     (`experts_held`) counts those, its router all it scores."""
     norms = 1 if cfg.parallel_block else 2
-    per_layer = _attn_params(cfg) + _mlp_params(cfg) + norms * cfg.d_model
+    per_layer = (_attn_params(cfg) + _ssm_params(cfg) + _mlp_params(cfg)
+                 + norms * cfg.d_model)
     total = cfg.n_layers * per_layer
     total += cfg.n_linear_layers * (_linear_attn_params(cfg)
                                     - _attn_params(cfg))
@@ -963,7 +1180,7 @@ def llama_compute_flops(cfg: LlamaConfig, batch: int, seq: int) -> float:
     full bank — the honest denominator for MFU."""
     n_moe = _n_moe_layers(cfg)
     n_dense = cfg.n_layers - n_moe
-    n_active = (cfg.n_layers * _attn_params(cfg)
+    n_active = (cfg.n_layers * (_attn_params(cfg) + _ssm_params(cfg))
                 + cfg.n_linear_layers * (_linear_attn_params(cfg)
                                          - _attn_params(cfg))
                 + n_dense * _mlp_params(cfg)

@@ -386,7 +386,10 @@ def continuation_blocks(t: int, g: int, dtype) -> Optional[int]:
     have to tile the chunk, in whole sublane tiles of the operands' type (a
     bucket clamped to what a row has left can be any length)."""
     tile = 8 * 4 // jnp.dtype(dtype).itemsize
-    block_q = min(t, max(tile, _CONT_ROWS // g))
+    # the largest power of two of queries whose g heads stack to `_CONT_ROWS`
+    # rows at most: `_CONT_ROWS // g` itself where g is a power of two; 128
+    # queries, 640 rows, at 5 heads a kv head, where 204 would tile nothing
+    block_q = min(t, max(tile, 1 << ((_CONT_ROWS // g).bit_length() - 1)))
     return None if t % block_q or block_q % tile else block_q
 
 

@@ -47,18 +47,22 @@ _HI = jax.lax.Precision.HIGHEST
 _HEADS_A_BLOCK = 8      # heads of one row a grid step of the decode kernel
 
 
-def causal_conv(x, carried, weight, n_valid=None):
+def causal_conv(x, carried, weight, n_valid=None, bias=None,
+                scope: str = "kda_conv"):
     """Causal depthwise convolution over time whose last `W - 1` inputs are
     carried. x [B, T, Ch]; carried [B, W - 1, Ch]: the inputs before x[:, 0];
-    weight [W, Ch]; n_valid [B] or None: how many of the T inputs are real.
-    y_t = sum_j weight[j] x_{t - (W - 1) + j}. Returns (y [B, T, Ch], the
-    W - 1 inputs that precede position n_valid, in `carried`'s type)."""
+    weight [W, Ch]; n_valid [B] or None: how many of the T inputs are real;
+    bias [Ch] or None. y_t = sum_j weight[j] x_{t - (W - 1) + j} (+ bias).
+    Returns (y [B, T, Ch], the W - 1 inputs that precede position n_valid,
+    in `carried`'s type)."""
     w = weight.shape[0]
     t = x.shape[1]
-    with jax.named_scope("kda_conv"):
+    with jax.named_scope(scope):
         window = jnp.concatenate([carried.astype(x.dtype), x], axis=1)
         y = sum(window[:, j:j + t] * weight[j].astype(x.dtype)
                 for j in range(w))
+        if bias is not None:
+            y = y + bias.astype(x.dtype)
         if n_valid is None:
             tail = window[:, t:]
         else:

@@ -70,10 +70,10 @@ NESTED_PHASES = ("admit_allocate", "evict", "demote", "demote_stash",
 # counted as a stall, and a watchdog samples the process while it lasts
 # (util.tracing.StallWatch). Not `yield`: it holds the profiler's stop.
 WATCHED_PHASES = ("decode_sync", "prefill_first_token")
-# A model with linear-attention layers only: the copy of a slot's recurrent
-# state into a snapshot where its prefill crosses the prompt's last page
-# boundary (inside prefill_dispatch), and out of one at admission (inside
-# admit_allocate).
+# A model with a recurrent state a slot only: the copy of a slot's state
+# into a snapshot where its prefill crosses the prompt's last page boundary or
+# leaves the tree (inside prefill_dispatch), and out of one at admission
+# (inside admit_allocate).
 STATE_PHASES = ("state_save", "state_restore")
 # A model with sliding-window layers only: the hand-back of window pages a row
 # has moved past and the taking of its next ones, before a prefill chunk
@@ -142,6 +142,10 @@ class LLMConfig:
     # worth at most). Default: every slot's budget, and as much again to
     # cache finished prompts' tails.
     num_window_pages: Optional[int] = None
+    # a model with a recurrent state a slot: the snapshots of it that the
+    # prefix cache keeps (one is a slot's state over again, megabytes).
+    # Default: SNAPSHOTS_PER_SLOT a slot.
+    num_snapshots: Optional[int] = None
     # Chunked prefill (ref: vLLM chunked prefill / the reference's
     # prefill-decode disaggregation, python/ray/llm/_internal/serve/
     # serving_patterns/prefill_decode/pd_server.py): prompts are fed through
@@ -278,8 +282,9 @@ class LLMServer:
                 self.model_cfg = _dc.replace(self.model_cfg,
                                              capacity_factor=dropless)
         self.model = Llama(self.model_cfg)
-        # recurrent state a slot beside the pages (linear-attention layers)
-        self._stateful = self.model_cfg.n_linear_layers > 0
+        # recurrent state a slot beside the pages (linear-attention layers,
+        # or a state-space mixer in every block)
+        self._stateful = self.model_cfg.n_state_layers > 0
         # sliding-window layers: a second pool of pages, with its own table
         self._windowed = self.model_cfg.n_window_layers > 0
         self._phases = PhaseTotals(
@@ -358,8 +363,8 @@ class LLMServer:
                 "cache's third pool")
         if self._stateful and not cfg.paged:
             raise ValueError(
-                "a model with linear-attention layers (full_attn_every > 0) "
-                "needs paged=True: a slot's recurrent state and its "
+                "a model with a recurrent state (full_attn_every > 0 or "
+                "ssm_heads > 0) needs paged=True: a slot's state and its "
                 "snapshots live in the paged cache")
         if self._windowed and not cfg.paged:
             raise ValueError(
@@ -400,7 +405,7 @@ class LLMServer:
             # them, so four a slot (one snapshot is a few thousand tokens'
             # worth of keys and values: one a page is out of the question,
             # one a prompt is cheap)
-            snapshots = (SNAPSHOTS_PER_SLOT * B
+            snapshots = ((cfg.num_snapshots or SNAPSHOTS_PER_SLOT * B)
                          if self._stateful and cfg.prefix_cache else 0)
             window = {}
             if self._windowed:
@@ -419,17 +424,13 @@ class LLMServer:
                 snapshots=snapshots, **window, **hooks)
             # the cache follows the model's schema: a model with an indexer
             # gets the third per-page pool (and the token-major layout), one
-            # with linear layers pools for its full layers only, a state a
-            # slot and the snapshot pool
-            linear = None
+            # with linear layers pools for its full layers only (one with a
+            # mixer in every block: for every layer), a state a slot and the
+            # snapshot pool
+            pools = {}
             if self._stateful:
-                linear = dict(
-                    layers=mc.n_linear_layers, heads=mc.linear_heads,
-                    key_dim=mc.linear_key_dim, value_dim=mc.linear_value_dim,
-                    conv=mc.linear_conv - 1, snapshots=max(snapshots, 1),
-                    channels=mc.linear_heads * (2 * mc.linear_key_dim
-                                                + mc.linear_value_dim))
-            pools = {"linear": linear} if linear else {}
+                pools["linear"] = dict(mc.state_schema(),
+                                       snapshots=max(snapshots, 1))
             if self._windowed:
                 pools["window"] = dict(layers=mc.n_window_layers,
                                        num_pages=window["window_pages"])
@@ -674,15 +675,18 @@ class LLMServer:
                 for x, r in zip(xs, rows))
             row_view = row_view.replace(state=take(cache.state),
                                         conv=take(cache.conv))
+            # the head runs on the row that is returned, not on the bucket
             (logits, new_row), seen = model.apply(
                 params, tokens, cache=row_view, paged_chunk_local=chunk_local,
-                n_valid=(true_end - start_len)[None], mutable=["moe_stats"])
+                n_valid=(true_end - start_len)[None],
+                logits_at=(true_end - start_len - 1)[None],
+                mutable=["moe_stats"])
             new_cache = cache.with_pools(new_row.pools()).replace(
                 lengths=cache.lengths.at[slot].set(true_end),
                 state=put(cache.state, new_row.state),
                 conv=put(cache.conv, new_row.conv),
                 held_pairs=cache.held_pairs + sown(seen, "held_pairs"))
-            return new_cache, logits[0, true_end - start_len - 1]
+            return new_cache, logits[0, 0]
 
         def prefill_windowed(params, cache, row_view, tokens, slot, start_len,
                              true_end, chunk_local):
@@ -1271,11 +1275,18 @@ class LLMServer:
         n = min(self.config.prefill_chunk, P - start)
         # a model with state stops at the prompt's last page boundary (what
         # a later prompt can match of this one ends there), saves the state
-        # and runs the tail, at most a page, as the final chunk
+        # and runs the tail, at most a page, as the final chunk; and before
+        # that where the prompt leaves the tree past the snapshot it resumed
+        # from, so that the next prompt that shares those pages finds a state
+        # at their end (radix_cache.py)
         ps = self.config.page_size
         boundary = (P - 1) // ps * ps if self._stateful else 0
-        if start < boundary:
-            n = min(n, boundary - start)
+        branch = (self.page_mgr.branch_stop(job.slot_idx) * ps
+                  if self._stateful else 0)
+        for stop in (branch, boundary):
+            if start < stop:
+                n = min(n, stop - start)
+                break
         final = start + n >= P
         # clamp the padded bucket to the row capacity: a write spanning past
         # max_seq_len would be CLAMPED by dynamic_update_slice and land
@@ -1298,8 +1309,9 @@ class LLMServer:
         else:
             self.cache, last_logits = self._prefill(*args)
         job.pos += n
-        if job.pos == boundary and n:
-            sid = self.page_mgr.reserve_snapshot(job.slot_idx, boundary // ps)
+        if n and job.pos in (branch, boundary):
+            sid = self.page_mgr.reserve_snapshot(
+                job.slot_idx, job.pos // ps, branch=job.pos != boundary)
             if sid is not None:
                 with phase(self._phases, "state_save"):
                     self._copy_state(job.slot_idx, sid, save=True)

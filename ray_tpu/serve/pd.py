@@ -56,12 +56,12 @@ def _require_paged(server: LLMServer, who: str):
     if server.page_mgr is None:
         raise ValueError(f"{who} needs LLMConfig(paged=True): KV pages are "
                          "the prefill→decode transfer unit")
-    if server.model_cfg.n_linear_layers:
+    if server.model_cfg.n_state_layers:
         raise NotImplementedError(
             f"{who}: the prefill→decode hand-off carries KV pages and not "
-            "the recurrent state of linear-attention layers "
-            "(LlamaConfig.full_attn_every); serve such a model on one "
-            "colocated LLMServer")
+            "the recurrent state of linear-attention layers or of a "
+            "state-space mixer (LlamaConfig.full_attn_every, ssm_heads); "
+            "serve such a model on one colocated LLMServer")
     if server.model_cfg.n_window_layers:
         raise NotImplementedError(
             f"{who}: the prefill→decode hand-off carries the full pool's "

@@ -45,6 +45,17 @@ used goes first; a request that resumed from one and
 saves a deeper one on the same path moves the older to the cold end, since
 the next turn of that conversation will match the deeper one.
 
+A second depth a prompt: where it LEAVES the tree. A prompt whose pages match
+`d` deep while the deepest live snapshot on that path lies at `s < d` (many
+requests behind one system prompt: each saved a snapshot at its own end, none
+where they part) resumes from `s` as above, and `branch_stop` tells the
+engine to stop once more, at `d`; `reserve_snapshot(branch=True)` there puts
+the id on that node AT ONCE, if the page is still resident and no other
+request got there first, so the next prompt that shares those pages resumes
+from `d`. One further stop a prompt at most. A snapshot saved so is not
+turned cold by the requests that resume from it (they all go their own way
+past it: it is the one they share), and is counted apart.
+
 Two kinds of PAGE in the one manager. A model with sliding-window layers
 keeps their keys and values in a pool of its own (`window_pages` > 0), with
 its own free list, table a slot, refcounts and LRU; everything above, and
@@ -94,7 +105,7 @@ class _Node:
     """One full page of tokens in the radix tree."""
 
     __slots__ = ("tokens", "parent", "children", "page", "handle", "hits",
-                 "snap", "win")
+                 "snap", "win", "branch")
 
     def __init__(self, tokens, parent):
         self.tokens = tokens      # tuple of page_size token ids
@@ -105,6 +116,7 @@ class _Node:
         self.hits = 0
         self.snap = None          # snapshot id of the state after this page
         self.win = None           # window-pool page id of the same tokens
+        self.branch = False       # `snap` was saved where a prompt left the tree
 
     @property
     def resident_children(self) -> int:
@@ -197,6 +209,11 @@ class PageManager:
         self._snap_lru = collections.OrderedDict()  # nodes, coldest first
         self._pending_snap = {}    # slot -> (snapshot id, pages before it)
         self._resumed = {}         # slot -> node it resumed from (or None)
+        # slot -> (node, depth in pages) where its prompt leaves the tree,
+        # past the snapshot it resumed from
+        self._branch = {}
+        self.branch_snapshots_saved = 0
+        self.branch_snapshot_hits = 0
         self.snapshots_saved = 0
         self.snapshots_evicted = 0
         self.snapshot_hits = 0
@@ -351,27 +368,29 @@ class PageManager:
     def _drop_snapshot(self, node):
         if node.snap is not None:
             self._snap_free.append(node.snap)
-            node.snap = None
+            node.snap, node.branch = None, False
             self._snap_lru.pop(node, None)
             self.snapshots_evicted += 1
 
     def _usable(self, prompt_ids) -> tuple:
-        """The chain a request for `prompt_ids` can start from, and how many
-        matched pages it has to give up: the walk, less what would leave no
-        token to prefill (the final chunk's logits come from running one),
-        and for a model with state cut back to the deepest live snapshot, for
-        one with a window pool to the deepest depth whose window is whole."""
+        """The chain a request for `prompt_ids` can start from, how many
+        matched pages it has to give up, and the node those end on: the walk,
+        less what would leave no token to prefill (the final chunk's logits
+        come from running one), and for a model with state cut back to the
+        deepest live snapshot, for one with a window pool to the deepest
+        depth whose window is whole."""
         matched = self._walk(prompt_ids)
         while matched and len(matched) * self.page_size >= len(prompt_ids):
             matched.pop()
         if self.win_num_pages:
             deep = self._window_depth(matched)
-            return matched[:deep], len(matched) - deep
+            return matched[:deep], len(matched) - deep, None
         if not self.snapshots:
-            return matched, 0
+            return matched, 0, None
         deep = max((i + 1 for i, n in enumerate(matched)
                     if n.snap is not None), default=0)
-        return matched[:deep], len(matched) - deep
+        gap = len(matched) - deep
+        return matched[:deep], gap, matched[-1] if gap else None
 
     # ---------------------------------------------------------- window pool
     def _window_first(self, position: int) -> int:
@@ -496,16 +515,40 @@ class PageManager:
         node = self._resumed.get(slot)
         return -1 if node is None or node.snap is None else node.snap
 
-    def reserve_snapshot(self, slot: int, n_pages: int):
+    def branch_stop(self, slot: int) -> int:
+        """How many pages deep the slot's prompt leaves the tree, where that
+        lies past the snapshot it resumed from and the node there holds none
+        yet: the prefill stops there too. 0: no such stop."""
+        node, depth = self._branch.get(slot, (None, 0))
+        return depth if node is not None and node.snap is None else 0
+
+    def reserve_snapshot(self, slot: int, n_pages: int, branch: bool = False):
         """An id for the state after the slot's first `n_pages` pages (the
         coldest snapshot gives way when none is free), to be recorded by
-        `register_prefix`; None where the prefix cache keeps none."""
+        `register_prefix`; None where the prefix cache keeps none. With
+        `branch` the pages are the matched ones the slot's prompt leaves the
+        tree after: the id goes onto that node now (it exists), unless its
+        page has gone or another request's snapshot is there already."""
         if not (self.snapshots and self.prefix_cache_enabled and n_pages):
             return None
+        node = None
+        if branch:
+            node, depth = self._branch.pop(slot, (None, 0))
+            if (node is None or depth != n_pages or node.page is None
+                    or node.snap is not None):
+                return None
         if not self._snap_free:
+            if not self._snap_lru:      # every id is some request's, unrecorded
+                return None
             self._drop_snapshot(next(iter(self._snap_lru)))
         sid = self._snap_free.pop()
-        self._pending_snap[slot] = (sid, n_pages)
+        if node is None:
+            self._pending_snap[slot] = (sid, n_pages)
+            return sid
+        node.snap, node.branch = sid, True
+        self._snap_lru[node] = True
+        self.snapshots_saved += 1
+        self.branch_snapshots_saved += 1
         return sid
 
     def _take_page(self):
@@ -528,7 +571,7 @@ class PageManager:
         pages), so it must not stall in admission behind the full page
         bill while the pool is busy serving the very prompts it shares."""
         ps = self.page_size
-        matched, _ = self._usable(prompt_ids)   # mirror allocate_prefix
+        matched, _, _ = self._usable(prompt_ids)  # mirror allocate_prefix
         live = [n for n in matched if n.page is not None]
         need_total = -(-n_tokens // ps)
         # demoted matches restore into a fresh page each, so only LIVE
@@ -580,7 +623,8 @@ class PageManager:
         self.prefix_query_tokens += P
         _count("radix_query_tokens", P)
         # a fully covered prompt still prefills its tail
-        matched, gap = self._usable(prompt_ids)
+        matched, gap, leaves_at = self._usable(prompt_ids)
+        leaves_depth = len(matched) + gap
         need_total = -(-n_tokens // ps)
         if need_total > self.max_pages_per_seq:
             raise ValueError(
@@ -682,7 +726,10 @@ class PageManager:
             if node is not None:
                 self._snap_lru.move_to_end(node)
                 self.snapshot_hits += 1
+                self.branch_snapshot_hits += node.branch
                 self.resume_gap_tokens += gap * ps
+            if leaves_at is not None and leaves_at.page is not None:
+                self._branch[slot] = (leaves_at, leaves_depth)
         self.prefix_hit_tokens += cached
         _count("radix_hit_tokens", cached)
         return self.table_row(slot), cached
@@ -756,7 +803,8 @@ class PageManager:
         self._snap_lru[node] = True
         self.snapshots_saved += 1
         old = self._resumed.get(slot)
-        if old is not None and old is not node and old in self._snap_lru:
+        if (old is not None and old is not node and old in self._snap_lru
+                and not old.branch):
             self._snap_lru.move_to_end(old, last=False)
 
     # ------------------------------------------------------- a slot's pages
@@ -792,6 +840,7 @@ class PageManager:
         self._win_budget[slot] = self._win_held[slot] = 0
         self._win_dead[slot] = 0
         self._resumed.pop(slot, None)
+        self._branch.pop(slot, None)
         sid, _ = self._pending_snap.pop(slot, (None, 0))
         if sid is not None:      # the request ended before it was recorded
             self._snap_free.append(sid)
@@ -868,5 +917,7 @@ class PageManager:
         return {"snapshots_saved": self.snapshots_saved,
                 "snapshots_evicted": self.snapshots_evicted,
                 "snapshot_hits": self.snapshot_hits,
+                "branch_snapshots_saved": self.branch_snapshots_saved,
+                "branch_snapshot_hits": self.branch_snapshot_hits,
                 "resume_gap_tokens": self.resume_gap_tokens,
                 "snapshots_live": len(self._snap_lru)}

@@ -123,3 +123,145 @@ def test_without_state_nothing_changes():
     assert _serve(mgr, 0, prompt)[:2] == (0, None)
     assert _serve(mgr, 1, prompt + [1, 2])[0] == 16      # pages alone are a hit
     assert mgr.reserve_snapshot(0, 3) is None
+
+
+# ------------------------------------------- a snapshot where a prompt leaves
+def _serve_branching(mgr, slot, prompt, extra=4, release=True):
+    """`_serve` as the engine drives it now: one further stop where the
+    prompt leaves the tree past the snapshot it resumed from. Returns
+    (cached, branch stop in pages, the branch id, the prompt's own id)."""
+    _, cached = mgr.allocate_prefix(slot, prompt, len(prompt) + extra)
+    boundary = (len(prompt) - 1) // PS * PS
+    stop = mgr.branch_stop(slot)
+    at_branch = own = None
+    if cached < stop * PS < boundary:
+        at_branch = mgr.reserve_snapshot(slot, stop, branch=True)
+    if cached < boundary:
+        own = mgr.reserve_snapshot(slot, boundary // PS)
+    mgr.register_prefix(slot, prompt)
+    if release:
+        mgr.free(slot)
+    return cached, stop, at_branch, own
+
+
+def test_a_prompt_that_leaves_a_shared_prefix_saves_where_it_leaves():
+    mgr = _mgr(snapshots=8)
+    system = list(range(14))            # 3 whole pages and two tokens
+    cached, stop, at_branch, own = _serve_branching(
+        mgr, 0, system + [50] * 9)
+    assert (cached, stop, at_branch) == (0, 0, None) and own is not None
+    # the second shares 3 pages and no snapshot lies on them: a miss that
+    # stops at depth 3 and saves there, and at its own end
+    cached, stop, at_branch, own = _serve_branching(mgr, 1, system + [60] * 9)
+    assert (cached, stop) == (0, 3)
+    assert at_branch is not None and own is not None and own != at_branch
+    st = mgr.state_stats()
+    assert (st["branch_snapshots_saved"], st["snapshots_saved"]) == (1, 3)
+    assert st["snapshot_hits"] == 0
+    # the third resumes there: its gap is the two tokens of the system prompt
+    # on the page it shares with nobody, under a page
+    cached, stop, at_branch, own = _serve_branching(mgr, 2, system + [70] * 9)
+    assert (cached, stop, at_branch) == (12, 0, None) and own is not None
+    st = mgr.state_stats()
+    assert (st["snapshot_hits"], st["branch_snapshot_hits"]) == (1, 1)
+    assert st["resume_gap_tokens"] == 0 and len(system) - cached < PS
+    assert mgr.resume_snapshot(2) == -1           # released: nothing is held
+
+
+def test_a_match_that_ends_on_a_snapshot_saves_only_its_own():
+    """A conversation's next turn: the pages it matches end on the last
+    turn's snapshot, so there is no further stop and no branch is counted."""
+    mgr = _mgr(snapshots=8)
+    first = list(range(18))
+    _serve_branching(mgr, 0, first)
+    cached, stop, at_branch, own = _serve_branching(
+        mgr, 1, first + list(range(100, 110)))
+    assert (cached, stop, at_branch) == (16, 0, None) and own is not None
+    st = mgr.state_stats()
+    assert st["branch_snapshots_saved"] == st["branch_snapshot_hits"] == 0
+    assert (st["snapshots_saved"], st["snapshot_hits"]) == (2, 1)
+
+
+def test_two_prompts_that_race_to_one_node_leave_one_snapshot():
+    mgr = _mgr(snapshots=8)
+    system = list(range(14))
+    _serve_branching(mgr, 0, system + [50] * 9)
+    # both admitted before either reaches the stop: both are told to stop
+    a, b = system + [60] * 9, system + [70] * 9
+    mgr.allocate_prefix(1, a, len(a) + 4)
+    mgr.allocate_prefix(2, b, len(b) + 4)
+    assert mgr.branch_stop(1) == mgr.branch_stop(2) == 3
+    first = mgr.reserve_snapshot(1, 3, branch=True)
+    assert first is not None
+    assert mgr.branch_stop(2) == 0                # the node has one now
+    assert mgr.reserve_snapshot(2, 3, branch=True) is None
+    for slot, prompt in ((1, a), (2, b)):
+        assert mgr.reserve_snapshot(slot, 5) is not None
+        mgr.register_prefix(slot, prompt)
+        mgr.free(slot)
+    st = mgr.state_stats()
+    assert st["branch_snapshots_saved"] == 1
+    assert st["snapshots_saved"] - st["snapshots_evicted"] == st["snapshots_live"] == 4
+    assert len(mgr._snap_free) + st["snapshots_live"] == 8      # none leaked
+    assert not mgr._pending_snap and not mgr._branch
+
+
+def test_a_branch_snapshot_outlives_the_requests_behind_it_in_a_pool_of_two():
+    """LRU under a pool of two: the requests that resume from the branch
+    snapshot each save their own, which take each other's place; the branch
+    snapshot, hit every time and never turned cold, stays."""
+    mgr = _mgr(snapshots=2)
+    system = list(range(14))
+    _serve_branching(mgr, 0, system + [50] * 9)           # own (1 of 2)
+    _serve_branching(mgr, 0, system + [60] * 9)           # branch + own: evicts
+    assert mgr.state_stats()["snapshots_live"] == 2
+    for k in range(5):
+        cached, _, _, own = _serve_branching(mgr, 0, system + [70 + k] * 9)
+        assert cached == 12 and own is not None
+    st = mgr.state_stats()
+    assert st["branch_snapshot_hits"] == 5 and st["branch_snapshots_saved"] == 1
+    assert st["snapshots_saved"] - st["snapshots_evicted"] == 2
+
+
+def test_a_branch_snapshot_goes_with_its_page_and_its_id_comes_back():
+    mgr = _mgr(snapshots=4, num_pages=12, slots=2)
+    system = list(range(14))
+    _serve_branching(mgr, 0, system + [50] * 5, extra=0)
+    _serve_branching(mgr, 0, system + [60] * 5, extra=0)
+    assert mgr.state_stats()["branch_snapshots_saved"] == 1
+    mgr.allocate(1, 11 * PS)            # every page: the chains are evicted
+    st = mgr.state_stats()
+    assert st["snapshots_live"] == 0 and len(mgr._snap_free) == 4
+    mgr.free(1)
+    assert _serve_branching(mgr, 0, system + [70] * 5, extra=0)[:2] == (0, 0)
+
+
+def test_growing_sessions_read_as_they_did():
+    """The agent-loop shape on the hybrid model's engine: every turn is the
+    last prompt plus new text, so its match ends on the last turn's snapshot
+    and the counters read what they read before the second depth: one save a
+    turn, one hit a turn after the first, no gap, nothing at a branch. (A
+    turn whose previous prompt is a whole number of pages long matches one
+    page past that snapshot and saves a second one there: 1 turn in 8 at this
+    page size, 1 in 64 at the cell's; none here.)"""
+    import asyncio
+
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+    srv = LLMServer(LLMConfig(
+        preset="solar_tiny", paged=True, prefix_cache=True, page_size=8,
+        prefill_chunk=16, max_seq_len=128, num_pages=80, max_batch_slots=2,
+        decode_chunk=4))
+    try:
+        assert srv.page_mgr.snapshots == 2 * 4        # four a slot, as before
+        prompt = list(range(1, 22))
+        for turn in range(4):
+            asyncio.run(srv.generate(prompt, max_tokens=2))
+            prompt = prompt + [100 + turn] * 10       # 21, 31 .. never k * 8
+            assert len(prompt) % 8
+        st = srv.stats()["state"]
+        assert (st["snapshots_saved"], st["snapshot_hits"]) == (4, 3)
+        assert st["branch_snapshots_saved"] == st["branch_snapshot_hits"] == 0
+        assert st["resume_gap_tokens"] == 0
+        assert (st["snapshot_copies"], st["restore_copies"]) == (4, 3)
+    finally:
+        srv.close()
