@@ -34,6 +34,7 @@ import asyncio
 
 from .. import exceptions as exc
 from .._native import codec as _codec
+from .._native import objdir as _objdir
 from ..util import tracing
 from . import ids, protocol, serialization
 from .object_store import StoreClient
@@ -457,18 +458,19 @@ class BaseClient:
             spec.owned_inline = inline
 
     def _materialize(self, oids, descs):
-        out = []
-        for oid, (kind, payload) in zip(oids, descs):
-            if kind == "err":
-                raise payload
-            if kind == "inline":
-                out.append(serialization.unpack(payload))
-            else:  # shm
-                try:
-                    out.append(self.store.get(oid, payload))
-                except FileNotFoundError:
-                    out.append(self._reread_demoted(oid))
-        return out
+        return [self._materialize_one(oid, desc)
+                for oid, desc in zip(oids, descs)]
+
+    def _materialize_one(self, oid, desc):
+        kind, payload = desc
+        if kind == "err":
+            raise payload
+        if kind == "inline":
+            return serialization.unpack(payload)
+        try:  # shm
+            return self.store.get(oid, payload)
+        except FileNotFoundError:
+            return self._reread_demoted(oid)
 
     def _reread_demoted(self, oid, attempts=16):
         """The shm read raced the spill ladder: the segment was demoted back
@@ -492,6 +494,16 @@ class BaseClient:
 
     def _descriptor_for(self, oid):
         raise NotImplementedError
+
+    def release_stream_items(self, oids):
+        """Give back the reference a stream's reader holds on each item a
+        read_stream batch handed it, for a reader that reads no more (its
+        next read would have carried them): ONE ordered flusher entry, a
+        packed decref run applied in one bulk directory call. Stream items
+        are never in the ownership table, so there is no local mirror."""
+        if oids:
+            self._flusher.append(("refdeltas", _objdir.pack_deltas(
+                [(_objdir.DECREF, oid) for oid in oids])))
 
     def _encode_to_store(self, oid, value):
         """Serialize once; returns (meta_len, size, inline_or_None, contained
@@ -578,10 +590,13 @@ class DriverClient(BaseClient):
         this before stopping the controller so nothing is silently dropped)."""
         self._flusher.flush()
 
-    def _call(self, coro, timeout=None):
+    def _call_future(self, coro):
         self._flusher.flush()  # pending deltas apply before `coro` runs
         protocol.note_roundtrip("driver_call")
-        fut = asyncio.run_coroutine_threadsafe(coro, self.loop)
+        return asyncio.run_coroutine_threadsafe(coro, self.loop)
+
+    def _call(self, coro, timeout=None):
+        fut = self._call_future(coro)
         try:
             return fut.result(timeout)
         except concurrent.futures.TimeoutError:
@@ -767,8 +782,14 @@ class DriverClient(BaseClient):
     def chaos_op(self, op):
         return self._call_soon(self.controller.chaos_op, op)
 
-    def next_stream_item(self, task_id, index, timeout=None):
-        return self._call(self.controller.next_stream_item(task_id, index, timeout))
+    def read_stream(self, task_id, index, timeout=None, release=()):
+        """Future of the stream's next batch (controller.read_stream): every
+        item the stream holds from `index` on as (oid, descriptor), None at
+        the end; `release` gives back items of earlier batches in the same
+        call. One blocking round trip whatever the batch holds; the caller
+        waits on the future from a thread or from an event loop."""
+        return self._call_future(
+            self.controller.read_stream(task_id, index, timeout, release))
 
     def create_placement_group(self, bundles, strategy, name=""):
         return self._call(
@@ -948,7 +969,7 @@ class WorkerClient(BaseClient):
         self._flusher.close()
         super().close()
 
-    def _rpc(self, kind, timeout=None, **payload):
+    def _rpc_future(self, kind, **payload):
         with self._lock:
             self._flusher.flush_locked()  # forced flush before any blocking RPC
             self._req_counter += 1
@@ -957,7 +978,10 @@ class WorkerClient(BaseClient):
             self._reqs[req_id] = fut
             protocol.send_msg(self.sock, kind, req_id=req_id, **payload)
         protocol.note_roundtrip(kind)
-        return fut.result(timeout)
+        return fut
+
+    def _rpc(self, kind, timeout=None, **payload):
+        return self._rpc_future(kind, **payload).result(timeout)
 
     def _send(self, kind, **payload):
         with self._lock:
@@ -1175,8 +1199,21 @@ class WorkerClient(BaseClient):
     def timeline(self):
         return self._rpc("timeline")["events"]
 
-    def next_stream_item(self, task_id, index, timeout=None):
-        return self._rpc("next_stream", task_id=task_id, index=index, timeout=timeout)["item"]
+    def read_stream(self, task_id, index, timeout=None, release=()):
+        """Future of the stream's next batch, as DriverClient.read_stream
+        gives it: one `next_stream` RPC whatever the batch holds."""
+        out = concurrent.futures.Future()
+
+        def done(reply):
+            try:
+                out.set_result(reply.result()["items"])
+            except BaseException as e:  # noqa: BLE001 - the reader raises it
+                out.set_exception(e)
+
+        self._rpc_future("next_stream", task_id=task_id, index=index,
+                         timeout=timeout,
+                         release=release).add_done_callback(done)
+        return out
 
     def create_placement_group(self, bundles, strategy, name=""):
         return self._rpc("create_pg", bundles=bundles, strategy=strategy,

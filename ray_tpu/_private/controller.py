@@ -13,6 +13,7 @@ The driver shares the controller's process and calls coroutines directly.
 
 import asyncio
 import collections
+import copy
 import os
 import signal
 import subprocess
@@ -412,13 +413,33 @@ class _ReadyIndex:
         return out
 
 
+def _fresh_error(err):
+    """A stream's stored error, to raise in a reader. Raising the stored
+    object itself would grow ITS traceback through the reader's frames, and
+    a driver-side generator held by those frames keeps its own StreamState
+    (which holds the error) alive for good."""
+    if not isinstance(err, Exception):
+        return exc.TaskError("stream", str(err))
+    try:
+        return copy.copy(err)
+    except Exception:  # noqa: BLE001 - an exception that cannot be rebuilt
+        return err
+
+
 @dataclass
 class StreamState:
     items: list = field(default_factory=list)  # object ids in yield order
+    # the task's one return object (the list of item ids): no client ever
+    # holds a ref to it, so it goes with the stream
+    handle_oid: Optional[str] = None
     finished: bool = False
     drained: bool = False  # consumer saw the end (StopIteration / error)
     open_handles: int = 0  # live ObjectRefGenerator copies
-    max_served: int = 0  # items[:max_served] were handed out (consumer owns them)
+    # items[:max_served] left in a read_stream batch: their reader holds the
+    # reference register_put gave each (in an ObjectRef, or in its buffer
+    # until it drops it) and releases it; items[max_served:] stay the
+    # controller's to release when the stream is dropped
+    max_served: int = 0
     error: Optional[Exception] = None
     cond: asyncio.Event = field(default_factory=asyncio.Event)
 
@@ -1083,8 +1104,9 @@ class Controller:
 
     async def _worker_next_stream(self, w, p):
         try:
-            item = await self.next_stream_item(p["task_id"], p["index"], p.get("timeout"))
-            self._reply(w, p["req_id"], item=item)
+            items = await self.read_stream(p["task_id"], p["index"],
+                                           p.get("timeout"), p.get("release", ()))
+            self._reply(w, p["req_id"], items=items)
         except Exception as e:  # noqa: BLE001
             self._reply(w, p["req_id"], error=e)
 
@@ -1145,6 +1167,7 @@ class Controller:
             st.error = err
             st.finished = True
             st.cond.set()
+            self._maybe_drop_stream(spec.task_id, st)  # already abandoned?
 
     def _submit_sync(self, spec: TaskSpec,
                      result_oids: List[str] = None) -> List[str]:
@@ -1156,7 +1179,7 @@ class Controller:
         fire-and-forget submit is fully applied before any later frame."""
         if spec.num_returns == "streaming":
             result_oids = result_oids or [ids.object_id()]  # generator handle
-            self.streams[spec.task_id] = StreamState()
+            self.streams[spec.task_id] = StreamState(handle_oid=result_oids[0])
         else:
             result_oids = result_oids or [
                 ids.object_id() for _ in range(max(spec.num_returns, 1))]
@@ -2311,6 +2334,7 @@ class Controller:
             st.error = err
             st.finished = True
             st.cond.set()
+            self._maybe_drop_stream(rec.spec.task_id, st)  # already abandoned?
         rec.done.set()
         # wake tasks depending on these now-errored objects
         for oid in rec.result_oids:
@@ -3335,18 +3359,28 @@ class Controller:
             st.items.append(p["oid"])
             st.cond.set()
 
-    async def next_stream_item(self, task_id: str, index: int, timeout=None):
+    async def read_stream(self, task_id: str, index: int, timeout=None,
+                          release=()):
+        """A read of a stream hands over what is there: wait until the stream
+        holds an item at `index` (or its error, or its end), then return
+        [(oid, descriptor), ...] for EVERY item from `index` on, descriptors
+        as get_descriptors gives them, and count them all as served. Never
+        waits to fill a batch: one item there is one item returned, at once.
+        None at the end; the producer's error is raised once the reader has
+        taken every item that came before it. `release` lists items of
+        earlier batches whose values the reader has taken: the reference
+        each carried is dropped here, so giving a batch back costs no
+        message of its own."""
+        if release:
+            self.decref(release)
         st = self.streams.get(task_id)
         if st is None:
             raise ValueError(f"no stream for task {task_id}")
         deadline = None if timeout is None else time.monotonic() + timeout
-        while True:
-            if index < len(st.items):
-                st.max_served = max(st.max_served, index + 1)
-                return st.items[index]
+        while index >= len(st.items):
             if st.error is not None:
                 self._mark_stream_drained(task_id, st)
-                raise st.error if isinstance(st.error, Exception) else exc.TaskError("stream", str(st.error))
+                raise _fresh_error(st.error)
             if st.finished:
                 self._mark_stream_drained(task_id, st)
                 return None  # StopIteration sentinel
@@ -3356,6 +3390,19 @@ class Controller:
                 await asyncio.wait_for(st.cond.wait(), remaining)
             except asyncio.TimeoutError:
                 raise exc.GetTimeoutError("stream next() timed out") from None
+        batch = []
+        for oid in st.items[index:]:
+            meta = self.objects.get(oid)
+            if meta is not None and meta.location == "inline":
+                desc = ("inline", meta.inline_value)  # a token: no await
+            else:
+                try:
+                    desc = (await self.get_descriptors([oid], None))[0]
+                except Exception as e:  # noqa: BLE001 - raised at ITS item
+                    desc = ("err", e)
+            batch.append((oid, desc))
+        st.max_served = max(st.max_served, index + len(batch))
+        return batch
 
     def _maybe_drop_stream(self, task_id: str, st: StreamState):
         """Single deletion rule: the producer finished, a consumer saw the end
@@ -3364,7 +3411,7 @@ class Controller:
         ObjectRef will ever balance."""
         if st.finished and st.drained and st.open_handles <= 0:
             if self.streams.pop(task_id, None) is not None:
-                self.decref(st.items[st.max_served:])
+                self.decref(st.items[st.max_served:] + [st.handle_oid])
 
     def _mark_stream_drained(self, task_id: str, st: StreamState):
         st.drained = True

@@ -27,7 +27,8 @@ Pipelined control plane additions:
   client._OwnedTable) — the owner's gets then resolve locally with zero
   round trips. "exec" dispatch frames ride the native codec (KIND_EXEC)
   when the worker negotiated codec_ver > 0.
-- per-process counters tally frames by kind and blocking round trips, read
+- per-process counters tally frames by kind, blocking round trips, and
+  stream reads beside the items they handed over (note_stream_read), read
   through ray_tpu.util.metrics.control_plane_counters(); benchmarks and the
   pipelining tests assert on deltas of these. Counters are kept in
   per-thread tables merged lazily at read time — the old single-lock dict
@@ -53,7 +54,7 @@ _HDR = struct.Struct("<I")
 # _tables_lock. Totals are exact for quiesced threads and at most one frame
 # stale for threads mid-send — fine for counters.
 _tables_lock = threading.Lock()
-_all_tables = []  # [(sent, received, roundtrips)] — one triple per thread
+_all_tables = []  # [(sent, received, roundtrips, local_gets, streams)] per thread
 
 
 class _ThreadTables(threading.local):
@@ -62,9 +63,10 @@ class _ThreadTables(threading.local):
         self.received: Dict[str, int] = {}
         self.roundtrips: Dict[str, int] = {}
         self.local_gets: Dict[str, int] = {}
+        self.streams: Dict[str, int] = {}
         with _tables_lock:
             _all_tables.append((self.sent, self.received, self.roundtrips,
-                                self.local_gets))
+                                self.local_gets, self.streams))
 
 
 _tls = _ThreadTables()
@@ -95,6 +97,16 @@ def note_local_get(n: int = 1) -> None:
     t["owned"] = t.get("owned", 0) + n
 
 
+def note_stream_read(n_items: int) -> None:
+    """Record one read of a stream that handed `n_items` items to its reader
+    (ObjectRefGenerator). items / reads is 1.0 while readers keep up and
+    rises with the backlog a read finds: each read is ONE round trip
+    whatever it carries."""
+    t = _tls.streams
+    t["reads"] = t.get("reads", 0) + 1
+    t["items"] = t.get("items", 0) + n_items
+
+
 def local_gets_total() -> int:
     return sum(_merged(3).values())
 
@@ -121,7 +133,8 @@ def counter_snapshot() -> Dict[str, Dict[str, int]]:
     return {"frames_sent": _merged(0),
             "frames_received": _merged(1),
             "roundtrips": _merged(2),
-            "local_gets": _merged(3)}
+            "local_gets": _merged(3),
+            "streams": _merged(4)}
 
 
 def _encode(kind: str, payload: dict, codec_on: bool) -> bytes:

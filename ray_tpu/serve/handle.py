@@ -126,9 +126,20 @@ class DeploymentResponse:
 
 
 class DeploymentResponseGenerator:
-    """Streaming response: iterate results as the replica yields them.
+    """Streaming response: iterate results as the replica yields them, one
+    value a turn of the caller's loop, in yield order, each once; an error
+    the replica raised arrives after the items that came before it.
     `on_finish` runs exactly once when the stream ends (exhausted, errored,
-    or GC'd) — the handle uses it to decrement its in-flight counter."""
+    or GC'd) — the handle uses it to decrement its in-flight counter.
+
+    Reads through ObjectRefGenerator's value form: one controller call
+    hands over every item the stream holds past this reader, descriptors
+    included, and the values are unpacked here, in the reader's thread (or
+    on its event loop: the async form awaits the same call and takes no
+    executor thread). No ObjectRef is built for an item; the references a
+    batch carried go back together at the next read, and what is left in
+    the generator's buffer when this response is dropped goes back with
+    it."""
 
     def __init__(self, gen, on_finish=None):
         self._gen = gen
@@ -140,18 +151,24 @@ class DeploymentResponseGenerator:
             cb()
 
     def __iter__(self):
-        import ray_tpu
         try:
-            for ref in self._gen:
-                yield ray_tpu.get(ref)
+            while True:
+                try:
+                    value = self._gen.next_value()
+                except StopIteration:
+                    return
+                yield value
         finally:
             self._finish()
 
     async def __aiter__(self):
-        import ray_tpu
         try:
-            async for ref in self._gen:
-                yield await ref
+            while True:
+                try:
+                    value = await self._gen.anext_value()
+                except StopAsyncIteration:
+                    return
+                yield value
         finally:
             self._finish()
 

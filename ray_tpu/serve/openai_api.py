@@ -182,12 +182,9 @@ class OpenAIIngress:
         gen = await loop.run_in_executor(
             None, lambda: eng.options(stream=True).generate_stream.remote(
                 prompt_ids, **kw))
-        it = iter(gen)
-        _END = object()
-        while True:
-            tok = await loop.run_in_executor(None, lambda: next(it, _END))
-            if tok is _END:
-                return
+        # the response's async form awaits the stream's reads on this loop:
+        # no executor thread a token
+        async for tok in gen:
             yield tok
 
     # -- request plumbing -----------------------------------------------------

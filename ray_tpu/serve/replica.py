@@ -79,7 +79,9 @@ class Replica:
             self._ongoing -= 1
 
     async def handle_request_streaming(self, method_name, *args, **kwargs):
-        """Generator methods: yield items (streams via ObjectRefGenerator)."""
+        """Generator methods: yield items (streams via ObjectRefGenerator).
+        Each item leaves as an object and a `stream_item` frame of its own;
+        the reader takes whatever has arrived in one read (read_stream)."""
         self._ongoing += 1
         self._total += 1
         try:

@@ -252,10 +252,13 @@ def histogram_window(name: str, state: Dict,
 
 def control_plane_counters() -> Dict[str, Dict[str, int]]:
     """Per-process frame/round-trip tallies by message kind:
-    {"frames_sent": {kind: n}, "frames_received": {...}, "roundtrips": {...}}.
+    {"frames_sent": {kind: n}, "frames_received": {...}, "roundtrips": {...},
+    "local_gets": {...}, "streams": {"reads": n, "items": n}}.
     Frames count unix-socket messages; round trips count blocking control
     calls (worker RPCs that awaited a reply, driver bridge calls into the
-    controller loop)."""
+    controller loop); streams count the reads this process's stream readers
+    made and the items those reads handed over (items / reads: 1.0 while
+    readers keep up, more when a read finds a backlog)."""
     from ray_tpu._private import protocol
     return protocol.counter_snapshot()
 
