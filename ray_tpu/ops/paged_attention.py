@@ -840,8 +840,10 @@ def row_keys_values(cache: PagedKVCache, layer_idx: int,
 # Learned sparse attention over the paged cache (a lightning indexer's top-k
 # selection, DeepSeek-V3.2-Exp style): the indexer scores every cached key of
 # a row, the `topk` best are selected (all of them while the row holds `topk`
-# or fewer), and attention runs over the selected keys only. Pure XLA on the
-# token-major layout; one selection a query token, shared by every head.
+# or fewer), and attention runs over the selected keys only. XLA on the
+# token-major layout, but for the decode step's scores, which a kernel
+# computes from the indexer's pool where it lies (`sparse_decode_scores`);
+# one selection a query token, shared by every head.
 # ---------------------------------------------------------------------------
 
 _NEG = -1e30       # masked attention logit: finite, so no row turns to NaN
@@ -871,8 +873,8 @@ def kth_largest(scores, k: int, bits: int = _SELECT_BITS):
     2^bits - 1 candidate prefixes, how many keys reach it. Returns (keys
     [T, S], kth [T, 1]) as uint32 whose order is the floats'. `lax.top_k` of a
     [512, 30720] block is a full sort on the TPU: 14 ms of a prefill chunk's
-    layer against 2 ms this way (v5e, PR 28); where the positions are wanted
-    too (decode) `lax.top_k` stays."""
+    layer against 2 ms this way (v5e, PR 28); decode, which wants the
+    positions too, gets them from the value (`top_k_places`)."""
     as_int = jax.lax.bitcast_convert_type(scores, jnp.int32)
     as_int = jnp.where(as_int < 0, as_int ^ 0x7FFFFFFF, as_int)
     keys = jax.lax.bitcast_convert_type(as_int, jnp.uint32) ^ jnp.uint32(1 << 31)
@@ -887,52 +889,296 @@ def kth_largest(scores, k: int, bits: int = _SELECT_BITS):
     return keys, kth
 
 
-def _table_lookup(tables, slots):
-    """tables[b, slots[b, j]] for tables [B, mp] of page ids and slots
-    [B, K], as a one-hot contraction on the MXU: XLA's gather of 49,152
-    scalars took 0.50 ms a layer a decode step on the v5e (PR 28), as long as
-    the sort. Exact: a page id goes through in base-128 digits, which bf16
-    holds, and each sum has one term."""
-    one_hot = (slots[:, :, None] == jnp.arange(tables.shape[1])[None, None]
-               ).astype(jnp.bfloat16)                             # [B, K, mp]
-    digits = jnp.stack([(tables >> shift) & 127 for shift in (0, 7, 14, 21)]
-                       ).astype(jnp.bfloat16)                     # [4, B, mp]
-    got = jnp.einsum("bkp,dbp->dbk", one_hot, digits,
+# Pages of one row that a step of `sparse_decode_scores` copies and scores, and
+# how many of them one stretch of straight code starts (a block whole, then
+# what is left of a row's last block in eights and ones). A page of the
+# indexer's pool is 8 KB at Keye's sizes (64 tokens x 64 values in bf16): a
+# block is small in VMEM whatever these are, and what a call costs is the
+# START of a copy a page, not its bytes. On the v5e (PR 48), 24 rows of
+# 16k-28k keys, 7,700-7,900 pages a call whose bytes' time is 0.08 ms: 0.40
+# with a loop of one start a turn at 16 pages a block, 0.26 with a block's
+# starts in straight code, 0.22 / 0.19 at 32 / 64 pages a block; 0.16 of the
+# 0.22 with nothing scored, 0.06 with nothing copied: 20 ns a page, what
+# XLA's own gather of the pages took too, and the two add. Starts laid
+# between the pieces of a block's product gained nothing.
+_INDEX_PAGES_PER_BLOCK = 32
+_INDEX_COPY_STRETCHES = (8, 1)
+
+
+def index_block_keys(page_size: int, max_pages: int) -> int:
+    """Keys a step of `sparse_decode_scores` scores: a row's walk covers its
+    length rounded up to this."""
+    return min(_INDEX_PAGES_PER_BLOCK, max_pages) * page_size
+
+
+def _scores_kernel(tbl_ref, pages_ref, slot_ref, layer_ref, q_ref, w_ref,
+                   pool_ref, o_ref, buf, sem, *, ppb, stretches, heads):
+    """Row b of the grid: the indexer's scores of every key the row holds,
+    block after block of `ppb` pages, a step only for a block that holds
+    keys of the row (a free slot runs none and reads nothing).
+
+    pages_ref [B]: the pages each row holds keys on; slot_ref [B]: the half
+    of `buf` a row's first block lies in (the rows' blocks alternate between
+    the halves right through the call). q_ref [1, r * J, r * Di]: the row's
+    J query heads laid out block-diagonally, once for each of the r tokens
+    that share a row of the pool, so that a packed row is scored as it lies:
+    [r * J, r * Di] x [n, r * Di]^T is [r * J, n], token s of pool row i
+    under rows s * J .. s * J + J - 1. w_ref [1, r * J, 1] f32: the heads'
+    weights, r times. pool_ref: the indexer's pool whole, [L, P, page / r,
+    r * Di], left in HBM: the kernel copies the pages a block names in the
+    table itself (buf [2, ppb, page / r, r * Di], one DMA semaphore a half),
+    the next block's while this one is scored, and while a row's last block
+    is scored the first of the row after it. o_ref [1, n_blocks, r, n],
+    n = ppb x page / r: o[0, blk, s, i] is the score of position
+    (blk * n + i) * r + s. A block the row has no key in is not written, and
+    a page past the row's last is not copied: what the result holds there is
+    left over, for the caller to mask."""
+    b = pl.program_id(0)
+    _, _, rows, lanes = buf.shape
+    n = ppb * rows
+    r = q_ref.shape[1] // heads
+    blocks_of = lambda row: pl.cdiv(pages_ref[row], ppb)
+    n_blocks = blocks_of(b)
+    layer = layer_ref[0]
+
+    def each_page(row, blk, slot, start: bool, stretches=stretches):
+        """Start, or wait for, the copy of every page of block `blk` of
+        `row` that the row holds keys on, in stretches of straight code:
+        as many of `stretches[0]` pages as fit, then of the next size, down
+        to 1. A wait reads its size alone, so one wait covers a stretch."""
+        def stretch(first, size):
+            if not start:
+                at = buf.at[slot, pl.ds(0, size)]
+                pltpu.make_async_copy(at, at, sem.at[slot]).wait()
+                return
+            for j in range(size):
+                pltpu.make_async_copy(
+                    pool_ref.at[layer, pl.ds(
+                        tbl_ref[row, blk * ppb + first + j], 1)],
+                    buf.at[slot, pl.ds(first + j, 1)], sem.at[slot]).start()
+
+        here = jnp.minimum(pages_ref[row] - blk * ppb, ppb)
+        done = 0
+        for size in stretches:
+            count = (here - done) // size
+            jax.lax.fori_loop(
+                0, count, lambda g, carry, size=size, done=done:
+                (stretch(done + g * size, size), carry)[1], 0)
+            done = done + count * size
+
+    @pl.when((n_blocks > 0) & ((b == 0) | (blocks_of(jnp.maximum(b - 1, 0))
+                                           == 0)))
+    def _first():
+        # no row before this one to have started its copies: the first row
+        # and one after a free slot (the short stretches: the kernel's
+        # straight code is what a program's lowering pays for, a layer)
+        each_page(b, 0, slot_ref[b], True, stretches[1:] or stretches)
+
+    after = jnp.minimum(b + 1, pl.num_programs(0) - 1)
+
+    def block(blk, carry):
+        slot = (slot_ref[b] + blk) % 2
+        # copied while this block is scored: the row's next block or, behind
+        # its last, the first block of the row after it
+        last = blk + 1 == n_blocks
+
+        @pl.when(jnp.logical_not(last) | ((after > b) & (blocks_of(after) > 0)))
+        def _ahead():
+            each_page(jnp.where(last, after, b), jnp.where(last, 0, blk + 1),
+                      1 - slot, True)
+
+        each_page(b, blk, slot, False)
+        keys = buf[slot].reshape(n, lanes)
+        s = jax.lax.dot_general(q_ref[0], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.maximum(s, 0.0) * w_ref[0]                        # [r * J, n]
+        o_ref[0, blk] = s.reshape(r, heads, n).sum(axis=1)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+
+def sparse_decode_scores(qi, wi, cache: PagedKVCache, layer_idx, lengths, *,
+                         interpret: Optional[bool] = None):
+    """The indexer's score of every cached key of each decode row, [B, S] in
+    f32 by position (S the table's width in tokens), -inf at and past a
+    row's length: `index_scores` of the row's query over `index_keys` of its
+    table, without the keys' copy. qi [B, J, Di], wi [B, J], lengths [B].
+
+    The kernel `sparse_decode_scores` takes the indexer's pool whole, as the
+    cache holds it, and the table, the rows' pages and the layer as
+    prefetched scalars; a grid step is a row, which walks its own pages in
+    blocks (`_scores_kernel`): what a call reads and multiplies follows the
+    keys the rows hold, not the table's width. bf16 operands, f32
+    accumulation, relu, the heads' weights and their sum in f32. `interpret`
+    None: compiled on the TPU, interpreted elsewhere."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    mp = cache.block_tables.shape[1]
+    ppb = min(_INDEX_PAGES_PER_BLOCK, mp)
+    stretches = (ppb,) + tuple(n for n in _INDEX_COPY_STRETCHES if n < ppb)
+    return _decode_scores(
+        qi, wi, cache.idx_pages, cache.block_tables, lengths,
+        jnp.asarray(layer_idx, jnp.int32), page_size=cache.page_size, ppb=ppb,
+        stretches=stretches, interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "ppb", "stretches",
+                                             "interpret"))
+def _decode_scores(qi, wi, pool, tables, lengths, layer, *, page_size, ppb,
+                   stretches, interpret):
+    """`sparse_decode_scores` with the layer a value: a program of its own
+    under `jit`, so that the layers of a model trace and lower ONE kernel
+    between them (its straight code is long: 0.2 s a lowering, and a cell's
+    set-up lowers the decode chunk some ten times)."""
+    b, heads, di = qi.shape
+    _layers, _pool, rows, lanes = pool.shape
+    r = lanes // di
+    ps, mp = page_size, tables.shape[1]
+    n_blocks, n = -(-mp // ppb), ppb * rows
+    pages = jnp.clip(-(-lengths.astype(jnp.int32) // ps), 0, mp)
+    blocks = -(-pages // ppb)
+    first_slot = (jnp.cumsum(blocks) - blocks) % 2
+    # [B, r * J, r * Di]: head j of token s reads the s-th Di lanes of a row
+    q = jnp.einsum("st,bjd->bsjtd", jnp.eye(r, dtype=qi.dtype), qi).reshape(
+        b, r * heads, lanes).astype(pool.dtype)
+    w = jnp.tile(wi.astype(jnp.float32), (1, r))[:, :, None]
+    of_row = lambda i, *scalars: (i, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_scores_kernel, ppb=ppb, heads=heads,
+                          stretches=stretches),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, r * heads, lanes), of_row),
+                      pl.BlockSpec((1, r * heads, 1), of_row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n_blocks, r, n),
+                                   lambda i, *scalars: (i, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, ppb, rows, lanes), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, n_blocks, r, n), jnp.float32),
+        interpret=interpret,
+        name="sparse_decode_scores",
+    )(tables, pages, first_slot.astype(jnp.int32), layer.reshape(1), q, w,
+      pool)
+    scores = out.swapaxes(2, 3).reshape(b, n_blocks * n * r)[:, :mp * ps]
+    return jnp.where(jnp.arange(mp * ps)[None] < lengths[:, None], scores,
+                     -jnp.inf)
+
+
+def _kept(scores, k: int):
+    """`lax.top_k`'s own set of each row of `scores` [T, S] as a mask: all
+    above the k-th value (`kth_largest`: no sort), and of those equal to it
+    the first by position (scores tie at 0, where every head's relu is
+    shut); a -inf is no key and is never kept, so a row of fewer than k keys
+    keeps them all. The count along a row that ranks the tied is the dear
+    part (1.7 ms a layer for [512, 29696] on the v5e, PR 44), and only a row
+    with more keys tied at its k-th value than it has room for needs it; one
+    whose k-th value is -inf does not: what is tied there is no key at all."""
+    keys, kth = kth_largest(scores, k)
+    above, tied = keys > kth, keys == kth
+    room = k - above.sum(-1, keepdims=True)
+    surplus = (tied.sum(-1, keepdims=True) > room) & (kth > _NEG_INF_KEY)
+    selected = jax.lax.cond(
+        surplus.any(),
+        lambda: above | (tied & (jnp.cumsum(tied, -1) <= room)),
+        lambda: above | tied)
+    return selected & (keys > _NEG_INF_KEY)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "page_size"))
+def top_k_places(scores, tables, k: int, page_size: int):
+    """Where the k best keys of each row lie in the pool, found by value,
+    without a sort, a scatter or a scalar gather: (page_ids [B, K], offsets
+    [B, K], chosen [B, K]) for scores [B, S] in f32 (-inf: no key) and
+    tables [B, S / page_size]. The set is `lax.top_k`'s own (`_kept`: ties go
+    to the earlier position); slot j of a row is its j-th kept key by
+    position, and a row of fewer than k keys leaves the rest of its slots
+    not `chosen` (page 0, offset 0).
+
+    A count a page and a running sum over the pages give each page the range
+    of slots it fills; a slot's page is the one whose range holds it, found
+    by comparison, as a one-hot [B, K, mp]; one contraction with it on the
+    MXU yields the page's id and the range's start (in base-128 digits) and
+    the page's kept keys as a bit a token, eight to a byte. The slot's
+    offset is the place of the bit whose rank among the page's set bits is
+    the slot's rank inside the page: the byte by the bytes' counts, the bit
+    by the counts of the byte's low bits. Exact: every sum has one term, and
+    a term is an integer below 256, which bf16 holds. (XLA's gather of
+    49,152 scalars, a page id a slot, took 0.50 ms a layer a decode step on
+    the v5e, PR 28, and `lax.top_k` of [24, 29696] is a full sort, 0.58 ms;
+    this is under 0.2, PR 48.) Under `jit`, as `_decode_scores` is: a
+    model's layers lower it once between them."""
+    b, s_max = scores.shape
+    mp = tables.shape[1]
+    n_bytes = page_size // 8
+    assert s_max == mp * page_size and page_size % 8 == 0 and k < 1 << 14
+    bits = _kept(scores, k).reshape(b, mp, n_bytes, 8).astype(jnp.int32)
+    kept_bytes = (bits << jnp.arange(8)).sum(-1)                # [B, mp, page/8]
+    count = bits.sum((2, 3))                                       # [B, mp]
+    ends = jnp.cumsum(count, axis=1)
+    starts = ends - count
+    slots = jnp.arange(k)[None, :, None]
+    one_hot = ((starts[:, None] <= slots) & (slots < ends[:, None])
+               ).astype(jnp.bfloat16)                              # [B, K, mp]
+    digits = [(x >> shift) & 127 for x, shifts in
+              ((tables, (0, 7, 14, 21)), (starts, (0, 7)))
+              for shift in shifts]
+    of_page = jnp.concatenate([jnp.stack(digits, -1), kept_bytes], -1)
+    got = jnp.einsum("bkp,bpc->bkc", one_hot, of_page.astype(jnp.bfloat16),
                      preferred_element_type=jnp.float32).astype(jnp.int32)
-    return got[0] | (got[1] << 7) | (got[2] << 14) | (got[3] << 21)
+    page_ids = (got[..., 0] | (got[..., 1] << 7) | (got[..., 2] << 14)
+                | (got[..., 3] << 21))
+    rank = jnp.arange(k)[None] - (got[..., 4] | (got[..., 5] << 7))
+    kept_bytes = got[..., 6:]                                  # [B, K, page/8]
+    held = jax.lax.population_count(kept_bytes)
+    byte_at = (jnp.cumsum(held, -1) <= rank[..., None]).sum(-1)    # [B, K]
+    this, earlier = (jnp.arange(n_bytes) == byte_at[..., None],
+                     jnp.arange(n_bytes) < byte_at[..., None])
+    rank = rank - jnp.where(earlier, held, 0).sum(-1)
+    low_bits = (jnp.where(this, kept_bytes, 0).sum(-1)[..., None]
+                & ((2 << jnp.arange(8)) - 1))                      # [B, K, 8]
+    bit_at = (jax.lax.population_count(low_bits) <= rank[..., None]).sum(-1)
+    chosen = jnp.arange(k)[None] < ends[:, -1:]
+    return page_ids, jnp.where(chosen, byte_at * 8 + bit_at, 0), chosen
 
 
 def sparse_paged_decode(q, qi, wi, cache: PagedKVCache, layer_idx: int,
-                        lengths, topk: int, *, scale=None):
+                        lengths, topk: int, *, scale=None,
+                        interpret: Optional[bool] = None):
     """One decode token a row over its `topk` selected cached keys.
 
     q [B, H, D]; qi [B, J, Di], wi [B, J]: the indexer's query heads and
     their weights; lengths [B]: valid tokens, this step's included (its
     keys are already written). Returns [B, H, D]. Shape-stable and free of
     host callbacks: it is the body of the fused decode scan. A row of `topk`
-    tokens or fewer selects every valid key (the masked ones fill the rest of
-    the static top-k and get no weight), so it attends to everything.
+    tokens or fewer selects every valid key (the rest of the static top-k's
+    slots are not `chosen` and get no weight), so it attends to everything.
+
+    The selection is computed from the pool where it lies, once
+    (`sparse_decode_scores`), and its k best are found by value
+    (`top_k_places`): the set is `lax.top_k`'s, ties to the earlier
+    position, in the order of the positions.
     """
     b, h, d = q.shape
     kh = cache.k_pages.shape[-2]
     ps, tb = cache.page_size, cache.block_tables
-    s_max = tb.shape[1] * ps
+    k = min(topk, tb.shape[1] * ps)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     with jax.named_scope("sparse_index"):
-        ki = index_keys(cache, layer_idx, tb)                     # [B, S, Di]
-        scores = index_scores(qi[:, None], wi[:, None], ki)[:, 0]  # [B, S]
-        scores = jnp.where(jnp.arange(s_max)[None] < lengths[:, None],
-                           scores, -jnp.inf)
+        scores = sparse_decode_scores(qi, wi, cache, layer_idx, lengths,
+                                      interpret=interpret)         # [B, S]
     with jax.named_scope("sparse_select"):
-        top, sel = jax.lax.top_k(scores, min(topk, s_max))        # [B, K]
-        page_ids = _table_lookup(tb, sel // ps)
-        k_sel = cache.k_pages[layer_idx, page_ids, sel % ps]      # [B,K,Kh,D]
-        v_sel = cache.v_pages[layer_idx, page_ids, sel % ps]
+        page_ids, offsets, chosen = top_k_places(scores, tb, k, ps)  # [B, K]
+        k_sel = cache.k_pages[layer_idx, page_ids, offsets]       # [B,K,Kh,D]
+        v_sel = cache.v_pages[layer_idx, page_ids, offsets]
     with jax.named_scope("sparse_attend"):
         qg = q.reshape(b, kh, h // kh, d)
         s = jnp.einsum("bkgd,bskd->bkgs", qg, k_sel,
                        preferred_element_type=jnp.float32) * scale
-        s = jnp.where((top > -jnp.inf)[:, None, None, :], s, _NEG)
+        s = jnp.where(chosen[:, None, None, :], s, _NEG)
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_sel.dtype), v_sel)
     return out.reshape(b, h, d).astype(q.dtype)
@@ -980,23 +1226,8 @@ def sparse_paged_prefill(q, qi, wi, cache: PagedKVCache, layer_idx: int,
             0, n_blocks, index_block,
             jnp.full((bsz, t, s_pad), -jnp.inf, jnp.float32))
     with jax.named_scope("sparse_select"):
-        # lax.top_k's own set as a mask: all above the k-th value, and of
-        # those equal to it the first by position (scores tie at 0, where
-        # every head's relu is shut). The count along a row that ranks the
-        # tied is the dear part (1.7 ms a layer for [512, 29696] on the v5e,
-        # PR 44), and only a query with more keys tied at its k-th value than
-        # it has room for needs it; one whose k-th value is -inf (a row
-        # shorter than topk) does not: what is tied there is no key at all
-        k_sel = min(topk, s_pad)
-        keys, kth = kth_largest(scores.reshape(bsz * t, s_pad), k_sel)
-        above, tied = keys > kth, keys == kth
-        room = k_sel - above.sum(-1, keepdims=True)
-        surplus = (tied.sum(-1, keepdims=True) > room) & (kth > _NEG_INF_KEY)
-        selected = jax.lax.cond(
-            surplus.any(),
-            lambda: above | (tied & (jnp.cumsum(tied, -1) <= room)),
-            lambda: above | tied)
-        selected = (selected & (keys > _NEG_INF_KEY)).reshape(bsz, t, s_pad)
+        selected = _kept(scores.reshape(bsz * t, s_pad),
+                         min(topk, s_pad)).reshape(bsz, t, s_pad)
 
     def attend_row(q, selected, table):
         qg = q.reshape(t, kh, g, d)

@@ -504,7 +504,13 @@ class LLMServer:
         # the host at the syncs that are there (stats()["sparse"], ["moe"]):
         # a decode row's context is known without asking the device
         self._sparse_stats = {"context_keys": 0, "selected_keys": 0,
-                              "decode_rows": 0, "dense_rows": 0}
+                              "scored_keys": 0, "decode_rows": 0,
+                              "dense_rows": 0}
+        if self.model_cfg.index_topk and cfg.paged:
+            from ray_tpu.ops.paged_attention import index_block_keys
+            # keys a step of the scoring kernel's walk covers
+            self._index_block = index_block_keys(
+                cfg.page_size, self.cache.block_tables.shape[1])
         self._moe_stats = {"routed_rows": 0, "computed_rows": 0,
                            "decode_layer_calls": 0,
                            "decode_experts_touched": 0}
@@ -1081,11 +1087,16 @@ class LLMServer:
         """`steps` decode steps of one row whose first query sees
         `first_context` keys (its own included) and each next one more: how
         many keys the contexts held, how many the selection kept (all of
-        them up to `index_topk`), and how many of the steps kept all."""
+        them up to `index_topk`), how many of the steps kept all, and how
+        many keys the scoring kernel's walk covered (a context rounded up to
+        the walk's block: it follows the rows while scored / context keys
+        stays near 1, and would be the table's width over the mean context
+        if it did not)."""
         topk = self.model_cfg.index_topk
         if not topk or steps <= 0:
             return
         last = first_context + steps - 1
+        block = self._index_block
         dense = max(0, min(last, topk) - first_context + 1)
         sp = self._sparse_stats
         sp["decode_rows"] += steps
@@ -1093,6 +1104,8 @@ class LLMServer:
         sp["context_keys"] += steps * (first_context + last) // 2
         sp["selected_keys"] += (dense * (2 * first_context + dense - 1) // 2
                                 + (steps - dense) * topk)
+        sp["scored_keys"] += block * sum(
+            -(-context // block) for context in range(first_context, last + 1))
 
     def reconfigure(self, user_config: Optional[Dict[str, Any]]):
         """Serve `user_config` hook (replica.py calls this at deployment

@@ -25,10 +25,14 @@ import pytest
 
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEMLP, grouped_experts, grouped_product
-from ray_tpu.ops.paged_attention import (PagedKVCache, index_scores,
+from ray_tpu.ops import paged_attention
+from ray_tpu.ops.paged_attention import (PagedKVCache, index_block_keys,
+                                         index_keys, index_scores,
                                          kth_largest,
                                          sparse_attention_reference,
-                                         sparse_paged_prefill,
+                                         sparse_decode_scores,
+                                         sparse_paged_decode,
+                                         sparse_paged_prefill, top_k_places,
                                          write_layer_tokens)
 from ray_tpu.serve.llm import LLMConfig, LLMServer
 
@@ -291,6 +295,168 @@ def test_selected_prefill_is_the_reference(case):
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, atol=2e-5 if dtype == jnp.float32
                                else 2e-2)
+
+
+# the decode step's selection (ISSUE 48): a row of the first of SELECTED's
+# kinds, its last token as the decode query
+DECODED = {
+    "row_shorter_than_topk": (40, 4, 64, {}),
+    "row_of_exactly_topk": (64, 4, 64, {}),
+    "no_key_of_a_middle_block": (160, 4, 16, {"dead": (2,)}),
+    "tied_at_0_across_the_kth_place": (96, 4, 16, {"live": 16}),
+    "G8_bfloat16": (164, 8, 16, {"dtype": jnp.bfloat16}),
+    "G1_inside_a_page": (45, 1, 16, {"live": 3}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODED))
+def test_selected_decode_is_the_reference(case):
+    """`sparse_paged_decode` of a row's last token on a cache (the kernel
+    interpreted, the positions by value) against the uncached reference's
+    last row: the same output whether the row holds fewer keys than `topk`,
+    exactly as many, keys tied at 0 across the k-th place, or a block none
+    is kept of."""
+    n, g, topk, made = DECODED[case]
+    made = dict(made)
+    dtype = made.pop("dtype", jnp.float32)
+    cache, (q, k, v, qi, ki, wi) = _selecting_row(n, g, 32, dtype, **made)
+    got = jax.jit(sparse_paged_decode, static_argnums=(4, 6))(
+        q[:, -1], qi[:, -1], wi[:, -1], cache, 1, cache.lengths, topk)
+    want = sparse_attention_reference(q, k, v, qi, ki, wi, topk)[:, -1]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5 if dtype == jnp.float32
+                               else 2e-2)
+
+
+# a table of 40 pages of 8: rows of 0 (a free slot), 1, under a page, on a
+# page's edge, on a block's edge (4 pages a block, and the kernel's own 32),
+# the table's full width; each between two rows that hold keys and, once
+# more, after a free slot, so that a row's first copies are started by the
+# row before it and by itself
+RAGGED = {"free_slot": 0, "one_key": 1, "under_a_page": 5, "a_pages_edge": 8,
+          "a_block_of_4s_edge": 32, "past_a_block_of_4s_edge": 33,
+          "a_block_of_32s_edge": 256, "the_tables_width": 320}
+
+
+@pytest.mark.parametrize("index_dim,dtype", [(8, jnp.float32),
+                                             (32, jnp.float32),
+                                             (32, jnp.bfloat16)])
+@pytest.mark.parametrize("pages_a_block", [4, 32])
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_decode_scores_are_the_copied_keys_scores(monkeypatch, case,
+                                                  pages_a_block, index_dim,
+                                                  dtype):
+    """The kernel `sparse_decode_scores` (interpreted) on the pool where it
+    lies against `index_scores` over `index_keys`, the copy it does without,
+    under the length mask: position for position, -inf at and past a row's
+    length, whatever the pool held there (NaN here). Eight tokens of 8
+    values to a row of the pool, and two rows of four of 32."""
+    monkeypatch.setattr(paged_attention, "_INDEX_PAGES_PER_BLOCK",
+                        pages_a_block)
+    monkeypatch.setattr(paged_attention, "_INDEX_COPY_STRETCHES", (8, 2, 1))
+    mp, heads, layers = 40, 2, 2
+    lengths = np.array([77, RAGGED[case], 300, 0, RAGGED[case], 9], np.int32)
+    rows = len(lengths)
+    r = paged_attention.index_pack(PAGE, index_dim)
+    ks = jax.random.split(jax.random.PRNGKey(RAGGED[case]), 3)
+    n_pages = 1 + rows * mp
+    pool = jax.random.normal(ks[0], (layers, n_pages, PAGE // r, r * index_dim),
+                             dtype)
+    table = np.zeros((rows, mp), np.int32)
+    free = 1 + np.random.default_rng(3).permutation(n_pages - 1)
+    for b, n in enumerate(-(-lengths // PAGE)):
+        table[b, :n] = free[b * mp:b * mp + n]
+    # a page no row holds keys on must not be read: NaN would show
+    held = np.zeros(n_pages, bool)
+    held[table[table > 0]] = True
+    pool = jnp.where(jnp.asarray(held)[None, :, None, None], pool, jnp.nan)
+    cache = PagedKVCache(
+        k_pages=jnp.zeros((layers, 1, PAGE, 1, 8), dtype),
+        v_pages=jnp.zeros((layers, 1, PAGE, 1, 8), dtype), idx_pages=pool,
+        block_tables=jnp.asarray(table), lengths=jnp.asarray(lengths))
+    qi = jax.random.normal(ks[1], (rows, heads, index_dim), dtype)
+    wi = jax.random.normal(ks[2], (rows, heads), dtype)
+    got = np.asarray(jax.jit(sparse_decode_scores, static_argnums=3)(
+        qi, wi, cache, 1, cache.lengths))
+    assert got.shape == (rows, mp * PAGE) and got.dtype == np.float32
+    valid = np.arange(mp * PAGE)[None] < lengths[:, None]
+    assert (got[~valid] == -np.inf).all() and np.isfinite(got[valid]).all()
+    ki = index_keys(cache, 1, cache.block_tables)
+    want = np.asarray(index_scores(qi[:, None], wi[:, None], ki)[:, 0])
+    np.testing.assert_allclose(got[valid], want[valid], rtol=1e-5, atol=1e-5)
+    assert index_block_keys(PAGE, mp) == min(pages_a_block, mp) * PAGE
+
+
+def _scores_to_order(case):
+    """[rows, 96] scores, `k` = 16, the rows' lengths in them as -inf: what
+    `lax.top_k` makes of each row is the test's oracle."""
+    rng = np.random.default_rng(len(case))
+    s_max, k = 96, 16
+    scores = rng.permutation(4 * s_max).reshape(4, s_max).astype(np.float32)
+    lengths = np.array([96, 96, 50, 96])
+    if case == "distinct":
+        pass
+    elif case == "ties_at_the_kth_value":
+        # 10 above, then 20 tied at the k-th value all over the row: the
+        # six earliest of them are kept
+        scores[:] = -1.0
+        for row in scores:
+            row[rng.choice(s_max, 30, replace=False)] = 5.0
+            row[rng.choice(np.flatnonzero(row == 5.0), 10, replace=False)] = 9.0
+    elif case == "all_tied":
+        scores[:] = 0.0
+    elif case == "fewer_than_k_keys":
+        lengths = np.array([0, 1, 7, 15])
+    elif case == "exactly_k_keys":
+        lengths = np.array([16, 16, 16, 16])
+    elif case == "minus_inf_inside_the_row":
+        # 40 keys, 30 of them -inf: 10 are kept, no -inf among them
+        lengths = np.array([40, 40, 40, 96])
+        for row in scores[:3]:
+            row[rng.choice(40, 30, replace=False)] = -np.inf
+        scores[3, rng.choice(96, 60, replace=False)] = -np.inf
+    elif case == "ties_at_the_kth_value_and_minus_inf":
+        scores[:] = 2.0
+        scores[:, ::3] = -np.inf
+        scores[:, 1::6] = 7.0
+    else:
+        raise KeyError(case)
+    scores = np.where(np.arange(s_max)[None] < lengths[:, None], scores,
+                      -np.inf)
+    return scores, k
+
+
+@pytest.mark.parametrize("page", [8, 32])     # a byte of kept bits, and four
+@pytest.mark.parametrize("case", [
+    "distinct", "ties_at_the_kth_value", "all_tied", "fewer_than_k_keys",
+    "exactly_k_keys", "minus_inf_inside_the_row",
+    "ties_at_the_kth_value_and_minus_inf"])
+def test_places_by_value_are_top_ks_set(case, page):
+    """`top_k_places` against `lax.top_k` on scores made to order: the same
+    SET of positions a row (of the keys tied at the k-th value the earliest;
+    `lax.top_k`'s order of equal values is by position too), each at its
+    page of a scrambled table and its offset; a -inf is never a key; a row
+    of fewer than k keys keeps them all and flags the rest of its slots,
+    which name page 0 and are given no weight."""
+    scores, k = _scores_to_order(case)
+    rows, s_max = scores.shape
+    mp = s_max // page
+    table = 1 + np.random.default_rng(1).permutation(rows * mp).reshape(
+        rows, mp).astype(np.int32)
+    page_ids, offsets, chosen = (np.asarray(x) for x in top_k_places(
+        jnp.asarray(scores), jnp.asarray(table), k, page))
+    top, sel = (np.asarray(x) for x in jax.lax.top_k(jnp.asarray(scores), k))
+    for b in range(rows):
+        want = sorted(sel[b][top[b] > -np.inf])
+        assert chosen[b].sum() == len(want)
+        assert chosen[b, :len(want)].all()       # the flagged slots are last
+        got = [int(np.flatnonzero(table[b] == p)[0]) * page + o
+               for p, o in zip(page_ids[b][chosen[b]], offsets[b][chosen[b]])]
+        assert got == want                       # by position, none twice
+        assert (page_ids[b][~chosen[b]] == 0).all()
+        assert (offsets[b][~chosen[b]] == 0).all()
 
 
 def test_the_made_to_order_scores_do_what_the_cases_say():
@@ -611,10 +777,14 @@ def test_pd_hand_off_is_bit_exact_in_three_pools(model, loop):
 # counters
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("first,steps", [(3, 5), (14, 6), (16, 1), (17, 9),
-                                         (40, 8), (10, 0)])
-def test_sparse_counters_arithmetic(loop, first, steps):
-    srv = LLMServer(_llm_cfg(num_pages=20))
+# (a row's first context, its steps, the engine's max_seq_len: the scoring
+# kernel's walk covers a context rounded up to its block, 32 pages of 8 or
+# the table's width where that is less: the last two cross a block's edge)
+@pytest.mark.parametrize("first,steps,max_seq_len", [
+    (3, 5, 192), (14, 6, 192), (16, 1, 192), (17, 9, 192), (40, 8, 192),
+    (10, 0, 192), (250, 8, 512), (257, 4, 512)])
+def test_sparse_counters_arithmetic(loop, first, steps, max_seq_len):
+    srv = LLMServer(_llm_cfg(num_pages=20, max_seq_len=max_seq_len))
     try:
         topk = srv.model_cfg.index_topk
         srv._count_sparse(first, steps)
@@ -622,6 +792,10 @@ def test_sparse_counters_arithmetic(loop, first, steps):
         got = srv.stats()["sparse"]
         assert got["decode_rows"] == steps
         assert got["context_keys"] == sum(contexts)
+        block = min(32 * PS, max_seq_len)
+        assert index_block_keys(PS, max_seq_len // PS) == block
+        assert got["scored_keys"] == sum(-(-c // block) * block
+                                         for c in contexts)
         assert got["selected_keys"] == sum(min(c, topk) for c in contexts)
         assert got["dense_rows"] == sum(c <= topk for c in contexts)
         assert got["topk"] == topk

@@ -20,13 +20,17 @@ ENGINE = dict(paged=True, prefix_cache=True, max_batch_slots=4, page_size=8,
 # produced that program), which meant to change it: its chunk writes the
 # indexer's keys page by page, its radix select settles 3 bits a pass and
 # ranks the tied only where a query has more of them than room, all on every
-# backend
+# backend; Keye's decode on PR 48's own tree, which meant to change it: the
+# step scores the indexer's pool where it lies (the kernel
+# `sparse_decode_scores`, interpreted off the TPU) and finds its k best by
+# value (`top_k_places`) where it gathered the keys' pages, unpacked them and
+# sorted the row; Keye's prefill and the four others are what they were
 WAS = {
     ("tiny", "decode"): "6fa7d332a70e69c3e8fd168f46119afc148f3978f10561428672587614fd182c",
     ("tiny", "prefill"): "29c20d0a0f89cbbbf9acfc95013f9e783809ebdd16164101ab0c53c374e8be49",
     ("moe_tiny", "decode"): "becb1ff876b3b0522e250e5d0241215e9c2b9213e50e6e3521f028be2390d3c5",
     ("moe_tiny", "prefill"): "dcea1e4272b0700de5273db8c215f6016c54a4d1993498dc4e3da362e2c8ea8f",
-    ("keye_tiny", "decode"): "03c373ef53111b7ef7a8f2da0f84e73692f8cc5a1f7669a80d9eb65f6c9878c0",
+    ("keye_tiny", "decode"): "595259172b0d00effd952e9b880a77f792c8926d6d23aab89929fa44a0cd243b",
     ("keye_tiny", "prefill"): "ef238414a9ec9571c8c91d6b49df1c4c843c1a3c5bec4ea3d3f3c75d3b992633",
 }
 

@@ -485,6 +485,37 @@ def test_selected_prefill_compiled_for_the_v5e_at_the_cells_shapes(v5e,
     assert not {m for m in moved if m[1] not in written}, sorted(moved)
 
 
+def test_selected_decode_compiled_for_the_v5e_at_the_cells_shape(v5e):
+    """`keye30b-longdoc-batch`'s decode step (24 rows of 464 pages of 64, a
+    16-head indexer of 64 that selects 2048, token-major pools of 12288):
+    the v5e's compiler takes the kernel `sparse_decode_scores` with the
+    indexer's pool left in HBM and its own copies of a block's pages (the
+    interpreter checks neither a copy's tiling nor the VMEM a block takes);
+    it is in the program once a layer under the name a reader can look for;
+    the step holds no sort, no copy of the indexer's keys (gathered
+    `[rows x width, 32, 128]` or unpacked `[rows, width x 64, 64]`), and
+    nothing the size of a pool but the in-place writes."""
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    layers, pages, rows, width = 2, 12288, 24, 464
+    cfg = LlamaConfig(vocab_size=256, d_model=256, n_layers=layers,
+                      n_heads=32, n_kv_heads=4, head_dim=128, ffn_dim=512,
+                      max_seq_len=width * 64, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16, qk_norm=True, index_heads=16,
+                      index_dim=64, index_topk=2048)
+    hlo = _step_compiled_for(v5e, cfg, pages, rows, width)
+    calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert calls == ["sparse_decode_scores"] * layers, calls
+    assert not re.search(r" sort\(", hlo)
+    assert not re.search(rf"bf16\[{rows * width},32,128\]", hlo)
+    assert not re.search(rf"bf16\[{rows},{width * 64},64\]", hlo)
+    pool = rf"bf16\[({layers},)?{pages},(64,4,128|32,128)\]"
+    assert not _pool_sized_results(hlo, pool)
+
+
 @pytest.mark.parametrize("cell,rows,width,heads,head_dim", [
     ("mixtral8x7b-batch", 32, 36, 32, 128),
     ("solar250b-agentloop-batch", 16, 640, 64, 128),
